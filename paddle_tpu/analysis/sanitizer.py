@@ -36,10 +36,9 @@ class CompileBudgetExceeded(AssertionError):
     AssertionError subclass so pytest reports it as a plain failure."""
 
 
-# the compile log line is "Compiling <name> ..." (pxla) — older JAX
-# said "Compiling <name> for args ..." and newer "Compiling <name> with
-# global shapes and types ..."; both start the same way
-_COMPILE_RE = re.compile(r"^(?:Compiling|Lowering)\s+([^\s(]+)")
+# the compile log line of the installed JAX (pxla):
+# "Compiling jit(<name>) with global shapes and types ..."
+_COMPILE_RE = re.compile(r"^Compiling jit\((.+?)\) with ")
 
 
 class _CaptureHandler(logging.Handler):
@@ -48,14 +47,9 @@ class _CaptureHandler(logging.Handler):
         self._watch = watch
 
     def emit(self, record: logging.LogRecord) -> None:
-        try:
-            msg = record.getMessage()
-        except Exception:
-            return
-        m = _COMPILE_RE.match(msg)
-        if not m or not msg.startswith("Compiling"):
-            return
-        self._watch._record(m.group(1))
+        m = _COMPILE_RE.match(record.getMessage())
+        if m:
+            self._watch._record(m.group(1))
 
 
 class CompileWatch:

@@ -42,21 +42,31 @@ def compile_aot(jitted, *args):
 
 def serialize_compiled(compiled) -> bytes:
     """``Compiled`` -> artifact payload bytes. Raises on backends that
-    cannot serialize executables (callers journal and fall back)."""
+    cannot serialize executables (callers journal and fall back). The
+    ids of the devices it was compiled for ride with the payload: a
+    loaded executable must be told them, or it wants one shard per
+    local device."""
     from jax.experimental import serialize_executable as se
     payload, in_tree, out_tree = se.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree),
+    device_ids = [d.id for d in
+                  compiled._executable._unloaded_executable.device_list]
+    return pickle.dumps((payload, in_tree, out_tree, device_ids),
                         protocol=_PICKLE_PROTO)
 
 
 def load_compiled(blob: bytes) -> Callable:
-    """Artifact payload bytes -> a loaded executable callable. Raises
-    ValueError on any malformed payload (the store's crc catches torn
-    bytes; this catches a valid frame around a wrong payload)."""
+    """Artifact payload bytes -> a loaded executable callable, bound to
+    the devices it was compiled for. Raises ValueError on any malformed
+    payload (the store's crc catches torn bytes; this catches a valid
+    frame around a wrong payload)."""
+    import jax
     from jax.experimental import serialize_executable as se
     try:
-        payload, in_tree, out_tree = pickle.loads(blob)
+        payload, in_tree, out_tree, device_ids = pickle.loads(blob)
     except Exception as e:  # noqa: BLE001 — any unpickle defect
         raise ValueError(f"artifact payload does not unpickle: {e}") \
             from e
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in device_ids])

@@ -13,6 +13,12 @@ all wire it the same way:
   (fleet/autopilot.py SubprocessProvisioner does, for warm fleets);
 - ``"0"`` (or ``"off"``) disables — the env-var and the CLI
   ``--compile_cache`` flag share one grammar via ``resolve_dir``;
+- where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself keeps the
+  cache there and this module sets NO directory in code — the
+  operator (or the machine image) places the cache from outside;
+  otherwise the default is the fixed ``<checkout>/.jax_cache`` (the
+  path is part of the cache key, so a directory that moves never
+  hits);
 - ``disabled()`` is the scoped opt-out (tests/test_oom.py pins
   OOM-vs-freshly-compiled-executable behavior under it).
 
@@ -25,21 +31,26 @@ serialization-incapable backend).
 from __future__ import annotations
 
 import os
-import tempfile
 from contextlib import contextmanager
 from typing import Optional
 
-__all__ = ["ENV_VAR", "default_dir", "resolve_dir", "enable",
+__all__ = ["ENV_VAR", "JAX_ENV_VAR", "default_dir", "resolve_dir", "enable",
            "enable_from_env", "ensure_default", "disabled"]
 
 ENV_VAR = "PADDLE_TPU_COMPILE_CACHE"
+
+#: JAX's own variable: set, it places the cache and nothing here does
+JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
 #: values of the env var / --compile_cache flag that mean "off"
 _OFF = ("0", "off", "none", "")
 
 
 def default_dir() -> str:
-    return os.path.join(tempfile.gettempdir(), "paddle_tpu_xla_cache")
+    """``<checkout>/.jax_cache``, from this package's own path."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    return os.path.join(os.path.dirname(os.path.dirname(here)),
+                        ".jax_cache")
 
 
 def resolve_dir(value: Optional[str] = None,
@@ -58,20 +69,24 @@ def enable(value: Optional[str] = None,
            min_compile_secs: float = 0.05) -> Optional[str]:
     """Point this process's XLA compilation cache at ``resolve_dir``'s
     answer (created if missing); ``None`` answer = leave disabled.
-    Returns the directory in effect."""
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is the
+    answer and JAX reads it itself. Returns the directory in effect."""
     import jax
     d = resolve_dir(value, fallback=default_dir())
     if d is None:
         return None
-    os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
+    if os.environ.get(JAX_ENV_VAR):
+        d = os.environ[JAX_ENV_VAR]
+    else:
+        os.makedirs(d, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", d)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_secs))
     return d
 
 
 def enable_from_env(min_compile_secs: float = 0.05) -> Optional[str]:
-    """The conftest seam: env var (or the default tempdir cache)
+    """The conftest seam: env var (or the default checkout cache)
     unless the env var says off."""
     return enable(None, min_compile_secs=min_compile_secs)
 

@@ -47,7 +47,9 @@ def init(use_tpu: Optional[bool] = None, use_gpu: Optional[bool] = None,
     """Initialize the framework. Mirrors paddle.v2.init(use_gpu=..., trainer_count=...).
 
     `use_gpu` is accepted for source compatibility with v2 scripts and treated
-    as a request for the accelerator backend (i.e. the TPU here).
+    as a request for the accelerator backend (i.e. the TPU here). An explicit
+    request that the process cannot meet raises: no run believes it is on the
+    chip while it computes on the CPU.
 
     `debug_nans=True` is the FPE-trap discipline of the reference trainer
     (TrainerMain.cpp:49 feenableexcept(FE_INVALID|FE_DIVBYZERO|FE_OVERFLOW)):
@@ -62,8 +64,14 @@ def init(use_tpu: Optional[bool] = None, use_gpu: Optional[bool] = None,
     _g.debug_nans = debug_nans
     if use_tpu is None:
         use_tpu = bool(use_gpu) if use_gpu is not None else None
+    on_tpu = jax.default_backend() == "tpu"
     if use_tpu is None:
-        use_tpu = jax.default_backend() == "tpu"
+        use_tpu = on_tpu
+    elif use_tpu and not on_tpu:
+        raise RuntimeError(
+            "init(use_tpu=True) but JAX's default backend is "
+            f"{jax.default_backend()!r}: no TPU is attached to this "
+            "process (leave use_tpu unset to take what is there)")
     _g.use_tpu = use_tpu
     _g.trainer_count = trainer_count if trainer_count > 0 else jax.local_device_count()
     _g.seed = seed

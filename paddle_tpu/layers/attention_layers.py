@@ -31,6 +31,35 @@ def _merge_heads(x: jnp.ndarray) -> jnp.ndarray:
     return x.reshape(b, t, h * dh)
 
 
+def flash_on_mesh(q, k, v, kv_lens, mesh, *, causal: bool,
+                  interpret: bool = False):
+    """The flash kernel inside a sharded train step. The compiler
+    cannot partition a Mosaic kernel on its own ("Mosaic kernels cannot
+    be automatically partitioned. Please wrap the call in a
+    shard_map"), and attention is independent per batch row and per
+    head: each device runs the kernel on its own rows (the `dp` split
+    of the feed) and, under tensor parallelism, its own heads."""
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.ops import pallas_attention as flash
+    from paddle_tpu.parallel._compat import shard_map
+    from paddle_tpu.parallel.mesh import DP_AXIS, MP_AXIS
+
+    def axis(name, dim):
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and dim % n == 0 else None
+
+    rows = axis(DP_AXIS, q.shape[0])
+    heads = axis(MP_AXIS, q.shape[2])
+    qkv = P(rows, None, heads, None)
+
+    def local(q, k, v, lens):
+        return flash.flash_attention(q, k, v, kv_lens=lens, causal=causal,
+                                     interpret=interpret)
+
+    return shard_map(local, mesh=mesh, in_specs=(qkv, qkv, qkv, P(rows)),
+                     out_specs=qkv)(q, k, v, kv_lens)
+
+
 @register_layer("dot_product_attention")
 class DotProductAttentionLayer:
     @staticmethod
@@ -80,8 +109,12 @@ class DotProductAttentionLayer:
             if (global_config().use_flash_attention and
                     jax.default_backend() == "tpu" and
                     flash.flash_supported(q, k)):
-                out = flash.flash_attention(q, k, v, kv_lens=ks.lengths,
-                                            causal=causal)
+                if mesh is None:
+                    out = flash.flash_attention(
+                        q, k, v, kv_lens=ks.lengths, causal=causal)
+                else:
+                    out = flash_on_mesh(q, k, v, ks.lengths, mesh,
+                                        causal=causal)
             else:
                 b, tq = q.shape[0], q.shape[1]
                 tk = k.shape[1]

@@ -588,8 +588,9 @@ class PagedDecoder:
             self.use_kernel = False
         else:
             self.use_kernel = on_tpu and \
-                paged_ops.paged_kernel_supported(probe_q, probe_k,
-                                                 probe_s)
+                paged_ops.paged_kernel_supported(
+                    probe_q, probe_k, probe_s,
+                    pages_per_slot=self.max_pages_per_slot)
         self.kernel_interpret = self.use_kernel and not on_tpu
         # donating the pools lets XLA update pages in place (the pools
         # ARE the device memory budget); the CPU backend has no donation

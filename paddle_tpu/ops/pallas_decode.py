@@ -255,14 +255,31 @@ def paged_attention(q, k_pages, v_pages, page_table, kv_lens, *,
 
 # ------------------------------------------- allocated-pages kernel
 def _paged_window_kernel(tables_ref, used_ref, lens_ref, q_ref, k_ref,
-                         v_ref, out_ref, m_ref, l_ref, acc_ref, *,
-                         scale, rep, page_size, window):
+                         v_ref, *rest, scale, rep, page_size, window,
+                         quant):
     """Grid (S, P), page axis fastest. Block p of slot s is the page
     the CLAMPED index map selected — for p >= used[s] that is the same
     physical page as step p-1, so Pallas skips the DMA (the
     allocated-pages traffic contract) and ``pl.when`` skips the math.
     Online softmax carries (m, l, acc) per (kv group, window row)
-    across the page axis in VMEM scratch."""
+    across the page axis in VMEM scratch.
+
+    q and the output arrive GROUP-MAJOR, [g, W*rep, dh] per slot (the
+    wrapper transposes in XLA), so the body never reshapes across the
+    tiled dims — Mosaic refuses those shape casts at rep > 1. The
+    per-token lengths are the third scalar-prefetch operand: an
+    [S, W] VMEM block of them does not tile.
+
+    ``quant`` is the dequant-FUSED variant: two more inputs carry the
+    per-row scales (their blocks ride the same clamped map, so a
+    skipped page DMA skips its scale DMA too) and the rescale
+    ``int8 * scale`` runs in VMEM right after the K/V block lands, so
+    the HBM read is 1 byte/element + 4 bytes/row instead of the float
+    pool's 2-4 bytes/element."""
+    if quant:
+        ks_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        out_ref, m_ref, l_ref, acc_ref = rest
     p = pl.program_id(1)
     s = pl.program_id(0)
     used = used_ref[s]
@@ -279,18 +296,24 @@ def _paged_window_kernel(tables_ref, used_ref, lens_ref, q_ref, k_ref,
     def _accumulate():
         k = k_ref[0].astype(jnp.float32)               # [ps, g, dh]
         v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)               # [W, h, dh]
-        lens = lens_ref[0]                             # [W] int32
+        if quant:
+            # fused dequant: [ps, g, dh] int8 * [ps, g, 1] f32 scales
+            k = k * ks_ref[0].astype(jnp.float32)[..., None]
+            v = v * vs_ref[0].astype(jnp.float32)[..., None]
         # per-token causal/ragged mask against ABSOLUTE positions:
-        # page p covers [p*ps, (p+1)*ps); token w sees < lens[w]
-        lens_rep = jnp.repeat(lens, rep)               # [W*rep]
+        # page p covers [p*ps, (p+1)*ps); row r is window token
+        # r // rep and sees < lens[s, r // rep]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (wr, page_size), 0)
         cols = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (wr, page_size), 1)
-        live = cols < lens_rep[:, None]
+        lim = jnp.full((wr, page_size), lens_ref[s, 0], jnp.int32)
+        for w in range(1, window):
+            lim = jnp.where(rows >= w * rep, lens_ref[s, w], lim)
+        live = cols < lim
         for gi in range(g):
             kg = k[:, gi, :]                           # [ps, dh]
             vg = v[:, gi, :]
-            qg = q[:, gi * rep:(gi + 1) * rep, :].reshape(wr, -1)
+            qg = q_ref[0, gi].astype(jnp.float32)      # [wr, dh]
             sc = jax.lax.dot_general(
                 qg, kg, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * (scale * LOG2E)
@@ -312,90 +335,49 @@ def _paged_window_kernel(tables_ref, used_ref, lens_ref, q_ref, k_ref,
         # fully-masked rows (lens 0 never happens live; engine clamps
         # masked tokens to kv_len >= 1) still divide by a finite l
         l = jnp.maximum(l_ref[...], 1e-30)             # [g, wr, 1]
-        o = acc_ref[...] / l                           # [g, wr, dh]
-        dh = o.shape[-1]
-        w = wr // rep
-        o = o.reshape(g, w, rep, dh).transpose(1, 0, 2, 3)
-        out_ref[0] = o.reshape(w, g * rep, dh).astype(out_ref.dtype)
+        out_ref[0] = (acc_ref[...] / l).astype(out_ref.dtype)
 
 
-def _paged_window_dequant_kernel(tables_ref, used_ref, lens_ref, q_ref,
-                                 k_ref, v_ref, ks_ref, vs_ref, out_ref,
-                                 m_ref, l_ref, acc_ref, *, scale, rep,
-                                 page_size, window):
-    """The dequant-FUSED twin of :func:`_paged_window_kernel`: same
-    grid, same clamped index maps (scale blocks ride the same
-    ``_table_map``, so a skipped page DMA skips its scale DMA too),
-    same online-softmax recurrence — the only delta is the per-row
-    rescale ``int8 * scale`` applied in VMEM right after the K/V block
-    lands, so the HBM read is 1 byte/element + 4 bytes/row instead of
-    the float pool's 2-4 bytes/element."""
-    p = pl.program_id(1)
-    s = pl.program_id(0)
-    used = used_ref[s]
-    g = m_ref.shape[0]
-    wr = m_ref.shape[1]                                # window * rep
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-    @pl.when(p < used)
-    def _accumulate():
-        # fused dequant: [ps, g, dh] int8 * [ps, g, 1] f32 scales
-        k = k_ref[0].astype(jnp.float32) * \
-            ks_ref[0].astype(jnp.float32)[..., None]
-        v = v_ref[0].astype(jnp.float32) * \
-            vs_ref[0].astype(jnp.float32)[..., None]
-        q = q_ref[0].astype(jnp.float32)               # [W, h, dh]
-        lens = lens_ref[0]                             # [W] int32
-        lens_rep = jnp.repeat(lens, rep)               # [W*rep]
-        cols = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (wr, page_size), 1)
-        live = cols < lens_rep[:, None]
-        for gi in range(g):
-            kg = k[:, gi, :]                           # [ps, dh]
-            vg = v[:, gi, :]
-            qg = q[:, gi * rep:(gi + 1) * rep, :].reshape(wr, -1)
-            sc = jax.lax.dot_general(
-                qg, kg, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * (scale * LOG2E)
-            sc = jnp.where(live, sc, NEG_INF)          # [wr, ps]
-            m_prev = m_ref[gi]                         # [wr, 1]
-            m_cur = jnp.maximum(m_prev,
-                                jnp.max(sc, axis=1, keepdims=True))
-            alpha = jnp.exp2(m_prev - m_cur)
-            pm = jnp.exp2(sc - m_cur)                  # [wr, ps]
-            l_ref[gi] = l_ref[gi] * alpha + \
-                jnp.sum(pm, axis=1, keepdims=True)
-            acc_ref[gi] = acc_ref[gi] * alpha + jax.lax.dot_general(
-                pm, vg, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[gi] = m_cur
-
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)             # [g, wr, 1]
-        o = acc_ref[...] / l                           # [g, wr, dh]
-        dh = o.shape[-1]
-        w = wr // rep
-        o = o.reshape(g, w, rep, dh).transpose(1, 0, 2, 3)
-        out_ref[0] = o.reshape(w, g * rep, dh).astype(out_ref.dtype)
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def paged_kernel_supported(q, k_pages, k_scales=None) -> bool:
-    """Gate for the allocated-pages kernel: tile-friendly head dim and
-    a per-page K+V block inside the VMEM budget. With ``k_scales``
-    (the int8 two-tier layout) the budget counts the int8 block plus
-    its float32 per-row scales."""
+# What the chip's compiler takes (v5e:2x2 described in the sandbox,
+# tests/test_chip_compile.py). One grid step holds every K/V block
+# twice (Pallas double-buffers) plus a float32 working copy, each
+# padded to whole (sublane, 128-lane) tiles over its (g, dh) dims,
+# against a 16 MiB scoped VMEM limit: past it the compiler answers
+# "Ran out of memory in memory space vmem" (f32 g32 dh128 pages of 256
+# rows; int8 pages of 512 rows, for their dequantized copies; bf16 g2
+# pages of 4096 rows). The budget is kept under what was seen to
+# compile, not at the limit: the compiler's own temporaries are not
+# modelled. The scalar-prefetched page tables and lengths live in
+# 1 MiB of SMEM ("Ran out of memory in memory space smem" at
+# [256, 1024] tables).
+_PAGED_VMEM_BYTES = 12 * 1024 * 1024
+_PAGED_SMEM_BYTES = 960 * 1024
+
+
+def paged_kernel_supported(q, k_pages, k_scales=None,
+                           pages_per_slot: int = 1) -> bool:
+    """Gate for the allocated-pages kernel — "supported" means the
+    kernel LOWERS on the chip for these shapes: a sublane-multiple
+    head dim, one page step's K+V (+ per-row scales of the int8
+    two-tier layout) inside the VMEM budget, and the [S, P] page
+    tables plus [S, W] lengths inside SMEM."""
+    S = q.shape[0]
     ps, g, dh = k_pages.shape[1:]
     esize = jnp.dtype(k_pages.dtype).itemsize
-    block = 2 * ps * g * dh * esize
+    lanes = _round_up(dh, 128)
+    stored = _round_up(g, 32 // esize) * lanes * esize
+    working = _round_up(g, 8) * lanes * 4
+    vmem = 2 * ps * (2 * stored + working)
     if k_scales is not None:
-        block += 2 * ps * g * jnp.dtype(k_scales.dtype).itemsize
-    return dh % 8 == 0 and block <= _VMEM_BYTES
+        vmem += 2 * 2 * ps * _round_up(g, 128) * \
+            jnp.dtype(k_scales.dtype).itemsize
+    smem = 4 * S * (_round_up(pages_per_slot, 128) + 128 + 1)
+    return (dh % 8 == 0 and vmem <= _PAGED_VMEM_BYTES
+            and smem <= _PAGED_SMEM_BYTES)
 
 
 def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
@@ -425,8 +407,8 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
     to the INT8 two-tier layout (:func:`quantize_kv` rows): the gather
     path dequantizes the gathered view then runs the same exact einsum
     (the dequant analogue of the existing kernel-gate fallback), and
-    the kernel path runs :func:`_paged_window_dequant_kernel`, which
-    fuses the per-row rescale into the online-softmax page walk —
+    the kernel path runs :func:`_paged_window_kernel` with ``quant``,
+    which fuses the per-row rescale into the online-softmax page walk —
     int8 K/V never round-trips through HBM at float width."""
     S, W, h, dh = q.shape
     n_pages, ps, g, _ = k_pages.shape
@@ -447,43 +429,46 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
     # page still feeds the pipeline for idle slots)
     used = jnp.clip(-(-jnp.max(lens, axis=1) // ps), 1, P)
 
-    def _table_map(si, pi, tables, used_):
+    def _slot_map(si, pi, tables, used_, lens_):
+        return (si, 0, 0, 0)
+
+    def _table_map(si, pi, tables, used_, lens_):
         return (tables[si, jnp.minimum(pi, used_[si] - 1)], 0, 0, 0)
 
-    def _scale_map(si, pi, tables, used_):
+    def _scale_map(si, pi, tables, used_, lens_):
         return (tables[si, jnp.minimum(pi, used_[si] - 1)], 0, 0)
 
-    kfn = _paged_window_dequant_kernel if quant else \
-        _paged_window_kernel
     kernel = functools.partial(
-        kfn, scale=scale, rep=rep, page_size=ps, window=W)
+        _paged_window_kernel, scale=scale, rep=rep, page_size=ps,
+        window=W, quant=quant)
     in_specs = [
-        pl.BlockSpec((1, W), lambda si, pi, tables, used_: (si, 0)),
-        pl.BlockSpec((1, W, h, dh),
-                     lambda si, pi, tables, used_: (si, 0, 0, 0)),
+        pl.BlockSpec((1, g, W * rep, dh), _slot_map),
         pl.BlockSpec((1, ps, g, dh), _table_map),
         pl.BlockSpec((1, ps, g, dh), _table_map),
     ]
+    # group-major rows: [S, W, (g, rep), dh] -> [S, g, (W, rep), dh]
+    qg = q.reshape(S, W, g, rep, dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(S, g, W * rep, dh)
     operands = [jnp.asarray(page_tables, jnp.int32),
-                used.astype(jnp.int32), lens, q, k_pages, v_pages]
+                used.astype(jnp.int32), lens, qg, k_pages, v_pages]
     if quant:
         in_specs += [pl.BlockSpec((1, ps, g), _scale_map),
                      pl.BlockSpec((1, ps, g), _scale_map)]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, W, h, dh),
-            lambda si, pi, tables, used_: (si, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, g, W * rep, dh), _slot_map),
         scratch_shapes=[
             pltpu.VMEM((g, W * rep, 1), jnp.float32),
             pltpu.VMEM((g, W * rep, 1), jnp.float32),
             pltpu.VMEM((g, W * rep, dh), jnp.float32),
         ])
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, W, h, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, g, W * rep, dh), q.dtype),
         interpret=interpret,
     )(*operands)
+    return out.reshape(S, g, W, rep, dh).transpose(0, 2, 1, 3, 4) \
+        .reshape(S, W, h, dh)

@@ -496,10 +496,7 @@ def _gru_call(xt, lens, w, b_arr, b, T, three_h, h, interpret):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # v5e-class chips expose ~128 MB of VMEM (measured: a 120 MB scratch

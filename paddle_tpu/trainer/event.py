@@ -59,9 +59,8 @@ class LazyEndIteration(EndIteration):
     ACCESSED. In an evaluator-free train loop nothing else needs per-step
     host data, so a handler that reads `e.cost` every `log_period` steps
     (the CLI's discipline) pays one device round-trip per log_period
-    instead of per step — through a remote/tunneled device that is the
-    difference between RTT-bound and device-bound throughput
-    (docs/perf.md 'One host sync per step'). Accessing cost on EVERY
+    instead of per step (docs/perf.md 'One host sync per step').
+    Accessing cost on EVERY
     event reproduces the eager behavior exactly."""
 
     def __init__(self, pass_id: int, batch_id: int, fetch):

@@ -564,6 +564,7 @@ class TestCompileCacheSeam:
 
     def test_enable_points_jax_at_dir(self, tmp_path, monkeypatch):
         monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        monkeypatch.delenv(compile_cache.JAX_ENV_VAR, raising=False)
         prev = jax.config.jax_compilation_cache_dir
         try:
             d = compile_cache.enable(str(tmp_path / "cc"))
@@ -571,6 +572,24 @@ class TestCompileCacheSeam:
             assert jax.config.jax_compilation_cache_dir == d
         finally:
             jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_jax_env_var_places_the_cache(self, tmp_path, monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set no code path sets a
+        directory: enable() answers JAX's own and leaves the config
+        as it found it, whatever flag or env var asked for."""
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv(compile_cache.JAX_ENV_VAR, outside)
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "e"))
+        prev = jax.config.jax_compilation_cache_dir
+        assert compile_cache.enable(str(tmp_path / "cc")) == outside
+        assert compile_cache.enable_from_env() == outside
+        assert jax.config.jax_compilation_cache_dir == prev
+        assert os.listdir(tmp_path) == []
+
+    def test_default_dir_is_in_the_checkout(self):
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache.default_dir() == \
+            os.path.join(repo, ".jax_cache")
 
     def test_disabled_scopes_and_restores(self):
         assert jax.config.jax_enable_compilation_cache is True

@@ -91,3 +91,31 @@ class TestAttentionLayer:
                                 output_names=["att_pool"])
             outs.append(np.asarray(o["att_pool"]))
         np.testing.assert_allclose(outs[0], outs[1], atol=1e-6)
+
+
+class TestFlashOnMesh:
+    """The flash kernel inside a sharded step (layers/attention_layers
+    flash_on_mesh): each device runs it on its own batch rows and
+    heads. Interpreted here on virtual devices; that the same call
+    lowers for four real chips is tests/test_chip_compile.py's."""
+
+    @pytest.mark.parametrize("axes", [[("dp", 4)], [("dp", 2), ("mp", 2)],
+                                      [("mp", 4)]],
+                             ids=["dp4", "dp2-mp2", "mp4"])
+    def test_matches_unsharded_kernel(self, axes):
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.layers.attention_layers import flash_on_mesh
+        from paddle_tpu.ops.pallas_attention import flash_attention
+        rng = np.random.RandomState(0)
+        b, t, h, d = 4, 16, 4, 8
+        q, k, v = (jnp.asarray(rng.randn(b, t, h, d).astype(np.float32))
+                   for _ in range(3))
+        lens = jnp.asarray([16, 9, 12, 3], jnp.int32)
+        want = flash_attention(q, k, v, kv_lens=lens, causal=True,
+                               interpret=True)
+        got = jax.jit(lambda *a: flash_on_mesh(
+            *a, create_mesh(axes), causal=True, interpret=True))(
+                q, k, v, lens)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)

@@ -1,0 +1,227 @@
+"""The main path's kernels, compiled for the real chip without the chip.
+
+The TPU's compiler is installed beside the CPU backend and compiles for
+a device that is described, not attached (topology ``v5e:2x2``). Nothing
+runs, so these say nothing about results or times — they say that what
+interpret mode accepts also LOWERS: block shapes that tile, kernels
+inside VMEM/SMEM, shape casts Mosaic implements. Every shape
+``paged_kernel_supported`` accepts on the serving path is held to that
+here; a shape it rejects must say so and compile through the gather
+path instead.
+
+The topology is described inside a fixture (only one process at a time
+may load the TPU's library, and every xdist worker imports this file),
+and everything built from it is built in a fixture or a test. All the
+cases stay in this one file. The persistent compile cache is off around
+them: an entry written for a described device cannot be read back.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def cache_off():
+    """No persistent compile cache around a compile for a described
+    device: such an entry is written but cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.artifacts import cache
+    with cache.disabled():
+        compilation_cache.reset_cache()
+        yield
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip_compile(one_chip, cache_off):
+    """compile(fn, *shape_structs) -> HLO text of ``fn`` compiled for
+    one described v5e chip. Raises what the chip's compiler raises."""
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def compile_(fn, *args):
+        return jax.jit(fn).lower(
+            *jax.tree_util.tree_map(on_chip, args)).compile().as_text()
+
+    return compile_
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ------------------------------------------------------------ trainer
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+def test_flash_attention_t1024(chip_compile, grad):
+    """The LM train step's attention at the benchmark's width."""
+    from paddle_tpu.ops.pallas_attention import flash_attention
+    x = _sds((8, 1024, 8, 64), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def loss(q, k, v):
+        return jnp.sum(fwd(q, k, v).astype(jnp.float32) ** 2)
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    assert "tpu_custom_call" in chip_compile(fn, x, x, x)
+
+
+def test_flash_on_a_four_chip_mesh(topo, cache_off):
+    """Data-parallel training: the compiler refuses to partition a
+    Mosaic kernel by itself, so the attention layer hands each device
+    its own batch rows (flash_on_mesh). Forward and gradient, batch
+    split over the four described chips."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.layers.attention_layers import flash_on_mesh
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
+    rows = NamedSharding(mesh, P("dp"))
+    x = jax.ShapeDtypeStruct((8, 1024, 8, 64), jnp.bfloat16, sharding=rows)
+    lens = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=rows)
+
+    def loss(q, k, v, lens):
+        out = flash_on_mesh(q, k, v, lens, mesh, causal=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x, lens).compile().as_text()
+    assert "tpu_custom_call" in hlo and "all-gather" not in hlo
+
+
+@pytest.fixture
+def bf16_compute():
+    from paddle_tpu.config import global_config
+    prev = global_config().compute_dtype
+    global_config().compute_dtype = "bfloat16"
+    yield
+    global_config().compute_dtype = prev
+
+
+@pytest.mark.parametrize("cell,grad", [("lstm", False), ("lstm", True),
+                                       ("gru", False)],
+                         ids=["lstm-fwd", "lstm-grad", "gru-fwd"])
+def test_fused_rnn_h1280(chip_compile, bf16_compute, cell, grad):
+    """The fused recurrent kernels at the lstm_bs128_h1280 row's shape."""
+    from paddle_tpu.ops import pallas_rnn
+    b, T, h = 128, 32, 1280
+    gates = 4 if cell == "lstm" else 3
+    seq = pallas_rnn.lstm_sequence if cell == "lstm" else \
+        pallas_rnn.gru_sequence
+    extra = (None,) if cell == "lstm" else ()      # lstm: no peephole
+
+    def fwd(x, lens, w, bias):
+        return seq(x, lens, w, bias, *extra)[0]
+
+    def loss(x, lens, w, bias):
+        return jnp.sum(fwd(x, lens, w, bias) ** 2)
+
+    fn = jax.grad(loss, argnums=(0, 2, 3)) if grad else fwd
+    hlo = chip_compile(fn, _sds((b, T, gates * h), jnp.float32),
+                       _sds((b,), jnp.int32),
+                       _sds((h, gates * h), jnp.float32),
+                       _sds((gates * h,), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+# ------------------------------------------------------------- serving
+def _paged_structs(S, W, h, g, dh, ps, P, quant, dtype):
+    n_pages = S * P + 1
+    q = _sds((S, W, h, dh), dtype)
+    pages = _sds((n_pages, ps, g, dh), jnp.int8 if quant else dtype)
+    scales = _sds((n_pages, ps, g), jnp.float32) if quant else None
+    return (q, pages, scales, _sds((S, P), jnp.int32),
+            _sds((S, W), jnp.int32))
+
+
+def _paged_fn(use_kernel, quant):
+    from paddle_tpu.ops.pallas_decode import paged_window_attention
+
+    def float_fn(q, k, v, tables, lens):
+        return paged_window_attention(q, k, v, tables, lens,
+                                      use_kernel=use_kernel)
+
+    def int8_fn(q, k, v, tables, lens, ks, vs):
+        return paged_window_attention(q, k, v, tables, lens,
+                                      use_kernel=use_kernel,
+                                      k_scales=ks, v_scales=vs)
+
+    return int8_fn if quant else float_fn
+
+
+@pytest.mark.parametrize("W", [1, 3], ids=["W1", "W3"])
+@pytest.mark.parametrize("g", [8, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_kernel_lowers(chip_compile, quant, g, W):
+    """What the gate accepts lowers: h == g and grouped-query, the
+    one-token step and the speculative verify window, float and int8
+    pools, at the benchmark decoder's widths."""
+    from paddle_tpu.ops.pallas_decode import paged_kernel_supported
+    q, pages, scales, tables, lens = _paged_structs(
+        8, W, 8, g, 64, 16, 34, quant, jnp.bfloat16)
+    assert paged_kernel_supported(q, pages, scales, pages_per_slot=34)
+    args = (q, pages, pages, tables, lens) + \
+        ((scales, scales) if quant else ())
+    assert "tpu_custom_call" in chip_compile(_paged_fn(True, quant), *args)
+
+
+def test_gate_rejects_what_vmem_cannot_hold(chip_compile):
+    """256-row f32 pages of 32 x 128 heads: the chip's compiler refuses
+    the kernel ("Ran out of memory in memory space vmem"), so the gate
+    answers False and the gather path serves the shape."""
+    from paddle_tpu.ops.pallas_decode import paged_kernel_supported
+    q, pages, _, tables, lens = _paged_structs(
+        4, 1, 32, 32, 128, 256, 8, False, jnp.float32)
+    assert not paged_kernel_supported(q, pages, pages_per_slot=8)
+    hlo = chip_compile(_paged_fn(False, False), q, pages, pages, tables,
+                       lens)
+    assert "tpu_custom_call" not in hlo
+
+
+def test_paged_decoder_step_at_benchmark_width(chip_compile, monkeypatch):
+    """The whole PagedDecoder step of the d512/L6/h8/vocab-32000 bf16
+    decoder (8 slots, page 16, 34 pages per slot — chip_smoke.py's
+    engine) on the path ``attention="auto"`` picks on the chip."""
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.core import registry
+
+    registry.reset_name_counters()
+    paddle.init(use_tpu=False, seed=0)
+    spec = models.transformer_lm(vocab_size=32000, d_model=512, n_heads=8,
+                                 n_layers=6, d_ff=2048, max_len=1024,
+                                 tie_embeddings=True)
+    shapes = jax.eval_shape(paddle.Topology(spec.cost).init_params,
+                            jax.random.PRNGKey(0))
+    params = {k: np.zeros(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    dec = models.TransformerDecoder(params, n_layers=6, n_heads=8)
+    # the decoder asks JAX for its backend and here sees the CPU: steer
+    # it, in the test, onto the branch it takes on the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paged = dec.paged(num_slots=8, page_size=16, num_pages=8 * 34 + 1,
+                      max_pages_per_slot=34, warm_start=False)
+    assert paged.use_kernel and not paged.kernel_interpret
+    k_pool, v_pool = jax.eval_shape(paged.init_pools)
+    sw = _sds((8, 1), jnp.int32)
+    hlo = chip_compile(paged._step_impl, paged.dense.p, k_pool, v_pool,
+                       sw, sw, _sds((8, 34), jnp.int32),
+                       _sds((8, 1), jnp.bool_), _sds((2,), jnp.uint32))
+    assert "tpu_custom_call" in hlo

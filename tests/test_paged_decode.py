@@ -771,8 +771,16 @@ class TestBenchSmoke:
     regression (row stops producing tokens / latency fields vanish)
     surfaces in tier-1 rather than in the next driver capture."""
 
-    def test_decode_continuous_row_smoke(self):
+    def test_unknown_device_has_no_assumed_peak(self):
         import bench
+        with pytest.raises(RuntimeError, match="no HBM peak known"):
+            bench._known_hbm_gbps(jax.devices()[0])
+
+    def test_decode_continuous_row_smoke(self, monkeypatch):
+        import bench
+        # the CPU is in no peak table: the row's roofline arithmetic
+        # is driven against a stand-in peak, its value asserted nowhere
+        monkeypatch.setattr(bench, "_device_hbm_gbps", lambda dev: 819.0)
         row = bench.bench_decode_continuous(
             num_slots=4, n_requests=6, page_size=4, d_model=16,
             n_layers=2, n_heads=2, vocab_size=40, max_len=32,

@@ -119,6 +119,29 @@ class TestTrainerCountMesh:
         finally:
             paddle.init(use_tpu=False, seed=0, trainer_count=1)
 
+    def test_trainer_count_above_devices_raises(self):
+        """More trainers than devices is an error, not a quiet run on
+        fewer: no training believes it spans chips it does not have."""
+        from paddle_tpu.core import registry
+        registry.reset_name_counters()
+        paddle.init(use_tpu=False, seed=0,
+                    trainer_count=len(jax.devices()) + 1)
+        try:
+            cost = _net()
+            params = paddle.create_parameters(paddle.Topology(cost))
+            with pytest.raises(RuntimeError, match="trainer_count"):
+                paddle.SGD(cost=cost, parameters=params,
+                           update_equation=paddle.optimizer.Momentum(
+                               learning_rate=0.1))
+        finally:
+            paddle.init(use_tpu=False, seed=0, trainer_count=1)
+
+    def test_init_use_tpu_without_a_tpu_raises(self):
+        with pytest.raises(RuntimeError, match="use_tpu=True"):
+            paddle.init(use_tpu=True)
+        with pytest.raises(RuntimeError, match="use_tpu=True"):
+            paddle.init(use_gpu=True)
+
     def test_trainer_count_numerics_match_explicit_mesh(self):
         explicit = _run(create_mesh([(DP_AXIS, 4)]))
         try:

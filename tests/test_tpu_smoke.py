@@ -110,6 +110,40 @@ class TestLstmCompiled:
                                        rtol=1e-2, atol=1e-3)
 
 
+class TestPagedKernelCompiled:
+    """The allocated-pages decode kernel, compiled: the serving path's
+    attention against the gather/einsum reference on ragged lengths,
+    out-of-order pages and a verify window (the interpret-mode pins of
+    tests/test_paged_decode.py, on the chip)."""
+
+    @pytest.mark.parametrize("h,g", [(8, 8), (8, 2), (8, 1)])
+    @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+    def test_window_matches_gather(self, h, g, quant):
+        from paddle_tpu.ops.pallas_decode import (paged_window_attention,
+                                                  quantize_kv)
+        rng = np.random.RandomState(5)
+        S, W, dh, ps, P = 8, 3, 64, 16, 34
+        n_pages = S * P + 1
+        k = jnp.asarray(rng.randn(n_pages, ps, g, dh), jnp.bfloat16)
+        v = jnp.asarray(rng.randn(n_pages, ps, g, dh), jnp.bfloat16)
+        q = jnp.asarray(rng.randn(S, W, h, dh), jnp.bfloat16)
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, n_pages)).reshape(S, P), jnp.int32)
+        base = rng.randint(1, P * ps - W, (S,))
+        lens = jnp.asarray(base[:, None] + np.arange(W)[None, :], jnp.int32)
+        kw = {}
+        if quant:
+            k, ks = quantize_kv(k)
+            v, vs = quantize_kv(v)
+            kw = dict(k_scales=ks, v_scales=vs)
+        want = paged_window_attention(q, k, v, tables, lens, **kw)
+        got = paged_window_attention(q, k, v, tables, lens,
+                                     use_kernel=True, **kw)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=2e-2, atol=2e-2)
+
+
 class TestCpuTpuParity:
     """The reference's CPU<->GPU parity discipline (test_matrixCompare.cpp,
     test_CpuGpuVector.cpp) applied for real: the SAME jitted computation
