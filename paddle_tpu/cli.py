@@ -139,15 +139,32 @@ def _job_time(trainer, batch_size: int, iters: int,
     return 0
 
 
+def _train_step_text(trainer, batch) -> str:
+    """The compiled text of the train step for ``batch``'s shapes: the
+    program the loop runs, so its instructions are the ones a trace
+    names, each with the ``jax.named_scope`` it lies in (``op_name``).
+    A second compile of the same program (the AOT path does not share
+    the jit's cache)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.trainer.data_feeder import DataFeeder
+    feed = DataFeeder(trainer.topology.data_type(), None)(batch)
+    n_real = jnp.asarray(feed.pop("__batch_size__"), jnp.int32)
+    return trainer._train_step.lower(
+        trainer._own_params(), trainer.opt_state, trainer.parameters.state,
+        feed, jax.random.PRNGKey(0), n_real).compile().as_text()
+
+
 def _job_profile(trainer, args) -> int:
     """Profile train steps into an xplane trace (--job=profile).
 
     The reference's profiling loop is Stat.h timers printed at pass end
     (SURVEY §5 tracing); the TPU-native loop is jax.profiler -> .xplane.pb
-    -> tools/xplane_top.py kernel summary. This verb runs warmup + traced
-    steps on synthetic data shaped by the config and prints where the
-    trace landed (plus the top-op summary when the xplane reader is
-    importable)."""
+    -> obs/xplane.py. This verb runs warmup + traced steps on synthetic
+    data shaped by the config and prints where the trace landed, then
+    the reader's report: device busy and idle time, device time by
+    operation and by named scope, and the idle gaps by the ``train*``
+    host span open during them."""
     import jax
     batch = _synthetic_batch(trainer, args.batch_size, args.seq_len)
 
@@ -163,26 +180,17 @@ def _job_profile(trainer, args) -> int:
     with jax.profiler.trace(out):
         trainer.train(reader, num_passes=1, event_handler=lambda e: None,
                       num_batches_per_pass=args.iters)
-    import glob as _glob
-    # lexicographic sort, matching tools/xplane_top.load(), so the path
-    # reported here IS the file the summary below reads
-    xs = sorted(_glob.glob(os.path.join(out, "**", "*.xplane.pb"),
-                           recursive=True))
+    from paddle_tpu.obs import xplane
+    try:
+        rep = xplane.report(out, hlo_text=_train_step_text(trainer, batch))
+    except FileNotFoundError:
+        rep = None
     print(json.dumps({"job": "profile", "status": "ok",
                       "trace_dir": out,
-                      "xplane": xs[-1] if xs else None,
+                      "xplane": rep["xplane"] if rep else None,
                       "iters": args.iters}))
-    if xs:
-        try:
-            sys.path.insert(0, os.path.join(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__))), "tools"))
-            import xplane_top
-            xplane_top.top_ops(xplane_top.load(out), 15)
-        except Exception as e:      # tf/tsl absent: the trace still stands
-            print(f"(xplane summary unavailable: {e})", file=sys.stderr)
-        finally:
-            if sys.path and sys.path[0].endswith("tools"):
-                sys.path.pop(0)
+    if rep:
+        print(xplane.format_report(rep))
     return 0
 
 

@@ -679,31 +679,39 @@ class PagedDecoder:
         # page by the caller.
         rows_p = page_idx.reshape(-1)
         rows_o = offs.reshape(-1)
+        # the scopes name the regions in a device trace (PERF.md
+        # section 3): the pool update and the pool slice + kernel
         if self.kv_quant == "int8":
-            kq, ks = paged_ops.quantize_kv(k.reshape(S * W, g, -1))
-            vq, vs = paged_ops.quantize_kv(v.reshape(S * W, g, -1))
-            k_pool = {"q": k_pool["q"].at[i, rows_p, rows_o].set(kq),
-                      "s": k_pool["s"].at[i, rows_p, rows_o].set(ks)}
-            v_pool = {"q": v_pool["q"].at[i, rows_p, rows_o].set(vq),
-                      "s": v_pool["s"].at[i, rows_p, rows_o].set(vs)}
-            attn = paged_ops.paged_window_attention(
-                q, k_pool["q"][i], v_pool["q"][i], page_tables,
-                kv_lens, use_kernel=self.use_kernel,
-                interpret=self.kernel_interpret,
-                k_scales=k_pool["s"][i], v_scales=v_pool["s"][i])
+            with jax.named_scope("kv_write"):
+                kq, ks = paged_ops.quantize_kv(k.reshape(S * W, g, -1))
+                vq, vs = paged_ops.quantize_kv(v.reshape(S * W, g, -1))
+                k_pool = {"q": k_pool["q"].at[i, rows_p, rows_o].set(kq),
+                          "s": k_pool["s"].at[i, rows_p, rows_o].set(ks)}
+                v_pool = {"q": v_pool["q"].at[i, rows_p, rows_o].set(vq),
+                          "s": v_pool["s"].at[i, rows_p, rows_o].set(vs)}
+            with jax.named_scope("paged_attn"):
+                attn = paged_ops.paged_window_attention(
+                    q, k_pool["q"][i], v_pool["q"][i], page_tables,
+                    kv_lens, use_kernel=self.use_kernel,
+                    interpret=self.kernel_interpret,
+                    k_scales=k_pool["s"][i], v_scales=v_pool["s"][i])
         else:
-            k_pool = k_pool.at[i, rows_p, rows_o
-                               ].set(k.reshape(S * W, g, -1)
-                                     .astype(k_pool.dtype))
-            v_pool = v_pool.at[i, rows_p, rows_o
-                               ].set(v.reshape(S * W, g, -1)
-                                     .astype(v_pool.dtype))
-            attn = paged_ops.paged_window_attention(
-                q, k_pool[i], v_pool[i], page_tables, kv_lens,
-                use_kernel=self.use_kernel,
-                interpret=self.kernel_interpret)
+            with jax.named_scope("kv_write"):
+                k_pool = k_pool.at[i, rows_p, rows_o
+                                   ].set(k.reshape(S * W, g, -1)
+                                         .astype(k_pool.dtype))
+                v_pool = v_pool.at[i, rows_p, rows_o
+                                   ].set(v.reshape(S * W, g, -1)
+                                         .astype(v_pool.dtype))
+            with jax.named_scope("paged_attn"):
+                attn = paged_ops.paged_window_attention(
+                    q, k_pool[i], v_pool[i], page_tables, kv_lens,
+                    use_kernel=self.use_kernel,
+                    interpret=self.kernel_interpret)
         x = x + attn.reshape(x.shape) @ p[f"_{n}_l{i}_proj.w0"]
-        return d0._ffn(p, i, x), k_pool, v_pool
+        with jax.named_scope("ffn"):
+            x = d0._ffn(p, i, x)
+        return x, k_pool, v_pool
 
     def _step_impl(self, p, k_pool, v_pool, tokens, positions,
                    page_tables, active, key):
@@ -714,7 +722,8 @@ class PagedDecoder:
         verify verdict in one read."""
         d0 = self.dense
         ps = self.page_size
-        x = d0._embed(p, tokens, positions)             # [S, W, d]
+        with jax.named_scope("embed"):
+            x = d0._embed(p, tokens, positions)         # [S, W, d]
         page_idx = jnp.take_along_axis(
             page_tables, positions // ps, axis=1)       # [S, W]
         page_idx = jnp.where(active, page_idx, 0)       # null the dead
@@ -724,13 +733,14 @@ class PagedDecoder:
             x, k_pool, v_pool = self._paged_block(
                 p, i, x, k_pool, v_pool, page_idx, offs, page_tables,
                 kv_lens)
-        logits = d0._logits(p, x)                       # [S, W, V]
-        if self.temperature is None:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            nxt = jax.random.categorical(
-                key, logits.astype(jnp.float32) /
-                self.temperature).astype(jnp.int32)
+        with jax.named_scope("logits"):
+            logits = d0._logits(p, x)                   # [S, W, V]
+            if self.temperature is None:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                nxt = jax.random.categorical(
+                    key, logits.astype(jnp.float32) /
+                    self.temperature).astype(jnp.int32)
         return nxt, k_pool, v_pool
 
     @staticmethod

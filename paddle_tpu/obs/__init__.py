@@ -27,6 +27,10 @@ One place the whole framework reports through (docs/observability.md):
 - :mod:`paddle_tpu.obs.profile` — continuous step profiler (per-phase
   breakdown, live MFU/roofline gauges, device-memory telemetry on the
   ``pt-obs-profiler`` thread, deep ``jax.profiler.trace`` windows).
+- :mod:`paddle_tpu.obs.xplane`  — the reader of those windows (and of
+  any ``jax.profiler`` trace directory): device busy/idle, time by
+  operation and named scope, idle gaps by the host span open during
+  them; ``jax.profiler.ProfileData`` alone.
 - :mod:`paddle_tpu.obs.slo`     — SLO watchdog: declarative objectives
   over rolling windows + step-regression detection with per-phase
   attribution, journaled under the ``slo`` domain.

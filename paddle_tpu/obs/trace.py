@@ -2,7 +2,7 @@
 JSON, with XLA compile events attached and correlation IDs stamped.
 
 ``jax.profiler`` already produces device-side XPlane traces
-(tools/xplane_top.py); what it cannot show is the HOST schedule a
+(read back by obs/xplane.py); what it cannot show is the HOST schedule a
 production trainer or decode engine lives or dies by — where the step
 loop waits on data, how long a checkpoint write holds its thread, when
 a compile lands in the middle of serving traffic. This tracer records

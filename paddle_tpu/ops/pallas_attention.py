@@ -190,7 +190,7 @@ def _flash_call(q3, k3, v3, lens2, *, scale, block_q, block_k, causal,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_fwd",
     )(lens2, q3, k3, v3)
     return outs if save_lse else (outs[0], None)
 
@@ -334,7 +334,7 @@ def _flash_grads(q3, k3, v3, do3, out3, lse, lens2, *, scale, block_q,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j, kk: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dq",
     )(lens2, q3, k3, v3, do3, lse, dd)
 
     dk, dv = pl.pallas_call(
@@ -361,7 +361,7 @@ def _flash_grads(q3, k3, v3, do3, out3, lse, lens2, *, scale, block_q,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dkv",
     )(lens2, q3, k3, v3, do3, lse, dd)
     return dq, dk, dv
 

@@ -170,7 +170,7 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, scale=None,
         ],
         out_specs=pl.BlockSpec((b, rep, dh, 1), lambda j: (0, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, dh, 1), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="decode_attention",
     )(lens, q4, k_cache, v_cache)
     return out.reshape(b, h, dh)
 
@@ -468,7 +468,7 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, g, W * rep, dh), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_window_attention",
     )(*operands)
     return out.reshape(S, g, W, rep, dh).transpose(0, 2, 1, 3, 4) \
         .reshape(S, W, h, dh)

@@ -261,7 +261,7 @@ def _lstm_fwd_call(x4, lens2d, w, bias2d, peep2d, interpret,
         ],
         compiler_params=_CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
-        interpret=interpret,
+        interpret=interpret, name="lstm_fwd",
     )(lens2d, xt, w.astype(mxu), bias2d, peep2d)
     if save_res:
         out, cseq, gates, hT, cT = outs
@@ -315,7 +315,7 @@ def _lstm_bwd(interpret, res, ct):
         ],
         compiler_params=_CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
-        interpret=interpret,
+        interpret=interpret, name="lstm_bwd",
     )(lens2d, w.astype(mxu), peep2d, gates, cseq, cseq, d_out_tb,
       d_hT.astype(jnp.float32), d_cT.astype(jnp.float32))[0]
 
@@ -486,7 +486,7 @@ def _gru_call(xt, lens, w, b_arr, b, T, three_h, h, interpret):
             jax.ShapeDtypeStruct((b, h), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((b, h), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="gru_fwd",
     )(lens, xt.astype(mxu), w[:, :2 * h].astype(mxu),
       w[:, 2 * h:].astype(mxu), b_arr)
 

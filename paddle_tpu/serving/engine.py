@@ -66,6 +66,7 @@ fault families.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
@@ -251,6 +252,11 @@ class GenRequest:
         self.error: Optional[ServingError] = None
         self.done = threading.Event()
         self.submitted_at = now
+        # engine clock at the FIRST admission to a slot (a preempted
+        # request keeps it): admitted_at - submitted_at is queue wait
+        self.admitted_at: Optional[float] = None
+        # engine clock of each committed token, parallel to ``tokens``
+        self.token_times: List[float] = []
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self.evictions = 0
@@ -428,7 +434,16 @@ class DecodeEngine:
                           "kv_pages_spilled": 0,
                           "kv_pages_restored": 0,
                           "kv_spill_integrity_drops": 0,
-                          "kv_spill_cleared": 0}
+                          "kv_spill_cleared": 0,
+                          # first admissions and their summed wait
+                          # (submit -> slot), on the engine's clock
+                          "admitted": 0, "queue_wait_ns": 0,
+                          # host nanoseconds by phase of step()/_loop:
+                          # each is written by _phase() with the span of
+                          # the same boundary (PERF.md section 3)
+                          "host_admit_ns": 0, "host_plan_ns": 0,
+                          "host_dispatch_ns": 0, "host_sync_ns": 0,
+                          "host_commit_ns": 0, "host_idle_ns": 0}
         import jax
         self._key0 = jax.random.PRNGKey(0)
         # live-state provider for postmortem bundles: the slot table
@@ -489,6 +504,22 @@ class DecodeEngine:
 
         from paddle_tpu.obs.slo import WATCHDOG
         WATCHDOG.add_source(f"engine-{id(self):x}", _slo_stats)
+
+    @contextlib.contextmanager
+    def _phase(self, span: str, counter: Optional[str] = None):
+        """One phase boundary of the loop, drawn once: the
+        ``stat_timer`` span (TraceAnnotation + TRACER + StatItem) and
+        the ``host_*_ns`` counter of ``stats()`` take the same start
+        and end, so a trace and the counters cannot disagree about
+        where a phase lies. Counters are always on; the span costs
+        nothing more than ``stat_timer`` does when no trace is."""
+        t0 = time.perf_counter_ns()
+        try:
+            with stat_timer(span):
+                yield
+        finally:
+            if counter is not None:
+                self._counters[counter] += time.perf_counter_ns() - t0
 
     # ------------------------------------------------------------ admission
     def _pages_for(self, n_tokens: int) -> int:
@@ -926,6 +957,11 @@ class DecodeEngine:
                     break              # page-aware: head waits for pages
                 self._waiting.popleft()
                 req.state = "running"
+                if req.admitted_at is None:     # not a re-admission
+                    req.admitted_at = self._clock()
+                    self._counters["admitted"] += 1
+                    self._counters["queue_wait_ns"] += int(round(
+                        (req.admitted_at - req.submitted_at) * 1e9))
                 self._arrival_seq += 1
                 slot = _Slot(req, self._arrival_seq)
                 self.slots[s] = slot
@@ -1050,17 +1086,55 @@ class DecodeEngine:
         plan, page-ensure, ONE jitted target dispatch, bookkeep.
         Returns True iff a device step ran. Single-threaded by
         contract: the engine thread in serving mode, the caller in
-        sync mode."""
-        interceptor = self._step_interceptor
-        if interceptor is not None:
-            interceptor(self._steps)
-        now = self._clock()
-        self._reap(now)
-        self._admit()
+        sync mode. Each phase is one ``_phase``: a ``host_*_ns``
+        counter and a span under ``serving/step``, whose ``step`` is
+        the ``engine_step`` of its slots' flight records."""
+        with obs_context.bind(step=self._steps + 1), \
+                self._phase("serving/step"):
+            with self._phase("serving/admit", "host_admit_ns"):
+                interceptor = self._step_interceptor
+                if interceptor is not None:
+                    interceptor(self._steps)
+                self._reap(self._clock())
+                self._admit()
+            with self._phase("serving/plan", "host_plan_ns"):
+                plan, live = self._plan_windows()
+                key = self._key0
+                if live and self.temperature is not None:
+                    import jax
+                    key = jax.random.fold_in(self._key0, self._steps)
+            if not live:
+                return False
+            try:
+                with stat_timer("serving/decode_step"):
+                    # dispatch: the host-to-device transfers and the
+                    # enqueue; sync: the host waiting for the device
+                    with self._phase("serving/dispatch",
+                                     "host_dispatch_ns"):
+                        nxt, self.k_pool, self.v_pool = self.paged.step(
+                            self.k_pool, self.v_pool, self._tokens,
+                            self._positions, self._tables, self._active,
+                            key)
+                    with self._phase("serving/sync", "host_sync_ns"):
+                        nxt = np.asarray(nxt)  # the ONE host sync per step
+            # ptlint: disable=R7(serving boundary — in-flight requests settle typed and the pools rebuild; the engine thread must never die)
+            except Exception as e:
+                self._recover_from_step_failure(e)
+                return False
+            with self._phase("serving/commit", "host_commit_ns"):
+                self._commit(plan, live, nxt)
+            return True
+
+    def _plan_windows(self):
+        """The step's host plan: each active slot's window (a replay
+        chunk, or the pending token + the draft's proposals), its pages
+        ensured (which may preempt), and the step's small int32 inputs
+        filled. -> (plan, live slot indices); nothing live means no
+        dispatch."""
         active_idx = [s for s in range(self.num_slots)
                       if self.slots[s] is not None]
         if not active_idx:
-            return False
+            return {}, []
         props = self._draft_propose(active_idx)
         # window plan: a replay chunk (multi-token prefill) or the
         # pending token + the draft's proposals (speculative verify)
@@ -1079,7 +1153,7 @@ class DecodeEngine:
         live = [s for s in active_idx
                 if self.slots[s] is not None and s in plan]
         if not live:
-            return False
+            return plan, live
         self._active[:, :] = False
         self._tokens[:, :] = 0
         self._positions[:, :] = 0
@@ -1089,20 +1163,12 @@ class DecodeEngine:
             self._tokens[s, :w] = plan[s]
             self._positions[s, :w] = np.arange(slot.pos, slot.pos + w)
             self._active[s, :w] = True
-        key = self._key0
-        if self.temperature is not None:
-            import jax
-            key = jax.random.fold_in(self._key0, self._steps)
-        try:
-            with stat_timer("serving/decode_step"):
-                nxt, self.k_pool, self.v_pool = self.paged.step(
-                    self.k_pool, self.v_pool, self._tokens,
-                    self._positions, self._tables, self._active, key)
-                nxt = np.asarray(nxt)  # the ONE host sync per step
-        # ptlint: disable=R7(serving boundary — in-flight requests settle typed and the pools rebuild; the engine thread must never die)
-        except Exception as e:
-            self._recover_from_step_failure(e)
-            return False
+        return plan, live
+
+    def _commit(self, plan: Dict[int, List[int]], live: List[int],
+                nxt) -> None:
+        """Everything after the sync: count the step, commit each live
+        slot's tokens, finish what is done."""
         t_after = self._clock()
         with self._cv:
             self._steps += 1
@@ -1171,7 +1237,8 @@ class DecodeEngine:
                     if slot.last_token_t is not None else None
                 slot.last_token_t = t_after
                 for tok in commits:
-                    req.tokens.append(tok)
+                    req.token_times.append(t_after)   # stamp first:
+                    req.tokens.append(tok)  # a reader never lacks one
                     slot.last_tok = tok
                     n_commit += 1
                     self._counters["tokens_out"] += 1
@@ -1190,7 +1257,6 @@ class DecodeEngine:
                 slot.draft_pos = min(slot.draft_pos, fed + n_commit)
             if done:
                 self._finish(s, "done")
-        return True
 
     def _recover_from_step_failure(self, exc: Exception) -> None:
         """A failed dispatch may have consumed the (donated) pools:
@@ -1298,7 +1364,11 @@ class DecodeEngine:
                 if not self._has_work():
                     if self._stopping:
                         return
-                    self._cv.wait(0.05)
+                    # one span and one counter update per idle stretch
+                    with self._phase("serving/idle", "host_idle_ns"):
+                        while not (self._has_work() or self._stopping
+                                   or self._close_now):
+                            self._cv.wait(0.05)
                     continue
             self.step()
         self._close_all()

@@ -111,6 +111,11 @@ _COUNTER_KEYS = {
     "prefix_hit_pages", "prefix_miss_pages", "prefix_cow_copies",
     "prefix_evicted_pages", "spec_proposed_tokens",
     "spec_accepted_tokens", "draft_failures",
+    # first admissions, their summed queue wait, and the engine loop's
+    # host nanoseconds by phase (DecodeEngine._phase)
+    "admitted", "queue_wait_ns", "host_admit_ns", "host_plan_ns",
+    "host_dispatch_ns", "host_sync_ns", "host_commit_ns",
+    "host_idle_ns",
 }
 
 

@@ -348,13 +348,16 @@ class SGD:
 
                 grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1),
                                              has_aux=True)
-                ((loss, (metrics, new_state, eval_outs)),
-                 (g_dense, g_rows)) = grad_fn(dense, rows0)
+                with jax.named_scope("loss_and_grad"):
+                    ((loss, (metrics, new_state, eval_outs)),
+                     (g_dense, g_rows)) = grad_fn(dense, rows0)
                 sparse_rows = {k: (uids_map[k], g_rows[k], rows0[k],
                                    slot_rows_map[k]) for k in g_rows}
-                new_params, new_opt_state = self.optimizer.update(
-                    params, g_dense, opt_state, n_real.astype(jnp.float32),
-                    sparse_rows=sparse_rows)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt_state = self.optimizer.update(
+                        params, g_dense, opt_state,
+                        n_real.astype(jnp.float32),
+                        sparse_rows=sparse_rows)
                 return (new_params, new_opt_state, new_state, loss, metrics,
                         eval_outs)
             if self._grad_tap_names:
@@ -377,8 +380,9 @@ class SGD:
                     lambda p, t: self._loss_and_metrics(
                         p, state, feed, rng, n_real, "train", taps=t),
                     argnums=(0, 1), has_aux=True)
-                ((loss, (metrics, new_state, eval_outs)),
-                 (grads, tap_grads)) = grad_fn(params, taps0)
+                with jax.named_scope("loss_and_grad"):
+                    ((loss, (metrics, new_state, eval_outs)),
+                     (grads, tap_grads)) = grad_fn(params, taps0)
                 eval_outs = dict(eval_outs)
                 for n, g in tap_grads.items():
                     eval_outs["__grad__" + n] = g
@@ -387,10 +391,14 @@ class SGD:
                     lambda p: self._loss_and_metrics(p, state, feed, rng,
                                                      n_real, "train"),
                     has_aux=True)
-                ((loss, (metrics, new_state, eval_outs)),
-                 grads) = grad_fn(params)
-            new_params, new_opt_state = self.optimizer.update(
-                params, grads, opt_state, n_real.astype(jnp.float32))
+                # the scopes name the step's two regions in a device
+                # trace (PERF.md section 3)
+                with jax.named_scope("loss_and_grad"):
+                    ((loss, (metrics, new_state, eval_outs)),
+                     grads) = grad_fn(params)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = self.optimizer.update(
+                    params, grads, opt_state, n_real.astype(jnp.float32))
             return (new_params, new_opt_state, new_state, loss, metrics,
                     eval_outs)
 
@@ -520,11 +528,13 @@ class SGD:
                 return (g_acc, loss_acc + loss_j.astype(jnp.float32),
                         m_acc, new_st), None
 
-            (grads, loss, metrics, new_state), _ = jax.lax.scan(
-                body, (g0, jnp.zeros((), jnp.float32), m0, state),
-                (feed_m, jnp.arange(k)))
-            new_params, new_opt_state = self.optimizer.update(
-                params, grads, opt_state, n_real.astype(jnp.float32))
+            with jax.named_scope("loss_and_grad"):
+                (grads, loss, metrics, new_state), _ = jax.lax.scan(
+                    body, (g0, jnp.zeros((), jnp.float32), m0, state),
+                    (feed_m, jnp.arange(k)))
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = self.optimizer.update(
+                    params, grads, opt_state, n_real.astype(jnp.float32))
             return (new_params, new_opt_state, new_state, loss, metrics,
                     {})
         return self._finalize_step(step, guarded)
@@ -1256,7 +1266,11 @@ class SGD:
             # nonfinite/rollback/oom, checkpoint writes) is then
             # attributable to run_id + step (docs/observability.md)
             obs_context.set_step(self._step_count)
-            event_handler(evt.BeginIteration(pass_id, batch_id))
+            # the caller's code (and, in a benchmark, its wait for the
+            # step before): with data_wait, train_step and settle, the
+            # fourth named span of an iteration's wall time
+            with stat_timer("train/event"):
+                event_handler(evt.BeginIteration(pass_id, batch_id))
             n_real_host = int(feed.pop("__batch_size__"))
             n_real = jnp.asarray(n_real_host, jnp.int32)
             self._rng, sub = jax.random.split(self._rng)
@@ -1308,10 +1322,11 @@ class SGD:
                         for k, v in metrics.items()}
                 fetch_host = self._fetch_host   # plain function — the
                 # event closure must not pin the trainer alive
-                event_handler(evt.LazyEndIteration(
-                    pass_id, batch_id,
-                    lambda loss=loss, metrics=metrics, fh=fetch_host:
-                        fh(loss, metrics)[:2]))
+                with stat_timer("train/event"):
+                    event_handler(evt.LazyEndIteration(
+                        pass_id, batch_id,
+                        lambda loss=loss, metrics=metrics, fh=fetch_host:
+                            fh(loss, metrics)[:2]))
             else:
                 loss_np, metrics_np, eval_host = self._fetch_host(
                     loss, metrics, eval_outs)
@@ -1319,8 +1334,9 @@ class SGD:
                     pass_metrics[k] = pass_metrics.get(k, 0.0) + v
                 metrics_np.update(
                     self._feed_evaluators(eval_host, n_real_host))
-                event_handler(evt.EndIteration(pass_id, batch_id,
-                                               loss_np, metrics_np))
+                with stat_timer("train/event"):
+                    event_handler(evt.EndIteration(pass_id, batch_id,
+                                                   loss_np, metrics_np))
             if policy is not None:
                 self._check_faults(policy, pass_id, batch_id,
                                    event_handler, checkpoint_manager)
