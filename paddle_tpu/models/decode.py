@@ -497,9 +497,16 @@ class PagedDecoder:
     every sequence's cache read to the longest, and recompiling per
     (batch, prompt_len) combination. This class is the serving
     replacement: K/V live in a shared preallocated POOL of fixed-size
-    pages ([L, n_pages, page_size, g, dh]); each slot of a fixed-size
-    slot batch owns a page-table row mapping its logical positions to
-    physical pages. Requests join and leave mid-flight by editing the
+    pages, stored for the engine's whole life in the layout the paged
+    kernel's blocks read — [L, n_pages, page_size, g*dh], every kv head
+    of a token side by side on the lane axis (a page of 16 x 2048 bf16
+    is whole tiles, nothing padded) — and updated IN PLACE: the step
+    scatters its S*W new rows into the donated pools and hands them
+    whole to the kernel with the layer in the block index, so the
+    compiled step holds no pool-sized copy and no per-layer slice. Each
+    slot of a fixed-size slot batch owns a page-table row mapping its
+    logical positions to physical pages. Requests join and leave
+    mid-flight by editing the
     small int32 inputs (tokens / positions / page tables / active mask)
     — the jitted step's shapes NEVER change, so continuous batching
     costs zero recompiles (pinned by @recompile_budget in
@@ -534,7 +541,7 @@ class PagedDecoder:
     (kernel on TPU when supported, gather elsewhere).
 
     ``kv_quant="int8"`` switches the pools to the two-tier INT8 layout:
-    each pool becomes a pytree ``{"q": int8 [L, N, ps, g, dh],
+    each pool becomes a pytree ``{"q": int8 [L, N, ps, g*dh],
     "s": float32 [L, N, ps, g]}`` — the scatter quantizes each K/V row
     per (token, kv-head) with ops/pallas_decode.quantize_kv (a pure
     function of the row, so prefix-shared pages stay bit-identical
@@ -542,6 +549,10 @@ class PagedDecoder:
     kernel or the dequantizing gather fallback. ~4x pages per HBM
     byte at fp32 base dtype; greedy output is prefix-identical to the
     fp path under the pinned INT8_KV_* contract."""
+
+    #: the stored pool layout, as the artifact fingerprints name it: an
+    #: executable built for another layout can never be resolved
+    POOL_LAYOUT = "L,N,page,g*dh"
 
     def __init__(self, dense: TransformerDecoder, *, num_slots: int,
                  page_size: int, num_pages: int,
@@ -576,8 +587,8 @@ class PagedDecoder:
             (self.num_slots, self.window, h, self.head_dim), self.dtype)
         kv_dtype = jnp.int8 if self.kv_quant == "int8" else self.dtype
         probe_k = jax.ShapeDtypeStruct(
-            (self.num_pages, self.page_size, self.kv_heads,
-             self.head_dim), kv_dtype)
+            (self.num_pages, self.page_size,
+             self.kv_heads * self.head_dim), kv_dtype)
         probe_s = jax.ShapeDtypeStruct(
             (self.num_pages, self.page_size, self.kv_heads),
             jnp.float32) if self.kv_quant == "int8" else None
@@ -614,7 +625,8 @@ class PagedDecoder:
                 "temperature": self.temperature,
                 "use_kernel": self.use_kernel,
                 "kernel_interpret": self.kernel_interpret,
-                "kv_quant": self.kv_quant}
+                "kv_quant": self.kv_quant,
+                "pool_layout": self.POOL_LAYOUT}
         self._step_fp = fingerprint("paged_step", dense.p, plan=plan)
         page_plan = {"num_pages": self.num_pages,
                      "page_size": self.page_size,
@@ -622,7 +634,8 @@ class PagedDecoder:
                      "kv_heads": self.kv_heads,
                      "head_dim": self.head_dim,
                      "dtype": str(jnp.dtype(self.dtype)),
-                     "kv_quant": self.kv_quant}
+                     "kv_quant": self.kv_quant,
+                     "pool_layout": self.POOL_LAYOUT}
         self._copy_fp = fingerprint("paged_copy", dense.p,
                                     plan=page_plan)
         self._read_fp = fingerprint("paged_read", dense.p,
@@ -639,18 +652,21 @@ class PagedDecoder:
         self._write_exe = None
 
     def init_pools(self):
-        """Zeroed (k_pool, v_pool): each [L, n_pages, page_size, g, dh]
-        arrays at the base dtype, or — under ``kv_quant="int8"`` — the
-        two-tier pytrees ``{"q": int8 values, "s": float32 per-row
-        scales [L, n_pages, page_size, g]}``."""
-        shape = (self.dense.n_layers, self.num_pages, self.page_size,
-                 self.kv_heads, self.head_dim)
+        """Zeroed (k_pool, v_pool): each [L, n_pages, page_size, g*dh]
+        at the base dtype — the layout the paged kernel's blocks read,
+        kept for the pools' whole life — or, under ``kv_quant="int8"``,
+        the two-tier pytrees ``{"q": int8 values in that layout,
+        "s": float32 per-row scales [L, n_pages, page_size, g]}``."""
+        rows = (self.dense.n_layers, self.num_pages, self.page_size)
+        row = self.kv_heads * self.head_dim
         if self.kv_quant == "int8":
             def one():
-                return {"q": jnp.zeros(shape, jnp.int8),
-                        "s": jnp.zeros(shape[:-1], jnp.float32)}
+                return {"q": jnp.zeros(rows + (row,), jnp.int8),
+                        "s": jnp.zeros(rows + (self.kv_heads,),
+                                       jnp.float32)}
             return one(), one()
-        return jnp.zeros(shape, self.dtype), jnp.zeros(shape, self.dtype)
+        return (jnp.zeros(rows + (row,), self.dtype),
+                jnp.zeros(rows + (row,), self.dtype))
 
     def pool_bytes(self) -> int:
         rows = self.dense.n_layers * self.num_pages * \
@@ -670,44 +686,42 @@ class PagedDecoder:
         ln1 = _ln(x, p[f"_{n}_l{i}_ln1.w0"], p[f"_{n}_l{i}_ln1.wbias"])
         q = _heads(ln1 @ p[f"_{n}_l{i}_q.w0"], h)       # [S, W, h, dh]
         g = self.kv_heads
-        k = _heads(ln1 @ p[f"_{n}_l{i}_k.w0"], g)        # [S, W, g, dh]
-        v = _heads(ln1 @ p[f"_{n}_l{i}_v.w0"], g)
+        # K/V rows as the pool stores them: [S*W, g*dh]
+        k = (ln1 @ p[f"_{n}_l{i}_k.w0"]).reshape(S * W, -1)
+        v = (ln1 @ p[f"_{n}_l{i}_v.w0"]).reshape(S * W, -1)
         # unconditional scatter: every window token writes its K/V at
-        # (physical page, in-page offset) — BEFORE attention, so later
-        # window tokens attend to earlier ones (in-window causality via
-        # each token's kv_len). Masked tokens were routed to the null
-        # page by the caller.
+        # (layer, physical page, in-page offset) of the donated pool,
+        # in place — BEFORE attention, so later window tokens attend to
+        # earlier ones (in-window causality via each token's kv_len).
+        # Masked tokens were routed to the null page by the caller.
         rows_p = page_idx.reshape(-1)
         rows_o = offs.reshape(-1)
+
+        def put(pool, rows):
+            return pool.at[i, rows_p, rows_o].set(rows.astype(pool.dtype))
+
         # the scopes name the regions in a device trace (PERF.md
-        # section 3): the pool update and the pool slice + kernel
+        # section 3): the pool update and the kernel
+        scales = {}
         if self.kv_quant == "int8":
             with jax.named_scope("kv_write"):
                 kq, ks = paged_ops.quantize_kv(k.reshape(S * W, g, -1))
                 vq, vs = paged_ops.quantize_kv(v.reshape(S * W, g, -1))
-                k_pool = {"q": k_pool["q"].at[i, rows_p, rows_o].set(kq),
-                          "s": k_pool["s"].at[i, rows_p, rows_o].set(ks)}
-                v_pool = {"q": v_pool["q"].at[i, rows_p, rows_o].set(vq),
-                          "s": v_pool["s"].at[i, rows_p, rows_o].set(vs)}
-            with jax.named_scope("paged_attn"):
-                attn = paged_ops.paged_window_attention(
-                    q, k_pool["q"][i], v_pool["q"][i], page_tables,
-                    kv_lens, use_kernel=self.use_kernel,
-                    interpret=self.kernel_interpret,
-                    k_scales=k_pool["s"][i], v_scales=v_pool["s"][i])
+                k_pool = {"q": put(k_pool["q"], kq.reshape(S * W, -1)),
+                          "s": put(k_pool["s"], ks)}
+                v_pool = {"q": put(v_pool["q"], vq.reshape(S * W, -1)),
+                          "s": put(v_pool["s"], vs)}
+            k_pages, v_pages = k_pool["q"], v_pool["q"]
+            scales = dict(k_scales=k_pool["s"], v_scales=v_pool["s"])
         else:
             with jax.named_scope("kv_write"):
-                k_pool = k_pool.at[i, rows_p, rows_o
-                                   ].set(k.reshape(S * W, g, -1)
-                                         .astype(k_pool.dtype))
-                v_pool = v_pool.at[i, rows_p, rows_o
-                                   ].set(v.reshape(S * W, g, -1)
-                                         .astype(v_pool.dtype))
-            with jax.named_scope("paged_attn"):
-                attn = paged_ops.paged_window_attention(
-                    q, k_pool[i], v_pool[i], page_tables, kv_lens,
-                    use_kernel=self.use_kernel,
-                    interpret=self.kernel_interpret)
+                k_pool, v_pool = put(k_pool, k), put(v_pool, v)
+            k_pages, v_pages = k_pool, v_pool
+        with jax.named_scope("paged_attn"):
+            attn = paged_ops.paged_window_attention(
+                q, k_pages, v_pages, page_tables, kv_lens, layer=i,
+                use_kernel=self.use_kernel,
+                interpret=self.kernel_interpret, **scales)
         x = x + attn.reshape(x.shape) @ p[f"_{n}_l{i}_proj.w0"]
         with jax.named_scope("ffn"):
             x = d0._ffn(p, i, x)
@@ -745,9 +759,9 @@ class PagedDecoder:
 
     @staticmethod
     def _page_slice(leaf, page):
-        """[L, 1, ...] view of one physical page — rank-generic so it
-        covers both the value leaves [L, N, ps, g, dh] and the int8
-        layout's scale leaves [L, N, ps, g]."""
+        """[L, 1, ...] view of one physical page in the stored layout —
+        rank-generic, so it covers the value leaves [L, N, ps, g*dh]
+        and the int8 layout's scale leaves [L, N, ps, g] alike."""
         start = (0, page) + (0,) * (leaf.ndim - 2)
         return jax.lax.dynamic_slice(
             leaf, start, (leaf.shape[0], 1) + leaf.shape[2:])
@@ -756,7 +770,20 @@ class PagedDecoder:
     def _page_update(leaf, data, page):
         start = (0, page) + (0,) * (leaf.ndim - 2)
         return jax.lax.dynamic_update_slice(
-            leaf, data.astype(leaf.dtype), start)
+            leaf, data.reshape((leaf.shape[0], 1) + leaf.shape[2:])
+            .astype(leaf.dtype), start)
+
+    def _page_payload(self, page):
+        """One stored page in the shape the spill payload has always
+        had: value leaves [L, 1, ps, g*dh] -> [L, 1, ps, g, dh] (the
+        codec and its checksums do not know the stored layout; the int8
+        layout's scale leaves pass as they are)."""
+        def heads(v):
+            return v.reshape(v.shape[:-1] + (self.kv_heads, self.head_dim))
+
+        if self.kv_quant == "int8":
+            return {"q": heads(page["q"]), "s": page["s"]}
+        return heads(page)
 
     def _copy_page_impl(self, k_pool, v_pool, src, dst):
         """Device-side page copy (all layers) — the copy-on-write step
@@ -773,10 +800,11 @@ class PagedDecoder:
 
     def _read_page_impl(self, k_pool, v_pool, page):
         """Device -> host leg of page spill (serving/spill.py): one
-        physical page of both pools as [L, 1, ...] leaves. ``page`` is
-        a traced scalar — one compilation covers every spill."""
-        rd = lambda pool: jax.tree_util.tree_map(
-            lambda leaf: self._page_slice(leaf, page), pool)
+        physical page of both pools as [L, 1, ps, g, dh] value leaves
+        (and [L, 1, ps, g] scale leaves). ``page`` is a traced scalar —
+        one compilation covers every spill."""
+        rd = lambda pool: self._page_payload(jax.tree_util.tree_map(
+            lambda leaf: self._page_slice(leaf, page), pool))
         return rd(k_pool), rd(v_pool)
 
     def _write_page_impl(self, k_pool, v_pool, k_page, v_page, page):

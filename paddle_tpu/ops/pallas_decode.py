@@ -56,7 +56,14 @@ ragged length (rounded up to a page). The query carries a W-token
 verify window per slot (speculative decoding + multi-token prefill,
 serving/engine.py), accumulated with the online-softmax recurrence
 across pages. Parity vs. the gather/einsum reference is pinned in
-tests/test_paged_decode.py (GQA/MQA, ragged lengths, W > 1)."""
+tests/test_paged_decode.py (GQA/MQA, ragged lengths, W > 1).
+
+Both paged paths read the pools AS STORED: a page is
+[page_size, g*dh], the kv heads of a token side by side on the lane
+axis, and a pool that keeps its layer axis is handed over whole with
+``layer=`` (the comment above :func:`gather_pages`). The kernel walks
+a page in 128-lane chunks against a block-diagonal q, so nothing is
+padded in HBM or relaid out in VMEM at the serving widths."""
 
 from __future__ import annotations
 
@@ -176,35 +183,41 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, scale=None,
 
 
 # --------------------------------------------------------------- paged
-def gather_pages(pages, page_table):
+# The pool layout (one contract for the kernel, the gather path and
+# models/decode.PagedDecoder, which stores it): a page is
+# [page_size, g*dh] — every kv head of a token side by side on the lane
+# axis, so a page of the serving widths (16 x 2048 bf16) is whole
+# (16, 128) tiles with nothing padded. Pools are [n_pages, page_size,
+# g*dh], or [L, n_pages, page_size, g*dh] with ``layer=`` naming the
+# layer to read: the layer goes into the gather's index / the kernel's
+# block index map, and no array op ever takes ``pool[i]`` out of the
+# pool (a slice of a pool is a pool-sized copy on the chip). The int8
+# layout's scales are [..., n_pages, page_size, g].
+def gather_pages(pages, page_table, layer=None):
     """Contiguous per-sequence view of a paged pool: ``pages``
-    [n_pages, page_size, g, dh] gathered through ``page_table`` [b, P]
-    -> [b, P*page_size, g, dh]. Rows of the table beyond a sequence's
-    allocation point at the reserved null page (0); the caller's length
-    mask keeps those positions out of the softmax."""
+    [n_pages, page_size, g*dh] (or [L, n_pages, page_size, g*dh] with
+    ``layer``) gathered through ``page_table`` [b, P] ->
+    [b, P*page_size, g*dh]; the int8 layout's scales
+    [..., n_pages, page_size, g] gather the same way. Rows of the table
+    beyond a sequence's allocation point at the reserved null page (0);
+    the caller's length mask keeps those positions out of the
+    softmax."""
     b, pp = page_table.shape
-    _, ps, g, dh = pages.shape
-    return pages[page_table].reshape(b, pp * ps, g, dh)
-
-
-def gather_scales(scales, page_table):
-    """Per-row dequant scales gathered like :func:`gather_pages`:
-    ``scales`` [n_pages, page_size, g] through ``page_table`` [b, P]
-    -> [b, P*page_size, g]."""
-    b, pp = page_table.shape
-    _, ps, g = scales.shape
-    return scales[page_table].reshape(b, pp * ps, g)
+    ps, width = pages.shape[-2:]
+    rows = pages[page_table] if layer is None else pages[layer, page_table]
+    return rows.reshape(b, pp * ps, width)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, kv_lens, *,
                     scale=None, use_kernel=False, interpret=False,
-                    k_scales=None, v_scales=None):
+                    k_scales=None, v_scales=None, layer=None):
     """Decode attention over a PAGED KV cache (the serving engine's hot
     path — serving/engine.py).
 
     q [b, h, dh]: one query token per sequence (slot batch);
-    k_pages/v_pages [n_pages, page_size, g, dh]: the shared page pools
-    (h % g == 0 — GQA reads the cache at stored width);
+    k_pages/v_pages [n_pages, page_size, g*dh]: the shared page pools
+    in the stored lane-dense layout (h % g == 0 — GQA reads the cache
+    at stored width), or [L, n_pages, page_size, g*dh] with ``layer``;
     page_table [b, P] int32: each row maps the sequence's logical pages
     to physical pages (entries past the allocation = the null page 0);
     kv_lens [b] int32: per-row valid positions — position kv_lens[i]-1
@@ -218,21 +231,22 @@ def paged_attention(q, k_pages, v_pages, page_table, kv_lens, *,
     transposes the view into the [b, g, dh, T] contract and composes
     with the :func:`decode_attention` GQA kernel."""
     b, h, dh = q.shape
-    g = k_pages.shape[2]
-    assert h % g == 0, (h, g)
+    g = k_pages.shape[-1] // dh
+    assert g * dh == k_pages.shape[-1] and h % g == 0, (
+        h, dh, k_pages.shape)
     rep = h // g
     if scale is None:
         scale = dh ** -0.5
-    k = gather_pages(k_pages, page_table)              # [b, T, g, dh]
-    v = gather_pages(v_pages, page_table)
+    k = gather_pages(k_pages, page_table, layer).reshape(b, -1, g, dh)
+    v = gather_pages(v_pages, page_table, layer).reshape(b, -1, g, dh)
     if k_scales is not None:
         # int8 pools: dequantize the GATHERED view (T rows, not the
         # whole pool) and fall through to the identical exact-einsum
         # formulation — the dequant analogue of the kernel-gate
         # fallback below
-        k = dequantize_kv(k, gather_scales(k_scales, page_table),
+        k = dequantize_kv(k, gather_pages(k_scales, page_table, layer),
                           q.dtype)
-        v = dequantize_kv(v, gather_scales(v_scales, page_table),
+        v = dequantize_kv(v, gather_pages(v_scales, page_table, layer),
                           q.dtype)
     lens = jnp.asarray(kv_lens, jnp.int32).reshape(-1)
     if use_kernel:
@@ -254,26 +268,47 @@ def paged_attention(q, k_pages, v_pages, page_table, kv_lens, *,
 
 
 # ------------------------------------------- allocated-pages kernel
+def _heads_per_chunk(g: int, dh: int) -> int:
+    """How many kv heads one lane chunk of the kernel holds. A chunk is
+    whole 128-lane tiles wherever the shapes allow it: 128 // dh heads
+    of a narrow head (two heads of 64), one head whose dh is a multiple
+    of 128. Shapes that fit neither (toy models: g*dh under 128, odd
+    head dims) run the same code on ONE chunk that is the whole row,
+    padded by the compiler."""
+    hp = 128 // dh if 128 % dh == 0 else 1
+    if (hp * dh) % 128 or g % hp:
+        return g
+    return hp
+
+
 def _paged_window_kernel(tables_ref, used_ref, lens_ref, q_ref, k_ref,
                          v_ref, *rest, scale, rep, page_size, window,
-                         quant):
+                         head_dim, quant):
     """Grid (S, P), page axis fastest. Block p of slot s is the page
     the CLAMPED index map selected — for p >= used[s] that is the same
     physical page as step p-1, so Pallas skips the DMA (the
     allocated-pages traffic contract) and ``pl.when`` skips the math.
-    Online softmax carries (m, l, acc) per (kv group, window row)
-    across the page axis in VMEM scratch.
+    Online softmax carries (m, l, acc) per (lane chunk, row) across the
+    page axis in VMEM scratch.
 
-    q and the output arrive GROUP-MAJOR, [g, W*rep, dh] per slot (the
-    wrapper transposes in XLA), so the body never reshapes across the
-    tiled dims — Mosaic refuses those shape casts at rep > 1. The
-    per-token lengths are the third scalar-prefetch operand: an
-    [S, W] VMEM block of them does not tile.
+    A K/V block is one page as stored, [page_size, g*dh]. The body
+    walks it in lane chunks of C = hp*dh lanes (:func:`_heads_per_chunk`
+    — whole 128-lane tiles at the serving widths, so no chunk is ever
+    sliced or reshaped across a tile). q arrives BLOCK-DIAGONAL per
+    chunk, [n_chunks, hp*W*rep, C] per slot (the wrapper lays it out in
+    XLA): row (j, w, r) holds the query of head (chunk*hp + j)*rep + r
+    at window token w in lanes [j*dh, (j+1)*dh) and zeros elsewhere, so
+    ONE q·kT per chunk gives every head's scores exactly, and one p·v
+    gives every head's output in its own lanes (the other lanes of a
+    row hold another head's values weighted by this row's
+    probabilities: finite, and dropped by the wrapper). The per-token
+    lengths are the third scalar-prefetch operand: an [S, W] VMEM block
+    of them does not tile.
 
     ``quant`` is the dequant-FUSED variant: two more inputs carry the
     per-row scales (their blocks ride the same clamped map, so a
     skipped page DMA skips its scale DMA too) and the rescale
-    ``int8 * scale`` runs in VMEM right after the K/V block lands, so
+    ``int8 * scale`` runs in VMEM right after the K/V chunk lands, so
     the HBM read is 1 byte/element + 4 bytes/row instead of the float
     pool's 2-4 bytes/element."""
     if quant:
@@ -283,8 +318,12 @@ def _paged_window_kernel(tables_ref, used_ref, lens_ref, q_ref, k_ref,
     p = pl.program_id(1)
     s = pl.program_id(0)
     used = used_ref[s]
-    g = m_ref.shape[0]
-    wr = m_ref.shape[1]                                # window * rep
+    n_chunks, rows_n, C = acc_ref.shape
+    wr = window * rep
+    hp = rows_n // wr
+    # the pools may keep their layer axis: the block is then
+    # [1, 1, page_size, g*dh] and the index map chose the layer
+    page = (0,) * (len(k_ref.shape) - 2)
 
     @pl.when(p == 0)
     def _init():
@@ -294,47 +333,63 @@ def _paged_window_kernel(tables_ref, used_ref, lens_ref, q_ref, k_ref,
 
     @pl.when(p < used)
     def _accumulate():
-        k = k_ref[0].astype(jnp.float32)               # [ps, g, dh]
-        v = v_ref[0].astype(jnp.float32)
-        if quant:
-            # fused dequant: [ps, g, dh] int8 * [ps, g, 1] f32 scales
-            k = k * ks_ref[0].astype(jnp.float32)[..., None]
-            v = v * vs_ref[0].astype(jnp.float32)[..., None]
         # per-token causal/ragged mask against ABSOLUTE positions:
-        # page p covers [p*ps, (p+1)*ps); row r is window token
-        # r // rep and sees < lens[s, r // rep]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (wr, page_size), 0)
+        # page p covers [p*ps, (p+1)*ps); row (j, w, r) is window token
+        # w and sees < lens[s, w]
         cols = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (wr, page_size), 1)
-        lim = jnp.full((wr, page_size), lens_ref[s, 0], jnp.int32)
-        for w in range(1, window):
-            lim = jnp.where(rows >= w * rep, lens_ref[s, w], lim)
+            jnp.int32, (rows_n, page_size), 1)
+        lim = jnp.full((rows_n, page_size), lens_ref[s, 0], jnp.int32)
+        if window > 1:
+            rows = jax.lax.broadcasted_iota(
+                jnp.int32, (rows_n, page_size), 0)
+            for j in range(hp):
+                for w in range(window):
+                    lim = jnp.where(rows >= j * wr + w * rep,
+                                    lens_ref[s, w], lim)
         live = cols < lim
-        for gi in range(g):
-            kg = k[:, gi, :]                           # [ps, dh]
-            vg = v[:, gi, :]
-            qg = q_ref[0, gi].astype(jnp.float32)      # [wr, dh]
+        if quant:
+            lane = jax.lax.broadcasted_iota(jnp.int32, (page_size, C), 1)
+            ks = ks_ref[page].astype(jnp.float32)      # [ps, g]
+            vs = vs_ref[page].astype(jnp.float32)
+
+        def rescale(x, sc, c):
+            # fused dequant: head j of the chunk owns lanes
+            # [j*dh, (j+1)*dh) and its own [ps, 1] column of scales
+            col = sc[:, c * hp:c * hp + 1]
+            for j in range(1, hp):
+                col = jnp.where(lane >= j * head_dim,
+                                sc[:, c * hp + j:c * hp + j + 1], col)
+            return x * col
+
+        for c in range(n_chunks):
+            lanes = (slice(None), slice(c * C, (c + 1) * C))
+            kc = k_ref[page + lanes].astype(jnp.float32)   # [ps, C]
+            vc = v_ref[page + lanes].astype(jnp.float32)
+            if quant:
+                kc = rescale(kc, ks, c)
+                vc = rescale(vc, vs, c)
+            qc = q_ref[0, c].astype(jnp.float32)       # [rows_n, C]
             sc = jax.lax.dot_general(
-                qg, kg, (((1,), (1,)), ((), ())),
+                qc, kc, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * (scale * LOG2E)
-            sc = jnp.where(live, sc, NEG_INF)          # [wr, ps]
-            m_prev = m_ref[gi]                         # [wr, 1]
+            sc = jnp.where(live, sc, NEG_INF)          # [rows_n, ps]
+            m_prev = m_ref[c]                          # [rows_n, 1]
             m_cur = jnp.maximum(m_prev,
                                 jnp.max(sc, axis=1, keepdims=True))
             alpha = jnp.exp2(m_prev - m_cur)
-            pm = jnp.exp2(sc - m_cur)                  # [wr, ps]
-            l_ref[gi] = l_ref[gi] * alpha + \
+            pm = jnp.exp2(sc - m_cur)                  # [rows_n, ps]
+            l_ref[c] = l_ref[c] * alpha + \
                 jnp.sum(pm, axis=1, keepdims=True)
-            acc_ref[gi] = acc_ref[gi] * alpha + jax.lax.dot_general(
-                pm, vg, (((1,), (0,)), ((), ())),
+            acc_ref[c] = acc_ref[c] * alpha + jax.lax.dot_general(
+                pm, vc, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_ref[gi] = m_cur
+            m_ref[c] = m_cur
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _finalize():
         # fully-masked rows (lens 0 never happens live; engine clamps
         # masked tokens to kv_len >= 1) still divide by a finite l
-        l = jnp.maximum(l_ref[...], 1e-30)             # [g, wr, 1]
+        l = jnp.maximum(l_ref[...], 1e-30)             # [n_chunks, rows, 1]
         out_ref[0] = (acc_ref[...] / l).astype(out_ref.dtype)
 
 
@@ -344,16 +399,17 @@ def _round_up(n: int, m: int) -> int:
 
 # What the chip's compiler takes (v5e:2x2 described in the sandbox,
 # tests/test_chip_compile.py). One grid step holds every K/V block
-# twice (Pallas double-buffers) plus a float32 working copy, each
-# padded to whole (sublane, 128-lane) tiles over its (g, dh) dims,
-# against a 16 MiB scoped VMEM limit: past it the compiler answers
-# "Ran out of memory in memory space vmem" (f32 g32 dh128 pages of 256
-# rows; int8 pages of 512 rows, for their dequantized copies; bf16 g2
-# pages of 4096 rows). The budget is kept under what was seen to
-# compile, not at the limit: the compiler's own temporaries are not
-# modelled. The scalar-prefetched page tables and lengths live in
-# 1 MiB of SMEM ("Ran out of memory in memory space smem" at
-# [256, 1024] tables).
+# twice (Pallas double-buffers), each padded to whole (sublane,
+# 128-lane) tiles over its (page_size, g*dh) dims, plus the float32
+# working copies of one lane chunk, against a 16 MiB scoped VMEM
+# limit: past it the compiler answers "Ran out of memory in memory
+# space vmem" (f32 g32 dh128 pages of 256 rows: 16 MiB of K/V blocks
+# alone). The budget is kept under what was seen to compile, not at
+# the limit — the compiler's own temporaries are not modelled, and the
+# gate turns away some shapes that would compile (int8 g8 dh64 pages of
+# 2048 rows, bf16 g2 dh64 pages of 4096 rows). The scalar-prefetched
+# page tables and lengths live in 1 MiB of SMEM ("Ran out of memory in
+# memory space smem" at [256, 1024] tables).
 _PAGED_VMEM_BYTES = 12 * 1024 * 1024
 _PAGED_SMEM_BYTES = 960 * 1024
 
@@ -362,35 +418,49 @@ def paged_kernel_supported(q, k_pages, k_scales=None,
                            pages_per_slot: int = 1) -> bool:
     """Gate for the allocated-pages kernel — "supported" means the
     kernel LOWERS on the chip for these shapes: a sublane-multiple
-    head dim, one page step's K+V (+ per-row scales of the int8
-    two-tier layout) inside the VMEM budget, and the [S, P] page
-    tables plus [S, W] lengths inside SMEM."""
-    S = q.shape[0]
-    ps, g, dh = k_pages.shape[1:]
+    head dim, one page step's K+V blocks ([page_size, g*dh] as stored,
+    + per-row scales of the int8 two-tier layout) inside the VMEM
+    budget, and the [S, P] page tables plus [S, W] lengths inside
+    SMEM. ``k_pages`` is a pool (or its ShapeDtypeStruct) in the
+    stored layout, with or without the layer axis."""
+    S, W, h, dh = q.shape
+    ps, gd = k_pages.shape[-2:]
+    g = gd // dh
+    if dh % 8 or g * dh != gd or h % g:
+        return False
     esize = jnp.dtype(k_pages.dtype).itemsize
-    lanes = _round_up(dh, 128)
-    stored = _round_up(g, 32 // esize) * lanes * esize
-    working = _round_up(g, 8) * lanes * 4
-    vmem = 2 * ps * (2 * stored + working)
+    hp = _heads_per_chunk(g, dh)
+    chunk = _round_up(hp * dh, 128)
+    rows = _round_up(hp * W * (h // g), 8)
+    stored = _round_up(ps, 32 // esize) * _round_up(gd, 128) * esize
+    working = _round_up(ps, 8) * chunk * 4
+    # K and V double-buffered, their chunk's f32 copies, and per chunk
+    # the block-diagonal q (double-buffered, at q's width), the output
+    # block and the f32 accumulator
+    vmem = 2 * 2 * stored + 4 * working + (g // hp) * rows * chunk * (
+        4 * jnp.dtype(q.dtype).itemsize + 4)
     if k_scales is not None:
-        vmem += 2 * 2 * ps * _round_up(g, 128) * \
+        vmem += 2 * 2 * _round_up(ps, 8) * _round_up(g, 128) * \
             jnp.dtype(k_scales.dtype).itemsize
     smem = 4 * S * (_round_up(pages_per_slot, 128) + 128 + 1)
-    return (dh % 8 == 0 and vmem <= _PAGED_VMEM_BYTES
-            and smem <= _PAGED_SMEM_BYTES)
+    return vmem <= _PAGED_VMEM_BYTES and smem <= _PAGED_SMEM_BYTES
 
 
 def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
                            *, scale=None, use_kernel=False,
                            interpret=False, k_scales=None,
-                           v_scales=None):
+                           v_scales=None, layer=None):
     """Decode attention over the paged pool for a W-token window per
     slot (W = 1 is the classic one-token step; the speculative engine
     feeds W = spec_k + 1 — serving/engine.py).
 
-    q [S, W, h, dh]; k_pages/v_pages [n_pages, page_size, g, dh];
-    page_tables [S, P] int32; kv_lens [S, W] int32 per-TOKEN valid
-    lengths (token w of slot s is the query at position
+    q [S, W, h, dh]; k_pages/v_pages [n_pages, page_size, g*dh] — the
+    pools AS STORED, every kv head of a token side by side on the lane
+    axis — or the whole [L, n_pages, page_size, g*dh] pools with
+    ``layer`` (a Python int) naming the layer: it goes into the
+    kernel's block index map (the gather's index), never into a slice
+    of the pool; page_tables [S, P] int32; kv_lens [S, W] int32
+    per-TOKEN valid lengths (token w of slot s is the query at position
     kv_lens[s, w] - 1 — the mask is causal within the window too,
     because earlier window tokens' K/V were scattered before this
     call). Returns [S, W, h, dh].
@@ -403,17 +473,20 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
     revisited blocks skip their DMA, and cache-read traffic is
     ceil(len/page_size) pages instead of P.
 
-    ``k_scales``/``v_scales`` [n_pages, page_size, g] switch the pools
-    to the INT8 two-tier layout (:func:`quantize_kv` rows): the gather
-    path dequantizes the gathered view then runs the same exact einsum
-    (the dequant analogue of the existing kernel-gate fallback), and
-    the kernel path runs :func:`_paged_window_kernel` with ``quant``,
-    which fuses the per-row rescale into the online-softmax page walk —
-    int8 K/V never round-trips through HBM at float width."""
+    ``k_scales``/``v_scales`` [n_pages, page_size, g] (or [L, ...])
+    switch the pools to the INT8 two-tier layout (:func:`quantize_kv`
+    rows): the gather path dequantizes the gathered view then runs the
+    same exact einsum (the dequant analogue of the existing kernel-gate
+    fallback), and the kernel path runs :func:`_paged_window_kernel`
+    with ``quant``, which fuses the per-row rescale into the
+    online-softmax page walk — int8 K/V never round-trips through HBM
+    at float width."""
     S, W, h, dh = q.shape
-    n_pages, ps, g, _ = k_pages.shape
+    ps, gd = k_pages.shape[-2:]
+    g = gd // dh
     P = page_tables.shape[1]
-    assert h % g == 0, (h, g)
+    assert g * dh == gd and h % g == 0, (h, dh, k_pages.shape)
+    assert (layer is None) == (k_pages.ndim == 3), (layer, k_pages.shape)
     rep = h // g
     if scale is None:
         scale = dh ** -0.5
@@ -423,52 +496,58 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
         out = paged_attention(
             q.reshape(S * W, h, dh), k_pages, v_pages,
             jnp.repeat(page_tables, W, axis=0), lens.reshape(-1),
-            scale=scale, k_scales=k_scales, v_scales=v_scales)
+            scale=scale, k_scales=k_scales, v_scales=v_scales,
+            layer=layer)
         return out.reshape(S, W, h, dh)
     # pages actually holding live KV for each slot (>= 1 so the null
     # page still feeds the pipeline for idle slots)
     used = jnp.clip(-(-jnp.max(lens, axis=1) // ps), 1, P)
+    hp = _heads_per_chunk(g, dh)
+    n_chunks, C, rows_n = g // hp, hp * dh, hp * W * rep
+    lead = () if layer is None else (layer,)
 
     def _slot_map(si, pi, tables, used_, lens_):
         return (si, 0, 0, 0)
 
     def _table_map(si, pi, tables, used_, lens_):
-        return (tables[si, jnp.minimum(pi, used_[si] - 1)], 0, 0, 0)
-
-    def _scale_map(si, pi, tables, used_, lens_):
-        return (tables[si, jnp.minimum(pi, used_[si] - 1)], 0, 0)
+        return lead + (tables[si, jnp.minimum(pi, used_[si] - 1)], 0, 0)
 
     kernel = functools.partial(
         _paged_window_kernel, scale=scale, rep=rep, page_size=ps,
-        window=W, quant=quant)
+        window=W, head_dim=dh, quant=quant)
+    one = (1,) * (len(lead) + 1)
     in_specs = [
-        pl.BlockSpec((1, g, W * rep, dh), _slot_map),
-        pl.BlockSpec((1, ps, g, dh), _table_map),
-        pl.BlockSpec((1, ps, g, dh), _table_map),
+        pl.BlockSpec((1, n_chunks, rows_n, C), _slot_map),
+        pl.BlockSpec(one + (ps, gd), _table_map),
+        pl.BlockSpec(one + (ps, gd), _table_map),
     ]
-    # group-major rows: [S, W, (g, rep), dh] -> [S, g, (W, rep), dh]
-    qg = q.reshape(S, W, g, rep, dh).transpose(0, 2, 1, 3, 4) \
-        .reshape(S, g, W * rep, dh)
+    # rows (j, w, r) of chunk c, block-diagonal over the chunk's heads:
+    # [S, W, (c, j, r), dh] -> [S, c, (j, w, r), (j', dh)]
+    qc = q.reshape(S, W, n_chunks, hp, rep, dh).transpose(0, 2, 3, 1, 4, 5)
+    eye = jnp.eye(hp, dtype=q.dtype)[:, None, None, :, None]
+    qd = (qc[:, :, :, :, :, None, :] * eye).reshape(S, n_chunks, rows_n, C)
     operands = [jnp.asarray(page_tables, jnp.int32),
-                used.astype(jnp.int32), lens, qg, k_pages, v_pages]
+                used.astype(jnp.int32), lens, qd, k_pages, v_pages]
     if quant:
-        in_specs += [pl.BlockSpec((1, ps, g), _scale_map),
-                     pl.BlockSpec((1, ps, g), _scale_map)]
+        in_specs += [pl.BlockSpec(one + (ps, g), _table_map),
+                     pl.BlockSpec(one + (ps, g), _table_map)]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g, W * rep, dh), _slot_map),
+        out_specs=pl.BlockSpec((1, n_chunks, rows_n, C), _slot_map),
         scratch_shapes=[
-            pltpu.VMEM((g, W * rep, 1), jnp.float32),
-            pltpu.VMEM((g, W * rep, 1), jnp.float32),
-            pltpu.VMEM((g, W * rep, dh), jnp.float32),
+            pltpu.VMEM((n_chunks, rows_n, 1), jnp.float32),
+            pltpu.VMEM((n_chunks, rows_n, 1), jnp.float32),
+            pltpu.VMEM((n_chunks, rows_n, C), jnp.float32),
         ])
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, g, W * rep, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, n_chunks, rows_n, C), q.dtype),
         interpret=interpret, name="paged_window_attention",
     )(*operands)
-    return out.reshape(S, g, W, rep, dh).transpose(0, 2, 1, 3, 4) \
-        .reshape(S, W, h, dh)
+    # head j's output sits in lanes [j*dh, (j+1)*dh) of its own rows
+    out = jnp.diagonal(out.reshape(S, n_chunks, hp, W, rep, hp, dh),
+                       axis1=2, axis2=5)            # [S, c, W, rep, dh, j]
+    return out.transpose(0, 2, 1, 5, 3, 4).reshape(S, W, h, dh)

@@ -9,8 +9,9 @@ module is the production loop those papers (Orca's iteration-level
 scheduling, PagedAttention's block-pooled KV) built for serving LLMs:
 
 - ``PagePool``: a host-side REFCOUNTED free-list over preallocated
-  device page pools ([L, n_pages, page_size, g, dh] —
-  models/decode.PagedDecoder). KV memory is pooled across ALL requests
+  device page pools ([L, n_pages, page_size, g*dh], the layout the
+  paged kernel reads, updated in place — models/decode.PagedDecoder;
+  the engine holds them as opaque pytrees). KV memory is pooled across ALL requests
   in fixed-size pages, so admission is a pages-free check, not a
   worst-case-length reservation. A page may be owned by several slots
   AND the prefix trie at once; it returns to the free list only at

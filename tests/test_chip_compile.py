@@ -52,17 +52,23 @@ def cache_off():
 
 
 @pytest.fixture
-def chip_compile(one_chip, cache_off):
-    """compile(fn, *shape_structs) -> HLO text of ``fn`` compiled for
-    one described v5e chip. Raises what the chip's compiler raises."""
+def chip_executable(one_chip, cache_off):
+    """compile(fn, *shape_structs, donate=()) -> ``fn`` compiled for one
+    described v5e chip. Raises what the chip's compiler raises."""
     def on_chip(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
-    def compile_(fn, *args):
-        return jax.jit(fn).lower(
-            *jax.tree_util.tree_map(on_chip, args)).compile().as_text()
+    def compile_(fn, *args, donate=()):
+        return jax.jit(fn, donate_argnums=donate).lower(
+            *jax.tree_util.tree_map(on_chip, args)).compile()
 
     return compile_
+
+
+@pytest.fixture
+def chip_compile(chip_executable):
+    """compile(fn, *shape_structs) -> the HLO text of that executable."""
+    return lambda fn, *args: chip_executable(fn, *args).as_text()
 
 
 def _sds(shape, dtype):
@@ -146,7 +152,8 @@ def test_fused_rnn_h1280(chip_compile, bf16_compute, cell, grad):
 def _paged_structs(S, W, h, g, dh, ps, P, quant, dtype):
     n_pages = S * P + 1
     q = _sds((S, W, h, dh), dtype)
-    pages = _sds((n_pages, ps, g, dh), jnp.int8 if quant else dtype)
+    # the pools' stored layout: the kv heads side by side on the lanes
+    pages = _sds((n_pages, ps, g * dh), jnp.int8 if quant else dtype)
     scales = _sds((n_pages, ps, g), jnp.float32) if quant else None
     return (q, pages, scales, _sds((S, P), jnp.int32),
             _sds((S, W), jnp.int32))
@@ -225,3 +232,86 @@ def test_paged_decoder_step_at_benchmark_width(chip_compile, monkeypatch):
                        sw, sw, _sds((8, 34), jnp.int32),
                        _sds((8, 1), jnp.bool_), _sds((2,), jnp.uint32))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.fixture(scope="module")
+def opt13b_decoder():
+    """OPT-1.3B's served decoder (24 layers, 32 heads of 64, vocab 50272,
+    bf16) over zero weights: only its shapes are compiled."""
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.core import registry
+
+    registry.reset_name_counters()
+    paddle.init(use_tpu=False, seed=0)
+    spec = models.transformer_lm(vocab_size=50272, d_model=2048, n_heads=32,
+                                 n_layers=24, d_ff=8192, max_len=2048,
+                                 tie_embeddings=True)
+    shapes = jax.eval_shape(paddle.Topology(spec.cost).init_params,
+                            jax.random.PRNGKey(0))
+    params = {k: np.zeros(v.shape, jnp.bfloat16) for k, v in shapes.items()}
+    return models.TransformerDecoder(params, n_layers=24, n_heads=32)
+
+
+def _pool_sized_ops(hlo, pool_shape):
+    """[(opcode, line)] of every instruction whose result is a whole
+    pool or one layer of it."""
+    import re
+    n_layers, rest = pool_shape[0], ",".join(map(str, pool_shape[1:]))
+    sized = re.compile(
+        r"= \w+\[(?:(?:1|%d),)?%s\]\S* ([\w\-]+)\(" % (n_layers, rest))
+    return [(m.group(1), line) for line in hlo.splitlines()
+            for m in [sized.search(line)] if m]
+
+
+@pytest.mark.parametrize("num_pages", [896, 1536],
+                         ids=["pages896", "pages1536"])
+def test_serving_step_updates_the_pools_in_place(
+        opt13b_decoder, chip_executable, monkeypatch, num_pages):
+    """The benchmark's serving step (32 slots, page 16, bf16, the pools
+    donated as the engine donates them): the pools stay in the layout
+    the kernel's blocks read and are written in place, so the compiled
+    step holds no copy and no slice of a pool or of one layer of it, and
+    its temporaries are far under the pools' bytes (they were 3 x the
+    pools, and 1536 pages did not compile, while the pool was
+    [L, N, page, g, dh])."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paged = opt13b_decoder.paged(num_slots=32, page_size=16,
+                                 num_pages=num_pages,
+                                 max_pages_per_slot=128, warm_start=False)
+    assert paged.use_kernel and not paged.kernel_interpret
+    k_pool, v_pool = jax.eval_shape(paged.init_pools)
+    sw = _sds((32, 1), jnp.int32)
+    args = (paged.dense.p, k_pool, v_pool, sw, sw, _sds((32, 128), jnp.int32),
+            _sds((32, 1), jnp.bool_), _sds((2,), jnp.uint32))
+    compiled = chip_executable(paged._step_impl, *args, donate=(1, 2))
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 24
+    # what may have a pool's shape: the pools themselves and the 48
+    # in-place row scatters of the kv_write scope
+    in_place = ("parameter", "get-tuple-element", "tuple", "bitcast",
+                "scatter")
+    strays = [line.strip()[:160]
+              for op, line in _pool_sized_ops(hlo, k_pool.shape)
+              if op not in in_place
+              and not (op == "fusion" and "kv_write/scatter" in line)]
+    assert not strays, strays
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == paged.pool_bytes()
+    assert mem.temp_size_in_bytes < paged.pool_bytes() // 8, mem
+    if num_pages != 896:
+        return
+    # the engine's three small page programs (copy-on-write, spill,
+    # restore) move one page and keep no copy of a pool either
+    page = _sds((), jnp.int32)
+    k_page, v_page = jax.eval_shape(paged._read_page_impl, k_pool, v_pool,
+                                    page)
+    for fn, donate, xs in (
+            (paged._copy_page_impl, (0, 1), (k_pool, v_pool, page, page)),
+            (paged._read_page_impl, (), (k_pool, v_pool, page)),
+            (paged._write_page_impl, (0, 1),
+             (k_pool, v_pool, k_page, v_page, page))):
+        mem = chip_executable(fn, *xs, donate=donate).memory_analysis()
+        assert mem.temp_size_in_bytes < k_page.size * 2 * 4, (fn, mem)
+        assert mem.alias_size_in_bytes == (
+            paged.pool_bytes() if donate else 0), (fn, mem)

@@ -51,6 +51,13 @@ def _ragged(rng, n, lo=3, hi=9):
             for _ in range(n)]
 
 
+def _stored(pages):
+    """[n_pages, page_size, g, dh] pages as the pools store them:
+    [n_pages, page_size, g*dh], the kv heads side by side on the lane
+    axis (ops/pallas_decode.py, the pool layout)."""
+    return jax.numpy.asarray(pages).reshape(pages.shape[:2] + (-1,))
+
+
 def _balanced(eng):
     """Zero leaks, zero refcount drift. With the prefix cache on (the
     default) a drained engine parks finished pages in the trie, so the
@@ -95,9 +102,8 @@ class TestPagedAttentionUnit:
                           [5, 6, 0, 0, 0]], np.int32)
         lens = np.array([9, 17, 5], np.int32)   # ragged, straddling
         got = np.asarray(paged_attention(
-            jax.numpy.asarray(q), jax.numpy.asarray(k_pages),
-            jax.numpy.asarray(v_pages), jax.numpy.asarray(table),
-            jax.numpy.asarray(lens)))
+            jax.numpy.asarray(q), _stored(k_pages), _stored(v_pages),
+            jax.numpy.asarray(table), jax.numpy.asarray(lens)))
         k = k_pages[table].reshape(b, P * ps, g, dh)
         v = v_pages[table].reshape(b, P * ps, g, dh)
         want = self._reference(q, k, v, lens)
@@ -109,10 +115,8 @@ class TestPagedAttentionUnit:
         from paddle_tpu.ops.pallas_decode import paged_attention
         rng = np.random.RandomState(1)
         b, h, g, dh, ps, npages, P = 2, 4, 2, 8, 4, 8, 4
-        k_pages = jax.numpy.asarray(
-            rng.randn(npages, ps, g, dh).astype(np.float32))
-        v_pages = jax.numpy.asarray(
-            rng.randn(npages, ps, g, dh).astype(np.float32))
+        k_pages = _stored(rng.randn(npages, ps, g, dh).astype(np.float32))
+        v_pages = _stored(rng.randn(npages, ps, g, dh).astype(np.float32))
         q = jax.numpy.asarray(rng.randn(b, h, dh).astype(np.float32))
         table = jax.numpy.asarray(
             np.array([[1, 4, 2, 0], [3, 5, 0, 0]], np.int32))
@@ -167,7 +171,7 @@ class TestPagedWindowKernel:
         base = np.array([9, 15, 5], np.int32)     # ragged, mid-page
         lens = (base[:, None] + np.arange(W)[None, :]).astype(np.int32)
         args = [jax.numpy.asarray(a) for a in
-                (q, k_pages, v_pages, tables, lens)]
+                (q, _stored(k_pages), _stored(v_pages), tables, lens)]
         want = np.asarray(paged_window_attention(*args))
         got = np.asarray(paged_window_attention(
             *args, use_kernel=True, interpret=True))
@@ -180,10 +184,8 @@ class TestPagedWindowKernel:
                                                   paged_window_attention)
         rng = np.random.RandomState(4)
         S, h, g, dh, ps, npages = 2, 4, 2, 8, 4, 8
-        k_pages = jax.numpy.asarray(
-            rng.randn(npages, ps, g, dh).astype(np.float32))
-        v_pages = jax.numpy.asarray(
-            rng.randn(npages, ps, g, dh).astype(np.float32))
+        k_pages = _stored(rng.randn(npages, ps, g, dh).astype(np.float32))
+        v_pages = _stored(rng.randn(npages, ps, g, dh).astype(np.float32))
         q = jax.numpy.asarray(rng.randn(S, h, dh).astype(np.float32))
         tables = jax.numpy.asarray(
             np.array([[1, 4, 2, 0], [3, 5, 0, 0]], np.int32))
@@ -198,11 +200,15 @@ class TestPagedWindowKernel:
     def test_kernel_gate(self):
         from paddle_tpu.ops.pallas_decode import paged_kernel_supported
         q = jax.numpy.zeros((2, 2, 4, 8), np.float32)
-        k = jax.numpy.zeros((8, 4, 2, 8), np.float32)
+        k = jax.numpy.zeros((8, 4, 2 * 8), np.float32)
         assert paged_kernel_supported(q, k)
+        # the gate reads the stored layout with its layer axis too
+        assert paged_kernel_supported(
+            q, jax.numpy.zeros((3, 8, 4, 2 * 8), np.float32))
         # head dim off the sublane multiple -> fall back to XLA
-        k_odd = jax.numpy.zeros((8, 4, 2, 6), np.float32)
-        assert not paged_kernel_supported(q, k_odd)
+        q_odd = jax.numpy.zeros((2, 2, 4, 6), np.float32)
+        k_odd = jax.numpy.zeros((8, 4, 2 * 6), np.float32)
+        assert not paged_kernel_supported(q_odd, k_odd)
 
 
 class TestDequantWindowKernel:
@@ -220,7 +226,7 @@ class TestDequantWindowKernel:
         v = rng.randn(npages, ps, g, dh).astype(np.float32)
         kq, ks = quantize_kv(jax.numpy.asarray(k))
         vq, vs = quantize_kv(jax.numpy.asarray(v))
-        return k, v, kq, ks, vq, vs
+        return k, v, _stored(kq), ks, _stored(vq), vs
 
     @pytest.mark.parametrize("h,g", [(4, 4), (4, 2), (4, 1)])
     def test_dequant_kernel_matches_gather_path(self, h, g):
@@ -266,8 +272,7 @@ class TestDequantWindowKernel:
         lens = jax.numpy.asarray(
             (base[:, None] + np.arange(W)[None, :]).astype(np.int32))
         exact = np.asarray(paged_window_attention(
-            q, jax.numpy.asarray(k), jax.numpy.asarray(v),
-            tables, lens))
+            q, _stored(k), _stored(v), tables, lens))
         for use_kernel in (False, True):
             got = np.asarray(paged_window_attention(
                 q, kq, vq, tables, lens, k_scales=ks, v_scales=vs,
@@ -303,13 +308,194 @@ class TestDequantWindowKernel:
     def test_gate_counts_scale_blocks(self):
         from paddle_tpu.ops.pallas_decode import paged_kernel_supported
         q = jax.numpy.zeros((2, 2, 4, 8), np.float32)
-        k8 = jax.numpy.zeros((8, 4, 2, 8), jax.numpy.int8)
+        k8 = jax.numpy.zeros((8, 4, 2 * 8), jax.numpy.int8)
         sc = jax.numpy.zeros((8, 4, 2), np.float32)
         assert paged_kernel_supported(q, k8, sc)
         # odd head dim still falls back, scales or not
-        k_odd = jax.numpy.zeros((8, 4, 2, 6), jax.numpy.int8)
+        q_odd = jax.numpy.zeros((2, 2, 4, 6), np.float32)
+        k_odd = jax.numpy.zeros((8, 4, 2 * 6), jax.numpy.int8)
         assert not paged_kernel_supported(
-            q, k_odd, jax.numpy.zeros((8, 4, 2), np.float32))
+            q_odd, k_odd, jax.numpy.zeros((8, 4, 2), np.float32))
+
+
+class TestStoredPoolLayout:
+    """ISSUE 32: the pools live in ONE layout, the one the kernel's
+    blocks read — [L, n_pages, page_size, g*dh], the kv heads side by
+    side on the lane axis — and the layer rides in the kernel's block
+    index / the gather's index, never in a slice of the pool. Pins the
+    kernel's 128-lane chunk paths (two heads of 64 a chunk with q laid
+    out block-diagonally; one head of 128 a chunk) that the toy widths
+    above never reach, and the small page programs on the stored
+    layout: copy, read, write, spill payload."""
+
+    def _case(self, rng, S, W, h, g, dh, ps, P, L=2):
+        npages = S * P + 1
+        k = rng.randn(L, npages, ps, g * dh).astype(np.float32)
+        v = rng.randn(L, npages, ps, g * dh).astype(np.float32)
+        q = rng.randn(S, W, h, dh).astype(np.float32)
+        tables = rng.permutation(np.arange(1, npages)).reshape(S, P)
+        tables[0, 2:] = 0                  # a short slot: null tail
+        base = rng.randint(1, P * ps - W, (S,))
+        base[0] = ps + 3                   # ... that ends mid-page 1
+        lens = base[:, None] + np.arange(W)[None, :]
+        return [jax.numpy.asarray(a) for a in
+                (q, k, v, tables.astype(np.int32), lens.astype(np.int32))]
+
+    @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+    @pytest.mark.parametrize("W", [1, 3], ids=["W1", "W3"])
+    @pytest.mark.parametrize("h,g,dh", [(8, 4, 64), (4, 4, 64), (4, 2, 128),
+                                        (6, 3, 64), (4, 2, 32)],
+                             ids=["gqa-2x64", "mha-2x64", "gqa-1x128",
+                                  "odd-g-row", "4x32"])
+    def test_chunked_kernel_matches_gather_with_layer(self, h, g, dh, W,
+                                                      quant):
+        """Kernel (interpret mode) against gather on the whole
+        [L, N, ps, g*dh] pool with ``layer=``, and both against the
+        gather over that layer handed alone as [N, ps, g*dh]."""
+        from paddle_tpu.ops.pallas_decode import (_heads_per_chunk,
+                                                  paged_window_attention,
+                                                  quantize_kv)
+        # the cases cover the chunk shapes by name: 2 heads of 64, one
+        # of 128, 4 of 32, and (g = 3) the whole row as one chunk
+        assert _heads_per_chunk(g, dh) == {"64": 2 if g % 2 == 0 else 3,
+                                           "128": 1, "32": g}[str(dh)]
+        rng = np.random.RandomState(21)
+        q, k, v, tables, lens = self._case(rng, 3, W, h, g, dh, 4, 5)
+        kw = {}
+        if quant:
+            def quantize(pool):
+                qv, sc = quantize_kv(pool.reshape(pool.shape[:-1] + (g, dh)))
+                return qv.reshape(pool.shape), sc
+            (k, ks), (v, vs) = quantize(k), quantize(v)
+            kw = dict(k_scales=ks, v_scales=vs)
+        for layer in (0, 1):
+            want = np.asarray(paged_window_attention(
+                q, k, v, tables, lens, layer=layer, **kw))
+            alone = np.asarray(paged_window_attention(
+                q, k[layer], v[layer], tables, lens,
+                **{n: a[layer] for n, a in kw.items()}))
+            np.testing.assert_array_equal(alone, want)
+        got = np.asarray(paged_window_attention(
+            q, k, v, tables, lens, layer=1, use_kernel=True,
+            interpret=True, **kw))
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+    def _paged(self, kv_quant, **over):
+        params = _model(**over)
+        dec = models.TransformerDecoder(
+            params, n_layers=CFG["n_layers"],
+            n_heads=over.get("n_heads", CFG["n_heads"]))
+        paged = dec.paged(num_slots=2, page_size=4, num_pages=6,
+                          max_pages_per_slot=4, warm_start=False,
+                          kv_quant=kv_quant)
+        rng = np.random.RandomState(22)
+        pools = jax.tree_util.tree_map(
+            lambda z: jax.numpy.asarray(
+                rng.randint(-100, 100, z.shape).astype(z.dtype)),
+            paged.init_pools())
+        return paged, pools
+
+    @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["fp", "int8"])
+    def test_pool_shapes_and_bytes(self, kv_quant):
+        paged, (k_pool, v_pool) = self._paged(kv_quant)
+        L, g, dh = CFG["n_layers"], paged.kv_heads, paged.head_dim
+        values = k_pool["q"] if kv_quant else k_pool
+        assert values.shape == (L, 6, 4, g * dh)
+        if kv_quant:
+            assert k_pool["s"].shape == (L, 6, 4, g)
+        assert paged.pool_bytes() == sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(
+                (k_pool, v_pool)))
+        assert "g*dh" in paged.POOL_LAYOUT
+
+    @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["fp", "int8"])
+    def test_read_write_page_round_trip(self, kv_quant):
+        """write_page(read_page(p)) is the identity, the payload keeps
+        the shape the spill codec has always had ([L, 1, ps, g, dh]
+        values, [L, 1, ps, g] scales), and a page written elsewhere
+        lands there and nowhere else."""
+        paged, (k_pool, v_pool) = self._paged(kv_quant)
+        L, g, dh = CFG["n_layers"], paged.kv_heads, paged.head_dim
+        before = jax.tree_util.tree_map(np.asarray, (k_pool, v_pool))
+        k_page, v_page = paged.read_page(k_pool, v_pool, 3)
+        values = k_page["q"] if kv_quant else k_page
+        assert values.shape == (L, 1, 4, g, dh)
+        if kv_quant:
+            assert set(k_page) == {"q", "s"}
+            assert k_page["s"].shape == (L, 1, 4, g)
+        stored = before[0]["q"] if kv_quant else before[0]
+        np.testing.assert_array_equal(
+            np.asarray(values).reshape(L, 4, g * dh), stored[:, 3])
+        same = paged.write_page(k_pool, v_pool, k_page, v_page, 3)
+        jax.tree_util.tree_map(np.testing.assert_array_equal,
+                               jax.tree_util.tree_map(np.asarray, same),
+                               before)
+        moved = jax.tree_util.tree_map(
+            np.asarray, paged.write_page(k_pool, v_pool, k_page, v_page, 5))
+
+        def check(after, was):
+            np.testing.assert_array_equal(after[:, 5], was[:, 3])
+            np.testing.assert_array_equal(after[:, :5], was[:, :5])
+
+        jax.tree_util.tree_map(check, moved, before)
+
+    @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["fp", "int8"])
+    def test_copy_page_on_stored_pools(self, kv_quant):
+        paged, (k_pool, v_pool) = self._paged(kv_quant)
+        before = jax.tree_util.tree_map(np.asarray, (k_pool, v_pool))
+        after = jax.tree_util.tree_map(
+            np.asarray, paged.copy_page(k_pool, v_pool, 2, 4))
+
+        def check(now, was):
+            np.testing.assert_array_equal(now[:, 4], was[:, 2])
+            keep = [0, 1, 2, 3, 5]
+            np.testing.assert_array_equal(now[:, keep], was[:, keep])
+
+        jax.tree_util.tree_map(check, after, before)
+
+    @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["fp", "int8"])
+    def test_spill_payload_shapes_unchanged(self, kv_quant):
+        """Spill -> restore through the engine's own codec: the host
+        payload's leaves are [L, 1, ps, g, dh] (+ [L, 1, ps, g] scales)
+        as before the pools changed layout, so an entry written then
+        is still one the codec accepts; the restored page reads back
+        what was spilled."""
+        from paddle_tpu.serving.spill import SpillEntry
+        paged, (k_pool, v_pool) = self._paged(kv_quant)
+        L, g, dh = CFG["n_layers"], paged.kv_heads, paged.head_dim
+        payload = {}
+        k_page, v_page = paged.read_page(k_pool, v_pool, 2)
+        DecodeEngine._flatten_page("k", k_page, payload)
+        DecodeEngine._flatten_page("v", v_page, payload)
+        want = {"k.q": (L, 1, 4, g, dh), "k.s": (L, 1, 4, g),
+                "v.q": (L, 1, 4, g, dh), "v.s": (L, 1, 4, g)} \
+            if kv_quant else {"k": (L, 1, 4, g, dh), "v": (L, 1, 4, g, dh)}
+        assert {n: a.shape for n, a in payload.items()} == want
+        entry = SpillEntry(payload)
+        assert entry.verify()
+        back_k = DecodeEngine._unflatten_page("k", k_pool, entry.payload)
+        back_v = DecodeEngine._unflatten_page("v", v_pool, entry.payload)
+        pools = paged.write_page(k_pool, v_pool, back_k, back_v, 1)
+        again = paged.read_page(*pools, 1)
+        jax.tree_util.tree_map(
+            np.testing.assert_array_equal,
+            jax.tree_util.tree_map(np.asarray, again),
+            jax.tree_util.tree_map(np.asarray, (k_page, v_page)))
+
+    def test_fingerprints_name_the_layout(self, monkeypatch):
+        """An executable stored for another pool layout can never be
+        resolved for this one: the layout is in every plan."""
+        from paddle_tpu.models.decode import PagedDecoder
+
+        def fingerprints():
+            paged, _ = self._paged(None)
+            return (paged._step_fp, paged._copy_fp, paged._read_fp,
+                    paged._write_fp)
+
+        now = fingerprints()
+        monkeypatch.setattr(PagedDecoder, "POOL_LAYOUT", "L,N,page,g,dh")
+        for a, b in zip(now, fingerprints()):
+            assert a != b
 
 
 class TestPagePool:

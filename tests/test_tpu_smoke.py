@@ -136,6 +136,9 @@ class TestPagedKernelCompiled:
             k, ks = quantize_kv(k)
             v, vs = quantize_kv(v)
             kw = dict(k_scales=ks, v_scales=vs)
+        # as the pools store them: the kv heads side by side on the lanes
+        k = k.reshape(n_pages, ps, g * dh)
+        v = v.reshape(n_pages, ps, g * dh)
         want = paged_window_attention(q, k, v, tables, lens, **kw)
         got = paged_window_attention(q, k, v, tables, lens,
                                      use_kernel=True, **kw)
