@@ -21,7 +21,7 @@ import time
 
 import numpy as np
 
-from benchmarks.lib import check, harness, paddle_lm, traffic
+from benchmarks.lib import check, harness, traffic
 from benchmarks.lib import names as names_of
 from benchmarks.lib.harness import now, percentile, say
 
@@ -105,17 +105,17 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, env: dict) -> dict:
     import jax.numpy as jnp
     cfg, mix, limits = cell["config"], cell["traffic"], cell["limits"]
     dep = cfg["deployment"]
-    reference = env["reference"]
+    reference, model = cell["reference"], cell["model"]
     vocab = int(cfg["vocab_size"])
     compiles = env["compiles"]
 
     # ------------------------------------------------------------- set-up
     marks = {"start": env["t_start"], "imports": now()}
-    named = paddle_lm.make_weights(reference, seed, cfg,
-                                   jnp.dtype(cfg["torch_dtype"]))
+    named = model.make_weights(reference, seed, cfg,
+                               jnp.dtype(cfg["torch_dtype"]))
     jax.block_until_ready(named)
     marks["weights"] = now()
-    dec, eng = paddle_lm.build_engine(named, cfg, dep)
+    dec, eng = model.build_engine(named, cfg, dep)
     del named
     if env["on_chip"] and not eng.paged.use_kernel:
         raise RuntimeError("the engine's default attention did not take the "
@@ -286,8 +286,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, env: dict) -> dict:
             program_gap=res["widest_gap"], control_gap=ctl["widest_gap"],
             control_per_request=ctl["per_request"],
             control_correct=check.decide(held))
-    ctx = {"config": cfg, "traffic": mix, "chips": cell["chips"],
-           "window_s": window_s, "counters": counters,
+    ctx = {"config": cfg, "model": model, "traffic": mix,
+           "chips": cell["chips"], "window_s": window_s, "counters": counters,
            "kv_itemsize": itemsize, "num_slots": num_slots,
            "late_ms": late_ms, "peaks": env["peaks"], "trace": None,
            "traced_counters": None}

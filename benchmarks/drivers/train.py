@@ -15,15 +15,16 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.lib import check, harness, paddle_lm, traffic
+from benchmarks.lib import check, harness, traffic
 from benchmarks.lib import names as names_of
 from benchmarks.lib.harness import now, percentile, say
 
 
-def _named_to_leaves(named: dict, n_layers: int) -> dict:
-    """{program name: value} -> {reference leaf: [per layer, ...]}."""
+def _named_to_leaves(named: dict, index: list) -> dict:
+    """{program name: value} -> {reference leaf: [per layer, ...]};
+    ``index`` is the model's ``leaf_index``."""
     out = {}
-    for name, leaf, layer in paddle_lm.leaf_index(n_layers):
+    for name, leaf, layer in index:
         out.setdefault(leaf, []).append(np.asarray(named[name]))
     return {k: np.stack(v) for k, v in out.items()}
 
@@ -34,59 +35,70 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, env: dict) -> dict:
     import paddle_tpu as paddle
     cfg, job, limits = cell["config"], cell["traffic"], cell["limits"]
     chips = cell["chips"]
-    reference = env["reference"]
+    reference, model = cell["reference"], cell["model"]
     compiles = env["compiles"]
-    n_layers = int(cfg["num_hidden_layers"])
+    index = model.leaf_index(cfg)
     lr = float(job["learning_rate"])
     b1 = reference.ADAM["b1"]
 
     # ------------------------------------------------------------- set-up
-    named = paddle_lm.make_weights(reference, seed, cfg, jnp.float32)
-    trainer = paddle_lm.build_trainer(named, cfg, job, chips, env["on_chip"])
+    marks = {"start": env["t_start"], "imports": now()}
+    named = model.make_weights(reference, seed, cfg, jnp.float32)
+    jax.block_until_ready(named)
+    marks["weights"] = now()
+    trainer = model.build_trainer(named, cfg, job, chips, env["on_chip"])
     del named
+    marks["trainer"] = now()
     gen = traffic.generate(job, seed, seconds, int(cfg["vocab_size"]), chips)
     tokens_per_step = gen["rows"] * gen["seq_len"]
 
     norm = lambda a: jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32))))
 
-    def first_gradient(slots):
+    # the two programs below take the seed's words as arguments
+    # (check.from_seed): one program each for every seed
+    def first_gradient(words, slots):
         """Norm and sketches (check.sketch_vectors) of every leaf of the
         first gradient as the optimizer got it: Adam's m after one step is
         (1 - b1) g."""
         g = {k: s["m"] / (1.0 - b1) for k, s in slots.items()}
 
         def one(k):
-            return check.sketch_of(g, paddle_lm.to_named(
-                check.sketch_vectors(reference, seed, cfg, k)))
+            return check.sketch_of(g, model.to_named(
+                check.sketch_vectors(reference, words, cfg, k)))
         return ({k: norm(a) for k, a in g.items()},
                 jax.lax.map(one, jnp.arange(check.SKETCHES)))
 
-    grad_norms = jax.jit(first_gradient)
-    change_norms = jax.jit(lambda p: {
-        k: norm(p[k] - v) for k, v in paddle_lm.to_named(
-            reference.init_params(seed, cfg, jnp.float32)).items()})
+    def change_norms(words, p):
+        return {k: norm(p[k] - v) for k, v in model.to_named(
+            reference.init_params(words, cfg, jnp.float32)).items()}
 
     first = {"events": [], "grad": None}
 
     def on_first(e):
         if isinstance(e, paddle.event.EndIteration):
             if e.batch_id == 0:     # before step 2 is dispatched: Adam's m
-                first["grad"] = grad_norms(trainer.opt_state["slots"])
+                first["grad"] = check.from_seed(
+                    first_gradient, seed, trainer.opt_state["slots"])
             first["events"].append(e)
 
     batches = [gen["batch"](i) for i in range(3)]
-    trainer.train(reader=lambda: iter([paddle_lm.rows_of(b)
+    trainer.train(reader=lambda: iter([model.rows_of(b)
                                        for b in batches]),
                   num_passes=1, event_handler=on_first)
+    marks["three_steps_and_step_program"] = now()
     g_norms, g_sketch = jax.device_get(first["grad"])
     prog = {"losses": [float(e.cost) for e in first["events"]],
-            "grad_norms": _named_to_leaves(g_norms, n_layers),
-            "grad_sketch": _named_to_leaves(g_sketch, n_layers),
+            "grad_norms": _named_to_leaves(g_norms, index),
+            "grad_sketch": _named_to_leaves(g_sketch, index),
             "change_norms": _named_to_leaves(
-                jax.device_get(change_norms(trainer._own_params())),
-                n_layers)}
+                jax.device_get(check.from_seed(
+                    change_norms, seed, trainer._own_params())), index)}
     if len(prog["losses"]) != 3:
         raise RuntimeError(f"set-up ran {len(prog['losses'])} steps, not 3")
+    marks["norms"] = now()
+    names = list(marks)
+    say(phase="setup", **{f"{b}_s": marks[b] - marks[a]
+                          for a, b in zip(names, names[1:])})
 
     # ------------------------------------------------------------- window
     stamps: list = []
@@ -102,7 +114,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, env: dict) -> dict:
     def reader():
         step = 3
         while not stop[0]:
-            yield paddle_lm.rows_of(gen["batch"](step))
+            yield model.rows_of(gen["batch"](step))
             step += 1
 
     def on_step(e):
@@ -176,9 +188,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, env: dict) -> dict:
             other.pop("_worst")
             say(phase=label, seed=seed, correct=check.decide(held(other)),
                 **other)
-    ctx = {"config": cfg, "traffic": job, "chips": chips, "window_s": window_s,
-           "peaks": env["peaks"], "trace": None, "seq_len": gen["seq_len"],
-           "rows_per_chip": gen["rows"] // chips,
+    ctx = {"config": cfg, "model": model, "traffic": job, "chips": chips,
+           "window_s": window_s, "peaks": env["peaks"], "trace": None,
+           "seq_len": gen["seq_len"], "rows_per_chip": gen["rows"] // chips,
            "counters": {"steps": n_in}}
     if tracer:
         ctx["trace"] = tracer.read(chips)

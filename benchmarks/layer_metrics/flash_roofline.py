@@ -2,7 +2,6 @@
 for the causal forward + backward FLOPs of one step on one chip (FLOPs
 bind: 3 x 2 T^2 d per layer and row at the bf16 peak) over the device time
 of the kernels' events per step."""
-from benchmarks.lib import counts
 from benchmarks.lib.names import is_flash_kernel, is_train_step
 
 
@@ -13,6 +12,6 @@ def read(ctx):
     steps = tr.steps(is_train_step, is_flash_kernel)
     if not steps["n"] or steps["ops_s"] <= 0:
         return None
-    least, _ = counts.flash_least_s(
+    least, _ = ctx["model"].flash_least_s(
         ctx["config"], ctx["rows_per_chip"], ctx["seq_len"], 2, ctx["peaks"])
     return 100.0 * least / steps["ops_s"]

@@ -2,7 +2,6 @@
 for the K/V bytes it must read (cache_tokens_read x bytes per token at the
 HBM peak; its FLOPs at the bf16 peak are 200x less, so bytes bind) over
 the device time of the kernel's events, both of the traced part."""
-from benchmarks.lib import counts
 from benchmarks.lib.names import is_paged_attn_kernel
 
 
@@ -13,7 +12,6 @@ def read(ctx):
     kernel_s = tr.seconds_matching(is_paged_attn_kernel)
     if kernel_s <= 0:
         return None
-    least, _ = counts.paged_attn_least_s(
-        ctx["config"], c["cache_tokens_read"], ctx["kv_itemsize"],
-        ctx["peaks"])
+    least, _ = ctx["model"].paged_attn_least_s(
+        ctx["config"], c, ctx["kv_itemsize"], ctx["peaks"])
     return 100.0 * least / kernel_s

@@ -2,14 +2,12 @@
 every token fed (prompt and output) per second, over chips x peak bf16
 FLOP/s. Tokens fed and their cache lengths are the engine's exact counters
 over the whole window; the seconds are the host's."""
-from benchmarks.lib import counts
 
 
 def read(ctx):
     c = ctx["counters"]
     if not c.get("active_slot_steps"):
         return None
-    flops = counts.serve_flops(ctx["config"], c["active_slot_steps"],
-                               c["cache_tokens_read"])
+    flops = ctx["model"].serve_flops(ctx["config"], c)
     return 100.0 * flops / ctx["window_s"] / (
         ctx["chips"] * ctx["peaks"]["bf16_flops"])

@@ -30,6 +30,25 @@ def decide(checks: dict) -> bool:
     return all(v <= l for v, l in checks.values())
 
 
+def seed_words(seed) -> tuple:
+    """The seed as two uint32 words (its low 31 bits, the rest): what a
+    jitted program takes as ARGUMENTS to be one program for every seed.
+    A seed closed over is a constant of the program, so that every new
+    seed compiles it anew: seconds of a shared host's CPU in every run's
+    set-up, and the widest swing ``setup_s`` had. A reference's
+    ``init_params(seed, ...)`` is handed these words, traced."""
+    seed = int(seed)
+    return np.uint32(seed & 0x7FFFFFFF), np.uint32(seed >> 31)
+
+
+def from_seed(fn, seed: int, *args):
+    """``fn((low, rest), *args)`` run as one jitted program that takes the
+    seed's words, and ``args``, as arguments."""
+    import jax
+    return jax.jit(lambda lo, hi, *a: fn((lo, hi), *a))(
+        *seed_words(seed), *args)
+
+
 def sample_finished(finished: list, n: int, seed: int) -> list:
     """``n`` of the finished requests, drawn from the seed, the longest
     (prompt + served tokens) always among them."""
@@ -50,7 +69,7 @@ def _gap_fn(reference, cfg_key, rounding):
     first (the control); without, the served one."""
     import jax
     import jax.numpy as jnp
-    cfg = dict(cfg_key)
+    cfg = cfg_of(cfg_key)
 
     def fn(params, seq, nxt):
         logits = reference.forward(params, seq, cfg)
@@ -63,9 +82,35 @@ def _gap_fn(reference, cfg_key, rounding):
     return jax.jit(fn)
 
 
+def _frozen(v):
+    """A scalar as it is, a list as a tuple (of scalars and lists);
+    anything else, a dict above all, raises TypeError."""
+    if isinstance(v, (int, float, str, bool)):
+        return v
+    if isinstance(v, list):
+        return tuple(_frozen(x) for x in v)
+    raise TypeError(type(v))
+
+
 def cfg_key(cfg: dict):
-    return tuple(sorted((k, v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, str, bool))))
+    """The configuration as a hashable key: its scalars and its lists
+    (``layer_types``), as tuples. Nested dicts (``deployment``,
+    ``assumed``, ``published``) stay out, with any list that holds one:
+    the reference never sees them."""
+    out = []
+    for k, v in cfg.items():
+        try:
+            out.append((k, _frozen(v)))
+        except TypeError:
+            pass
+    return tuple(sorted(out))
+
+
+def cfg_of(key) -> dict:
+    """``cfg_key``'s configuration again, its lists restored."""
+    def thaw(v):
+        return [thaw(x) for x in v] if isinstance(v, tuple) else v
+    return {k: thaw(v) for k, v in key}
 
 
 def served_logit_gap(reference, cfg: dict, seed: int, sample: list,
@@ -75,7 +120,7 @@ def served_logit_gap(reference, cfg: dict, seed: int, sample: list,
     import jax
     import jax.numpy as jnp
     wdtype = jnp.dtype(cfg.get("torch_dtype", "float32"))
-    params = jax.jit(lambda: reference.init_params(seed, cfg, wdtype))()
+    params = from_seed(lambda w: reference.init_params(w, cfg, wdtype), seed)
     fn = _gap_fn(reference, cfg_key(cfg), rounding)
     widest, n_tokens, per_request = 0.0, 0, []
     for prompt, served in sample:
@@ -119,19 +164,20 @@ def leaf_norms(reference, tree: dict) -> dict:
 SKETCHES = 4       # random directions per leaf
 
 
-def sketch_vectors(reference, seed: int, cfg: dict, k) -> dict:
-    """The k-th random direction of every leaf, from the seed, in the
-    reference's stacked layout (trace inside a jit: nothing of it is
-    kept). The inner product of a gradient with it is a SKETCH of the
-    gradient: unlike a norm, in which unbiased rounding noise cancels to
-    second order, it moves in the first order of every element's error."""
+def sketch_vectors(reference, words: tuple, cfg: dict, k) -> dict:
+    """The k-th random direction of every leaf, from the seed's
+    ``seed_words``, in the reference's stacked layout (trace inside a jit
+    that takes the words as arguments: nothing of it is kept). The inner
+    product of a gradient with it is a SKETCH of the gradient: unlike a
+    norm, in which unbiased rounding noise cancels to second order, it
+    moves in the first order of every element's error."""
     import jax
     import jax.numpy as jnp
     shapes = jax.eval_shape(
         lambda: reference.init_params(0, cfg, jnp.float32))
+    lo, hi = words
     key = jax.random.fold_in(jax.random.fold_in(
-        jax.random.key((int(seed) ^ 0x2545F491) & 0x7FFFFFFF, impl="rbg"),
-        int(seed) >> 31), k)
+        jax.random.key(lo ^ np.uint32(0x2545F491), impl="rbg"), hi), k)
     return {name: jax.random.normal(jax.random.fold_in(key, i), sh.shape,
                                     jnp.float32)
             for i, (name, sh) in enumerate(sorted(shapes.items()))}
@@ -160,12 +206,12 @@ def leaf_sketch(reference, seed: int, cfg: dict, tree: dict) -> dict:
     import jax
     import jax.numpy as jnp
 
-    def fn(t):
+    def fn(words, t):
         def one(k):
-            return sketch_of(t, sketch_vectors(reference, seed, cfg, k),
+            return sketch_of(t, sketch_vectors(reference, words, cfg, k),
                              reference.LAYER_LEAVES)
         return jax.lax.map(one, jnp.arange(SKETCHES))
-    out = jax.jit(fn)(tree)
+    out = from_seed(fn, seed, tree)
     return {k: np.asarray(v).reshape(SKETCHES, -1).T for k, v in out.items()}
 
 
@@ -189,7 +235,8 @@ def reference_three_steps(reference, cfg: dict, seed: int, batches: list,
     program's place with that fault."""
     import jax
     import jax.numpy as jnp
-    make = jax.jit(lambda: reference.init_params(seed, cfg, jnp.float32))
+    make = lambda: from_seed(
+        lambda w: reference.init_params(w, cfg, jnp.float32), seed)
     p = make()
     m = jax.tree_util.tree_map(jnp.zeros_like, p)
     v = jax.tree_util.tree_map(jnp.zeros_like, p)

@@ -26,6 +26,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 LN_EPS = 1e-5
@@ -51,13 +52,25 @@ def sizes(cfg: dict) -> dict:
             "P": int(cfg["max_position_embeddings"])}
 
 
-def seed_key(seed: int):
-    """A key from any whole number: 31 bits seed the key, the rest are
-    folded in, so seeds past 2**31 neither wrap nor overflow. The 'rbg'
-    generator: threefry took 70 s for these 1.3e9 values on the v5e."""
+def seed_words(seed) -> tuple:
+    """The two uint32 words a key is made from: the seed's low 31 bits and
+    the rest. A jitted program takes them as ARGUMENTS and is then one
+    program for every seed; a seed closed over is a constant of the
+    program, which every new seed compiles anew (6 s of set-up for these
+    weights on the v5e's host). A pair, traced or not, passes through."""
+    if isinstance(seed, tuple):
+        return seed
     seed = int(seed)
-    return jax.random.fold_in(
-        jax.random.key(seed & 0x7FFFFFFF, impl="rbg"), seed >> 31)
+    return np.uint32(seed & 0x7FFFFFFF), np.uint32(seed >> 31)
+
+
+def seed_key(seed):
+    """A key from any whole number (or its ``seed_words``): 31 bits seed
+    the key, the rest are folded in, so seeds past 2**31 neither wrap nor
+    overflow. The 'rbg' generator: threefry took 70 s for these 1.3e9
+    values on the v5e."""
+    lo, hi = seed_words(seed)
+    return jax.random.fold_in(jax.random.key(lo, impl="rbg"), hi)
 
 
 def _leaf(key, name: str, shape, dtype, std: float):
@@ -69,10 +82,11 @@ def _leaf(key, name: str, shape, dtype, std: float):
     return r.astype(dtype)
 
 
-def init_params(seed: int, cfg: dict, dtype=jnp.float32) -> dict:
-    """All weights from the seed, layers stacked on a leading axis.
-    Trace it inside one ``jax.jit``: the device then makes them in one
-    call, in ``dtype``."""
+def init_params(seed, cfg: dict, dtype=jnp.float32) -> dict:
+    """All weights from the seed (a whole number or its ``seed_words``),
+    layers stacked on a leading axis. Trace it inside one ``jax.jit`` that
+    takes the words as arguments: the device then makes them in one call,
+    in ``dtype``, by one program for every seed."""
     z = sizes(cfg)
     key = seed_key(seed)
     shapes = {"tok_emb": (z["V"], z["d"]), "pos_emb": (z["P"], z["d"]),

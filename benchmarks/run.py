@@ -11,8 +11,9 @@ the configuration's plain reference. The last line of standard output is
 the result (benchmarks/lib/harness.print_result); medians and counters go
 on earlier lines.
 
-Adding a cell, a configuration, a traffic mix or a per-layer metric is
-adding files and manifest entries only (benchmarks/lib/manifest.py).
+Adding a cell, a configuration (of a new architecture too: its reference
+and benchmarks/models/<reference>.py), a traffic mix or a per-layer metric
+is adding files and manifest entries only (benchmarks/lib/manifest.py).
 ``--set key=value`` overrides a parameter of the traffic mix for a sweep
 (finding a knee); the driver's runs never pass it.
 """
@@ -84,10 +85,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     """Everything of a run after the look for a chip: drive the cell,
     read its metrics, print the result. Returns the exit code."""
     from benchmarks.lib import harness, manifest
-    driver = manifest.load_module("drivers", cell["traffic"]["driver"])
-    reference = manifest.load_module("reference",
-                                     cell["config"]["reference"])
-    env = {"reference": reference, "compiles": harness.CompileCounter(),
+    driver = manifest.load_module("drivers", cell["traffic"]["driver"],
+                                  cell["home"])
+    env = {"compiles": harness.CompileCounter(),
            "on_chip": device["platform"] == "tpu", "peaks": peak_table,
            "t_start": t_start, "dump_trace": dump_trace}
     out = driver.run(cell, seed, seconds, trace, env)
@@ -101,8 +101,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         tr = ctx["trace"]
         metrics = {}
         for m in cell["per_layer"]:
-            value = manifest.load_module("layer_metrics",
-                                         m["name"]).read(ctx)
+            value = manifest.load_module("layer_metrics", m["name"],
+                                         cell["home"]).read(ctx)
             if value is not None:       # nothing to read: left out
                 metrics[m["name"]] = {"value": float(value),
                                       "unit": m["unit"]}

@@ -55,10 +55,10 @@ def main(argv=None) -> int:
         cell = manifest.cell(man, args.workload)
         if override:
             run.apply_overrides(cell["traffic"], [override])
-        driver = manifest.load_module("drivers", cell["traffic"]["driver"])
-        env = {"reference": manifest.load_module(
-                   "reference", cell["config"]["reference"]),
-               "compiles": counter, "on_chip": True,
+        driver = manifest.load_module("drivers", cell["traffic"]["driver"],
+                                      cell["home"])
+        env = {"compiles": counter,
+               "on_chip": device["platform"] == "tpu",
                "peaks": peaks.peaks_for(device["kind"]),
                "t_start": time.monotonic(),
                "control": "fp8" if i < args.control_seeds else None}

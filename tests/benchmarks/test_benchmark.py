@@ -20,18 +20,38 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmarks.lib import counts, manifest, trace, traffic  # noqa: E402
+from benchmarks.lib import manifest, trace, traffic  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 MANIFEST = manifest.load_manifest()
 WORKLOADS = [w["name"] for w in MANIFEST["workloads"]]
 LAYER_METRICS = [m["name"] for m in MANIFEST["per_layer"]]
+CONFIGS = [c["name"] for c in MANIFEST["configs"]]
+OPT = manifest.load_module("models", "opt")
+TINY = OPT.tiny(deployment=False)
 
-TINY = {"name": "tiny", "reference": "opt", "hidden_size": 32,
-        "num_hidden_layers": 2, "num_attention_heads": 4, "ffn_dim": 64,
-        "vocab_size": 64, "max_position_embeddings": 64,
-        "torch_dtype": "float32"}
+#: the cuts the model-configs guide (section 4) allows, by the keys that
+#: the catalog's ``config.json``s give them: depth and the layer pattern,
+#: the experts held here, the vocabulary. No width is among them.
+REDUCIBLE = {
+    "num_hidden_layers", "num_layers", "n_layer", "n_layers",
+    "layer_types", "mlp_layer_types", "layers_block_type",
+    "hybrid_override_pattern", "hybrid_layer_pattern", "mlp_only_layers",
+    "first_k_dense_replace", "num_dense_layers", "n_dense_first_layers",
+    "max_window_layers", "attn_layer_indices", "full_attention_layers",
+    "num_nextn_predict_layers",
+    "n_routed_experts", "num_experts", "num_local_experts",
+    "moe_num_experts", "vocab_size"}
+
+
+def is_width(key: str) -> bool:
+    """What ``reduced`` may never name: a hidden, intermediate, latent,
+    state or projection size, a key that ends in _dim or _rank, a head
+    size, an expansion factor, the experts per token."""
+    return key != "vocab_size" and re.search(
+        r"(_dim|_rank|_size|_width|_per_tok|_per_token|top_k|topk|factor|"
+        r"expand|window)$|^d_[a-z]+$", key) is not None
 
 
 # ------------------------------------------------------------------ manifest
@@ -56,8 +76,18 @@ def test_cell_files_exist_and_parse(workload):
     assert cell["traffic"]["kind"] in ("open_loop", "closed_loop",
                                        "train_job")
     manifest.load_module("drivers", cell["traffic"]["driver"])
-    ref = manifest.load_module("reference", cell["config"]["reference"])
+    ref, model = cell["reference"], cell["model"]
     assert hasattr(ref, "forward") and hasattr(ref, "init_params")
+    # the contract of benchmarks/models/<reference>.py, by the cell's driver
+    needs = {"serve": ("build_engine", "serve_flops", "paged_attn_least_s"),
+             "train": ("build_trainer", "to_named", "leaf_index", "rows_of",
+                       "train_flops_per_token", "flash_least_s")}
+    for fn in ("make_weights", "kv_bytes_per_token", "total_params",
+               "tiny") + needs[cell["traffic"]["driver"]]:
+        assert callable(getattr(model, fn, None)), fn
+    tiny = model.tiny()
+    assert tiny["reference"] == cell["config"]["reference"]
+    assert model.total_params(tiny) < 1e6 < model.total_params(cell["config"])
     assert any(m["name"] == "setup_s" for m in cell["end_to_end"])
     assert len(cell["end_to_end"]) >= 2 and cell["per_layer"]
     assert any("mfu" in m["name"] for m in cell["per_layer"])
@@ -67,21 +97,108 @@ def test_cell_files_exist_and_parse(workload):
             assert lim["lower"] < lim["limit"] < lim["upper"], check_name
 
 
-def test_config_files_keep_the_published_widths():
-    published = {"hidden_size": 2048, "num_attention_heads": 32,
-                 "ffn_dim": 8192, "vocab_size": 50272,
-                 "max_position_embeddings": 2048}
-    for conf in MANIFEST["configs"]:
-        with open(os.path.join(ROOT, conf["file"])) as f:
-            cfg = json.load(f)
-        for k, v in published.items():
-            assert cfg[k] == v, (conf["name"], k)
-        assert cfg["reduced"] == conf["reduced"]
-        if "num_hidden_layers" in conf["reduced"]:
-            assert cfg["published"]["num_hidden_layers"] == 24
-        else:
-            assert cfg["num_hidden_layers"] == 24
-        assert cfg["assumed"], conf["name"]
+def config_faults(conf: dict, cfg: dict, pinned: dict) -> list:
+    """What is wrong with one configuration's file against its entry of
+    the manifest and its pinned case (the published sizes by their keys),
+    whatever its architecture: [] where nothing is. A key that is not
+    reduced holds the published value; a reduced one says it under
+    ``published``."""
+    faults = []
+    if not any(is_width(k) for k in pinned):
+        faults.append("no width is pinned")
+    for k, v in pinned.items():
+        where = cfg.get("published", {}) if k in conf["reduced"] else cfg
+        if where.get(k) != v:
+            faults.append(f"{k!r} is {where.get(k)!r}, published {v!r}")
+    if cfg.get("reduced") != conf["reduced"]:
+        faults.append("reduced differs between the file and the manifest")
+    for k in conf["reduced"]:
+        if k not in REDUCIBLE:
+            faults.append(f"reduced names {k!r}, which is no cut of depth, "
+                          f"experts held or vocabulary")
+        if k not in cfg.get("published", {}):
+            faults.append(f"no published value of the reduced key {k!r}")
+        elif cfg["published"][k] == cfg.get(k):
+            faults.append(f"{k!r} is listed as reduced and is as published")
+    if not cfg.get("assumed"):
+        faults.append("assumed is empty")
+    if not str(cfg.get("source", "")).startswith("https://") \
+            or cfg.get("source") != conf["source"]:
+        faults.append("source is no https:// URL, or not the manifest's")
+    return faults
+
+
+def _config(name):
+    conf = next(c for c in MANIFEST["configs"] if c["name"] == name)
+    with open(os.path.join(ROOT, conf["file"])) as f:
+        return conf, json.load(f)
+
+
+def _pinned(name) -> dict:
+    """tests/benchmarks/published/<name>.json: the configuration's
+    published sizes by their keys, written down by the PR that adds it."""
+    with open(os.path.join(os.path.dirname(__file__), "published",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_config_files_keep_the_published_widths(name):
+    """Structure, for every entry, and its pinned case."""
+    conf, cfg = _config(name)
+    assert config_faults(conf, cfg, _pinned(name)) == []
+
+
+def test_opt_is_pinned_at_its_published_sizes():
+    for name in ("opt-1.3b", "opt-1.3b-train"):
+        assert _pinned(name) == {
+            "hidden_size": 2048, "num_attention_heads": 32, "ffn_dim": 8192,
+            "vocab_size": 50272, "max_position_embeddings": 2048,
+            "num_hidden_layers": 24}
+
+
+def test_the_width_test_takes_another_architecture_and_refuses_a_width():
+    """A second entry whose widths are not OPT's passes the test of
+    structure; a ``reduced`` that names a width, a reduced key without
+    its published value, an empty ``assumed`` and a source that is no
+    URL are each refused."""
+    conf = {"name": "toy-moe", "source": "https://example.org/toy-moe",
+            "file": "x/configs/toy-moe.json",
+            "reduced": ["num_hidden_layers", "layer_types", "num_experts"],
+            "why": "-"}
+    cfg = {"name": "toy-moe", "source": conf["source"], "reference": "toy",
+           "hidden_size": 1536, "num_attention_heads": 12, "head_dim": 128,
+           "moe_intermediate_size": 768, "num_experts_per_tok": 8,
+           "vocab_size": 200192, "num_hidden_layers": 5, "num_experts": 16,
+           "layer_types": ["sliding", "sliding", "sliding", "full",
+                           "sliding"],
+           "reduced": list(conf["reduced"]),
+           "published": {"num_hidden_layers": 32, "num_experts": 128,
+                         "layer_types": ["sliding", "sliding", "sliding",
+                                         "full"] * 8},
+           "assumed": {"weights": "random from --seed"}}
+    pinned = {"hidden_size": 1536, "head_dim": 128, "num_experts": 128,
+              "moe_intermediate_size": 768, "num_experts_per_tok": 8,
+              "vocab_size": 200192, "num_hidden_layers": 32}
+    assert config_faults(conf, cfg, pinned) == []
+    for width in ("hidden_size", "moe_intermediate_size", "head_dim",
+                  "num_experts_per_tok", "kv_lora_rank"):
+        bad = dict(conf, reduced=conf["reduced"] + [width])
+        faults = config_faults(bad, dict(cfg, reduced=bad["reduced"]),
+                               pinned)
+        assert any(width in f and "no cut" in f for f in faults), width
+    assert config_faults(conf, dict(cfg, hidden_size=1024), pinned)
+    assert config_faults(conf, dict(cfg, published={}), pinned)
+    assert config_faults(conf, dict(cfg, assumed={}), pinned)
+    assert config_faults(dict(conf, source="a paper"),
+                         dict(cfg, source="a paper"), pinned)
+    assert config_faults(conf, dict(cfg, reduced=[]), pinned)
+    assert config_faults(conf, cfg, {"vocab_size": 200192})
+    assert not [k for k in REDUCIBLE if is_width(k)]
+    assert all(is_width(k) for k in (
+        "hidden_size", "ffn_dim", "intermediate_size", "kv_lora_rank",
+        "head_dim", "num_experts_per_tok", "moe_intermediate_size",
+        "d_model", "expert_ffn_hidden_size", "mamba_expand"))
 
 
 def test_names_and_units_hold_only_the_allowed_characters():
@@ -121,6 +238,12 @@ def _mix(name):
     return manifest.load_json("traffic", name + ".json")
 
 
+def _vocab(mix_name) -> int:
+    """The vocabulary of a configuration whose cell uses the mix."""
+    w = next(w for w in MANIFEST["workloads"] if w["traffic"] == mix_name)
+    return int(_config(w["config"])[1]["vocab_size"])
+
+
 @pytest.mark.parametrize("mix_name", sorted({w["traffic"] for w in
                                              MANIFEST["workloads"]}))
 def test_traffic_repeats_for_a_seed_and_differs_across_seeds(mix_name):
@@ -137,9 +260,10 @@ def test_traffic_repeats_for_a_seed_and_differs_across_seeds(mix_name):
                       for t in c["turns"]]) for c in g["clients"]]
         return [g["batch"](i).tolist() for i in range(2)]
 
-    a = flat(traffic.generate(mix, big, 5.0, 50272))
-    b = flat(traffic.generate(mix, big, 5.0, 50272))
-    c = flat(traffic.generate(mix, big + 1, 5.0, 50272))
+    vocab = _vocab(mix_name)
+    a = flat(traffic.generate(mix, big, 5.0, vocab))
+    b = flat(traffic.generate(mix, big, 5.0, vocab))
+    c = flat(traffic.generate(mix, big + 1, 5.0, vocab))
     assert a == b and a != c
 
 
@@ -147,7 +271,7 @@ def test_every_seed_carries_the_same_work():
     mix = _mix("chat")
     sizes = []
     for seed in (1, 2, 2 ** 31 + 5):
-        g = traffic.generate(mix, seed, 10.0, 50272)["requests"]
+        g = traffic.generate(mix, seed, 10.0, _vocab("chat"))["requests"]
         sizes.append((len(g), sorted(len(r["prompt"]) for r in g),
                       sorted(r["max_new"] for r in g)))
         due = [r["due"] for r in g]
@@ -159,7 +283,8 @@ def test_every_seed_carries_the_same_work():
 
 
 def test_train_rows_all_differ():
-    g = traffic.generate(_mix("pretrain_4x2048"), 3, 1.0, 50272, chips=4)
+    g = traffic.generate(_mix("pretrain_4x2048"), 3, 1.0,
+                         _vocab("pretrain_4x2048"), chips=4)
     b0, b1 = g["batch"](0), g["batch"](1)
     assert b0.shape == (16, 2049)
     rows = {tuple(r[:32]) for r in np.concatenate([b0, b1])}
@@ -170,7 +295,7 @@ def test_the_ramp_is_a_stratified_set_of_its_own_before_the_window():
     mix = _mix("chat")
     sets = []
     for seed in (4, 2 ** 31 + 9):
-        g = traffic.generate(mix, seed, 10.0, 50272)
+        g = traffic.generate(mix, seed, 10.0, _vocab("chat"))
         ramp = g["ramp_requests"]
         due = [r["due"] for r in ramp]
         assert due == sorted(due) and -mix["ramp_s"] <= due[0] and due[-1] < 0
@@ -245,6 +370,7 @@ def test_trace_reducer_on_a_hand_made_list():
 
 # -------------------------------------------------------------------- counts
 def test_flop_and_byte_functions_match_a_hand_count():
+    counts = OPT
     cfg = {"hidden_size": 2048, "ffn_dim": 8192, "num_hidden_layers": 1,
            "vocab_size": 50272, "max_position_embeddings": 2048}
     # one layer: q,k,v,out 4 x 2048^2 = 16,777,216; ffn 2 x 2048 x 8192
@@ -254,9 +380,11 @@ def test_flop_and_byte_functions_match_a_hand_count():
     assert counts.kv_bytes_per_token(full, 2) == 196_608
     # one token fed at cache length 100: 2 x weights + head + 4 d n
     want = 2 * 50_331_648 + 2 * 2048 * 50272 + 4 * 2048 * 100
-    assert counts.serve_flops(cfg, 1, 100) == pytest.approx(want)
+    fed = {"active_slot_steps": 1, "cache_tokens_read": 100}
+    assert counts.serve_flops(cfg, fed) == pytest.approx(want)
     peaks = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
-    s, which = counts.paged_attn_least_s(full, 36_000, 2, peaks)
+    s, which = counts.paged_attn_least_s(
+        full, {"cache_tokens_read": 36_000}, 2, peaks)
     assert which == "hbm_bytes"
     assert s == pytest.approx(36_000 * 196_608 / 819e9)
     # training: 6 x weights + 6 d V + 3 x (2 T^2 d)/T per token
@@ -272,9 +400,8 @@ def test_flop_and_byte_functions_match_a_hand_count():
 # ----------------------------------------------------- reference vs program
 def _tiny_weights(dtype="float32"):
     import jax.numpy as jnp
-    from benchmarks.lib import paddle_lm
     ref = manifest.load_module("reference", "opt")
-    return ref, paddle_lm.make_weights(ref, 7, TINY, jnp.dtype(dtype))
+    return ref, OPT.make_weights(ref, 7, TINY, jnp.dtype(dtype))
 
 
 def test_reference_agrees_with_transformer_decoder():
@@ -294,19 +421,69 @@ def test_reference_agrees_with_transformer_decoder():
 def test_reference_agrees_with_transformer_lm_cost():
     import jax
     import jax.numpy as jnp
-    from benchmarks.lib import paddle_lm
     ref, named = _tiny_weights()
     job = {"learning_rate": 1e-4, "compute_dtype": "float32"}
     cfg = dict(TINY, max_position_embeddings=32)
-    named = paddle_lm.make_weights(ref, 7, cfg, jnp.float32)
-    trainer = paddle_lm.build_trainer(named, cfg, job, 1, False)
+    named = OPT.make_weights(ref, 7, cfg, jnp.float32)
+    trainer = OPT.build_trainer(named, cfg, job, 1, False)
     batch = np.random.default_rng(1).integers(0, 64, (4, 33)).astype(np.int32)
-    cost = trainer.train_batch(paddle_lm.rows_of(batch))
+    cost = trainer.train_batch(OPT.rows_of(batch))
     cost = float(cost[0] if isinstance(cost, (tuple, list)) else cost)
     p = jax.jit(lambda: ref.init_params(7, cfg))()
     want, _ = ref.batch_loss_and_grad(p, jnp.asarray(batch[:, :-1]),
                                       jnp.asarray(batch[:, 1:]), cfg)
     assert cost == pytest.approx(float(want), rel=1e-5)
+
+
+# ----------------------------------------------- one program for every seed
+@pytest.mark.parametrize("seed", [7, 2**31 - 1, 2**31 + 5, 9500000011])
+def test_the_seeds_words_give_the_weights_the_whole_number_gives(seed):
+    """The seed as two words that a program takes as arguments
+    (check.seed_words) makes the very weights that the whole number, closed
+    over as a constant, made before: no reading of any cell moves."""
+    import jax
+    from benchmarks.lib import check
+    ref = manifest.load_module("reference", "opt")
+    closed = jax.jit(lambda: ref.init_params(seed, TINY))()
+    words = check.from_seed(lambda w: ref.init_params(w, TINY), seed)
+    assert check.seed_words(seed) == ref.seed_words(seed)
+    for k in closed:
+        np.testing.assert_array_equal(np.asarray(closed[k]),
+                                      np.asarray(words[k]))
+    a = jax.jit(lambda: check.sketch_vectors(
+        ref, check.seed_words(seed), TINY, 2))()
+    b = check.from_seed(lambda w: check.sketch_vectors(ref, w, TINY, 2), seed)
+    for k in a:
+        np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
+
+
+def test_a_new_seed_compiles_nothing(monkeypatch):
+    """Every program that set-up makes from the seed is the same program
+    for every seed, to the letter of its lowered text: the persistent cache
+    then holds it after a checkout's first run, whatever seed comes."""
+    import jax
+    import jax.numpy as jnp
+    from benchmarks.lib import check
+    ref = manifest.load_module("reference", "opt")
+    texts, real = {}, jax.jit
+
+    def spy(fn, **kw):
+        jitted = real(fn, **kw)
+
+        def call(*args):
+            texts.setdefault(seed, []).append(jitted.lower(*args).as_text())
+            return jitted(*args)
+        return call
+
+    monkeypatch.setattr(jax, "jit", spy)
+    for seed in (7, 9500000011):
+        named = OPT.make_weights(ref, seed, TINY, jnp.float32)
+        check.from_seed(lambda w: ref.init_params(w, TINY), seed)
+        check.leaf_sketch(ref, seed, TINY, ref.init_params(seed, TINY))
+        check.from_seed(lambda w, p: {
+            k: p[k] - v for k, v in OPT.to_named(
+                ref.init_params(w, TINY)).items()}, seed, named)
+    assert len(texts[7]) == 4 and texts[7] == texts[9500000011]
 
 
 # ------------------------------------------------------------------ command

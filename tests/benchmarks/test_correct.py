@@ -27,19 +27,16 @@ from benchmarks import run  # noqa: E402
 from benchmarks.lib import check, manifest  # noqa: E402
 
 MANIFEST = manifest.load_manifest()
-TINY = {"name": "tiny", "reference": "opt", "hidden_size": 32,
-        "num_hidden_layers": 2, "num_attention_heads": 4, "ffn_dim": 64,
-        "vocab_size": 64, "max_position_embeddings": 64,
-        "torch_dtype": "float32", "init_std": 0.2,
-        "deployment": {"num_slots": 4, "page_size": 4, "max_seq_len": 64,
-                       "num_pages": 80}}
+CELL = manifest.cell        # the readings' test stands tiny_cell in its place
 DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}
 PEAKS = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11}
 
 
 def tiny_cell(workload: str) -> dict:
-    cell = manifest.cell(MANIFEST, workload)
-    cell["config"] = dict(TINY)
+    """The cell with its own architecture's tiny configuration
+    (``cell["model"].tiny()``) and its mix cut to a CPU's size."""
+    cell = CELL(MANIFEST, workload)
+    cell["config"] = cell["model"].tiny()
     mix = cell["traffic"]
     if mix["kind"] == "open_loop":
         mix["arrivals"] = {"rate_per_s": 6.0}
@@ -70,11 +67,13 @@ def drive(cell, capsys, seed=2 ** 31 + 99, seconds=1.5) -> dict:
     return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
 
 
-SERVE = [w["name"] for w in MANIFEST["workloads"]
-         if manifest.cell(MANIFEST, w["name"])["traffic"]["driver"] == "serve"]
+def _driver(w) -> str:
+    return manifest.load_json("traffic", w["traffic"] + ".json")["driver"]
+
+
+SERVE = [w["name"] for w in MANIFEST["workloads"] if _driver(w) == "serve"]
 TRAIN = [w["name"] for w in MANIFEST["workloads"]
-         if manifest.cell(MANIFEST, w["name"])["traffic"]["driver"] == "train"
-         and w["chips"] == 1]
+         if _driver(w) == "train" and w["chips"] == 1]
 
 
 @pytest.mark.parametrize("workload", SERVE + TRAIN)
@@ -96,16 +95,18 @@ def test_an_altered_token_is_not_correct(workload, capsys, monkeypatch):
     from paddle_tpu.models.decode import PagedDecoder
     orig = PagedDecoder.step
     calls = {"n": 0}
+    cell = tiny_cell(workload)
+    vocab = int(cell["config"]["vocab_size"])
 
     def bad_step(self, *a, **kw):
         nxt, k, v = orig(self, *a, **kw)
         calls["n"] += 1
         if calls["n"] % 3 == 0:
-            nxt = (nxt + 1) % 64
+            nxt = (nxt + 1) % vocab
         return nxt, k, v
 
     monkeypatch.setattr(PagedDecoder, "step", bad_step)
-    res = drive(tiny_cell(workload), capsys)
+    res = drive(cell, capsys)
     assert res["correct"] is False
     gap = res["checks"]["served_logit_gap"]
     assert gap["value"] > gap["limit"]
@@ -131,11 +132,11 @@ def test_half_of_the_batch_left_out_is_not_correct(workload, capsys,
                                                    monkeypatch):
     """The program steps on the first half of each batch's rows, the mean
     taken over those."""
-    from benchmarks.lib import paddle_lm
-    orig = paddle_lm.rows_of
-    monkeypatch.setattr(paddle_lm, "rows_of",
+    cell = tiny_cell(workload)
+    orig = cell["model"].rows_of
+    monkeypatch.setattr(cell["model"], "rows_of",
                         lambda b: orig(b)[:b.shape[0] // 2])
-    res = drive(tiny_cell(workload), capsys)
+    res = drive(cell, capsys)
     assert res["correct"] is False
     g = res["checks"]["grad_norm_gap_worst_leaf"]
     assert g["value"] > g["limit"]
@@ -150,8 +151,7 @@ def test_the_control_put_through_the_run_is_not_correct(workload, capsys):
     from benchmarks.lib import harness
     cell = tiny_cell(workload)
     driver = manifest.load_module("drivers", cell["traffic"]["driver"])
-    env = {"reference": manifest.load_module("reference", "opt"),
-           "compiles": harness.CompileCounter(), "on_chip": False,
+    env = {"compiles": harness.CompileCounter(), "on_chip": False,
            "peaks": PEAKS, "t_start": time.monotonic(), "control": "fp8"}
     out = driver.run(cell, 2 ** 31 + 5, 1.5, False, env)
     assert out["correct"] is True
@@ -165,6 +165,32 @@ def test_the_control_put_through_the_run_is_not_correct(workload, capsys):
         for fault in ("fault_half_batch", "fault_frozen_state"):
             assert [l["correct"] for l in lines
                     if l.get("phase") == fault] == [False]
+
+def test_readings_tool_reads_the_control_of_a_serving_cell(capsys,
+                                                           monkeypatch):
+    """benchmarks/tools/readings.py (the chip's tool for the readings that
+    limits are set from) on the CPU at the cell's tiny size: per seed a
+    reading, and for the first seed the fp8 control's, held to the cell's
+    own limit by the run's own decision."""
+    from benchmarks.lib import peaks
+    from benchmarks.tools import readings
+    monkeypatch.setattr(run, "look_for_chips", lambda chips: {
+        "platform": "cpu", "kind": "cpu", "count": chips, "visible": chips})
+    monkeypatch.setattr(run, "configure_cache", lambda: None)
+    monkeypatch.setattr(peaks, "peaks_for", lambda kind: PEAKS)
+    monkeypatch.setattr(manifest, "cell", lambda man, w: tiny_cell(w))
+    assert readings.main(["--workload", SERVE[0], "--seconds", "1.0",
+                          "--seeds", str(2 ** 31 + 3), "12",
+                          "--control-seeds", "1"]) == 0
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
+    got = [l for l in lines if l.get("phase") == "reading"]
+    assert [l["seed"] for l in got] == [2 ** 31 + 3, 12]
+    assert all(l["correct"] for l in got)
+    control, = [l for l in lines if l.get("phase") == "control"]
+    assert control["seed"] == 2 ** 31 + 3 and control["rounding"] == "fp8"
+    assert control["control_correct"] is False
+    assert control["control_gap"] > control["program_gap"]
+
 
 
 def _served_like(ref, cfg, n=4, seed=5):
@@ -189,7 +215,7 @@ def _served_like(ref, cfg, n=4, seed=5):
 
 def test_serving_control_in_fp8_reads_a_gap():
     ref = manifest.load_module("reference", "opt")
-    cfg = {k: v for k, v in TINY.items() if k != "deployment"}
+    cfg = manifest.load_module("models", "opt").tiny(deployment=False)
     sample = _served_like(ref, cfg)
     sound = check.served_logit_gap(ref, cfg, 5, sample, 32)
     control = check.served_logit_gap(ref, cfg, 5, sample, 32,
@@ -208,7 +234,7 @@ def test_serving_control_in_fp8_reads_a_gap():
 
 def test_training_control_in_fp8_and_faults_read_gaps():
     ref = manifest.load_module("reference", "opt")
-    cfg = dict({k: v for k, v in TINY.items() if k != "deployment"},
+    cfg = dict(manifest.load_module("models", "opt").tiny(deployment=False),
                max_position_embeddings=32)
     rng = np.random.default_rng(3)
     batches = [rng.integers(0, 64, (4, 33)).astype(np.int32)

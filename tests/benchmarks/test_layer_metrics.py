@@ -1,6 +1,8 @@
 """The per-layer readers that PR 31 adds (benchmarks/layer_metrics/), each
 on a hand-made ``ctx`` with known deltas; ``None`` (never 0) where its
-counters are missing, as on a parent commit that lacks them."""
+counters are missing, as on a parent commit that lacks them; and (PR 33)
+the kernels' exact names: a roofline reads the same with and without a
+second Mosaic kernel in its step."""
 
 import os
 import sys
@@ -96,3 +98,85 @@ def test_the_four_chip_mix_is_the_one_chip_mix_unchanged():
     four = manifest.load_json("traffic", "pretrain_4x2048_dp4.json")
     one.pop("note"), four.pop("note")
     assert four == one
+
+
+# ------------------------------------------------- kernels by their own names
+MS = 1_000_000
+PEAKS = {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11}
+
+
+@pytest.mark.parametrize("op,paged,flash", [
+    ("tpu_custom_call:paged_window_attention", True, False),
+    ("tpu_custom_call:flash_fwd", False, True),
+    ("tpu_custom_call:flash_bwd_dq", False, True),
+    ("tpu_custom_call:flash_bwd_dkv", False, True),
+    ("tpu_custom_call:jvp_flash_fwd_", False, True),
+    ("tpu_custom_call:jvp_flash_bwd_dq_", False, True),
+    ("tpu_custom_call:jvp_flash_bwd_dkv_", False, True),
+    ("tpu_custom_call:grouped_expert_matmul", False, False),
+    ("tpu_custom_call:decode_attention", False, False),
+    ("tpu_custom_call:paged_window_attention_v2", False, False),
+    ("tpu_custom_call:jvp_flash_fwd", False, False),
+    ("tpu_custom_call:lstm_fwd", False, False),
+    ("fusion:paged_window_attention", False, False),
+    ("paged_window_attention", False, False),
+    ("tpu_custom_call:", False, False),
+])
+def test_kernel_matches_are_exact(op, paged, flash):
+    from benchmarks.lib import names
+    assert names.is_paged_attn_kernel(op) is paged
+    assert names.is_flash_kernel(op) is flash
+
+
+def _step_trace(kernels, other=None):
+    """Ten executions of a step program, 10 ms each: 2 ms of fusions, then
+    ``kernels`` (name, ms) back to back, then ``other`` (a second Mosaic
+    kernel that is none of the benchmark's) if given."""
+    from benchmarks.lib import trace
+    ops, mods = [], []
+    for i in range(10):
+        t = i * 12 * MS
+        mods.append((t, 10 * MS, "jit__step_impl(1)"))
+        mods.append((t, 10 * MS, "jit_step(1)"))
+        ops.append((t, 2 * MS, "fusion:fusion"))
+        at = t + 2 * MS
+        for name, ms in kernels + ([other] if other else []):
+            ops.append((at, ms * MS, name))
+            at += ms * MS
+    return trace.Trace([{"ops": ops, "modules": mods}])
+
+
+@pytest.mark.parametrize("metric,kernels,more", [
+    ("paged_attn_roofline",
+     [("tpu_custom_call:paged_window_attention", 4)],
+     {"traced_counters": {"cache_tokens_read": 30_000}, "kv_itemsize": 2}),
+    ("flash_roofline",
+     [("tpu_custom_call:jvp_flash_fwd_", 1),
+      ("tpu_custom_call:jvp_flash_bwd_dq_", 2),
+      ("tpu_custom_call:jvp_flash_bwd_dkv_", 1)],
+     {"rows_per_chip": 4, "seq_len": 2048}),
+])
+def test_a_second_custom_call_in_the_step_is_not_counted(metric, kernels,
+                                                         more):
+    """The first configuration that puts a second Mosaic kernel into a
+    step (a grouped expert product, say) must not have it read as
+    attention: each roofline reads the same with and without it."""
+    opt = manifest.load_module("models", "opt")
+    cfg = {"hidden_size": 2048, "ffn_dim": 8192, "num_hidden_layers": 24,
+           "vocab_size": 50272, "max_position_embeddings": 2048}
+    base = dict(EMPTY, config=cfg, model=opt, peaks=PEAKS, **more)
+    reader = manifest.load_module("layer_metrics", metric)
+    alone = reader.read(dict(base, trace=_step_trace(kernels)))
+    beside = reader.read(dict(base, trace=_step_trace(
+        kernels, ("tpu_custom_call:grouped_expert_matmul", 3))))
+    assert alone is not None and alone == pytest.approx(beside)
+    if metric == "paged_attn_roofline":     # 30,000 tokens x 196,608 B
+        assert alone == pytest.approx(
+            100.0 * 30_000 * 196_608 / 1e11 / 0.040)
+    else:                                   # 4 ms of kernels a step
+        least = 4 * 3 * 2 * 2048 * 2048 * 2048 * 24 / 1e12
+        assert alone == pytest.approx(100.0 * least / 0.004)
+    # a step whose only custom call is another kernel reads nothing
+    none = reader.read(dict(base, trace=_step_trace(
+        [], ("tpu_custom_call:grouped_expert_matmul", 3))))
+    assert none is None
