@@ -48,8 +48,14 @@ class TransformerDecoder:
 
     def __init__(self, params, *, n_layers: int, n_heads: int,
                  name: str = "tfm", moe_k: int = 2,
-                 moe_capacity_factor: Optional[float] = None):
+                 moe_capacity_factor: Optional[float] = None,
+                 block=None):
         prefix = f"_{name}"
+        # the block description (models/block.py): None is the block the
+        # layer DSL trains, written out below; a LatentBlock replaces
+        # embed / attention / FFN / head here and in PagedDecoder's step
+        self.block = block
+        self._pre = f"_{name}_"
         self.p = {k: jnp.asarray(v) for k, v in params.items()
                   if k.startswith(prefix)}
         self.n_layers = n_layers
@@ -83,7 +89,17 @@ class TransformerDecoder:
                 and global_config().use_flash_attention
                 and jax.default_backend() == "tpu")
 
+    @property
+    def max_positions(self) -> int:
+        """Positions the model can address: the learned table's rows,
+        or what a rotary block's description states."""
+        if self.block is not None:
+            return int(self.block.max_positions)
+        return int(self.p[f"_{self.name}_pos_emb.w0"].shape[0])
+
     def _embed(self, p, ids, pos):
+        if self.block is not None:
+            return self.block.embed(p, self._pre, ids)
         n = self.name
         return (p[f"_{n}_tok_emb.w0"][ids]
                 + p[f"_{n}_pos_emb.w0"][pos])
@@ -91,6 +107,9 @@ class TransformerDecoder:
     def _block(self, p, i, x, k_cache, v_cache, pos, kv_len):
         """One decoder block over a [b, t, d] slice; reads/extends the
         [b, T, h, dh] caches at positions [pos, pos+t)."""
+        if self.block is not None:
+            return self._latent_block(p, i, x, k_cache, v_cache, pos,
+                                      kv_len)
         n, h = self.name, self.n_heads
         ln1 = _ln(x, p[f"_{n}_l{i}_ln1.w0"], p[f"_{n}_l{i}_ln1.wbias"])
         q = _heads(ln1 @ p[f"_{n}_l{i}_q.w0"], h)
@@ -143,10 +162,34 @@ class TransformerDecoder:
         x = x + attn @ p[f"_{n}_l{i}_proj.w0"]
         return self._ffn(p, i, x), k_cache, v_cache
 
+    def _latent_block(self, p, i, x, c_cache, r_cache, pos, kv_len):
+        """A LatentBlock layer over [b, t, d]: the caches hold one
+        [b, T, rkv] latent and one [b, T, dr] rotary-key row a token; the
+        expanded (unabsorbed) attention reads them (the paged step uses
+        the absorbed form; tests hold the two to each other)."""
+        blk, pre = self.block, self._pre
+        t, T = x.shape[1], c_cache.shape[1]
+        qpos = pos + jnp.arange(t)
+        q_nope, q_rope, c_kv, k_rope = blk.qkv(
+            p, pre, i, x, jnp.broadcast_to(qpos[None], x.shape[:2]))
+        c_cache = jax.lax.dynamic_update_slice(
+            c_cache, c_kv.astype(c_cache.dtype), (0, pos, 0))
+        r_cache = jax.lax.dynamic_update_slice(
+            r_cache, k_rope.astype(r_cache.dtype), (0, pos, 0))
+        kpos = jnp.arange(T)[None, :]
+        mask = (kpos <= qpos[:, None]) & (kpos < kv_len)
+        attn = blk.attend(p, pre, i, q_nope, q_rope, c_cache, r_cache,
+                          jnp.broadcast_to(mask[None], (x.shape[0], t, T)),
+                          absorbed=False)
+        x = x + blk.project(p, pre, i, attn)
+        return self._ffn(p, i, x), c_cache, r_cache
+
     def _ffn(self, p, i, x):
         """ln2 + FFN (dense or MoE) + residual over [b, t, d] — shared
         between the dense-cache block and the paged step (PagedDecoder),
         so the two paths cannot drift numerically."""
+        if self.block is not None:
+            return self.block.ffn(p, self._pre, i, x)[0]
         n = self.name
         ln2 = _ln(x, p[f"_{n}_l{i}_ln2.w0"], p[f"_{n}_l{i}_ln2.wbias"])
         if f"_{n}_l{i}_moe.gate" in p:
@@ -184,6 +227,8 @@ class TransformerDecoder:
         return x
 
     def _logits(self, p, x):
+        if self.block is not None:
+            return self.block.logits(p, self._pre, x)
         n = self.name
         x = _ln(x, p[f"_{n}_lnf.w0"], p[f"_{n}_lnf.wbias"])
         if f"_{n}_head.w0" in p:
@@ -210,6 +255,11 @@ class TransformerDecoder:
         b = prompt.shape[0]
         d = p[f"_{n}_tok_emb.w0"].shape[1]
         dtype = p[f"_{n}_tok_emb.w0"].dtype
+        if self.block is not None:
+            caches = [tuple(jnp.zeros((b, max_len, w), dtype) for w in
+                            self.block.cache_widths(p, self._pre))
+                      for _ in range(self.n_layers)]
+            return self._forward(p, prompt, None, caches, 0, plen)
         # kv head count from the k projection's width (grouped-query
         # attention stores kv_h-sized caches — THE decode win of GQA)
         dh = d // h
@@ -223,7 +273,7 @@ class TransformerDecoder:
     def _validate(self, prompt, max_len):
         plen = int(prompt.shape[1])
         assert max_len > plen, f"max_len {max_len} <= prompt length {plen}"
-        pos_rows = self.p[f"_{self.name}_pos_emb.w0"].shape[0]
+        pos_rows = self.max_positions
         assert max_len <= pos_rows, (
             f"max_len {max_len} exceeds the position table ({pos_rows} "
             "rows) — jit gathers clamp silently, so positions past the "
@@ -553,6 +603,8 @@ class PagedDecoder:
     #: the stored pool layout, as the artifact fingerprints name it: an
     #: executable built for another layout can never be resolved
     POOL_LAYOUT = "L,N,page,g*dh"
+    #: a LatentBlock's one pool: rows [c_kv | k_rope | zero lanes]
+    LATENT_POOL_LAYOUT = "L,N,page,c_kv|k_rope|0"
 
     def __init__(self, dense: TransformerDecoder, *, num_slots: int,
                  page_size: int, num_pages: int,
@@ -562,14 +614,22 @@ class PagedDecoder:
                  warm_start: bool = True,
                  kv_quant: Optional[str] = None):
         assert num_pages >= 2, "need at least the null page + one real"
-        assert max_pages_per_slot * page_size <= \
-            dense.p[f"_{dense.name}_pos_emb.w0"].shape[0], (
+        assert max_pages_per_slot * page_size <= dense.max_positions, (
             "slot capacity exceeds the position table — positions past "
             "it would silently clamp to its last row")
         assert window >= 1, window
         assert attention in ("auto", "kernel", "gather"), attention
         assert kv_quant in (None, "int8"), kv_quant
+        #: a LatentBlock has ONE pool, a [c_kv | k_rope] row a token and
+        #: layer; its step also counts the held experts' load
+        self.latent = dense.block is not None
+        if self.latent and kv_quant is not None:
+            raise ValueError(
+                "kv_quant is not supported on a latent (MLA) cache: the "
+                "int8 layout packs per-head scales, and a latent row has "
+                "no heads")
         self.kv_quant = kv_quant
+        self.expert_counts = None   # the last step's held load (device)
         self.dense = dense
         self.num_slots = int(num_slots)
         self.page_size = int(page_size)
@@ -579,29 +639,44 @@ class PagedDecoder:
         self.window = int(window)
         n, h = dense.name, dense.n_heads
         d = dense.p[f"_{n}_tok_emb.w0"].shape[1]
-        self.head_dim = d // h
-        self.kv_heads = dense.p[f"_{n}_l0_k.w0"].shape[1] // self.head_dim
         self.dtype = dense.p[f"_{n}_tok_emb.w0"].dtype
         from paddle_tpu.ops import pallas_decode as paged_ops
-        probe_q = jax.ShapeDtypeStruct(
-            (self.num_slots, self.window, h, self.head_dim), self.dtype)
-        kv_dtype = jnp.int8 if self.kv_quant == "int8" else self.dtype
-        probe_k = jax.ShapeDtypeStruct(
-            (self.num_pages, self.page_size,
-             self.kv_heads * self.head_dim), kv_dtype)
-        probe_s = jax.ShapeDtypeStruct(
-            (self.num_pages, self.page_size, self.kv_heads),
-            jnp.float32) if self.kv_quant == "int8" else None
         on_tpu = jax.default_backend() == "tpu"
+        if self.latent:
+            # a token's row [c_kv | k_rope], padded with zero lanes to
+            # whole 128-lane tiles: 576 -> 640 at the published widths (a
+            # pool whose rows are not whole tiles reaches the kernel
+            # through a pool-sized relayout copy every layer)
+            rkv, dr = dense.block.cache_widths(dense.p, dense._pre)
+            self.row_lanes = -(-(rkv + dr) // 128) * 128
+            self.counts_experts = \
+                dense.block.n_expert_layers(dense.n_layers) > 0
+            supported = paged_ops.latent_kernel_supported(
+                self.num_slots, self.window * h, self.row_lanes, rkv,
+                self.page_size, self.max_pages_per_slot, self.dtype)
+        else:
+            self.counts_experts = False
+            self.head_dim = d // h
+            self.kv_heads = \
+                dense.p[f"_{n}_l0_k.w0"].shape[1] // self.head_dim
+            probe_q = jax.ShapeDtypeStruct(
+                (self.num_slots, self.window, h, self.head_dim), self.dtype)
+            kv_dtype = jnp.int8 if self.kv_quant == "int8" else self.dtype
+            probe_k = jax.ShapeDtypeStruct(
+                (self.num_pages, self.page_size,
+                 self.kv_heads * self.head_dim), kv_dtype)
+            probe_s = jax.ShapeDtypeStruct(
+                (self.num_pages, self.page_size, self.kv_heads),
+                jnp.float32) if self.kv_quant == "int8" else None
+            supported = paged_ops.paged_kernel_supported(
+                probe_q, probe_k, probe_s,
+                pages_per_slot=self.max_pages_per_slot)
         if attention == "kernel":
             self.use_kernel = True
         elif attention == "gather":
             self.use_kernel = False
         else:
-            self.use_kernel = on_tpu and \
-                paged_ops.paged_kernel_supported(
-                    probe_q, probe_k, probe_s,
-                    pages_per_slot=self.max_pages_per_slot)
+            self.use_kernel = on_tpu and supported
         self.kernel_interpret = self.use_kernel and not on_tpu
         # donating the pools lets XLA update pages in place (the pools
         # ARE the device memory budget); the CPU backend has no donation
@@ -627,15 +702,22 @@ class PagedDecoder:
                 "kernel_interpret": self.kernel_interpret,
                 "kv_quant": self.kv_quant,
                 "pool_layout": self.POOL_LAYOUT}
-        self._step_fp = fingerprint("paged_step", dense.p, plan=plan)
         page_plan = {"num_pages": self.num_pages,
                      "page_size": self.page_size,
                      "n_layers": dense.n_layers,
-                     "kv_heads": self.kv_heads,
-                     "head_dim": self.head_dim,
                      "dtype": str(jnp.dtype(self.dtype)),
                      "kv_quant": self.kv_quant,
                      "pool_layout": self.POOL_LAYOUT}
+        if self.latent:
+            # the description changes the program and the pools' shape
+            layout = {"block": repr(dense.block),
+                      "pool_layout": self.LATENT_POOL_LAYOUT}
+            plan.update(layout)
+            page_plan.update(layout, row_lanes=self.row_lanes)
+        else:
+            page_plan.update(kv_heads=self.kv_heads,
+                             head_dim=self.head_dim)
+        self._step_fp = fingerprint("paged_step", dense.p, plan=plan)
         self._copy_fp = fingerprint("paged_copy", dense.p,
                                     plan=page_plan)
         self._read_fp = fingerprint("paged_read", dense.p,
@@ -658,6 +740,11 @@ class PagedDecoder:
         the two-tier pytrees ``{"q": int8 values in that layout,
         "s": float32 per-row scales [L, n_pages, page_size, g]}``."""
         rows = (self.dense.n_layers, self.num_pages, self.page_size)
+        if self.latent:
+            # one pool; the engine and its callers know two attribute
+            # names, so the second is an empty pytree: every page copy,
+            # read, write and donation maps over it and finds nothing
+            return jnp.zeros(rows + (self.row_lanes,), self.dtype), {}
         row = self.kv_heads * self.head_dim
         if self.kv_quant == "int8":
             def one():
@@ -669,6 +756,10 @@ class PagedDecoder:
                 jnp.zeros(rows + (row,), self.dtype))
 
     def pool_bytes(self) -> int:
+        if self.latent:
+            return int(jnp.dtype(self.dtype).itemsize) * \
+                self.dense.n_layers * self.num_pages * self.page_size * \
+                self.row_lanes
         rows = self.dense.n_layers * self.num_pages * \
             self.page_size * self.kv_heads
         if self.kv_quant == "int8":
@@ -727,6 +818,34 @@ class PagedDecoder:
             x = d0._ffn(p, i, x)
         return x, k_pool, v_pool
 
+    def _latent_paged_block(self, p, i, x, pool, page_idx, offs,
+                            positions, page_tables, kv_lens, active):
+        """A LatentBlock layer of the step: the token's [c_kv | k_rope]
+        row scattered into the donated pool in place, then the absorbed
+        attention over the slot's pages (one latent row a token serves
+        as key and as value), then the block's own FFN. -> (x, pool,
+        held load or None)."""
+        from paddle_tpu.ops import pallas_decode as paged_ops
+        d0 = self.dense
+        blk, pre = d0.block, d0._pre
+        q_nope, q_rope, c_kv, k_rope = blk.qkv(p, pre, i, x, positions)
+        with jax.named_scope("latent_kv_write"):
+            row = jnp.concatenate([c_kv, k_rope], axis=-1)
+            row = row.reshape(-1, row.shape[-1]).astype(pool.dtype)
+            pool = pool.at[i, page_idx.reshape(-1), offs.reshape(-1)].set(
+                jnp.pad(row, ((0, 0), (0, pool.shape[-1] - row.shape[-1]))))
+        with jax.named_scope("latent_attn"):
+            o_lat = paged_ops.paged_latent_attention(
+                blk.absorb_q(p, pre, i, q_nope), q_rope, pool,
+                page_tables, kv_lens, layer=i, scale=blk.softmax_scale,
+                use_kernel=self.use_kernel,
+                interpret=self.kernel_interpret)
+            attn = blk.expand_o(p, pre, i, o_lat)
+        x = x + blk.project(p, pre, i, attn.reshape(x.shape[:2] + (-1,)))
+        with jax.named_scope("ffn"):
+            x, load = blk.ffn(p, pre, i, x, active)
+        return x, pool, load
+
     def _step_impl(self, p, k_pool, v_pool, tokens, positions,
                    page_tables, active, key):
         """tokens/positions/active [S, W]; page_tables [S, P] int32 ->
@@ -743,7 +862,15 @@ class PagedDecoder:
         page_idx = jnp.where(active, page_idx, 0)       # null the dead
         offs = jnp.where(active, positions % ps, 0)
         kv_lens = positions + 1
+        loads = []
         for i in range(d0.n_layers):
+            if self.latent:
+                x, k_pool, load = self._latent_paged_block(
+                    p, i, x, k_pool, page_idx, offs, positions,
+                    page_tables, kv_lens, active)
+                if load is not None:
+                    loads.append(load)
+                continue
             x, k_pool, v_pool = self._paged_block(
                 p, i, x, k_pool, v_pool, page_idx, offs, page_tables,
                 kv_lens)
@@ -755,6 +882,10 @@ class PagedDecoder:
                 nxt = jax.random.categorical(
                     key, logits.astype(jnp.float32) /
                     self.temperature).astype(jnp.int32)
+        if self.counts_experts:
+            # two small sums over the step's expert layers ride beside
+            # the tokens: (assignments on held experts, held experts hit)
+            nxt = (nxt, sum(loads))
         return nxt, k_pool, v_pool
 
     @staticmethod
@@ -781,6 +912,8 @@ class PagedDecoder:
         def heads(v):
             return v.reshape(v.shape[:-1] + (self.kv_heads, self.head_dim))
 
+        if self.latent:
+            return page            # a latent row has no heads to split
         if self.kv_quant == "int8":
             return {"q": heads(page["q"]), "s": page["s"]}
         return heads(page)
@@ -870,6 +1003,9 @@ class PagedDecoder:
             self._step_exe = resolve(self._step_fp, self._step, args,
                                      warm=self.warm_start)
         nxt, k_pool, v_pool = self._step_exe(*args)
+        if self.counts_experts:
+            # the engine fetches it with the tokens (one sync)
+            nxt, self.expert_counts = nxt
         if squeeze:
             nxt = nxt[:, 0]
         return nxt, k_pool, v_pool
@@ -898,7 +1034,11 @@ class DraftDecoder:
     def __init__(self, dense: TransformerDecoder, *, num_slots: int,
                  max_seq_len: int, window: int = 1,
                  warm_start: bool = True):
-        pos_rows = dense.p[f"_{dense.name}_pos_emb.w0"].shape[0]
+        if dense.block is not None:
+            raise ValueError(
+                "a draft over a latent (MLA) block is not supported: "
+                "DraftDecoder's slot-private caches are per-head K/V")
+        pos_rows = dense.max_positions
         assert max_seq_len <= pos_rows, (max_seq_len, pos_rows)
         self.dense = dense
         self.num_slots = int(num_slots)

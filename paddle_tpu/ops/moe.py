@@ -31,6 +31,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops.linear import einsum_two_terms as _mm
+
 
 def moe_capacity(n_tokens: int, num_experts: int, k: int,
                  capacity_factor: float) -> int:
@@ -257,3 +259,70 @@ def moe_ffn(x: jnp.ndarray, valid: Optional[jnp.ndarray],
     expert_out = _ep(jnp.einsum("ecf,efd->ecd", h, w_down.astype(cdt)))
     y = jnp.einsum("nec,ecd->nd", combine.astype(cdt), expert_out)
     return y, aux
+
+
+# ------------------------------------------------- a chip's share, drop-free
+# The serving form of the DeepSeek-V3 family's expert layer (models/block.py
+# LatentBlock; used inside PagedDecoder's step): sigmoid scores, a
+# bias-corrected top-k over ALL router outputs, normalised and scaled
+# weights, NO token dropped at any token count and no [n, E, n] tensor. The
+# layer is told which experts it holds, [lo, lo + n_held) of the router's
+# outputs, and adds only their terms: what an expert-parallel deployment's
+# one chip computes before the exchange, which this file does not stand in
+# for.
+def sigmoid_topk_route(h: jnp.ndarray, gate_w: jnp.ndarray,
+                       bias: jnp.ndarray, *, k: int, scale: float
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """h [n, d], gate_w [d, E], bias [E] -> (idx [n, k] int32 over all E
+    outputs, weights [n, k] float32). Scores, choice and weights are
+    float32 at the highest precision whatever the compute dtype: the bias
+    moves the CHOICE (``top_k(s + bias)``), never the weight
+    (``s[idx] / (sum s[idx] + 1e-20) * scale``)."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        h.astype(jnp.float32), gate_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    picked = jnp.take_along_axis(s, idx, axis=-1)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), weights * scale
+
+
+def _held_onehot(idx, lo: int, n_held: int):
+    """[n, k, n_held] bool: assignment j of token t fell on held expert e."""
+    return idx[:, :, None] == lo + jnp.arange(n_held, dtype=idx.dtype)
+
+
+def held_combine(idx, weights, *, lo: int, n_held: int) -> jnp.ndarray:
+    """[n, n_held] float32: the weight token t gives held expert e, zero
+    where it did not choose it."""
+    return jnp.sum(jnp.where(_held_onehot(idx, lo, n_held),
+                             weights[:, :, None], 0.0), axis=1)
+
+
+def held_load(idx, active, *, lo: int, n_held: int) -> jnp.ndarray:
+    """int32 [2]: (assignments of ACTIVE tokens that fell on held experts,
+    held experts that got at least one active token). The second sizes a
+    grouped product that would skip the experts no token chose."""
+    hit = _held_onehot(idx, lo, n_held) & active[:, None, None]
+    return jnp.stack([jnp.sum(hit, dtype=jnp.int32),
+                      jnp.sum(jnp.any(hit, axis=(0, 1)), dtype=jnp.int32)])
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    """(silu(x Wg) * x Wu) Wd over x [n, d] float32 -> float32, each
+    product at the activations' precision (ops/linear.einsum_two_terms)."""
+    a = jax.nn.silu(_mm("nd,df->nf", x, w_gate)) * _mm("nd,df->nf", x, w_up)
+    return _mm("nf,fd->nd", a, w_down)
+
+
+def held_experts_ffn(x, comb, w_gate, w_up, w_down) -> jnp.ndarray:
+    """sum_e comb[:, e] * SwiGLU_e(x): x [n, d] float32, comb [n, n_held]
+    float32, w_gate/w_up [n_held, d, f], w_down [n_held, f, d] -> [n, d]
+    float32. Every held expert's SwiGLU over all n tokens as one batched
+    product, weighted before the down projection so that experts and
+    their width contract in ONE product: at a decode step's token counts
+    the layer's time is the reading of its weights, which this form reads
+    once."""
+    a = jax.nn.silu(_mm("nd,edf->nef", x, w_gate)) * \
+        _mm("nd,edf->nef", x, w_up)
+    return _mm("nef,efd->nd", a * comb[:, :, None], w_down)

@@ -551,3 +551,215 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
     out = jnp.diagonal(out.reshape(S, n_chunks, hp, W, rep, hp, dh),
                        axis1=2, axis2=5)            # [S, c, W, rep, dh, j]
     return out.transpose(0, 2, 1, 5, 3, 4).reshape(S, W, h, dh)
+
+
+# ------------------------------------------------------- latent (MLA) pool
+# A latent cache holds ONE row a token and layer, [c_kv | k_rope], shared
+# by every query head (models/block.py LatentBlock): ONE pool
+# [L, n_pages, page_size, lanes], lanes = rkv + dr rounded up to whole
+# 128-lane tiles (zero lanes at the end), kept in that layout for the
+# engine's life like the per-head pools above. In the absorbed form every
+# head scores [q_lat | q_rope] (q_lat = q_nope W_uk^T, rkv wide) against the
+# row and the probabilities weight its first rkv lanes again: a latent page
+# brought from HBM once serves as key and as value, for all heads.
+#
+# What the kernel's grid costs (v5e, 64 slots x 64 heads, 4096 positions a
+# slot, bf16; my chip run, PR 35): every visit of a page operand by a grid
+# step costs ~65 ns of the scalar core whether its DMA is skipped or not,
+# so a call's time follows slots x table width x operands a page, not the
+# live tokens (an EMPTY cache read 1.89 ms where 2,300 tokens a slot read
+# 2.41, with c_kv and k_rope as two pools of 16-row pages). Hence one pool
+# (one operand a page), and pages of 32 rows where the deployment can
+# choose (1.28 against 2.41 ms at the same tokens).
+_LATENT_ROWS_PER_STEP = 512
+
+
+def _pages_per_block(page_size: int, pages_per_slot: int) -> int:
+    """Pages one grid step of the latent kernel walks: 512 cache rows,
+    each page its own input block with its own DMA in flight."""
+    return max(1, min(_LATENT_ROWS_PER_STEP // page_size, pages_per_slot))
+
+
+def latent_kernel_supported(num_slots: int, rows: int, lanes: int,
+                            rkv: int, page_size: int, pages_per_slot: int,
+                            dtype) -> bool:
+    """Does :func:`paged_latent_attention`'s kernel lower on the chip for
+    these shapes: rows and their c_kv part of whole 128-lane tiles, pages
+    of whole sublane tiles of the pool's dtype, a step's pages and the
+    [rows, rkv] accumulator inside VMEM, the page tables inside SMEM.
+    ``rows`` = window x heads (the kernel stacks two terms of each)."""
+    esize = jnp.dtype(dtype).itemsize
+    if lanes % 128 or rkv % 128 or page_size % (32 // esize):
+        return False
+    k = _pages_per_block(page_size, pages_per_slot)
+    span = k * page_size
+    rows8 = _round_up(2 * rows, 8)
+    pages = 2 * span * lanes * esize                     # double-buffered
+    work = span * lanes * esize + rows8 * (
+        2 * lanes * esize + 3 * rkv * 4 + 3 * span * 4)
+    smem = 4 * num_slots * (_round_up(pages_per_slot, 128) + 128 + 1)
+    return pages + work <= _PAGED_VMEM_BYTES and smem <= _PAGED_SMEM_BYTES
+
+
+def _two_terms(x):
+    """float32 x as two bfloat16 terms stacked on the leading axis,
+    [hi; lo]: hi is x with its low 16 bits cleared (exactly a bfloat16),
+    lo = round(x - hi). A product with the stack, its two halves added,
+    is the product with x itself to 16 mantissa bits at one pass over the
+    other operand. The bits are masked, not cast there and back: a cast
+    pair may be kept at float32 by the compiler, and lo is then nought."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    hi = jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                      jnp.float32)
+    return jnp.concatenate([hi, x - hi], axis=0).astype(jnp.bfloat16)
+
+
+def _paged_latent_kernel(tables_ref, used_ref, lens_ref, q_ref, *rest,
+                         scale, page_size, window, heads, rkv,
+                         pages_per_block, split):
+    """Grid (S, ceil(P / K)), page blocks fastest. A step holds K pages
+    of the slot, each its own input block whose index map clamped the
+    page to the slot's last allocated one (a revisited page skips its
+    DMA; a step wholly past the allocation skips the math). The K pages
+    stand as one [K*page, lanes] tile of cache rows: the scores of all
+    ``window x heads`` query rows in ONE product ``q . rows^T`` (q is
+    [q_lat | q_rope | 0]), online softmax in float32, then
+    ``p . rows[:, :rkv]`` into the [rows, rkv] float32 accumulator: the
+    latent row is read from HBM once and used twice.
+
+    Precision: the cache is what it is stored as; nothing else is
+    rounded to it. With ``split`` (a bfloat16 pool) q
+    arrives as two terms of the pool's dtype stacked on its rows and the
+    probabilities are split the same way before their product (the two
+    halves of each result are added: 128 rows where 64 left the MXU half
+    empty), and the output stays float32. A query, a probability or an
+    output rounded to bfloat16 is an error of 0.2-0.4 % of the attention
+    output that no averaging over the cached tokens removes, and a router
+    downstream turns it into moved top-k choices."""
+    K = pages_per_block
+    page_refs = rest[:K]
+    out_ref, m_ref, l_ref, acc_ref = rest[K:]
+    s = pl.program_id(0)
+    b = pl.program_id(1)
+    rows_n = acc_ref.shape[0]
+    span = K * page_size
+    page = (0,) * (len(page_refs[0].shape) - 2)
+
+    @pl.when(b == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(b * K < used_ref[s])
+    def _accumulate():
+        c = jnp.concatenate([r[page] for r in page_refs], axis=0) \
+            if K > 1 else page_refs[0][page]               # [span, lanes]
+        sc = jax.lax.dot_general(
+            q_ref[0], c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if split:
+            sc = sc[:rows_n] + sc[rows_n:]
+        # causal/ragged mask against ABSOLUTE positions: row (w, h) is
+        # window token w and sees < lens[s, w]; a clamped (repeated)
+        # page lies past every length and is masked whole
+        cols = b * span + jax.lax.broadcasted_iota(
+            jnp.int32, (rows_n, span), 1)
+        lim = jnp.full((rows_n, span), lens_ref[s, 0], jnp.int32)
+        if window > 1:
+            rows = jax.lax.broadcasted_iota(jnp.int32, (rows_n, span), 0)
+            for w in range(1, window):
+                lim = jnp.where(rows >= w * heads, lens_ref[s, w], lim)
+        sc = jnp.where(cols < lim, sc * (scale * LOG2E), NEG_INF)
+        m_prev = m_ref[...]
+        m_cur = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp2(m_prev - m_cur)
+        pm = jnp.exp2(sc - m_cur)                          # [rows, span]
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(pm, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            _two_terms(pm) if split else pm, c[:, :rkv],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        if split:
+            pv = pv[:rows_n] + pv[rows_n:]
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_cur
+
+    @pl.when(b == pl.num_programs(1) - 1)
+    def _finalize():
+        out_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def paged_latent_attention(q_lat, q_rope, pages, page_tables, kv_lens, *,
+                           layer, scale, use_kernel=False, interpret=False):
+    """Absorbed multi-head latent attention over the paged latent pool.
+
+    q_lat [S, W, H, rkv] (``q_nope W_uk^T``) and q_rope [S, W, H, dr]
+    (rotated); pages [L, n_pages, page_size, lanes], the pool AS STORED
+    (a row is [c_kv | k_rope | zero lanes]), with ``layer`` (a Python
+    int) naming the layer read: it goes into the kernel's block index map
+    or the gather's index, never into a slice of the pool; page_tables
+    [S, P] int32; kv_lens [S, W] per-token valid lengths (token w of slot
+    s is the query at position kv_lens[s, w] - 1). Returns float32 o_lat
+    [S, W, H, rkv] = softmax(scores * scale) . c_kv, which the caller
+    expands by W_uv.
+
+    ``use_kernel=False`` gathers the slot's full table width and runs the
+    same mathematics as einsums (the CPU path and the kernel's test
+    reference); ``use_kernel=True`` runs :func:`_paged_latent_kernel`,
+    whose cache reads follow the slot's allocated pages."""
+    S, W, H, rkv = q_lat.shape
+    ps, lanes = pages.shape[-2:]
+    P = page_tables.shape[1]
+    lens = jnp.asarray(kv_lens, jnp.int32).reshape(S, W)
+    # q as the row is laid out: zero lanes where the row has them
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32)
+    q = jnp.pad(q, [(0, 0)] * 3 + [(0, lanes - q.shape[-1])])
+    if not use_kernel:
+        hp = jax.lax.Precision.HIGHEST
+        c = gather_pages(pages, page_tables, layer).astype(jnp.float32)
+        sc = jnp.einsum("swhr,skr->shwk", q, c, precision=hp)
+        mask = jnp.arange(P * ps)[None, None, :] < lens[:, :, None]
+        sc = jnp.where(mask[:, None], sc * scale, NEG_INF)
+        return jnp.einsum("shwk,skr->swhr", jax.nn.softmax(sc, axis=-1),
+                          c[..., :rkv], precision=hp)
+    K = _pages_per_block(ps, P)
+    used = jnp.clip(-(-jnp.max(lens, axis=1) // ps), 1, P)
+    rows_n = W * H
+    split = pages.dtype == jnp.bfloat16
+    q = q.reshape(S, rows_n, lanes)
+    if split:       # [hi; lo] on each slot's rows
+        q = jax.vmap(_two_terms)(q)
+    else:
+        q = q.astype(pages.dtype)
+    q_rows = q.shape[1]
+
+    def _slot_map(si, bi, tables, used_, lens_):
+        return (si, 0, 0)
+
+    def _page_map(j):
+        def index(si, bi, tables, used_, lens_):
+            return (layer, tables[si, jnp.minimum(bi * K + j,
+                                                  used_[si] - 1)], 0, 0)
+        return index
+
+    kernel = functools.partial(
+        _paged_latent_kernel, scale=scale, page_size=ps, window=W,
+        heads=H, rkv=rkv, pages_per_block=K, split=split)
+    in_specs = [pl.BlockSpec((1, q_rows, lanes), _slot_map)]
+    in_specs += [pl.BlockSpec((1, 1, ps, lanes), _page_map(j))
+                 for j in range(K)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S, -(-P // K)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, rows_n, rkv), _slot_map),
+        scratch_shapes=[pltpu.VMEM((rows_n, 1), jnp.float32),
+                        pltpu.VMEM((rows_n, 1), jnp.float32),
+                        pltpu.VMEM((rows_n, rkv), jnp.float32)])
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, rows_n, rkv), jnp.float32),
+        interpret=interpret, name="paged_latent_attention",
+    )(jnp.asarray(page_tables, jnp.int32), used.astype(jnp.int32), lens,
+      q, *([pages] * K))
+    return out.reshape(S, W, H, rkv)

@@ -342,7 +342,12 @@ class DecodeEngine:
                  warm_start: bool = True,
                  kv_quant: Optional[str] = None,
                  kv_spill_pages: int = 0):
-        pos_rows = decoder.p[f"_{decoder.name}_pos_emb.w0"].shape[0]
+        pos_rows = decoder.max_positions
+        block = getattr(decoder, "block", None)     # models/block.py
+        if block is not None and draft is not None and spec_k:
+            raise ValueError(
+                "speculative decoding (draft / spec_k) is not supported "
+                "on a latent (MLA) block")
         if max_seq_len is None:
             max_seq_len = pos_rows
         self.max_seq_len = min(int(max_seq_len), pos_rows)
@@ -376,7 +381,13 @@ class DecodeEngine:
             journal_emit("engine", "dequant_fallback",
                          reason="kernel_unsupported", kv_quant=kv_quant)
         self.pool = PagePool(int(num_pages))
+        # the pools are the decoder's business: whatever init_pools()
+        # gives (per-head K and V, the int8 pytrees, a latent block's
+        # one pool and an empty pytree) is only ever handed back to its
+        # step and its page copy / read / write
         self.k_pool, self.v_pool = self.paged.init_pools()
+        self._expert_layers = block.n_expert_layers(decoder.n_layers) \
+            if block is not None else 0
         self.prefix: Optional[PrefixIndex] = (
             PrefixIndex(self.pool, self.page_size) if prefix_cache
             else None)
@@ -439,6 +450,16 @@ class DecodeEngine:
                           # first admissions and their summed wait
                           # (submit -> slot), on the engine's clock
                           "admitted": 0, "queue_wait_ns": 0,
+                          # a share-aware expert layer's load, summed
+                          # over the step's expert layers by the step
+                          # itself: token-expert assignments of active
+                          # tokens that fell on HELD experts, held
+                          # experts that got at least one, and expert
+                          # layers x steps to divide by (all 0 for a
+                          # model without such layers)
+                          "expert_assignments_held": 0,
+                          "expert_hits_held": 0,
+                          "expert_layer_steps": 0,
                           # host nanoseconds by phase of step()/_loop:
                           # each is written by _phase() with the span of
                           # the same boundary (PERF.md section 3)
@@ -1117,13 +1138,26 @@ class DecodeEngine:
                             self._positions, self._tables, self._active,
                             key)
                     with self._phase("serving/sync", "host_sync_ns"):
-                        nxt = np.asarray(nxt)  # the ONE host sync per step
+                        # the ONE host sync per step; an expert model's
+                        # two load sums come with the tokens
+                        load = self.paged.expert_counts
+                        if load is None:
+                            nxt = np.asarray(nxt)
+                        else:
+                            import jax
+                            nxt, load = jax.device_get((nxt, load))
             # ptlint: disable=R7(serving boundary — in-flight requests settle typed and the pools rebuild; the engine thread must never die)
             except Exception as e:
                 self._recover_from_step_failure(e)
                 return False
             with self._phase("serving/commit", "host_commit_ns"):
                 self._commit(plan, live, nxt)
+                if load is not None:
+                    with self._cv:
+                        c = self._counters
+                        c["expert_assignments_held"] += int(load[0])
+                        c["expert_hits_held"] += int(load[1])
+                        c["expert_layer_steps"] += self._expert_layers
             return True
 
     def _plan_windows(self):

@@ -315,3 +315,81 @@ def test_serving_step_updates_the_pools_in_place(
         assert mem.temp_size_in_bytes < k_page.size * 2 * 4, (fn, mem)
         assert mem.alias_size_in_bytes == (
             paged.pool_bytes() if donate else 0), (fn, mem)
+
+
+# ------------------------------------------------------- latent (MLA) step
+def _kimi_k2_decoder(n_layers=3):
+    """Kimi-K2's served decoder at the published widths over zero
+    weights (only shapes are compiled): 1 dense + ``n_layers - 1`` expert
+    layers holding 12 of 384 experts, an eighth of the vocabulary, bf16."""
+    from benchmarks.lib import manifest
+    from paddle_tpu import models
+    cell = manifest.cell(manifest.load_manifest(), "kimik2_agent_2k")
+    cfg = dict(cell["config"], num_hidden_layers=n_layers)
+    shapes = cell["reference"].leaf_shapes(cfg)
+    params = {cell["model"].program_name(k):
+              jax.ShapeDtypeStruct(v, jnp.bfloat16)
+              for k, v in shapes.items()}
+    dec = models.TransformerDecoder(
+        {}, n_layers=n_layers, n_heads=64, name=cell["model"].NAME,
+        block=cell["model"].block_of(cfg, 4096))
+    dec.p = params
+    return dec
+
+
+@pytest.mark.parametrize("W", [1, 2], ids=["W1", "W2"])
+@pytest.mark.parametrize("ps", [16, 32], ids=["page16", "page32"])
+def test_latent_kernel_lowers(chip_compile, ps, W):
+    """What ``latent_kernel_supported`` accepts lowers: 64 heads against
+    one [c_kv 512 | k_rope 64 | 64 zero lanes] row a token, bf16."""
+    from paddle_tpu.ops import pallas_decode as pd
+    S, H, P, N = 8, 64, 4096 // ps, 64
+    assert pd.latent_kernel_supported(S, W * H, 640, 512, ps, P,
+                                      jnp.bfloat16)
+    assert not pd.latent_kernel_supported(S, W * H, 576, 512, ps, P,
+                                          jnp.bfloat16)
+
+    def fn(ql, qr, pool, tables, lens):
+        return pd.paged_latent_attention(ql, qr, pool, tables, lens,
+                                         layer=1, scale=0.1, use_kernel=True)
+
+    hlo = chip_compile(
+        fn, _sds((S, W, H, 512), jnp.float32),
+        _sds((S, W, H, 64), jnp.float32),
+        _sds((2, N, ps, 640), jnp.bfloat16), _sds((S, P), jnp.int32),
+        _sds((S, W), jnp.int32))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_latent_step_updates_the_pool_in_place(chip_executable,
+                                               monkeypatch):
+    """``kimik2_agent_2k``'s serving step (64 slots, 8,192 pages of 32,
+    bf16, the latent pool donated; three of its six layers, which is
+    every kind of layer): the pool stays in the layout the kernel's
+    blocks read and is written in place: no copy and no slice of it or
+    of one layer of it, all its bytes aliased, temporaries far under
+    them."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dec = _kimi_k2_decoder()
+    paged = dec.paged(num_slots=64, page_size=32, num_pages=8192,
+                      max_pages_per_slot=128, warm_start=False)
+    assert paged.use_kernel and not paged.kernel_interpret
+    pool, none = jax.eval_shape(paged.init_pools)
+    assert pool.shape == (3, 8192, 32, 640) and none == {}
+    sw = _sds((64, 1), jnp.int32)
+    args = (dec.p, pool, none, sw, sw, _sds((64, 128), jnp.int32),
+            _sds((64, 1), jnp.bool_), _sds((2,), jnp.uint32))
+    compiled = chip_executable(paged._step_impl, *args, donate=(1, 2))
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    in_place = ("parameter", "get-tuple-element", "tuple", "bitcast",
+                "scatter")
+    strays = [line.strip()[:160]
+              for op, line in _pool_sized_ops(hlo, pool.shape)
+              if op not in in_place
+              and not (op == "fusion"
+                       and "latent_kv_write/scatter" in line)]
+    assert not strays, strays
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == paged.pool_bytes()
+    assert mem.temp_size_in_bytes < paged.pool_bytes() // 8, mem

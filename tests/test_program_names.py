@@ -43,6 +43,36 @@ def test_decode_step_module_name_is_what_the_benchmark_matches(
 
 
 @pytest.fixture(scope="module")
+def latent_step_text():
+    """The step of a latent (MLA) block with expert layers
+    (models/block.py), on the kernel path."""
+    from test_latent_decode import CFG as KCFG, MODEL, REF, SEED
+    from paddle_tpu import models
+    named = MODEL.make_weights(REF, SEED, KCFG, jnp.float32)
+    dec = models.TransformerDecoder(
+        named, n_layers=KCFG["num_hidden_layers"],
+        n_heads=KCFG["num_attention_heads"], name=MODEL.NAME,
+        block=MODEL.block_of(KCFG, 64))
+    eng = DecodeEngine(dec, num_slots=2, page_size=4, max_seq_len=32,
+                       attention="kernel")
+    z = jnp.zeros((2, 1), jnp.int32)
+    args = (dec.p, eng.k_pool, eng.v_pool, z, z, jnp.asarray(eng._tables),
+            jnp.zeros((2, 1), jnp.bool_), jax.random.PRNGKey(0))
+    return eng.paged._step.lower(*args).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [
+    "embed", "latent_kv_write", "latent_attn", "ffn", "router", "experts",
+    "shared_expert", "logits", "paged_latent_attention"])
+def test_latent_step_carries_the_name(latent_step_text, name):
+    assert _has(latent_step_text, name)
+
+
+def test_latent_step_is_the_module_the_benchmark_matches(latent_step_text):
+    assert "module @jit__step_impl" in latent_step_text
+
+
+@pytest.fixture(scope="module")
 def train_step_text():
     from benchmarks.lib import manifest, paddle_lm
     cfg = {"hidden_size": 32, "num_hidden_layers": 2,
