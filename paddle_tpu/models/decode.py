@@ -587,7 +587,7 @@ class PagedDecoder:
     scattered into the pool BEFORE attention and each token's kv_len
     masks later positions. ``attention`` selects the cache-read path:
     "gather" (the exact einsum over the full page view), "kernel" (the
-    allocated-pages Pallas kernel — ops/pallas_decode.py), or "auto"
+    live-pages Pallas kernel — ops/pallas_decode.py), or "auto"
     (kernel on TPU when supported, gather elsewhere).
 
     ``kv_quant="int8"`` switches the pools to the two-tier INT8 layout:
@@ -862,6 +862,10 @@ class PagedDecoder:
         page_idx = jnp.where(active, page_idx, 0)       # null the dead
         offs = jnp.where(active, positions % ps, 0)
         kv_lens = positions + 1
+        # a token that is not active attends to nothing: a slot whose
+        # window is all masked has no live page, and the window kernel
+        # then copies none for it
+        live_lens = jnp.where(active, kv_lens, 0)
         loads = []
         for i in range(d0.n_layers):
             if self.latent:
@@ -873,7 +877,7 @@ class PagedDecoder:
                 continue
             x, k_pool, v_pool = self._paged_block(
                 p, i, x, k_pool, v_pool, page_idx, offs, page_tables,
-                kv_lens)
+                live_lens)
         with jax.named_scope("logits"):
             logits = d0._logits(p, x)                   # [S, W, V]
             if self.temperature is None:

@@ -42,28 +42,41 @@ the recorded-experiment kernel via ``use_kernel=True`` — both paths
 take PER-ROW kv lengths, which is what lets one fixed-shape jitted
 step serve ragged sequences (serving/engine.py).
 
-Round 9 replaces the gather's traffic profile with
-:func:`paged_window_attention` + the ALLOCATED-PAGES kernel
-(:func:`_paged_window_kernel`): the gather path reads every slot's full
-page-table width (P * page_size positions — ``max_seq_len`` traffic per
-slot per step regardless of actual length), which docs/perf.md "Known
-headroom" names as the decode-roofline lever. The kernel walks the
-page axis with the page table SCALAR-PREFETCHED: the block index map
-clamps the page-axis grid index to the slot's last allocated page, so
-every out-of-range grid step repeats the previous block index and
-Pallas SKIPS the DMA — HBM cache reads scale with the slot's TRUE
-ragged length (rounded up to a page). The query carries a W-token
-verify window per slot (speculative decoding + multi-token prefill,
-serving/engine.py), accumulated with the online-softmax recurrence
-across pages. Parity vs. the gather/einsum reference is pinned in
-tests/test_paged_decode.py (GQA/MQA, ragged lengths, W > 1).
+Round 9 replaced the gather's traffic profile with
+:func:`paged_window_attention` + a kernel whose reads follow the
+ALLOCATED pages: the gather path reads every slot's full page-table
+width (P * page_size positions — ``max_seq_len`` traffic per slot per
+step regardless of actual length). That kernel let Pallas's pipeline
+walk the table, grid (S, P), with a block index clamped to the slot's
+last page: a step past it skipped its DMA and its math, but the visit
+was still paid, ~65 ns a page operand, so a call cost slots x table
+width whatever was cached (0.55 ms at 32 x 128 with every slot at one
+token; PERF.md, PR 35 and PR 36).
+
+The kernel that stands now (:func:`_paged_window_kernel`, PR 36) walks
+the LIVE pages itself. The pools stay in HBM whole; the grid is over
+slots alone; inside a slot's step a loop of ``ceil(used[s] / K)``
+compute blocks (:func:`_walk_live_pages`, which knows nothing of heads
+or softmax) copies each live page with a DMA of its own into its rows
+of a double-buffered [K*page_size, g*dh] tile while the block before
+is computed, and starts the NEXT live slot's first block before this
+slot's last one ends. A page past a slot's allocation is never named,
+copied or visited, and an idle slot costs no DMA: a call's time
+follows the cached tokens (0.13 ms at one token a slot, 1.3 ms at
+2,048; tests/test_tpu_smoke.py holds the ratio on the chip). The
+query carries a W-token verify window per slot (speculative decoding
++ multi-token prefill, serving/engine.py), accumulated with the
+online-softmax recurrence across blocks. Parity vs. the gather/einsum
+reference is pinned in tests/test_paged_decode.py (GQA/MQA, ragged
+lengths, W > 1, idle slots, block edges, repeated pages).
 
 Both paged paths read the pools AS STORED: a page is
 [page_size, g*dh], the kv heads of a token side by side on the lane
 axis, and a pool that keeps its layer axis is handed over whole with
 ``layer=`` (the comment above :func:`gather_pages`). The kernel walks
-a page in 128-lane chunks against a block-diagonal q, so nothing is
-padded in HBM or relaid out in VMEM at the serving widths."""
+a block's tile in 128-lane chunks against a block-diagonal q, so
+nothing is padded in HBM or relaid out in VMEM at the serving
+widths."""
 
 from __future__ import annotations
 
@@ -189,9 +202,9 @@ def decode_attention(q, k_cache, v_cache, kv_len, *, scale=None,
 # axis, so a page of the serving widths (16 x 2048 bf16) is whole
 # (16, 128) tiles with nothing padded. Pools are [n_pages, page_size,
 # g*dh], or [L, n_pages, page_size, g*dh] with ``layer=`` naming the
-# layer to read: the layer goes into the gather's index / the kernel's
-# block index map, and no array op ever takes ``pool[i]`` out of the
-# pool (a slice of a pool is a pool-sized copy on the chip). The int8
+# layer to read: the layer goes into the gather's index / the index of
+# the kernel's page copies, and no array op ever takes ``pool[i]`` out of
+# the pool (a slice of a pool is a pool-sized copy on the chip). The int8
 # layout's scales are [..., n_pages, page_size, g].
 def gather_pages(pages, page_table, layer=None):
     """Contiguous per-sequence view of a paged pool: ``pages``
@@ -267,7 +280,7 @@ def paged_attention(q, k_pages, v_pages, page_table, kv_lens, *,
     return attn.reshape(b, h, dh)
 
 
-# ------------------------------------------- allocated-pages kernel
+# ------------------------------------------------ live-pages kernel
 def _heads_per_chunk(g: int, dh: int) -> int:
     """How many kv heads one lane chunk of the kernel holds. A chunk is
     whole 128-lane tiles wherever the shapes allow it: 128 // dh heads
@@ -281,169 +294,301 @@ def _heads_per_chunk(g: int, dh: int) -> int:
     return hp
 
 
-def _paged_window_kernel(tables_ref, used_ref, lens_ref, q_ref, k_ref,
-                         v_ref, *rest, scale, rep, page_size, window,
-                         head_dim, quant):
-    """Grid (S, P), page axis fastest. Block p of slot s is the page
-    the CLAMPED index map selected — for p >= used[s] that is the same
-    physical page as step p-1, so Pallas skips the DMA (the
-    allocated-pages traffic contract) and ``pl.when`` skips the math.
-    Online softmax carries (m, l, acc) per (lane chunk, row) across the
-    page axis in VMEM scratch.
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
-    A K/V block is one page as stored, [page_size, g*dh]. The body
-    walks it in lane chunks of C = hp*dh lanes (:func:`_heads_per_chunk`
-    — whole 128-lane tiles at the serving widths, so no chunk is ever
-    sliced or reshaped across a tile). q arrives BLOCK-DIAGONAL per
-    chunk, [n_chunks, hp*W*rep, C] per slot (the wrapper lays it out in
-    XLA): row (j, w, r) holds the query of head (chunk*hp + j)*rep + r
-    at window token w in lanes [j*dh, (j+1)*dh) and zeros elsewhere, so
-    ONE q·kT per chunk gives every head's scores exactly, and one p·v
-    gives every head's output in its own lanes (the other lanes of a
-    row hold another head's values weighted by this row's
-    probabilities: finite, and dropped by the wrapper). The per-token
-    lengths are the third scalar-prefetch operand: an [S, W] VMEM block
-    of them does not tile.
 
-    ``quant`` is the dequant-FUSED variant: two more inputs carry the
-    per-row scales (their blocks ride the same clamped map, so a
-    skipped page DMA skips its scale DMA too) and the rescale
-    ``int8 * scale`` runs in VMEM right after the K/V chunk lands, so
-    the HBM read is 1 byte/element + 4 bytes/row instead of the float
-    pool's 2-4 bytes/element."""
-    if quant:
-        ks_ref, vs_ref, out_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        out_ref, m_ref, l_ref, acc_ref = rest
-    p = pl.program_id(1)
+def _walk_live_pages(tables_ref, used_ref, pools, bufs, sems, half_ref,
+                     body, *, lead, page_size, pages_per_block):
+    """One slot's share of the page walk: the grid is over slots, and
+    this is what a grid step does about pages. It knows tables, counts,
+    pools and buffers, and nothing of heads, chunks or softmax.
+
+    ``pools`` are HBM refs [..., n_pages, page_size, width_i], whole, as
+    stored (``lead`` = the layer's index, or ()); ``bufs`` their VMEM
+    scratch [2, K*page_size, width_i], ``sems`` DMA semaphores
+    [len(pools), 2]. Slot s holds ``used[s]`` live pages = ceil(used/K)
+    compute blocks of K pages. A block's live pages are copied each by
+    its own DMA into its rows of one half of every buffer while
+    ``body(b, tiles)`` computes the block in the other half; a page past
+    ``used[s]`` is never named, copied or waited for (its rows keep what
+    an earlier block left there, finite, for the body's mask). The walk
+    does not stop at a slot's end: while a slot's last block is computed
+    the first block of the NEXT LIVE slot is already on its way, so a
+    call pays one pipeline bubble, not one a slot, and a slot with
+    ``used == 0`` issues nothing at all. ``half_ref`` (SMEM [1]) carries
+    which half the next block lands in from one grid step to the
+    next."""
     s = pl.program_id(0)
-    used = used_ref[s]
+    n_slots = pl.num_programs(0)
+    K = pages_per_block
+
+    def copies(slot, b, half, wait):
+        def page(j, carry):
+            # a wait reads only the destination's size
+            src = 0 if wait else tables_ref[slot, b * K + j]
+            row = 0 if wait else pl.multiple_of(j * page_size, page_size)
+            for i, (pool, buf) in enumerate(zip(pools, bufs)):
+                dma = pltpu.make_async_copy(
+                    pool.at[lead + (src,)],
+                    buf.at[half, pl.ds(row, page_size)], sems.at[i, half])
+                dma.wait() if wait else dma.start()
+            return carry
+
+        live = jnp.minimum(K, used_ref[slot] - b * K)
+        jax.lax.fori_loop(0, live, page, 0)
+
+    def next_live(start):
+        return jax.lax.while_loop(
+            lambda i: jnp.logical_and(
+                i < n_slots, used_ref[jnp.minimum(i, n_slots - 1)] == 0),
+            lambda i: i + 1, start)
+
+    @pl.when(s == 0)
+    def _first():
+        # rows no DMA ever writes must not hold what VMEM held before
+        # the call (a NaN times a zero probability is a NaN)
+        for buf in bufs:
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        half_ref[0] = 0
+        first = next_live(0)
+
+        @pl.when(first < n_slots)
+        def _():
+            copies(first, 0, 0, wait=False)
+
+    n_blocks = (used_ref[s] + K - 1) // K
+
+    @pl.when(n_blocks > 0)
+    def _live():
+        after = next_live(s + 1)
+
+        def block(b, half):
+            last = b + 1 == n_blocks
+            slot_n = jnp.where(last, after, s)
+
+            @pl.when(slot_n < n_slots)
+            def _():
+                copies(slot_n, jnp.where(last, 0, b + 1), 1 - half,
+                       wait=False)
+
+            copies(s, b, half, wait=True)
+            body(b, [buf.at[half] for buf in bufs])
+            return 1 - half
+
+        half_ref[0] = jax.lax.fori_loop(0, n_blocks, block, half_ref[0])
+
+
+def _paged_window_kernel(tables_ref, used_ref, lens_ref, layer_ref, q_ref,
+                         *rest, scale, rep, page_size, window,
+                         pages_per_block, quant):
+    """Grid (S,): one step a slot, and inside it
+    :func:`_walk_live_pages` over the slot's live pages, K pages a
+    compute block. The K pages stand as one [K*page_size, g*dh] tile of
+    cache rows; the body below is called once a block. Online softmax
+    carries (m, l, acc) per (lane chunk, row) across a slot's blocks in
+    VMEM scratch, updated once a block.
+
+    The body walks the tile in lane chunks of C = hp*dh lanes
+    (:func:`_heads_per_chunk` — whole 128-lane tiles at the serving
+    widths, so no chunk is ever sliced or reshaped across a tile). q
+    arrives BLOCK-DIAGONAL per chunk, [n_chunks, hp*W*rep, C] per slot
+    (the wrapper lays it out in XLA): row (j, w, r) holds the query of
+    head (chunk*hp + j)*rep + r at window token w in lanes
+    [j*dh, (j+1)*dh) and zeros elsewhere, so ONE q·kT per chunk gives
+    every head's scores exactly, [rows, K*page_size] with the cache rows
+    on the lanes, and one p·v gives every head's output in its own lanes
+    (the other lanes of a row hold another head's values weighted by
+    this row's probabilities: finite, and dropped by the wrapper). The
+    per-token lengths are the third scalar-prefetch operand: an [S, W]
+    VMEM block of them does not tile. The layer is the fourth, a number
+    the program reads and not a constant of it, so that every layer of
+    a model runs ONE kernel, traced and lowered once.
+
+    Precision: nothing is rounded below what it is stored as. Where q
+    is bfloat16 and the pools bfloat16 or int8 (every int8 is a
+    bfloat16), their product goes to the MXU as it is — a bf16 x bf16
+    product summed in float32 is exact — and the float32 probabilities
+    go as two bfloat16 terms (:func:`_two_terms`); every other pairing
+    of dtypes is cast to float32 first. m, l and the accumulator are
+    float32.
+
+    ``quant`` is the dequant-FUSED variant over int8 pools. A row's
+    scale is one number a kv head, so it multiplies that head's SCORE
+    (k) and PROBABILITY (v) instead of the row's dh values: two more
+    inputs carry the slot's scales with the cache rows on the lanes,
+    [g, P*page_size] float32 in table order, and the int8 pages go to
+    the products unscaled. The HBM read is 1 byte/element for the live
+    pages + 4 bytes/row/head over the table's width for the scales
+    (the wrapper says why they do not ride with their pages)."""
+    if quant:
+        ks_ref, vs_ref = rest[:2]
+        rest = rest[2:]
+    pools, out_ref, bufs = rest[:2], rest[2], rest[3:5]
+    sems, half_ref, m_ref, l_ref, acc_ref = rest[5:]
+    s = pl.program_id(0)
     n_chunks, rows_n, C = acc_ref.shape
     wr = window * rep
     hp = rows_n // wr
-    # the pools may keep their layer axis: the block is then
-    # [1, 1, page_size, g*dh] and the index map chose the layer
-    page = (0,) * (len(k_ref.shape) - 2)
+    span = pages_per_block * page_size
+    exact = q_ref.dtype == jnp.bfloat16 and pools[0].dtype in (
+        jnp.bfloat16, jnp.int8)
+    cdt = jnp.bfloat16 if exact else jnp.float32
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(p < used)
-    def _accumulate():
+    def body(b, tiles):
+        k_ref, v_ref = tiles                           # [span, g*dh]
         # per-token causal/ragged mask against ABSOLUTE positions:
-        # page p covers [p*ps, (p+1)*ps); row (j, w, r) is window token
-        # w and sees < lens[s, w]
-        cols = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows_n, page_size), 1)
-        lim = jnp.full((rows_n, page_size), lens_ref[s, 0], jnp.int32)
+        # block b covers [b*span, (b+1)*span); row (j, w, r) is window
+        # token w and sees < lens[s, w]. Rows of the tile past the
+        # slot's last live page lie past every length.
+        cols = b * span + jax.lax.broadcasted_iota(
+            jnp.int32, (rows_n, span), 1)
+        lim = jnp.full((rows_n, span), lens_ref[s, 0], jnp.int32)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (rows_n, span), 0)
         if window > 1:
-            rows = jax.lax.broadcasted_iota(
-                jnp.int32, (rows_n, page_size), 0)
             for j in range(hp):
                 for w in range(window):
                     lim = jnp.where(rows >= j * wr + w * rep,
                                     lens_ref[s, w], lim)
         live = cols < lim
-        if quant:
-            lane = jax.lax.broadcasted_iota(jnp.int32, (page_size, C), 1)
-            ks = ks_ref[page].astype(jnp.float32)      # [ps, g]
-            vs = vs_ref[page].astype(jnp.float32)
+        here = pl.ds(pl.multiple_of(b * span, span), span)
 
-        def rescale(x, sc, c):
-            # fused dequant: head j of the chunk owns lanes
-            # [j*dh, (j+1)*dh) and its own [ps, 1] column of scales
-            col = sc[:, c * hp:c * hp + 1]
+        def row_scales(ref, c):
+            # [rows_n, span]: row (j, w, r) takes head c*hp + j's scales
+            sc = ref[0, c * hp:c * hp + 1, here]
             for j in range(1, hp):
-                col = jnp.where(lane >= j * head_dim,
-                                sc[:, c * hp + j:c * hp + j + 1], col)
-            return x * col
+                sc = jnp.where(rows >= j * wr,
+                               ref[0, c * hp + j:c * hp + j + 1, here], sc)
+            return sc
 
         for c in range(n_chunks):
             lanes = (slice(None), slice(c * C, (c + 1) * C))
-            kc = k_ref[page + lanes].astype(jnp.float32)   # [ps, C]
-            vc = v_ref[page + lanes].astype(jnp.float32)
-            if quant:
-                kc = rescale(kc, ks, c)
-                vc = rescale(vc, vs, c)
-            qc = q_ref[0, c].astype(jnp.float32)       # [rows_n, C]
             sc = jax.lax.dot_general(
-                qc, kc, (((1,), (1,)), ((), ())),
+                q_ref[0, c].astype(cdt), k_ref[lanes].astype(cdt),
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * (scale * LOG2E)
-            sc = jnp.where(live, sc, NEG_INF)          # [rows_n, ps]
+            if quant:
+                sc = sc * row_scales(ks_ref, c)
+            sc = jnp.where(live, sc, NEG_INF)          # [rows_n, span]
             m_prev = m_ref[c]                          # [rows_n, 1]
             m_cur = jnp.maximum(m_prev,
                                 jnp.max(sc, axis=1, keepdims=True))
             alpha = jnp.exp2(m_prev - m_cur)
-            pm = jnp.exp2(sc - m_cur)                  # [rows_n, ps]
+            pm = jnp.exp2(sc - m_cur)                  # [rows_n, span]
             l_ref[c] = l_ref[c] * alpha + \
                 jnp.sum(pm, axis=1, keepdims=True)
-            acc_ref[c] = acc_ref[c] * alpha + jax.lax.dot_general(
-                pm, vc, (((1,), (0,)), ((), ())),
+            if quant:
+                pm = pm * row_scales(vs_ref, c)
+            pv = jax.lax.dot_general(
+                _two_terms(pm) if exact else pm, v_ref[lanes].astype(cdt),
+                (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            if exact:
+                pv = pv[:rows_n] + pv[rows_n:]
+            acc_ref[c] = acc_ref[c] * alpha + pv
             m_ref[c] = m_cur
 
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _finalize():
-        # fully-masked rows (lens 0 never happens live; engine clamps
-        # masked tokens to kv_len >= 1) still divide by a finite l
-        l = jnp.maximum(l_ref[...], 1e-30)             # [n_chunks, rows, 1]
-        out_ref[0] = (acc_ref[...] / l).astype(out_ref.dtype)
-
-
-def _round_up(n: int, m: int) -> int:
-    return -(-n // m) * m
+    # a pool that keeps its layer axis is read at the layer the call
+    # names: the index of every page copy, never a slice of the pool
+    lead = (layer_ref[0],) if len(pools[0].shape) == 4 else ()
+    _walk_live_pages(tables_ref, used_ref, pools, bufs, sems, half_ref,
+                     body, lead=lead, page_size=page_size,
+                     pages_per_block=pages_per_block)
+    # a slot with no live page (idle) leaves l = 0 and acc = 0: its
+    # output is zeros, finite
+    l = jnp.maximum(l_ref[...], 1e-30)                 # [n_chunks, rows, 1]
+    out_ref[0] = (acc_ref[...] / l).astype(out_ref.dtype)
 
 
 # What the chip's compiler takes (v5e:2x2 described in the sandbox,
-# tests/test_chip_compile.py). One grid step holds every K/V block
-# twice (Pallas double-buffers), each padded to whole (sublane,
-# 128-lane) tiles over its (page_size, g*dh) dims, plus the float32
-# working copies of one lane chunk, against a 16 MiB scoped VMEM
-# limit: past it the compiler answers "Ran out of memory in memory
-# space vmem" (f32 g32 dh128 pages of 256 rows: 16 MiB of K/V blocks
-# alone). The budget is kept under what was seen to compile, not at
-# the limit — the compiler's own temporaries are not modelled, and the
-# gate turns away some shapes that would compile (int8 g8 dh64 pages of
-# 2048 rows, bf16 g2 dh64 pages of 4096 rows). The scalar-prefetched
-# page tables and lengths live in 1 MiB of SMEM ("Ran out of memory in
-# memory space smem" at [256, 1024] tables).
+# tests/test_chip_compile.py). The kernel holds, against a 16 MiB scoped
+# VMEM limit: K's and V's compute block twice (the walk's double
+# buffer), [K*page_size, g*dh] each; the working set of one lane chunk
+# (its float32 copies where the dtypes ask for them, the scores and
+# probabilities of the block); per chunk the block-diagonal q and the
+# output block (both double-buffered by Pallas) and the float32
+# accumulator; and the int8 layout's per-slot scales [g, P*page_size],
+# double-buffered. Past the limit the compiler answers "Ran out of
+# memory in memory space vmem". The budget is kept under what was seen
+# to compile, not at the limit — the compiler's own temporaries are not
+# modelled. A page is copied from the pool by a DMA of its own into its
+# rows of the tile, and this compiler slices an HBM ref only along whole
+# tiles: g*dh must be whole 128-lane tiles ("Slice shape along dimension
+# 3 must be aligned to tiling (128), but is 64" for one kv head of 64)
+# and a page whole 8-row sublane tiles. Other shapes go to the gather
+# path. The scalar-prefetched page tables and lengths live in 1 MiB of
+# SMEM ("Ran out of memory in memory space smem" at [256, 1024]
+# tables).
 _PAGED_VMEM_BYTES = 12 * 1024 * 1024
 _PAGED_SMEM_BYTES = 960 * 1024
+# cache rows in a compute block of the window kernel: enough that a lane
+# chunk's q·kT fills MXU tiles and the softmax state is touched once for
+# many pages, few enough that a slot's last, part-filled block wastes
+# little (a block is computed whole)
+_WINDOW_ROWS_PER_BLOCK = 128
+
+
+def _window_pages_per_block(page_size: int, pages_per_slot: int,
+                            row_bytes: int) -> int:
+    """Pages in one compute block of the window kernel: about
+    ``_WINDOW_ROWS_PER_BLOCK`` cache rows, no more than a slot's table
+    holds, halved until K's and V's double-buffered tiles
+    (``row_bytes`` a cache row of one) take at most half the VMEM
+    budget. -> 0 where not even one page does."""
+    k = max(1, min(_WINDOW_ROWS_PER_BLOCK // page_size, pages_per_slot))
+    while k and 4 * k * page_size * row_bytes > _PAGED_VMEM_BYTES // 2:
+        k //= 2
+    return k
+
+
+def _window_vmem(q, k_pages, quant, pages_per_slot):
+    """(pages a block, modelled VMEM bytes) of the window kernel for
+    these shapes (arrays or ShapeDtypeStructs)."""
+    S, W, h, dh = q.shape
+    ps, gd = k_pages.shape[-2:]
+    g = gd // dh
+    row_bytes = _round_up(gd, 128) * jnp.dtype(k_pages.dtype).itemsize
+    K = _window_pages_per_block(ps, pages_per_slot, row_bytes)
+    span = _round_up(max(K, 1) * ps, 128)
+    hp = _heads_per_chunk(g, dh)
+    chunk = _round_up(hp * dh, 128)
+    rows = _round_up(hp * W * (h // g), 8)
+    # a chunk's K and V cast for the product, the block's mask, scores,
+    # probabilities, their two terms and a row of scales
+    working = 2 * span * chunk * 4 + 8 * rows * span * 4
+    per_chunk = rows * chunk * (4 * jnp.dtype(q.dtype).itemsize + 4)
+    vmem = 4 * K * ps * row_bytes + working + (g // hp) * per_chunk
+    if quant:
+        vmem += 2 * 2 * _round_up(g, 8) * 4 * _round_up(
+            -(-pages_per_slot // max(K, 1)) * K * ps, 128)
+    return K, vmem
 
 
 def paged_kernel_supported(q, k_pages, k_scales=None,
                            pages_per_slot: int = 1) -> bool:
-    """Gate for the allocated-pages kernel — "supported" means the
-    kernel LOWERS on the chip for these shapes: a sublane-multiple
-    head dim, one page step's K+V blocks ([page_size, g*dh] as stored,
-    + per-row scales of the int8 two-tier layout) inside the VMEM
-    budget, and the [S, P] page tables plus [S, W] lengths inside
-    SMEM. ``k_pages`` is a pool (or its ShapeDtypeStruct) in the
-    stored layout, with or without the layer axis."""
+    """Gate for the live-pages kernel — "supported" means the kernel
+    LOWERS on the chip for these shapes: a sublane-multiple head dim,
+    cache rows of whole 128-lane tiles and pages of whole 8-row tiles
+    (what a page's own DMA needs), the compute block's tiles
+    ([K*page_size, g*dh] of K and of V as stored, twice), the chunk's
+    working set and the int8 layout's per-slot scales inside the VMEM
+    budget, and the [S, P] page tables plus [S, W] lengths inside SMEM.
+    ``k_pages`` is a pool (or its ShapeDtypeStruct) in the stored
+    layout, with or without the layer axis."""
     S, W, h, dh = q.shape
     ps, gd = k_pages.shape[-2:]
     g = gd // dh
-    if dh % 8 or g * dh != gd or h % g:
+    if dh % 8 or g * dh != gd or h % g or gd % 128 or ps % 8:
         return False
-    esize = jnp.dtype(k_pages.dtype).itemsize
-    hp = _heads_per_chunk(g, dh)
-    chunk = _round_up(hp * dh, 128)
-    rows = _round_up(hp * W * (h // g), 8)
-    stored = _round_up(ps, 32 // esize) * _round_up(gd, 128) * esize
-    working = _round_up(ps, 8) * chunk * 4
-    # K and V double-buffered, their chunk's f32 copies, and per chunk
-    # the block-diagonal q (double-buffered, at q's width), the output
-    # block and the f32 accumulator
-    vmem = 2 * 2 * stored + 4 * working + (g // hp) * rows * chunk * (
-        4 * jnp.dtype(q.dtype).itemsize + 4)
-    if k_scales is not None:
-        vmem += 2 * 2 * _round_up(ps, 8) * _round_up(g, 128) * \
-            jnp.dtype(k_scales.dtype).itemsize
+    K, vmem = _window_vmem(q, k_pages, k_scales is not None,
+                           pages_per_slot)
     smem = 4 * S * (_round_up(pages_per_slot, 128) + 128 + 1)
-    return vmem <= _PAGED_VMEM_BYTES and smem <= _PAGED_SMEM_BYTES
+    return K > 0 and vmem <= _PAGED_VMEM_BYTES and \
+        smem <= _PAGED_SMEM_BYTES
 
 
 def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
@@ -457,9 +602,10 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
     q [S, W, h, dh]; k_pages/v_pages [n_pages, page_size, g*dh] — the
     pools AS STORED, every kv head of a token side by side on the lane
     axis — or the whole [L, n_pages, page_size, g*dh] pools with
-    ``layer`` (a Python int) naming the layer: it goes into the
-    kernel's block index map (the gather's index), never into a slice
-    of the pool; page_tables [S, P] int32; kv_lens [S, W] int32
+    ``layer`` (a Python int) naming the layer: it goes into the index
+    of the kernel's page copies (the gather's index), never into a
+    slice of the pool, and reaches the kernel as an operand, so every
+    layer runs one program; page_tables [S, P] int32; kv_lens [S, W] int32
     per-TOKEN valid lengths (token w of slot s is the query at position
     kv_lens[s, w] - 1 — the mask is causal within the window too,
     because earlier window tokens' K/V were scattered before this
@@ -467,11 +613,12 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
 
     ``use_kernel=False`` flattens the window into the gather/einsum
     reference (:func:`paged_attention` — exact, reads the full table
-    width). ``use_kernel=True`` runs the allocated-pages Pallas kernel:
-    page tables and per-slot used-page counts are scalar-prefetched,
-    the page-axis block index is clamped to the last allocated page so
-    revisited blocks skip their DMA, and cache-read traffic is
-    ceil(len/page_size) pages instead of P.
+    width). ``use_kernel=True`` runs the live-pages Pallas kernel
+    (:func:`_paged_window_kernel`): the pools stay in HBM, page tables
+    and per-slot live-page counts are scalar-prefetched, and the kernel
+    copies ceil(len/page_size) pages a slot itself, so a call's time
+    follows the cached tokens and not slots x table width. A slot whose
+    lengths are all 0 (idle) costs no copy and returns zeros.
 
     ``k_scales``/``v_scales`` [n_pages, page_size, g] (or [L, ...])
     switch the pools to the INT8 two-tier layout (:func:`quantize_kv`
@@ -499,45 +646,74 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
             scale=scale, k_scales=k_scales, v_scales=v_scales,
             layer=layer)
         return out.reshape(S, W, h, dh)
-    # pages actually holding live KV for each slot (>= 1 so the null
-    # page still feeds the pipeline for idle slots)
-    used = jnp.clip(-(-jnp.max(lens, axis=1) // ps), 1, P)
+    # at least one page a block: shapes the gate turns away still run
+    # here in interpret mode (the tests' toy pages)
+    K = max(1, _window_vmem(q, k_pages, quant, P)[0])
+    return _live_pages_call(
+        q, k_pages, v_pages, jnp.asarray(page_tables, jnp.int32), lens,
+        None if layer is None else jnp.full((1,), layer, jnp.int32),
+        k_scales, v_scales, scale=float(scale), pages_per_block=K,
+        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages_per_block",
+                                             "interpret"))
+def _live_pages_call(q, k_pages, v_pages, page_tables, lens, layer,
+                     k_scales, v_scales, *, scale, pages_per_block,
+                     interpret):
+    """:func:`paged_window_attention`'s kernel path. A program of its
+    own, with the layer an operand: the 24 layers of a step call the
+    same traced and lowered function, where a kernel with the layer
+    built in was traced, lowered and compiled once a layer."""
+    S, W, h, dh = q.shape
+    ps, gd = k_pages.shape[-2:]
+    g, P = gd // dh, page_tables.shape[1]
+    rep = h // g
+    quant = k_scales is not None
+    # pages holding live KV for each slot; 0 for an idle one
+    used = jnp.clip(-(-jnp.max(lens, axis=1) // ps), 0, P)
     hp = _heads_per_chunk(g, dh)
     n_chunks, C, rows_n = g // hp, hp * dh, hp * W * rep
-    lead = () if layer is None else (layer,)
+    K = pages_per_block
 
-    def _slot_map(si, pi, tables, used_, lens_):
+    def _slot_map(si, *_):
         return (si, 0, 0, 0)
-
-    def _table_map(si, pi, tables, used_, lens_):
-        return lead + (tables[si, jnp.minimum(pi, used_[si] - 1)], 0, 0)
 
     kernel = functools.partial(
         _paged_window_kernel, scale=scale, rep=rep, page_size=ps,
-        window=W, head_dim=dh, quant=quant)
-    one = (1,) * (len(lead) + 1)
-    in_specs = [
-        pl.BlockSpec((1, n_chunks, rows_n, C), _slot_map),
-        pl.BlockSpec(one + (ps, gd), _table_map),
-        pl.BlockSpec(one + (ps, gd), _table_map),
-    ]
+        window=W, pages_per_block=K, quant=quant)
     # rows (j, w, r) of chunk c, block-diagonal over the chunk's heads:
     # [S, W, (c, j, r), dh] -> [S, c, (j, w, r), (j', dh)]
     qc = q.reshape(S, W, n_chunks, hp, rep, dh).transpose(0, 2, 3, 1, 4, 5)
     eye = jnp.eye(hp, dtype=q.dtype)[:, None, None, :, None]
     qd = (qc[:, :, :, :, :, None, :] * eye).reshape(S, n_chunks, rows_n, C)
-    operands = [jnp.asarray(page_tables, jnp.int32),
-                used.astype(jnp.int32), lens, qd, k_pages, v_pages]
+    in_specs = [pl.BlockSpec((1, n_chunks, rows_n, C), _slot_map)]
+    operands = [qd]
     if quant:
-        in_specs += [pl.BlockSpec(one + (ps, g), _table_map),
-                     pl.BlockSpec(one + (ps, g), _table_map)]
-        operands += [k_scales, v_scales]
+        # [page_size, g] of scales is no whole lane tile, so the kernel
+        # cannot copy a page of them itself (the gate's comment): XLA
+        # gathers each slot's scales over its table's width, cache rows
+        # on the lanes, whole compute blocks
+        t = -(-P // K) * K * ps
+
+        def by_slot(scales):
+            rows = gather_pages(scales, page_tables,
+                                None if layer is None else layer[0])
+            return jnp.pad(rows.astype(jnp.float32).transpose(0, 2, 1),
+                           ((0, 0), (0, 0), (0, t - P * ps)))
+
+        in_specs += [pl.BlockSpec((1, g, t), lambda si, *_: (si, 0, 0))] * 2
+        operands += [by_slot(k_scales), by_slot(v_scales)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, P),
-        in_specs=in_specs,
+        num_scalar_prefetch=4,
+        grid=(S,),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_specs=pl.BlockSpec((1, n_chunks, rows_n, C), _slot_map),
         scratch_shapes=[
+            pltpu.VMEM((2, K * ps, gd), k_pages.dtype),
+            pltpu.VMEM((2, K * ps, gd), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((n_chunks, rows_n, 1), jnp.float32),
             pltpu.VMEM((n_chunks, rows_n, 1), jnp.float32),
             pltpu.VMEM((n_chunks, rows_n, C), jnp.float32),
@@ -545,8 +721,13 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, n_chunks, rows_n, C), q.dtype),
+        # the walk's state goes from one slot's grid step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret, name="paged_window_attention",
-    )(*operands)
+    )(page_tables, used.astype(jnp.int32), lens,
+      jnp.zeros((1,), jnp.int32) if layer is None else layer,
+      *operands, k_pages, v_pages)
     # head j's output sits in lanes [j*dh, (j+1)*dh) of its own rows
     out = jnp.diagonal(out.reshape(S, n_chunks, hp, W, rep, hp, dh),
                        axis1=2, axis2=5)            # [S, c, W, rep, dh, j]

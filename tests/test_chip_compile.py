@@ -190,6 +190,57 @@ def test_paged_kernel_lowers(chip_compile, quant, g, W):
     assert "tpu_custom_call" in chip_compile(_paged_fn(True, quant), *args)
 
 
+@pytest.mark.parametrize("S,W,h,g,dh,P,quant", [
+    (32, 1, 32, 32, 64, 128, False),
+    (32, 1, 32, 8, 128, 128, False),
+    (32, 1, 32, 32, 64, 128, True),
+    (32, 4, 32, 32, 64, 128, False),
+], ids=["opt13b-bf16", "gqa-dh128", "opt13b-int8", "opt13b-W4"])
+def test_paged_kernel_lowers_at_the_benchmark_shape(chip_compile, S, W, h,
+                                                    g, dh, P, quant):
+    """The benchmark's own call (32 slots, a table of 128 pages of 16,
+    32 heads of 64, bf16, the pool with its layer axis) and its
+    neighbours: grouped-query heads of 128, the int8 layout, a verify
+    window. One Mosaic call, and no copy of a pool beside it."""
+    from paddle_tpu.ops.pallas_decode import (paged_kernel_supported,
+                                              paged_window_attention)
+    q, pages, scales, tables, lens = _paged_structs(
+        S, W, h, g, dh, 16, P, quant, jnp.bfloat16)
+    assert paged_kernel_supported(q, pages, scales, pages_per_slot=P)
+    pool = _sds((2,) + pages.shape, pages.dtype)
+    sc = _sds((2,) + scales.shape, scales.dtype) if quant else None
+
+    def fn(q, k, v, tables, lens, *s):
+        kw = dict(k_scales=s[0], v_scales=s[1]) if s else {}
+        return paged_window_attention(q, k, v, tables, lens, layer=1,
+                                      use_kernel=True, **kw)
+
+    hlo = chip_compile(fn, q, pool, pool, tables, lens,
+                       *((sc, sc) if quant else ()))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    strays = [line.strip()[:160]
+              for op, line in _pool_sized_ops(hlo, pool.shape)
+              if op != "parameter"]
+    assert not strays, strays
+
+
+def test_gate_rejects_rows_that_are_not_whole_lane_tiles(chip_compile):
+    """One kv head of 64 (MQA) is half a lane tile: the chip's compiler
+    does not slice such a pool in HBM ("Slice shape along dimension 2
+    must be aligned to tiling (128), but is 64"), so a page cannot be
+    copied by itself; the gate answers False and the gather path serves
+    the shape."""
+    from paddle_tpu.ops.pallas_decode import paged_kernel_supported
+    q, pages, _, tables, lens = _paged_structs(
+        8, 1, 8, 1, 64, 16, 34, False, jnp.bfloat16)
+    assert not paged_kernel_supported(q, pages, pages_per_slot=34)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        chip_compile(_paged_fn(True, False), q, pages, pages, tables, lens)
+    hlo = chip_compile(_paged_fn(False, False), q, pages, pages, tables,
+                       lens)
+    assert "tpu_custom_call" not in hlo
+
+
 def test_gate_rejects_what_vmem_cannot_hold(chip_compile):
     """256-row f32 pages of 32 x 128 heads: the chip's compiler refuses
     the kernel ("Ran out of memory in memory space vmem"), so the gate

@@ -70,6 +70,90 @@ def _balanced(eng):
     return acc
 
 
+# What the live-pages walk of ops/pallas_decode.py can get wrong, as
+# cases shared by the three kernel test classes below. K pages make a
+# compute block; ``_walk_parity`` cuts the block to K = 4 pages so that
+# a toy table (3 blocks wide) holds several. Each case is (per-slot
+# first lengths, table kind); a length is a plain int, 0 for an idle
+# slot, ("blk", n, d) = n blocks + d tokens, or "full" = the table's
+# whole width.
+WALK_CASES = {
+    "idle_among_live": ([5, 0, ("blk", 1, 3), 0, 1], None),
+    "full_table": (["full", 3, "full"], None),
+    "block_edges": ([("blk", 1, -1), ("blk", 1, 0), ("blk", 1, 1), 2],
+                    None),
+    "repeated_pages": ([("blk", 1, 2), "full", 7], "repeated"),
+    "two_blocks_and_one_page": ([("blk", 2, 0), 1, ("blk", 1, 5)], None),
+}
+
+
+def _walk_parity(case, *, W, h, g, dh, ps, layered, quant, seed=31,
+                 interpret=True):
+    """Kernel (interpret mode) against gather on one WALK_CASES entry.
+    A window of W tokens starts at each slot's first length, so with
+    W > 1 the ("blk", 1, -1) slot's window straddles a block's edge. Idle
+    slots (length 0 for every token) must come back as zeros; the
+    gather reference is not asked about them."""
+    from paddle_tpu.ops import pallas_decode as pd
+    K = 4
+    firsts, table_kind = WALK_CASES[case]
+    S, P = len(firsts), 3 * K
+    span = K * ps
+
+    def resolve(x):
+        if x == "full":
+            return P * ps - (W - 1)
+        return x[1] * span + x[2] if isinstance(x, tuple) else x
+
+    rng = np.random.RandomState(seed)
+    npages = S * P + 1
+    L = 2
+    k = rng.randn(L, npages, ps, g * dh).astype(np.float32)
+    v = rng.randn(L, npages, ps, g * dh).astype(np.float32)
+    q = rng.randn(S, W, h, dh).astype(np.float32)
+    tables = rng.permutation(np.arange(1, npages)).reshape(S, P)
+    if table_kind == "repeated":
+        # out of order AND the same physical page at several places of
+        # one slot's table and in two slots' tables (shared prefixes)
+        tables[0, :] = [7, 7, 3, 7, 1, 3, 9, 9, 2, 7, 5, 1]
+        tables[1, :6] = tables[0, :6]
+    base = np.array([resolve(x) for x in firsts])
+    lens = np.where(base[:, None] > 0,
+                    base[:, None] + np.arange(W)[None, :], 0)
+    assert lens.max() <= P * ps
+    for si in range(S):                 # null tail past the allocation
+        tables[si, -(-int(lens[si].max()) // ps):] = 0
+    kw = {}
+    if quant:
+        def quantize(pool):
+            qv, sc = pd.quantize_kv(
+                jax.numpy.asarray(pool).reshape(pool.shape[:-1] + (g, dh)))
+            return qv.reshape(pool.shape), sc
+        (k, ks), (v, vs) = quantize(k), quantize(v)
+        kw = dict(k_scales=ks, v_scales=vs)
+    k, v = jax.numpy.asarray(k), jax.numpy.asarray(v)
+    if layered:
+        kw["layer"] = 1
+    else:
+        k, v = k[1], v[1]
+        kw = {n: (a[1] if n != "layer" else a) for n, a in kw.items()}
+    args = (jax.numpy.asarray(q), k, v,
+            jax.numpy.asarray(tables.astype(np.int32)),
+            jax.numpy.asarray(lens.astype(np.int32)))
+    want = np.asarray(pd.paged_window_attention(*args, **kw))
+    rows_was = pd._WINDOW_ROWS_PER_BLOCK
+    pd._WINDOW_ROWS_PER_BLOCK = span
+    try:
+        got = np.asarray(pd.paged_window_attention(
+            *args, use_kernel=True, interpret=interpret, **kw))
+    finally:
+        pd._WINDOW_ROWS_PER_BLOCK = rows_was
+    live = base > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-4,
+                               atol=2e-5)
+    np.testing.assert_array_equal(got[~live], 0.0)
+
+
 class TestPagedAttentionUnit:
     """ops/pallas_decode.paged_attention vs a straight dense reference,
     including GQA widths, per-row ragged lengths, and the composition
@@ -150,10 +234,10 @@ class TestPagedAttentionUnit:
 
 
 class TestPagedWindowKernel:
-    """Round 9 allocated-pages kernel (ops/pallas_decode.py
+    """The live-pages kernel (ops/pallas_decode.py
     paged_window_attention) vs the gather/einsum reference: W-token
     verify windows, GQA/MQA widths, ragged lengths whose trailing
-    page-table entries the clamped index map must never read."""
+    page-table entries the kernel's walk must never read."""
 
     @pytest.mark.parametrize("h,g", [(4, 4), (4, 2), (4, 1)])
     def test_window_parity_gqa(self, h, g):
@@ -177,6 +261,34 @@ class TestPagedWindowKernel:
             *args, use_kernel=True, interpret=True))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
+    @pytest.mark.parametrize("layered", [False, True],
+                             ids=["pool", "layer-axis"])
+    @pytest.mark.parametrize("W", [1, 3], ids=["W1", "W3"])
+    @pytest.mark.parametrize("case", sorted(WALK_CASES))
+    def test_live_page_walk(self, case, W, layered):
+        """The kernel walks each slot's live pages itself: idle slots
+        among live ones, a slot that fills its table, lengths at a
+        compute block's edge, repeated physical pages, a window that
+        straddles a block's edge — GQA at toy width."""
+        _walk_parity(case, W=W, h=4, g=2, dh=8, ps=4, layered=layered,
+                     quant=False)
+
+    @pytest.mark.parametrize("case", ["idle_among_live", "block_edges"])
+    def test_walk_under_the_tpu_interpreter(self, case):
+        """The same walk under the interpreter that models the chip's
+        memories: every byte the kernel did not write reads NaN (the
+        tile's rows past a slot's last live page), a DMA lands only
+        when it is waited for (a tile read before its wait reads NaN),
+        and a buffer written while the other half's reader still runs
+        is reported as a race."""
+        from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+        from jax.experimental.pallas import tpu as pltpu
+        _walk_parity(case, W=3, h=4, g=2, dh=8, ps=4, layered=True,
+                     quant=False, interpret=pltpu.InterpretParams(
+                         uninitialized_memory="nan", detect_races=True,
+                         dma_execution_mode="on_wait"))
+        assert not interpret_pallas_call.races.races_found
+
     def test_w1_matches_paged_attention(self):
         """W = 1 is the classic one-token step — same numbers as the
         round-6 paged_attention path."""
@@ -198,17 +310,35 @@ class TestPagedWindowKernel:
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
     def test_kernel_gate(self):
+        """What the gate models: the kernel copies each live page with a
+        DMA of its own, which the chip's compiler takes only for cache
+        rows of whole 128-lane tiles and pages of whole 8-row tiles."""
         from paddle_tpu.ops.pallas_decode import paged_kernel_supported
-        q = jax.numpy.zeros((2, 2, 4, 8), np.float32)
-        k = jax.numpy.zeros((8, 4, 2 * 8), np.float32)
+        q = jax.numpy.zeros((2, 2, 4, 64), np.float32)
+        k = jax.numpy.zeros((8, 8, 2 * 64), np.float32)
         assert paged_kernel_supported(q, k)
         # the gate reads the stored layout with its layer axis too
         assert paged_kernel_supported(
-            q, jax.numpy.zeros((3, 8, 4, 2 * 8), np.float32))
+            q, jax.numpy.zeros((3, 8, 8, 2 * 64), np.float32))
         # head dim off the sublane multiple -> fall back to XLA
         q_odd = jax.numpy.zeros((2, 2, 4, 6), np.float32)
         k_odd = jax.numpy.zeros((8, 4, 2 * 6), np.float32)
         assert not paged_kernel_supported(q_odd, k_odd)
+        # one kv head of 64 is half a lane tile; a page of 4 rows is
+        # half a sublane tile: neither page can be copied by itself
+        assert not paged_kernel_supported(
+            q, jax.numpy.zeros((8, 8, 64), np.float32))
+        assert not paged_kernel_supported(
+            q, jax.numpy.zeros((8, 4, 2 * 64), np.float32))
+        # the compute block is cut to what VMEM holds twice over, and a
+        # page that does not fit even alone is turned away
+        q_wide = jax.numpy.zeros((4, 1, 32, 128), np.float32)
+        assert paged_kernel_supported(
+            q_wide, jax.numpy.zeros((9, 16, 32 * 128), np.float32),
+            pages_per_slot=8)
+        assert not paged_kernel_supported(
+            q_wide, jax.numpy.zeros((9, 256, 32 * 128), np.float32),
+            pages_per_slot=8)
 
 
 class TestDequantWindowKernel:
@@ -252,6 +382,15 @@ class TestDequantWindowKernel:
             q, kq, vq, tables, lens, k_scales=ks, v_scales=vs,
             use_kernel=True, interpret=True))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("W", [1, 3], ids=["W1", "W3"])
+    @pytest.mark.parametrize("case", sorted(WALK_CASES))
+    def test_live_page_walk_int8(self, case, W):
+        """The walk's cases over int8 pools: the pages are copied by
+        the kernel, their scales arrive per slot in table order and
+        multiply the scores and the probabilities."""
+        _walk_parity(case, W=W, h=4, g=2, dh=8, ps=4, layered=False,
+                     quant=True)
 
     def test_int8_within_pinned_contract_of_fp32(self):
         """The token-identity tolerance contract: int8 attention
@@ -306,11 +445,19 @@ class TestDequantWindowKernel:
         assert (err <= bound).all()
 
     def test_gate_counts_scale_blocks(self):
-        from paddle_tpu.ops.pallas_decode import paged_kernel_supported
-        q = jax.numpy.zeros((2, 2, 4, 8), np.float32)
-        k8 = jax.numpy.zeros((8, 4, 2 * 8), jax.numpy.int8)
-        sc = jax.numpy.zeros((8, 4, 2), np.float32)
+        """The int8 layout's scales reach the kernel per slot, over the
+        table's whole width: the gate counts them."""
+        from paddle_tpu.ops.pallas_decode import (_window_vmem,
+                                                  paged_kernel_supported)
+        q = jax.numpy.zeros((2, 2, 4, 64), np.float32)
+        k8 = jax.numpy.zeros((8, 8, 2 * 64), jax.numpy.int8)
+        sc = jax.numpy.zeros((8, 8, 2), np.float32)
         assert paged_kernel_supported(q, k8, sc)
+        assert _window_vmem(q, k8, True, 64)[1] - \
+            _window_vmem(q, k8, False, 64)[1] == 2 * 2 * 8 * 4 * 64 * 8
+        # a table so wide that its scales alone fill VMEM
+        assert paged_kernel_supported(q, k8, None, pages_per_slot=16384)
+        assert not paged_kernel_supported(q, k8, sc, pages_per_slot=16384)
         # odd head dim still falls back, scales or not
         q_odd = jax.numpy.zeros((2, 2, 4, 6), np.float32)
         k_odd = jax.numpy.zeros((8, 4, 2 * 6), jax.numpy.int8)
@@ -379,6 +526,15 @@ class TestStoredPoolLayout:
             q, k, v, tables, lens, layer=1, use_kernel=True,
             interpret=True, **kw))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+    @pytest.mark.parametrize("case", sorted(WALK_CASES))
+    def test_live_page_walk_at_lane_chunks(self, case, quant):
+        """The walk's cases at 128-lane chunks (two heads of 64 a
+        chunk, pages of 8 rows, W = 2) on the whole [L, N, ps, g*dh]
+        pool with ``layer=``."""
+        _walk_parity(case, W=2, h=8, g=4, dh=64, ps=8, layered=True,
+                     quant=quant)
 
     def _paged(self, kv_quant, **over):
         params = _model(**over)

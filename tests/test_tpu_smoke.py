@@ -111,18 +111,26 @@ class TestLstmCompiled:
 
 
 class TestPagedKernelCompiled:
-    """The allocated-pages decode kernel, compiled: the serving path's
+    """The live-pages decode kernel, compiled: the serving path's
     attention against the gather/einsum reference on ragged lengths,
     out-of-order pages and a verify window (the interpret-mode pins of
-    tests/test_paged_decode.py, on the chip)."""
+    tests/test_paged_decode.py, on the chip), and the property the
+    kernel exists for: its time follows the cached tokens."""
 
-    @pytest.mark.parametrize("h,g", [(8, 8), (8, 2), (8, 1)])
+    @pytest.mark.parametrize("h,g,dh,ps,lowers", [
+        (8, 8, 64, 16, True), (8, 2, 64, 16, True), (8, 1, 128, 16, True),
+        (8, 8, 64, 8, True), (8, 1, 64, 16, False)],
+        ids=["mha", "gqa", "mqa-dh128", "mha-page8", "mqa-dh64"])
     @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-    def test_window_matches_gather(self, h, g, quant):
-        from paddle_tpu.ops.pallas_decode import (paged_window_attention,
+    def test_window_matches_gather(self, h, g, dh, ps, lowers, quant):
+        """On the path the gate picks: one kv head of 64 is half a lane
+        tile, which the kernel's page copies cannot take, so that shape
+        is served by the gather path."""
+        from paddle_tpu.ops.pallas_decode import (paged_kernel_supported,
+                                                  paged_window_attention,
                                                   quantize_kv)
         rng = np.random.RandomState(5)
-        S, W, dh, ps, P = 8, 3, 64, 16, 34
+        S, W, P = 8, 3, 34
         n_pages = S * P + 1
         k = jnp.asarray(rng.randn(n_pages, ps, g, dh), jnp.bfloat16)
         v = jnp.asarray(rng.randn(n_pages, ps, g, dh), jnp.bfloat16)
@@ -139,12 +147,60 @@ class TestPagedKernelCompiled:
         # as the pools store them: the kv heads side by side on the lanes
         k = k.reshape(n_pages, ps, g * dh)
         v = v.reshape(n_pages, ps, g * dh)
+        supported = paged_kernel_supported(q, k, kw.get("k_scales"),
+                                           pages_per_slot=P)
+        assert supported == lowers
         want = paged_window_attention(q, k, v, tables, lens, **kw)
         got = paged_window_attention(q, k, v, tables, lens,
-                                     use_kernel=True, **kw)
+                                     use_kernel=supported, **kw)
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32),
             rtol=2e-2, atol=2e-2)
+
+    def test_time_follows_the_cached_tokens(self):
+        """A layer's call at the benchmark's shape (32 slots, a table
+        of 128 pages of 16, 32 heads of 64, bf16, the pool with its
+        layer axis), 24 calls chained in one program as a step chains
+        them: with every slot at 1 token it takes under a fifth of what
+        it takes with every slot at 2,048. The grid-walk kernel this one
+        replaced read 0.55 ms against 3.42 (its grid visited 32 x 128
+        table entries whatever was live; ROADMAP.md D12)."""
+        import time
+        from paddle_tpu.ops.pallas_decode import paged_window_attention
+        S, P, h, dh, ps, L, calls = 32, 128, 32, 64, 16, 2, 24
+        n_pages = S * P + 1
+        key = jax.random.PRNGKey(0)
+        k = jax.random.normal(key, (L, n_pages, ps, h * dh), jnp.bfloat16)
+        v = jax.random.normal(jax.random.fold_in(key, 1), k.shape,
+                              jnp.bfloat16)
+        q = jax.random.normal(jax.random.fold_in(key, 2), (S, 1, h, dh),
+                              jnp.bfloat16)
+        tables = jnp.asarray(np.random.RandomState(0).permutation(
+            np.arange(1, n_pages)).reshape(S, P), jnp.int32)
+
+        @jax.jit
+        def step(q, k, v, tables, lens):
+            x = q
+            for i in range(calls):
+                o = paged_window_attention(x, k, v, tables, lens,
+                                           layer=i % L, use_kernel=True)
+                x = (q + 0.001 * o).astype(q.dtype)
+            return x
+
+        def ms_a_call(tokens):
+            lens = jnp.full((S, 1), tokens, jnp.int32)
+            step(q, k, v, tables, lens).block_until_ready()
+            times = []
+            for _ in range(5):
+                t0 = time.perf_counter()
+                step(q, k, v, tables, lens).block_until_ready()
+                times.append(time.perf_counter() - t0)
+            return 1e3 * sorted(times)[2] / calls
+
+        empty, full = ms_a_call(1), ms_a_call(P * ps)
+        print(f"paged_window_attention ms a call: 1 token a slot "
+              f"{empty:.4f}, {P * ps} tokens a slot {full:.4f}")
+        assert empty < full / 5, (empty, full)
 
 
 class TestCpuTpuParity:
