@@ -1,9 +1,9 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
-One process, the user's entry points, the full width of the language
-model the repo's benchmark uses (bench.py bench_transformer /
-bench_decode_continuous: vocab 32000, d_model 512, 8 heads, 6 layers,
-d_ff 2048, bf16), random weights from a seed:
+One process, the user's entry points, a small language model (vocab
+32000, d_model 512, 8 heads, 6 layers, d_ff 2048, bf16: the width of the
+older `bench.py` rows, NOT a configuration of the benchmark, which is
+`BENCHMARK.json` / `benchmarks/run.py`), random weights from a seed:
 
 - train: five ``paddle.SGD(...).train(...)`` steps at batch 8 x T 1024;
 - serve: six requests through ``DecodeEngine`` with its default

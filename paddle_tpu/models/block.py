@@ -1,12 +1,43 @@
-"""Block descriptions for the decoders (models/decode.py).
+"""Block descriptions and their cache kinds: what a decoder computes.
 
-``TransformerDecoder(params, ..., block=None)`` runs the block the
-``layer`` DSL trains (pre-LayerNorm, learned positions, MHA/GQA, ReLU or
-capacity-routed FFN, tied head): that description is written out in
-decode.py itself. A ``block=`` description replaces it: one frozen object
-that carries the configuration and the block's pure functions over the
-parameter table, used by the dense-cache path (``generate``) and by
-``PagedDecoder``'s step alike, so that the two cannot drift.
+The decoders (models/decode.py) and ``DecodeEngine`` hold a parameter
+table ``p`` and ONE description of the block that reads it. What a block
+computes, what it caches a token and which paged attention reads that
+cache are stated here and nowhere else: the decoders call the description
+and never ask which one it is. The protocol is informal (two descriptions,
+two cache kinds: no base class, no registry); ``pre`` is ``_<name>_``.
+
+A description (a frozen object of pure functions over the table):
+
+    cache                          its cache kind, a class below
+    positions(p, pre) -> int       positions the model can address
+    table_dtype(p, pre), vocab_size(p, pre)
+    embed(p, pre, ids, pos)        ids [B, t] at positions pos -> [B, t, d]
+    ffn(p, pre, i, x, active=None) -> (x + FFN_i(norm(x)), held load int32
+                                   [2] or None); ``active`` [B, t] masks
+                                   the load count, never the result
+    logits(p, pre, x)              final norm and head -> [B, t, V]
+    n_expert_layers(n_layers)      layers whose held load the step sums
+    ... and what its cache kind asks of it (the kind's docstring)
+
+A cache kind:
+
+    refuses                        {"kv_quant" | "draft" | "speculation":
+                                   why this kind cannot}
+    dense_init(block, p, pre, b, max_len)    one layer's dense caches
+    dense_layer(block, p, pre, i, x, cache, positions, pos, kv_len)
+                                   -> (x, cache): generate / beam_search
+    Kind(block, p, pre, n_layers=, num_slots=, window=, page_size=,
+         num_pages=, max_pages_per_slot=, kv_quant=)    the paged pools:
+      .dtype, .plan (the artifact fingerprints' facts), .init_pools(),
+      .kernel_supported(), .page_payload(page) (the spill codec's shape),
+      .layer(p, i, x, k_pool, v_pool, tok, use_kernel=, interpret=)
+                                   -> (x, k_pool, v_pool, held load or None)
+
+:class:`DefaultBlock` is the block the ``layer`` DSL trains
+(models/transformer.py keeps its own copy of the names): pre-LayerNorm,
+learned positions, MHA/GQA, ReLU FFN or capacity-routed experts, tied or
+untied head, over per-head K/V.
 
 :class:`LatentBlock` is the DeepSeek-V3 / Kimi-K2 block: RMSNorm, rotary
 positions (YaRN) on part of each query head, a low-rank query, multi-head
@@ -14,8 +45,8 @@ LATENT attention whose cache row is one ``[c_kv | k_rope]`` per token and
 layer (not per head), SwiGLU, leading dense layers, then sigmoid-routed
 experts of which this chip holds a share, a shared expert, an untied head.
 
-Precision. The weights and the cache are what the table holds (bfloat16
-as served); the residual stream, the norms, the router and every
+Its precision. The weights and the cache are what the table holds
+(bfloat16 as served); the residual stream, the norms, the router and every
 elementwise step are float32, and a product with a stored weight takes
 its float32 activation as two terms of the weight's dtype in one pass
 (ops/linear.einsum_two_terms). That is what keeps the router's top-k the
@@ -23,14 +54,14 @@ reference's: rounding the stream to bfloat16 moved a 384-way top-8 choice
 at one position in ten a layer, and a moved choice on a held expert adds
 or drops a whole expert's term.
 
-Parameter table (``pre`` = ``_<name>_``; every norm a gain, no bias):
+Its table (every norm a gain, no bias):
 
     <pre>tok_emb.w0 [V, d]   <pre>lm_head.w0 [V, d]   <pre>norm_f.w0 [d]
     <pre>l<i>_attn_norm.w0 [d]     <pre>l<i>_ffn_norm.w0 [d]
     <pre>l<i>_q_down.w0 [d, rq]    <pre>l<i>_q_norm.w0 [rq]
-    <pre>l<i>_q_up.w0 [rq, H*(dn+dr)]
+    <pre>l<i>_q_up.w0 [rq, H*(dn+dr)]   <pre>l<i>_proj.w0 [H*dv, d]
     <pre>l<i>_kv_down.w0 [d, rkv+dr]   <pre>l<i>_kv_norm.w0 [rkv]
-    <pre>l<i>_kv_up.w0 [rkv, H*(dn+dv)]   <pre>l<i>_proj.w0 [H*dv, d]
+    <pre>l<i>_kv_up.w0 [rkv, H*(dn+dv)]
     dense layers:  <pre>l<i>_gate.w0, _up.w0 [d, f], _down.w0 [f, d]
     expert layers: <pre>l<i>_router.w0 [d, E], _router.wbias [E],
                    _experts.gate, _experts.up [held, d, fm],
@@ -43,21 +74,411 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.ops import moe as moe_ops
+from paddle_tpu.ops import pallas_decode as paged_ops
 from paddle_tpu.ops.linear import einsum_two_terms as mm
 
 NEG_INF = -1e30
+
+
+def layer_norm(x, g, b, eps=1e-5):
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.maximum(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                      - mean * mean, 0.0)
+    y = (xf - mean) * jax.lax.rsqrt(var + eps)
+    return (y * g + b).astype(x.dtype)
 
 
 def rms_norm(x, g, eps):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return y * g.astype(jnp.float32)
+
+
+def split_heads(x, h):
+    return x.reshape(x.shape[:-1] + (h, x.shape[-1] // h))
+
+
+def use_flash_prefill(t, pos, dh) -> bool:
+    """Flash-prefill gate: a long (>=256) prompt on TPU with a
+    tile-friendly head dim, and the cache empty before this call (pos is
+    the static int 0 at prefill; a decode step's is a traced scalar)."""
+    from paddle_tpu.config import global_config
+    from paddle_tpu.ops import pallas_attention as flash
+    probe = jax.ShapeDtypeStruct((1, t, 1, dh), jnp.float32)
+    return (isinstance(pos, int) and pos == 0 and t >= 256
+            and flash.flash_supported(probe, probe)
+            and global_config().use_flash_attention
+            and jax.default_backend() == "tpu")
+
+
+class PagedTokens(NamedTuple):
+    """What PagedDecoder's step knows of its [S, W] window tokens."""
+    positions: jax.Array
+    active: jax.Array        # bool: a masked token writes to the null page
+    page_idx: jax.Array      # the physical page each token's row goes to
+    offs: jax.Array          # and the row inside it
+    page_tables: jax.Array   # [S, P]
+    kv_lens: jax.Array       # positions + 1
+    live_lens: jax.Array     # 0 where not active: such a token attends to
+    #                          nothing, an all-masked slot copies no page
+
+
+# ------------------------------------------------------------ cache kinds
+class PerHeadCache:
+    """K and V, a row per token and kv head. Asks of its block:
+    ``heads(p, pre) -> (h, g, dh)``; ``qkv(p, pre, i, x, pos, flat=False)
+    -> q [B, t, h, dh], k, v [B, t, g, dh]`` (``flat``: k, v as the pool
+    stores a token's row, [B*t, g*dh]); ``project(p, pre, i, attn)``.
+
+    Dense: a [b, T, g, dh] pair a layer, read at stored width (GQA never
+    repeats the cache). Paged: two pools [L, n_pages, page_size, g*dh],
+    the kv heads of a token side by side on the lane axis (whole tiles at
+    16 x 2048 bf16), in the layout paged_window_attention reads, in place.
+    ``kv_quant="int8"`` makes each pool ``{"q": int8 values in that
+    layout, "s": float32 [L, n_pages, page_size, g]}``: the scatter
+    quantizes a row per (token, kv head) with ``quantize_kv`` (a pure
+    function of the row, so prefix-shared pages stay bit-identical) and
+    attention dequantizes as it reads; ~4x pages per byte at an fp32
+    base, greedy output prefix-identical under the INT8_KV_* contract."""
+
+    #: the stored layout, as the artifact fingerprints name it: an
+    #: executable built for another layout can never be resolved
+    LAYOUT = "L,N,page,g*dh"
+    refuses: dict = {}
+
+    @staticmethod
+    def dense_init(block, p, pre, b, max_len):
+        _, g, dh = block.heads(p, pre)
+        dtype = block.table_dtype(p, pre)
+        return (jnp.zeros((b, max_len, g, dh), dtype),
+                jnp.zeros((b, max_len, g, dh), dtype))
+
+    @staticmethod
+    def dense_layer(block, p, pre, i, x, cache, positions, pos, kv_len):
+        """One block over a [b, t, d] slice; reads/extends the caches at
+        positions [pos, pos+t)."""
+        k_cache, v_cache = cache
+        q, k, v = block.qkv(p, pre, i, x, positions)
+        h, dh = q.shape[2:]
+        kv_h = k_cache.shape[2]
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, k.astype(k_cache.dtype), (0, pos, 0, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, v.astype(v_cache.dtype), (0, pos, 0, 0))
+        t = x.shape[1]
+        T = k_cache.shape[1]
+        scale = dh ** -0.5
+        rep = h // kv_h
+        if use_flash_prefill(t, pos, dh):
+            # LONG-prompt prefill: the einsum path materializes a
+            # [b,g,rep,t,t] score tensor (quadratic HBM); the flash kernel
+            # streams K/V blocks instead (the cache is empty before this
+            # call). GQA repeats K/V here: once, never per decode step.
+            from paddle_tpu.ops import pallas_attention as flash
+            kq = k if rep == 1 else jnp.repeat(k, rep, axis=2)
+            vq = v if rep == 1 else jnp.repeat(v, rep, axis=2)
+            lens = jnp.minimum(jnp.full((x.shape[0],), t, jnp.int32),
+                               kv_len)
+            attn = flash.flash_attention(
+                q.astype(x.dtype), kq.astype(x.dtype),
+                vq.astype(x.dtype), q_lens=lens, kv_lens=lens,
+                causal=True, scale=scale,
+                interpret=jax.default_backend() == "cpu")
+        else:
+            q5 = q.reshape(q.shape[0], t, kv_h, rep, dh)
+            logits = jnp.einsum("bqgrd,bkgd->bgrqk", q5,
+                                k_cache.astype(q.dtype)) * scale
+            # causal against absolute positions: query row j is at pos+j
+            qpos = pos + jnp.arange(t)[:, None]
+            kpos = jnp.arange(T)[None, :]
+            mask = (kpos <= qpos) & (kpos < kv_len)
+            logits = jnp.where(mask[None, None, None], logits, NEG_INF)
+            w = jax.nn.softmax(logits, axis=-1)
+            attn = jnp.einsum("bgrqk,bkgd->bqgrd", w,
+                              v_cache.astype(q.dtype))
+        x = x + block.project(p, pre, i, attn.reshape(x.shape))
+        return block.ffn(p, pre, i, x)[0], (k_cache, v_cache)
+
+    def __init__(self, block, p, pre, *, n_layers, num_slots, window,
+                 page_size, num_pages, max_pages_per_slot, kv_quant):
+        self.block, self.pre, self.kv_quant = block, pre, kv_quant
+        self.dtype = block.table_dtype(p, pre)
+        self.n_heads, self.kv_heads, self.head_dim = block.heads(p, pre)
+        self.rows = (n_layers, num_pages, page_size)
+        self._query = (num_slots, window, max_pages_per_slot)
+        self.plan = {"pool_layout": self.LAYOUT, "kv_heads": self.kv_heads,
+                     "head_dim": self.head_dim}
+
+    def kernel_supported(self) -> bool:
+        (S, W, P), (_, N, ps) = self._query, self.rows
+        g, dh, int8 = self.kv_heads, self.head_dim, self.kv_quant == "int8"
+        return paged_ops.paged_kernel_supported(
+            jax.ShapeDtypeStruct((S, W, self.n_heads, dh), self.dtype),
+            jax.ShapeDtypeStruct((N, ps, g * dh),
+                                 jnp.int8 if int8 else self.dtype),
+            jax.ShapeDtypeStruct((N, ps, g), jnp.float32) if int8 else None,
+            pages_per_slot=P)
+
+    def init_pools(self):
+        """Zeroed (k_pool, v_pool) in the stored layout."""
+        row = self.kv_heads * self.head_dim
+
+        def one():
+            if self.kv_quant == "int8":
+                return {"q": jnp.zeros(self.rows + (row,), jnp.int8),
+                        "s": jnp.zeros(self.rows + (self.kv_heads,),
+                                       jnp.float32)}
+            return jnp.zeros(self.rows + (row,), self.dtype)
+
+        return one(), one()
+
+    def page_payload(self, page):
+        """Value leaves [L, 1, ps, g*dh] -> [L, 1, ps, g, dh]: the shape
+        the spill payload has always had (the codec and its checksums do
+        not know the stored layout; scale leaves pass as they are)."""
+        def heads(v):
+            return split_heads(v, self.kv_heads)
+
+        if self.kv_quant == "int8":
+            return {"q": heads(page["q"]), "s": page["s"]}
+        return heads(page)
+
+    def layer(self, p, i, x, k_pool, v_pool, tok, *, use_kernel,
+              interpret):
+        blk, pre, g = self.block, self.pre, self.kv_heads
+        S, W = x.shape[0], x.shape[1]
+        q, k, v = blk.qkv(p, pre, i, x, tok.positions, flat=True)
+        # unconditional scatter: every window token writes its K/V at
+        # (layer, page, offset) of the donated pool, in place and BEFORE
+        # attention, so later window tokens attend to earlier ones; the
+        # caller routed masked tokens to the null page
+        rows_p = tok.page_idx.reshape(-1)
+        rows_o = tok.offs.reshape(-1)
+
+        def put(pool, rows):
+            return pool.at[i, rows_p, rows_o].set(rows.astype(pool.dtype))
+
+        # the scopes name the regions in a device trace (PERF.md section 3)
+        scales = {}
+        if self.kv_quant == "int8":
+            with jax.named_scope("kv_write"):
+                kq, ks = paged_ops.quantize_kv(k.reshape(S * W, g, -1))
+                vq, vs = paged_ops.quantize_kv(v.reshape(S * W, g, -1))
+                k_pool = {"q": put(k_pool["q"], kq.reshape(S * W, -1)),
+                          "s": put(k_pool["s"], ks)}
+                v_pool = {"q": put(v_pool["q"], vq.reshape(S * W, -1)),
+                          "s": put(v_pool["s"], vs)}
+            k_pages, v_pages = k_pool["q"], v_pool["q"]
+            scales = dict(k_scales=k_pool["s"], v_scales=v_pool["s"])
+        else:
+            with jax.named_scope("kv_write"):
+                k_pool, v_pool = put(k_pool, k), put(v_pool, v)
+            k_pages, v_pages = k_pool, v_pool
+        with jax.named_scope("paged_attn"):
+            attn = paged_ops.paged_window_attention(
+                q, k_pages, v_pages, tok.page_tables, tok.live_lens,
+                layer=i, use_kernel=use_kernel, interpret=interpret,
+                **scales)
+        x = x + blk.project(p, pre, i, attn.reshape(x.shape))
+        with jax.named_scope("ffn"):
+            x, load = blk.ffn(p, pre, i, x, tok.active)
+        return x, k_pool, v_pool, load
+
+
+class LatentCache:
+    """One ``[c_kv | k_rope]`` row a token and layer (no heads). Asks of
+    its block: ``cache_widths``, ``sizes``, ``qkv`` (-> q_nope, q_rope,
+    c_kv, k_rope), ``absorb_q``, ``expand_o``, ``project``, ``attend``,
+    ``softmax_scale``.
+
+    Dense: a ([b, T, rkv], [b, T, dr]) pair a layer, read by the expanded
+    (unabsorbed) attention. Paged: ONE pool [L, n_pages, page_size, lanes],
+    a row padded with zero lanes to whole 128-lane tiles (576 -> 640 at the
+    published widths: a pool whose rows are not whole tiles reaches the
+    kernel through a pool-sized relayout copy every layer), read by the
+    absorbed attention of ops/pallas_decode.paged_latent_attention, where
+    one latent row serves as key and as value (tests hold the two forms
+    to each other). The engine knows two pool names, so the second is an
+    empty pytree: every page program and donation maps over nothing."""
+
+    LAYOUT = "L,N,page,c_kv|k_rope|0"
+    refuses = {
+        "kv_quant": "kv_quant is not supported on a latent (MLA) cache: the "
+        "int8 layout packs per-head scales, and a latent row has no heads",
+        "draft": "a draft over a latent (MLA) block is not supported: "
+        "DraftDecoder's slot-private caches are per-head K/V",
+        "speculation": "speculative decoding (draft / spec_k) is not "
+        "supported on a latent (MLA) block"}
+
+    @staticmethod
+    def dense_init(block, p, pre, b, max_len):
+        dtype = block.table_dtype(p, pre)
+        return tuple(jnp.zeros((b, max_len, w), dtype)
+                     for w in block.cache_widths(p, pre))
+
+    @staticmethod
+    def dense_layer(block, p, pre, i, x, cache, positions, pos, kv_len):
+        c_cache, r_cache = cache
+        t, T = x.shape[1], c_cache.shape[1]
+        qpos = pos + jnp.arange(t)
+        q_nope, q_rope, c_kv, k_rope = block.qkv(
+            p, pre, i, x, jnp.broadcast_to(qpos[None], x.shape[:2]))
+        c_cache = jax.lax.dynamic_update_slice(
+            c_cache, c_kv.astype(c_cache.dtype), (0, pos, 0))
+        r_cache = jax.lax.dynamic_update_slice(
+            r_cache, k_rope.astype(r_cache.dtype), (0, pos, 0))
+        kpos = jnp.arange(T)[None, :]
+        mask = (kpos <= qpos[:, None]) & (kpos < kv_len)
+        attn = block.attend(p, pre, i, q_nope, q_rope, c_cache, r_cache,
+                            jnp.broadcast_to(mask[None], (x.shape[0], t, T)),
+                            absorbed=False)
+        x = x + block.project(p, pre, i, attn)
+        return block.ffn(p, pre, i, x)[0], (c_cache, r_cache)
+
+    def __init__(self, block, p, pre, *, n_layers, num_slots, window,
+                 page_size, num_pages, max_pages_per_slot, kv_quant):
+        self.block, self.pre = block, pre
+        self.dtype = block.table_dtype(p, pre)
+        rkv, dr = block.cache_widths(p, pre)
+        self.row_lanes = -(-(rkv + dr) // 128) * 128
+        self.shape = (n_layers, num_pages, page_size, self.row_lanes)
+        self._query = (num_slots, window * block.sizes(p, pre)["H"],
+                       self.row_lanes, rkv, page_size, max_pages_per_slot)
+        self.plan = {"pool_layout": self.LAYOUT,
+                     "row_lanes": self.row_lanes}
+
+    def kernel_supported(self) -> bool:
+        return paged_ops.latent_kernel_supported(*self._query, self.dtype)
+
+    def init_pools(self):
+        return jnp.zeros(self.shape, self.dtype), {}
+
+    def page_payload(self, page):
+        return page                # a latent row has no heads to split
+
+    def layer(self, p, i, x, pool, none, tok, *, use_kernel, interpret):
+        """The token's row scattered into the donated pool in place, the
+        absorbed attention over the slot's pages, the block's own FFN."""
+        blk, pre = self.block, self.pre
+        q_nope, q_rope, c_kv, k_rope = blk.qkv(p, pre, i, x, tok.positions)
+        with jax.named_scope("latent_kv_write"):
+            row = jnp.concatenate([c_kv, k_rope], axis=-1)
+            row = row.reshape(-1, row.shape[-1]).astype(pool.dtype)
+            pool = pool.at[i, tok.page_idx.reshape(-1),
+                           tok.offs.reshape(-1)].set(
+                jnp.pad(row, ((0, 0), (0, pool.shape[-1] - row.shape[-1]))))
+        with jax.named_scope("latent_attn"):
+            o_lat = paged_ops.paged_latent_attention(
+                blk.absorb_q(p, pre, i, q_nope), q_rope, pool,
+                tok.page_tables, tok.kv_lens, layer=i,
+                scale=blk.softmax_scale, use_kernel=use_kernel,
+                interpret=interpret)
+            attn = blk.expand_o(p, pre, i, o_lat)
+        x = x + blk.project(p, pre, i, attn.reshape(x.shape[:2] + (-1,)))
+        with jax.named_scope("ffn"):
+            x, load = blk.ffn(p, pre, i, x, tok.active)
+        return x, pool, none, load
+
+
+# ----------------------------------------------------------- descriptions
+@dataclasses.dataclass(frozen=True)
+class DefaultBlock:
+    """The ``layer`` DSL's block. Expert layers and their count are read
+    from the table, but ``moe_k`` is NOT recoverable from it: it MUST
+    match the training config or decode silently diverges.
+    ``moe_capacity_factor=None`` routes DROP-FREE (capacity = each call's
+    token count), so decode matches the training forward whenever training
+    dropped nothing; a float reproduces a training capacity limit exactly."""
+
+    n_heads: int
+    moe_k: int = 2
+    moe_capacity_factor: Optional[float] = None
+
+    cache = PerHeadCache
+
+    def positions(self, p, pre) -> int:
+        return p[f"{pre}pos_emb.w0"].shape[0]    # the learned table's rows
+
+    def table_dtype(self, p, pre):
+        return p[f"{pre}tok_emb.w0"].dtype
+
+    def vocab_size(self, p, pre) -> int:
+        if f"{pre}head.w0" in p:
+            return p[f"{pre}head.w0"].shape[1]
+        return p[f"{pre}tok_emb.w0"].shape[0]
+
+    def heads(self, p, pre) -> tuple:
+        """(h, g, dh): the kv head count from the k projection's width
+        (GQA stores g-sized caches — THE decode win of GQA)."""
+        dh = p[f"{pre}tok_emb.w0"].shape[1] // self.n_heads
+        return self.n_heads, p[f"{pre}l0_k.w0"].shape[1] // dh, dh
+
+    def n_expert_layers(self, n_layers: int) -> int:
+        return 0          # its experts are all here: no held load to count
+
+    def embed(self, p, pre, ids, pos):
+        return p[f"{pre}tok_emb.w0"][ids] + p[f"{pre}pos_emb.w0"][pos]
+
+    def qkv(self, p, pre, i, x, pos, flat: bool = False):
+        n = f"{pre}l{i}"
+        h, g, _ = self.heads(p, pre)
+        rows = (lambda a: a.reshape(x.shape[0] * x.shape[1], -1)) if flat \
+            else (lambda a: split_heads(a, g))
+        ln1 = layer_norm(x, p[f"{n}_ln1.w0"], p[f"{n}_ln1.wbias"])
+        return (split_heads(ln1 @ p[f"{n}_q.w0"], h),
+                rows(ln1 @ p[f"{n}_k.w0"]), rows(ln1 @ p[f"{n}_v.w0"]))
+
+    def project(self, p, pre, i, attn):
+        return attn @ p[f"{pre}l{i}_proj.w0"]
+
+    def ffn(self, p, pre, i, x, active=None):
+        n = f"{pre}l{i}"
+        ln2 = layer_norm(x, p[f"{n}_ln2.w0"], p[f"{n}_ln2.wbias"])
+        if f"{n}_moe.gate" not in p:
+            up = jax.nn.relu(ln2 @ p[f"{n}_up.w0"] + p[f"{n}_up.wbias"])
+            return x + up @ p[f"{n}_down.w0"], None
+        b_, t_, d_ = ln2.shape
+        gate = p[f"{n}_moe.gate"]
+        cf, cap = self.moe_capacity_factor, None
+        if cf is None:
+            cap = b_ * t_
+            # drop-free routing materializes [n, E, C=n] dispatch tensors
+            # — quadratic in tokens. Cheap for the per-step call (n =
+            # batch); for a LARGE prefill fall back to a generous factor
+            # instead of OOMing the chip.
+            if cap * cap * gate.shape[-1] > (1 << 27):
+                import warnings
+                warnings.warn(
+                    f"moe prefill with {cap} tokens: drop-free routing "
+                    f"would need a [{cap},{gate.shape[-1]},{cap}] dispatch "
+                    "tensor; falling back to capacity_factor=2.0 (set "
+                    "moe_capacity_factor explicitly to choose)",
+                    stacklevel=2)
+                cap, cf = None, 2.0
+        y2d, _ = moe_ops.moe_ffn(
+            ln2.reshape(b_ * t_, d_), None, gate, p[f"{n}_moe.moe_up"],
+            p[f"{n}_moe.moe_down"], k=self.moe_k,
+            capacity_factor=cf if cf is not None else 1.25,
+            capacity=cap, dispatch_mode="auto")
+        return x + y2d.reshape(b_, t_, d_), None
+
+    def logits(self, p, pre, x):
+        x = layer_norm(x, p[f"{pre}lnf.w0"], p[f"{pre}lnf.wbias"])
+        if f"{pre}head.w0" in p:
+            logits = x @ p[f"{pre}head.w0"]
+        else:  # tie_embeddings: the head IS the token table, transposed
+            logits = x @ p[f"{pre}tok_emb.w0"].T
+        if f"{pre}head.wbias" in p:  # older checkpoints carried a bias
+            logits = logits + p[f"{pre}head.wbias"]
+        return logits
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,6 +502,17 @@ class LatentBlock:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+
+    cache = LatentCache
+
+    def positions(self, p, pre) -> int:
+        return self.max_positions        # rotary: what the table was cut to
+
+    def table_dtype(self, p, pre):
+        return p[f"{pre}tok_emb.w0"].dtype
+
+    def vocab_size(self, p, pre) -> int:
+        return p[f"{pre}lm_head.w0"].shape[0]
 
     # ----------------------------------------------------------- rotary
     def inv_freq(self) -> np.ndarray:
@@ -154,7 +586,7 @@ class LatentBlock:
         return (p[f"{pre}l0_kv_norm.w0"].shape[0], self.qk_rope_head_dim)
 
     # ------------------------------------------------------------- parts
-    def embed(self, p, pre, ids):
+    def embed(self, p, pre, ids, pos=None):
         return p[f"{pre}tok_emb.w0"][ids].astype(jnp.float32)
 
     def logits(self, p, pre, x):

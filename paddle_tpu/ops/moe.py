@@ -217,7 +217,7 @@ def moe_ffn(x: jnp.ndarray, valid: Optional[jnp.ndarray],
 
     `capacity` overrides the factor-derived per-expert buffer; pass
     capacity=n at inference for drop-free routing (the capacity limit
-    only buys memory/balance at training scale — see models/decode.py).
+    only buys memory/balance at training scale — see models/block.py).
     """
     if dispatch_mode == "auto":
         # measured (tools/moe_dispatch_bench.py, v5e, bf16, d=512 f=2048

@@ -1,82 +1,47 @@
-"""Decode attention: the paged serving path + a recorded Pallas
-experiment.
+"""Paged decode attention: the gather reference and the live-pages kernels.
 
-:func:`paged_attention` (bottom) is LIVE — the continuous-batching
-engine's per-step attention over the paged KV pool. The Pallas kernel
-that opens this file is the round-5 recorded experiment it can compose
-with.
+The serving step (models/decode.py PagedDecoder, through the cache kinds
+of models/block.py) reads its page pools through two entry points:
 
-Round-5 verdict on the kernel: measured and REJECTED. The decode trace (docs/perf.md,
-"the decode gap, traced") showed XLA lowering the per-step attention
-(q [b,h,dh] against cached K/V over T positions) to VPU multiply-reduce
-fusions at ~160 GB/s effective — the hypothesis was that a Pallas
-kernel, which dictates its own block tiling, could stream the cache
-with T on the lane axis at full width. Two grid shapes were measured on
-device against the einsum path inside the real decode scan (bs32,
-T=544, 6 layers):
+:func:`paged_window_attention` over per-head K/V pools. Its kernel
+(:func:`_paged_window_kernel`) walks the LIVE pages itself. The pools
+stay in HBM whole; the grid is over slots alone; inside a slot's step a
+loop of ``ceil(used[s] / K)`` compute blocks (:func:`_walk_live_pages`,
+which knows nothing of heads or softmax, and of which this kernel is the
+one caller) copies each live page with a DMA of its own into its rows of
+a double-buffered [K*page_size, g*dh] tile while the block before is
+computed, and starts the NEXT live slot's first block before this slot's
+last one ends. A page past a slot's allocation is never named, copied or
+visited, and an idle slot costs no DMA: a call's time follows the cached
+tokens (0.13 ms at one token a slot, 1.3 ms at 2,048;
+tests/test_tpu_smoke.py holds the ratio on the chip). The kernel it
+replaced let Pallas's pipeline walk the table, grid (S, P), and paid
+~65 ns a page operand visited, slots x table width whatever was cached
+(PERF.md, PR 35 and PR 36). The query carries a W-token verify window
+per slot (speculative decoding + multi-token prefill,
+serving/engine.py), accumulated with the online-softmax recurrence
+across blocks.
 
-  - grid (b, h) — one step per row/head: 1.86 ms/step vs 0.92 einsum.
-    TPU Pallas grids run SEQUENTIALLY on the core; b*h tiny DMAs
-    serialize.
-  - grid (g,) — this kernel: whole-batch [b, dh, T] K/V blocks per kv
-    group, all GQA query heads inside the step: 1.50 ms/step. Fewer,
-    larger DMAs, still loses: Mosaic loops the leading batch dim and
-    the per-b [dh, T] reductions pipeline worse than XLA's fused
-    lowering of the same math.
+:func:`paged_latent_attention` over a latent (MLA) pool, one
+[c_kv | k_rope] row a token. Its kernel (:func:`_paged_latent_kernel`)
+still lets the pipeline walk the table with its own grid (S, blocks), a
+second page walk beside ``_walk_live_pages`` (ROADMAP D11).
 
-The einsum formulation in models/decode.py remains the measured
-optimum (two cache-layout variants of it also lost — see perf.md). The
-kernel stays here, correct and parity-tested
-(tests/test_decode.py::TestPallasDecodeAttention), as the starting
-point if a future round wants to hand-tune the Mosaic lowering.
+:func:`paged_attention` is the GATHER REFERENCE both fall back to and
+are held to: the page gather produces the contiguous [b, T, g, dh] view
+of each slot's whole table width and runs the dense decoder's exact
+einsum over it, so it is token-identical to the dense cache by
+construction and reads ``max_seq_len`` of traffic a slot whatever is
+cached. Parity of the kernels against it is pinned in
+tests/test_paged_decode.py (GQA/MQA, ragged lengths, W > 1, idle slots,
+block edges, repeated pages) and tests/test_latent_decode.py.
 
-Cache layout contract: [b, g, dh, T].
-
-Round 6 adds the LIVE serving path: :func:`paged_attention`, decode
-attention over a PAGED KV cache (fixed-size pages in a preallocated
-pool, per-sequence page tables — the PagedAttention design). The page
-gather produces the contiguous [b, T, g, dh] view and then runs the
-exact einsum formulation above (token-identical to the dense cache by
-construction, pinned in tests/test_paged_decode.py), or composes with
-the recorded-experiment kernel via ``use_kernel=True`` — both paths
-take PER-ROW kv lengths, which is what lets one fixed-shape jitted
-step serve ragged sequences (serving/engine.py).
-
-Round 9 replaced the gather's traffic profile with
-:func:`paged_window_attention` + a kernel whose reads follow the
-ALLOCATED pages: the gather path reads every slot's full page-table
-width (P * page_size positions — ``max_seq_len`` traffic per slot per
-step regardless of actual length). That kernel let Pallas's pipeline
-walk the table, grid (S, P), with a block index clamped to the slot's
-last page: a step past it skipped its DMA and its math, but the visit
-was still paid, ~65 ns a page operand, so a call cost slots x table
-width whatever was cached (0.55 ms at 32 x 128 with every slot at one
-token; PERF.md, PR 35 and PR 36).
-
-The kernel that stands now (:func:`_paged_window_kernel`, PR 36) walks
-the LIVE pages itself. The pools stay in HBM whole; the grid is over
-slots alone; inside a slot's step a loop of ``ceil(used[s] / K)``
-compute blocks (:func:`_walk_live_pages`, which knows nothing of heads
-or softmax) copies each live page with a DMA of its own into its rows
-of a double-buffered [K*page_size, g*dh] tile while the block before
-is computed, and starts the NEXT live slot's first block before this
-slot's last one ends. A page past a slot's allocation is never named,
-copied or visited, and an idle slot costs no DMA: a call's time
-follows the cached tokens (0.13 ms at one token a slot, 1.3 ms at
-2,048; tests/test_tpu_smoke.py holds the ratio on the chip). The
-query carries a W-token verify window per slot (speculative decoding
-+ multi-token prefill, serving/engine.py), accumulated with the
-online-softmax recurrence across blocks. Parity vs. the gather/einsum
-reference is pinned in tests/test_paged_decode.py (GQA/MQA, ragged
-lengths, W > 1, idle slots, block edges, repeated pages).
-
-Both paged paths read the pools AS STORED: a page is
-[page_size, g*dh], the kv heads of a token side by side on the lane
-axis, and a pool that keeps its layer axis is handed over whole with
-``layer=`` (the comment above :func:`gather_pages`). The kernel walks
-a block's tile in 128-lane chunks against a block-diagonal q, so
-nothing is padded in HBM or relaid out in VMEM at the serving
-widths."""
+All paths read the pools AS STORED: a page is [page_size, g*dh], the kv
+heads of a token side by side on the lane axis, and a pool that keeps
+its layer axis is handed over whole with ``layer=`` (the comment above
+:func:`gather_pages`). The window kernel walks a block's tile in
+128-lane chunks against a block-diagonal q, so nothing is padded in HBM
+or relaid out in VMEM at the serving widths."""
 
 from __future__ import annotations
 
@@ -89,10 +54,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634
-
-# per-block VMEM budget for K+V (+ double buffering headroom): beyond
-# this the caller falls back to XLA rather than risk a VMEM OOM
-_VMEM_BYTES = 8 * 1024 * 1024
 
 # ---- int8 KV token-identity contract (the two-tier KV plane) ----
 # The int8 paged path must be GREEDY-PREFIX-IDENTICAL to the fp
@@ -129,72 +90,6 @@ def dequantize_kv(q, scales, dtype=jnp.float32):
             * scales.astype(jnp.float32)[..., None]).astype(dtype)
 
 
-def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, out_ref, *, scale,
-                   rep):
-    k = k_ref[...]                                    # [b, 1, dh, T]
-    v = v_ref[...]
-    b, _, dh, t = k.shape
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (b, 1, 1, t), 3)
-    if lens_ref.shape[0] == 1:        # one shared length (dense decode)
-        live = cols < lens_ref[0]
-    else:                             # per-row lengths (ragged serving)
-        live = cols < lens_ref[...].reshape(b, 1, 1, 1)
-    for r in range(rep):
-        q = q_ref[:, r:r + 1].astype(jnp.float32)     # [b, 1, dh, 1]
-        s2 = jnp.sum(q * kf, axis=2, keepdims=True) * (scale * LOG2E)
-        s2 = jnp.where(live, s2, NEG_INF)             # [b, 1, 1, T]
-        m = jnp.max(s2, axis=3, keepdims=True)
-        p = jnp.exp2(s2 - m)                          # [b, 1, 1, T]
-        l = jnp.sum(p, axis=3, keepdims=True)
-        acc = jnp.sum(vf * p, axis=3, keepdims=True)  # [b, 1, dh, 1]
-        out_ref[:, r:r + 1] = (acc / l).astype(out_ref.dtype)
-
-
-def decode_supported(q, k_cache) -> bool:
-    """Tile-friendly and VMEM-sized? dh a sublane multiple; whole-batch
-    K+V group blocks within the VMEM budget."""
-    b, g, dh, t = k_cache.shape
-    esize = jnp.dtype(k_cache.dtype).itemsize
-    return dh % 8 == 0 and 2 * b * dh * t * esize <= _VMEM_BYTES
-
-
-def decode_attention(q, k_cache, v_cache, kv_len, *, scale=None,
-                     interpret=False):
-    """q [b, h, dh]; k_cache/v_cache [b, g, dh, T] with h % g == 0
-    (GQA: h == g*rep); kv_len: traced scalar (shared by every row) or a
-    per-row [b] vector — positions >= kv_len are masked (decode calls
-    always have each row's query at position kv_len-1, so this IS the
-    causal mask). Returns [b, h, dh]."""
-    b, h, dh = q.shape
-    g = k_cache.shape[1]
-    t = k_cache.shape[-1]
-    assert h % g == 0, (h, g)
-    rep = h // g
-    if scale is None:
-        scale = dh ** -0.5
-    q4 = q.reshape(b, h, dh, 1)
-    lens = jnp.asarray(kv_len, jnp.int32).reshape(-1)
-    assert lens.shape[0] in (1, b), (lens.shape, b)
-
-    kernel = functools.partial(_decode_kernel, scale=scale, rep=rep)
-    out = pl.pallas_call(
-        kernel,
-        grid=(g,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),        # lens [1|b]
-            pl.BlockSpec((b, rep, dh, 1), lambda j: (0, j, 0, 0)),
-            pl.BlockSpec((b, 1, dh, t), lambda j: (0, j, 0, 0)),
-            pl.BlockSpec((b, 1, dh, t), lambda j: (0, j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((b, rep, dh, 1), lambda j: (0, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, dh, 1), q.dtype),
-        interpret=interpret, name="decode_attention",
-    )(lens, q4, k_cache, v_cache)
-    return out.reshape(b, h, dh)
-
-
 # --------------------------------------------------------------- paged
 # The pool layout (one contract for the kernel, the gather path and
 # models/decode.PagedDecoder, which stores it): a page is
@@ -222,10 +117,9 @@ def gather_pages(pages, page_table, layer=None):
 
 
 def paged_attention(q, k_pages, v_pages, page_table, kv_lens, *,
-                    scale=None, use_kernel=False, interpret=False,
-                    k_scales=None, v_scales=None, layer=None):
-    """Decode attention over a PAGED KV cache (the serving engine's hot
-    path — serving/engine.py).
+                    scale=None, k_scales=None, v_scales=None, layer=None):
+    """Decode attention over a PAGED KV cache by gather: the reference
+    :func:`paged_window_attention` falls back to and is held to.
 
     q [b, h, dh]: one query token per sequence (slot batch);
     k_pages/v_pages [n_pages, page_size, g*dh]: the shared page pools
@@ -238,11 +132,10 @@ def paged_attention(q, k_pages, v_pages, page_table, kv_lens, *,
     ragged-length mask. Returns [b, h, dh].
 
     The gather materializes the same [b, T, g, dh] view the dense cache
-    stores, then runs models/decode.py's exact einsum formulation (the
-    measured optimum of five — docs/perf.md), so paged decode is
-    token-identical to the dense path. ``use_kernel=True`` instead
-    transposes the view into the [b, g, dh, T] contract and composes
-    with the :func:`decode_attention` GQA kernel."""
+    stores, then runs the dense decoder's exact einsum formulation
+    (models/block.py PerHeadCache.dense_layer; the measured optimum of
+    five — docs/perf.md), so paged decode is token-identical to the
+    dense path."""
     b, h, dh = q.shape
     g = k_pages.shape[-1] // dh
     assert g * dh == k_pages.shape[-1] and h % g == 0, (
@@ -255,21 +148,15 @@ def paged_attention(q, k_pages, v_pages, page_table, kv_lens, *,
     if k_scales is not None:
         # int8 pools: dequantize the GATHERED view (T rows, not the
         # whole pool) and fall through to the identical exact-einsum
-        # formulation — the dequant analogue of the kernel-gate
-        # fallback below
+        # formulation
         k = dequantize_kv(k, gather_pages(k_scales, page_table, layer),
                           q.dtype)
         v = dequantize_kv(v, gather_pages(v_scales, page_table, layer),
                           q.dtype)
     lens = jnp.asarray(kv_lens, jnp.int32).reshape(-1)
-    if use_kernel:
-        kt = k.transpose(0, 2, 3, 1)                   # [b, g, dh, T]
-        vt = v.transpose(0, 2, 3, 1)
-        return decode_attention(q, kt, vt, lens, scale=scale,
-                                interpret=interpret)
     t = k.shape[1]
     # identical formulation (einsum strings, mask value, softmax dtype
-    # path) to models/decode.py _block at t=1 — parity is structural
+    # path) to PerHeadCache.dense_layer at t=1 — parity is structural
     q5 = q.reshape(b, 1, g, rep, dh)
     logits = jnp.einsum("bqgrd,bkgd->bgrqk", q5,
                         k.astype(q.dtype)) * scale
@@ -623,8 +510,7 @@ def paged_window_attention(q, k_pages, v_pages, page_tables, kv_lens,
     ``k_scales``/``v_scales`` [n_pages, page_size, g] (or [L, ...])
     switch the pools to the INT8 two-tier layout (:func:`quantize_kv`
     rows): the gather path dequantizes the gathered view then runs the
-    same exact einsum (the dequant analogue of the existing kernel-gate
-    fallback), and the kernel path runs :func:`_paged_window_kernel`
+    same exact einsum, and the kernel path runs :func:`_paged_window_kernel`
     with ``quant``, which fuses the per-row rescale into the
     online-softmax page walk — int8 K/V never round-trips through HBM
     at float width."""

@@ -343,11 +343,8 @@ class DecodeEngine:
                  kv_quant: Optional[str] = None,
                  kv_spill_pages: int = 0):
         pos_rows = decoder.max_positions
-        block = getattr(decoder, "block", None)     # models/block.py
-        if block is not None and draft is not None and spec_k:
-            raise ValueError(
-                "speculative decoding (draft / spec_k) is not supported "
-                "on a latent (MLA) block")
+        if draft is not None and spec_k:
+            decoder.require("speculation")
         if max_seq_len is None:
             max_seq_len = pos_rows
         self.max_seq_len = min(int(max_seq_len), pos_rows)
@@ -386,8 +383,6 @@ class DecodeEngine:
         # one pool and an empty pytree) is only ever handed back to its
         # step and its page copy / read / write
         self.k_pool, self.v_pool = self.paged.init_pools()
-        self._expert_layers = block.n_expert_layers(decoder.n_layers) \
-            if block is not None else 0
         self.prefix: Optional[PrefixIndex] = (
             PrefixIndex(self.pool, self.page_size) if prefix_cache
             else None)
@@ -1157,7 +1152,7 @@ class DecodeEngine:
                         c = self._counters
                         c["expert_assignments_held"] += int(load[0])
                         c["expert_hits_held"] += int(load[1])
-                        c["expert_layer_steps"] += self._expert_layers
+                        c["expert_layer_steps"] += self.paged.n_expert_layers
             return True
 
     def _plan_windows(self):

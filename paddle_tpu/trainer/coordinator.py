@@ -433,14 +433,14 @@ class Coordinator:
             self._requeue_timed_out()
             if worker_id is not None and worker_id in self._workers:
                 self._workers[worker_id]["deadline"] = \
-                    time.time() + self.worker_lease_s
+                    self.time() + self.worker_lease_s
             if epoch is not None and self._epoch != epoch:
                 return None
             if not self._todo:
                 return None
             task = self._todo.pop(0)
             self._pending[task.task_id] = {
-                "task": task, "deadline": time.time() + self.timeout_s,
+                "task": task, "deadline": self.time() + self.timeout_s,
                 "worker_id": worker_id, "generation": self._generation}
             grant = {"task_id": task.task_id, "chunks": task.chunks,
                      "generation": self._generation,
@@ -525,13 +525,13 @@ class Coordinator:
             ent = self._pending.get(task_id)
             if ent is None:
                 return False
-            if ent["deadline"] <= time.time():
+            if ent["deadline"] <= self.time():
                 # the lease already lapsed — the task belongs to the
                 # queue again (a late heartbeat must not resurrect it
                 # after another trainer may have been promised it)
                 self._requeue_timed_out()
                 return False
-            ent["deadline"] = time.time() + self.timeout_s
+            ent["deadline"] = self.time() + self.timeout_s
             return True
 
     def task_failed(self, task_id: int,
@@ -560,7 +560,7 @@ class Coordinator:
             return True
 
     def _requeue_timed_out(self):
-        now = time.time()
+        now = self.time()
         mutated = False
         for tid in list(self._pending):
             if self._pending[tid]["deadline"] <= now:
@@ -609,8 +609,8 @@ class Coordinator:
             rejoin = worker_id in self._workers
             self._workers[worker_id] = {
                 "info": dict(info or {}),
-                "joined_at": time.time(),
-                "deadline": time.time() + self.worker_lease_s,
+                "joined_at": self.time(),
+                "deadline": self.time() + self.worker_lease_s,
             }
             if not rejoin:
                 self._reshard_locked("join", worker_id=worker_id)
@@ -650,7 +650,7 @@ class Coordinator:
             w = self._workers.get(worker_id)
             if w is None:
                 return -1
-            w["deadline"] = time.time() + self.worker_lease_s
+            w["deadline"] = self.time() + self.worker_lease_s
             return self._generation
 
     def _release_worker_tasks_locked(self, worker_id: str,
@@ -683,7 +683,7 @@ class Coordinator:
         expiries is a fleet event, not one sick host: the flight
         recorder dumps a postmortem bundle on a storm (>= 2 within
         10s)."""
-        now = time.time()
+        now = self.time()
         expired = [w for w, ent in self._workers.items()
                    if ent["deadline"] <= now]
         if not expired:
@@ -800,7 +800,9 @@ class Coordinator:
         """The coordinator's wall clock (unix seconds) — the reference
         clock every worker measures its offset against (sync_clock) so
         merged multi-host timelines share a time base
-        (tools/trace_merge.py; docs/observability.md)."""
+        (tools/trace_merge.py; docs/observability.md), and the one clock
+        every lease and task deadline here is set and swept by (a test
+        that moves it moves them all)."""
         return time.time()
 
     # ------------------------------------------------- read-only status

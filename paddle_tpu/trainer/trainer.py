@@ -107,8 +107,8 @@ class SGD:
         # a loaded table can carry a bias for a layer this topology builds
         # bias-FREE (e.g. a pre-round-4 transformer_lm head). Training
         # would silently ignore it while raw-table consumers
-        # (TransformerDecoder._logits) still apply it — numerics diverge
-        # with no error. Surface it. (Params for layers absent from the
+        # (models/block.py DefaultBlock.logits) still apply it — numerics
+        # diverge with no error. Surface it. (Params for layers absent from the
         # topology entirely stay silent: that's the normal transfer-
         # learning shape, e.g. an MLM head alongside a classifier.)
         stale_bias = [

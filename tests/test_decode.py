@@ -392,39 +392,14 @@ class TestGroupedQueryAttention:
                 [prefix, want[:, None].astype("int32")], axis=1)
 
 
-class TestPallasDecodeAttention:
-    """ops/pallas_decode kernel vs the einsum reference, incl. GQA."""
-
-    @pytest.mark.parametrize("h,g", [(8, 8), (8, 2)])
-    def test_matches_einsum(self, h, g):
-        import jax.numpy as jnp
-        from paddle_tpu.ops.pallas_decode import decode_attention
-        rng = np.random.RandomState(0)
-        b, dh, T, kv_len = 4, 16, 64, 37
-        q = jnp.asarray(rng.randn(b, h, dh).astype(np.float32))
-        kc = jnp.asarray(rng.randn(b, g, dh, T).astype(np.float32))
-        vc = jnp.asarray(rng.randn(b, g, dh, T).astype(np.float32))
-
-        got = decode_attention(q, kc, vc, kv_len, interpret=True)
-
-        rep = h // g
-        q5 = q.reshape(b, g, rep, dh)
-        logits = jnp.einsum("bgrd,bgdk->bgrk", q5, kc) * dh ** -0.5
-        mask = jnp.arange(T) < kv_len
-        logits = jnp.where(mask[None, None, None], logits, -1e30)
-        w = jax.nn.softmax(logits, axis=-1)
-        want = jnp.einsum("bgrk,bgdk->bgrd", w, vc).reshape(b, h, dh)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-4, atol=2e-5)
-
-
 class TestFlashPrefill:
     """Long-prompt prefill through the flash kernel must match the
-    quadratic einsum path (models/decode.py _use_flash_prefill gate)."""
+    quadratic einsum path (models/block.py use_flash_prefill gate)."""
 
     def test_prefill_logits_match_einsum(self, monkeypatch):
         import jax.numpy as jnp
         from paddle_tpu import models
+        from paddle_tpu.models import block
         paddle.init(seed=0)
         plen, max_len, d, L = 256, 272, 64, 2
         spec = models.transformer_lm(vocab_size=97, d_model=d, n_heads=4,
@@ -439,11 +414,9 @@ class TestFlashPrefill:
         lg_e, _ = dec._prefill(dec.p, prompt, plen, max_len)
 
         # force the flash gate on (CPU runs the kernel in interpret mode)
-        monkeypatch.setattr(models.TransformerDecoder,
-                            "_use_flash_prefill",
-                            staticmethod(lambda t, pos, dh:
-                                         isinstance(pos, int) and pos == 0
-                                         and t > 1))
+        monkeypatch.setattr(block, "use_flash_prefill",
+                            lambda t, pos, dh:
+                            isinstance(pos, int) and pos == 0 and t > 1)
         lg_f, _ = dec._prefill(dec.p, prompt, plen, max_len)
         np.testing.assert_allclose(np.asarray(lg_f), np.asarray(lg_e),
                                    rtol=2e-4, atol=2e-4)
@@ -451,6 +424,7 @@ class TestFlashPrefill:
     def test_gqa_prefill_logits_match_einsum(self, monkeypatch):
         import jax.numpy as jnp
         from paddle_tpu import models
+        from paddle_tpu.models import block
         paddle.init(seed=0)
         plen, max_len, d, L = 256, 272, 64, 1
         spec = models.transformer_lm(vocab_size=61, d_model=d, n_heads=4,
@@ -462,11 +436,9 @@ class TestFlashPrefill:
             0, 61, (2, plen)).astype("int32"))
         dec = models.TransformerDecoder(params, n_layers=L, n_heads=4)
         lg_e, _ = dec._prefill(dec.p, prompt, plen, max_len)
-        monkeypatch.setattr(models.TransformerDecoder,
-                            "_use_flash_prefill",
-                            staticmethod(lambda t, pos, dh:
-                                         isinstance(pos, int) and pos == 0
-                                         and t > 1))
+        monkeypatch.setattr(block, "use_flash_prefill",
+                            lambda t, pos, dh:
+                            isinstance(pos, int) and pos == 0 and t > 1)
         lg_f, _ = dec._prefill(dec.p, prompt, plen, max_len)
         np.testing.assert_allclose(np.asarray(lg_f), np.asarray(lg_e),
                                    rtol=2e-4, atol=2e-4)

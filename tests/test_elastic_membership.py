@@ -204,10 +204,17 @@ class TestExactlyOnceChaos:
     worker's completion is ever refused."""
 
     def test_kill_and_join_mid_pass_exactly_once(self):
+        # Leases no scheduler pause can outrun (10 s, 60 s a task), on the
+        # coordinator's own clock, which here only the test moves: the
+        # victim's lease lapses because the clock passes it, never because
+        # a live thread was descheduled for half a second.
+        now = [1000.0]
         coord = Coordinator(list(range(6)), chunks_per_task=1,
-                            timeout_s=0.5, failure_max=10,
-                            worker_lease_s=0.5)
+                            timeout_s=60.0, failure_max=10,
+                            worker_lease_s=10.0)
+        coord.time = lambda: now[0]
         accepted = collections.Counter()
+        polls = collections.Counter()       # get_task calls, by worker
         lock = threading.Lock()
         deadline = time.time() + 30.0
 
@@ -216,6 +223,7 @@ class TestExactlyOnceChaos:
             my_grants = 0
             while time.time() < deadline:
                 t = coord.get_task(0, wid)
+                polls[wid] += 1
                 if t is None:
                     if coord.epoch != 0:
                         break
@@ -251,7 +259,18 @@ class TestExactlyOnceChaos:
             ]
             for th in threads:
                 th.start()
-            for th in threads + joiners:
+            threads[0].join(35.0)   # the victim is gone, lease and task held
+            # 12 s in two moves: past the victim's last renewal by more
+            # than a lease, and never 10 s past a live worker's, each of
+            # which has renewed (a whole get_task) after the first move
+            now[0] += 6.0
+            seen = {w: polls[w] for w in coord.workers() if w != "w1"}
+            while time.time() < deadline and any(
+                    polls[w] < n + 2 for w, n in seen.items()):
+                time.sleep(0.01)
+            now[0] += 6.0
+            threads[1].join(35.0)
+            for th in joiners:
                 th.join(35.0)
         assert st["fired"] == [3]           # the join landed on schedule
         assert coord.epoch == 1, "pass never completed under churn"

@@ -193,45 +193,6 @@ class TestPagedAttentionUnit:
         want = self._reference(q, k, v, lens)
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
-    def test_kernel_composition_matches_einsum_path(self):
-        """use_kernel=True gathers the same pages and runs the GQA
-        decode kernel — same numbers (interpret mode on CPU)."""
-        from paddle_tpu.ops.pallas_decode import paged_attention
-        rng = np.random.RandomState(1)
-        b, h, g, dh, ps, npages, P = 2, 4, 2, 8, 4, 8, 4
-        k_pages = _stored(rng.randn(npages, ps, g, dh).astype(np.float32))
-        v_pages = _stored(rng.randn(npages, ps, g, dh).astype(np.float32))
-        q = jax.numpy.asarray(rng.randn(b, h, dh).astype(np.float32))
-        table = jax.numpy.asarray(
-            np.array([[1, 4, 2, 0], [3, 5, 0, 0]], np.int32))
-        lens = jax.numpy.asarray(np.array([10, 7], np.int32))
-        ein = paged_attention(q, k_pages, v_pages, table, lens)
-        ker = paged_attention(q, k_pages, v_pages, table, lens,
-                              use_kernel=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(ker), np.asarray(ein),
-                                   rtol=2e-4, atol=2e-5)
-
-    def test_decode_attention_per_row_lens(self):
-        """The dense-layout kernel now takes per-row kv lengths: each
-        row must mask at ITS length (scalar path unchanged)."""
-        from paddle_tpu.ops.pallas_decode import decode_attention
-        rng = np.random.RandomState(2)
-        b, h, g, dh, T = 3, 4, 2, 8, 16
-        q = jax.numpy.asarray(rng.randn(b, h, dh).astype(np.float32))
-        kc = jax.numpy.asarray(
-            rng.randn(b, g, dh, T).astype(np.float32))
-        vc = jax.numpy.asarray(
-            rng.randn(b, g, dh, T).astype(np.float32))
-        lens = np.array([5, 16, 11], np.int32)
-        got = np.asarray(decode_attention(
-            q, kc, vc, jax.numpy.asarray(lens), interpret=True))
-        for i, ln in enumerate(lens):
-            one = np.asarray(decode_attention(
-                q[i:i + 1], kc[i:i + 1], vc[i:i + 1], int(ln),
-                interpret=True))
-            np.testing.assert_allclose(got[i:i + 1], one,
-                                       rtol=2e-5, atol=2e-6)
-
 
 class TestPagedWindowKernel:
     """The live-pages kernel (ops/pallas_decode.py
@@ -554,7 +515,8 @@ class TestStoredPoolLayout:
     @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["fp", "int8"])
     def test_pool_shapes_and_bytes(self, kv_quant):
         paged, (k_pool, v_pool) = self._paged(kv_quant)
-        L, g, dh = CFG["n_layers"], paged.kv_heads, paged.head_dim
+        L, g, dh = (CFG["n_layers"], paged.cache.kv_heads,
+                    paged.cache.head_dim)
         values = k_pool["q"] if kv_quant else k_pool
         assert values.shape == (L, 6, 4, g * dh)
         if kv_quant:
@@ -562,7 +524,7 @@ class TestStoredPoolLayout:
         assert paged.pool_bytes() == sum(
             leaf.nbytes for leaf in jax.tree_util.tree_leaves(
                 (k_pool, v_pool)))
-        assert "g*dh" in paged.POOL_LAYOUT
+        assert "g*dh" in paged.cache.LAYOUT
 
     @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["fp", "int8"])
     def test_read_write_page_round_trip(self, kv_quant):
@@ -571,7 +533,8 @@ class TestStoredPoolLayout:
         values, [L, 1, ps, g] scales), and a page written elsewhere
         lands there and nowhere else."""
         paged, (k_pool, v_pool) = self._paged(kv_quant)
-        L, g, dh = CFG["n_layers"], paged.kv_heads, paged.head_dim
+        L, g, dh = (CFG["n_layers"], paged.cache.kv_heads,
+                    paged.cache.head_dim)
         before = jax.tree_util.tree_map(np.asarray, (k_pool, v_pool))
         k_page, v_page = paged.read_page(k_pool, v_pool, 3)
         values = k_page["q"] if kv_quant else k_page
@@ -618,7 +581,8 @@ class TestStoredPoolLayout:
         what was spilled."""
         from paddle_tpu.serving.spill import SpillEntry
         paged, (k_pool, v_pool) = self._paged(kv_quant)
-        L, g, dh = CFG["n_layers"], paged.kv_heads, paged.head_dim
+        L, g, dh = (CFG["n_layers"], paged.cache.kv_heads,
+                    paged.cache.head_dim)
         payload = {}
         k_page, v_page = paged.read_page(k_pool, v_pool, 2)
         DecodeEngine._flatten_page("k", k_page, payload)
@@ -641,7 +605,7 @@ class TestStoredPoolLayout:
     def test_fingerprints_name_the_layout(self, monkeypatch):
         """An executable stored for another pool layout can never be
         resolved for this one: the layout is in every plan."""
-        from paddle_tpu.models.decode import PagedDecoder
+        from paddle_tpu.models.block import PerHeadCache
 
         def fingerprints():
             paged, _ = self._paged(None)
@@ -649,7 +613,7 @@ class TestStoredPoolLayout:
                     paged._write_fp)
 
         now = fingerprints()
-        monkeypatch.setattr(PagedDecoder, "POOL_LAYOUT", "L,N,page,g,dh")
+        monkeypatch.setattr(PerHeadCache, "LAYOUT", "L,N,page,g,dh")
         for a, b in zip(now, fingerprints()):
             assert a != b
 
