@@ -736,10 +736,13 @@ def bench_smoke(train_steps: int = 12, serve_requests: int = 16,
         # prompts run twice through one engine — the first pass
         # prefills and indexes its pages in the radix trie, the second
         # attaches the cached pages and feeds only the final token, so
-        # both the step count (deterministic) and the wall time
-        # collapse. ``warm_step_ratio`` and ``hit_pages`` are the
-        # gated metrics: prefix reuse silently breaking drives the
-        # ratio to ~1 and the hits to 0.
+        # the prompt tokens teacher-forced (deterministic) collapse.
+        # ``warm_prefill_ratio`` and ``hit_pages`` are the gated
+        # metrics: prefix reuse silently breaking drives the ratio to
+        # ~1 and the hits to 0. (Until the prefill lanes it was the
+        # ratio of STEPS, 13: a cold 13-token prompt is one lane step
+        # now, as a warm one's last token is, so steps no longer tell
+        # the two apart.)
         from paddle_tpu.serving import DecodeEngine
         dec = _smoke_decoder()
         eng = DecodeEngine(dec, num_slots=2, page_size=4,
@@ -764,12 +767,13 @@ def bench_smoke(train_steps: int = 12, serve_requests: int = 16,
             r.get(timeout=1)                    # surface failures
         steps_cold = st1["steps"] - st0["steps"]
         steps_warm = st2["steps"] - st1["steps"]
+        fed_cold = st1["prefill_tokens"] - st0["prefill_tokens"]
+        fed_warm = st2["prefill_tokens"] - st1["prefill_tokens"]
         out["decode_prefix_hit"] = {
             "ttft_cold_ms": round(dt_cold / len(hit_prompts) * 1e3, 3),
             "ttft_warm_ms": round(dt_warm / len(hit_prompts) * 1e3, 3),
             "steps_cold": steps_cold, "steps_warm": steps_warm,
-            "warm_step_ratio": round(steps_cold / max(steps_warm, 1),
-                                     2),
+            "warm_prefill_ratio": round(fed_cold / max(fed_warm, 1), 2),
             "hit_pages": sum(r.prefix_hit_pages for r in warm),
         }
     if "decode_speculative" in rows:
@@ -1257,10 +1261,17 @@ def bench_smoke(train_steps: int = 12, serve_requests: int = 16,
                          queue_poll=0.02, drain_timeout=5.0).start()
         try:
             # compile + warm — the same request shape as the burst,
-            # twice: the first caches its prefix pages, the second's
-            # prefix hit resolves the CoW copy_page executable, so
-            # nothing is left to compile during the rollout
-            drouter.generate([1, 2, 3], 4)
+            # twice ON EACH REPLICA'S OWN ENGINE: the first runs the
+            # step's two programs (the prompt's lane step, then plain
+            # ones) and caches its prefix pages, the second's prefix hit
+            # resolves the CoW copy_page executable, so nothing is left
+            # to compile during the rollout. (Through the router the
+            # second request went to whichever replica the last scrape
+            # favoured, and the copy was warmed or not by timing.)
+            for rep in dreps.values():
+                for _ in range(2):
+                    rep["server"].engine.submit([1, 2, 3], 4).get(
+                        timeout=120)
             drouter.generate([1, 2, 3], 4)
             dl = time.monotonic() + 5
             while time.monotonic() < dl and any(
@@ -1481,7 +1492,7 @@ def bench_smoke(train_steps: int = 12, serve_requests: int = 16,
         # revisit_from past the last wave: the storm only spills, so
         # the store still holds the early prompts' pages afterwards
         schedule, submitted = plan.spill_storm(
-            eng, waves=4, per_wave=2, gap=4, prompt_len=8, max_new=3,
+            eng, waves=4, per_wave=2, gap=2, prompt_len=8, max_new=3,
             vocab=40, revisit_from=4)
         with _FPk.decode_script(eng, schedule):
             eng.run(timeout=300)

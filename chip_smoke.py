@@ -196,7 +196,7 @@ def serve_phase(cfg, trainer, on_chip: bool):
     assert paged.kernel_interpret is False, "interpreted kernel on the path"
 
     t0 = time.perf_counter()
-    eng.warmup()
+    eng.warmup()                # both step programs
     jax.block_until_ready((eng.k_pool, eng.v_pool))
     compile_s = time.perf_counter() - t0
     kernel_in_hlo = "tpu_custom_call" in paged._step_exe.as_text()

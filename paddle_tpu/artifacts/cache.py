@@ -31,6 +31,7 @@ serialization-incapable backend).
 from __future__ import annotations
 
 import os
+import threading
 from contextlib import contextmanager
 from typing import Optional
 
@@ -41,6 +42,8 @@ ENV_VAR = "PADDLE_TPU_COMPILE_CACHE"
 
 #: JAX's own variable: set, it places the cache and nothing here does
 JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+_disabled_lock = threading.RLock()
 
 #: values of the env var / --compile_cache flag that mean "off"
 _OFF = ("0", "off", "none", "")
@@ -105,11 +108,19 @@ def disabled():
     """Scoped compile-cache OFF (reads AND writes): inside, every
     executable is freshly compiled. The OOM chaos suite races the
     allocator against compilation and must never be handed a
-    deserialized executable instead."""
+    deserialized executable instead, and what goes into the artifact
+    store is compiled past the cache (artifacts/runtime.py). JAX asks
+    its flag once and remembers the answer, so the scope forgets that
+    answer on its way in and on its way out; one scope at a time (a
+    thread may nest its own)."""
     import jax
-    prev = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", prev)
+    from jax.experimental.compilation_cache import compilation_cache
+    with _disabled_lock:
+        prev = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield
+        finally:
+            jax.config.update("jax_enable_compilation_cache", prev)
+            compilation_cache.reset_cache()

@@ -24,6 +24,7 @@ defect — the cold path always works.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -33,7 +34,7 @@ from paddle_tpu.analysis.lockdep import named_lock
 from paddle_tpu.obs.events import emit as journal_emit
 from paddle_tpu.utils.logging import get_logger
 
-from paddle_tpu.artifacts import aot
+from paddle_tpu.artifacts import aot, cache
 from paddle_tpu.artifacts.fingerprint import Fingerprint
 from paddle_tpu.artifacts.store import ArtifactStore
 
@@ -157,10 +158,16 @@ def resolve(fp: Fingerprint, jitted, args, *,
                              digest=fp.digest, source="store")
                 EXECUTABLES.put(fp, exe)
                 return exe
-    # cold: compile eagerly so both layers can be backfilled
+    # cold: compile eagerly so both layers can be backfilled. What goes
+    # into a store is compiled here and now, past the persistent compile
+    # cache: this jaxlib's CPU executable that came out of that cache
+    # serializes without its object code (the artifact loads, then
+    # fails its first dispatch with "Function ... not found")
     t0 = time.monotonic()
     try:
-        exe = aot.compile_aot(jitted, *args)
+        with (cache.disabled() if store is not None
+              else contextlib.nullcontext()):
+            exe = aot.compile_aot(jitted, *args)
     except Exception:  # noqa: BLE001 — lower/compile quirk: plain JIT
         get_logger().warning(
             "artifact %s: eager lower+compile failed; serving via "

@@ -517,7 +517,7 @@ def _cmd_artifacts(args) -> int:
         raise SystemExit("artifacts build needs --decode_config")
     configure(store.root)
     engine = _build_engine(args)
-    stats = engine.warmup()
+    stats = engine.warmup()     # both step programs' artifacts
     rows = store.entries()
     print(json.dumps({"job": "artifacts", "action": "build",
                       "dir": store.root, "executables": stats,

@@ -31,8 +31,34 @@ A cache kind:
          num_pages=, max_pages_per_slot=, kv_quant=)    the paged pools:
       .dtype, .plan (the artifact fingerprints' facts), .init_pools(),
       .kernel_supported(), .page_payload(page) (the spill codec's shape),
-      .layer(p, i, x, k_pool, v_pool, tok, use_kernel=, interpret=)
+      .lanes() -> (lanes, tokens a lane): the prefill group its paged read
+                                   takes beside the slots; (0, 0) is none
+      .layer(p, i, x, k_pool, v_pool, toks, use_kernel=, interpret=)
                                    -> (x, k_pool, v_pool, held load or None)
+      .layer_operand               True where ``layer`` also takes ``at=``,
+                                   the pool's layer index as an OPERAND (a
+                                   traced number) beside the ``i`` that names
+                                   the parameters: :func:`shared_layers`
+
+The paged step feeds its rows in GROUPS (``toks``, a tuple of
+:class:`PagedTokens`): the [S, W] slot windows and, in the lane program,
+[Sp, C] prefill lanes, each lane a chunk of one slot's prompt read and
+written through that slot's page-table row. ``x`` holds the rows of all
+groups: one group's own [S, W, d], or every group's rows side by side as
+[1, R, d] (:func:`join_rows`, :func:`split_rows`). A ``layer`` runs what
+reads weights ONCE over ``x``, scatters the rows of every group into the
+pool, and only then reads the cache, once a group with that group's page
+tables and lengths: a lane's rows are causal among themselves, over other
+lanes of its slot at earlier positions and over what the slot cached
+before, because every row is written before any is read and masks by its
+own length.
+
+Layer ``i`` of a description is a function of its OWN parameters, named
+``{pre}l{i}_...``, and of the table's shared ones (it may read widths off
+layer 0's shapes): layers whose own parameters have equal names, shapes
+and dtypes compute the same function.
+The lane program traces and lowers one layer for all of such a kind
+(:func:`shared_layers`); the plain program, held to its text, unrolls them.
 
 :class:`DefaultBlock` is the block the ``layer`` DSL trains
 (models/transformer.py keeps its own copy of the names): pre-LayerNorm,
@@ -74,6 +100,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import re
 from typing import NamedTuple, Optional
 
 import jax
@@ -120,7 +147,8 @@ def use_flash_prefill(t, pos, dh) -> bool:
 
 
 class PagedTokens(NamedTuple):
-    """What PagedDecoder's step knows of its [S, W] window tokens."""
+    """What PagedDecoder's step knows of one group of its tokens: the
+    [S, W] slot windows, or the [Sp, C] prefill lanes."""
     positions: jax.Array
     active: jax.Array        # bool: a masked token writes to the null page
     page_idx: jax.Array      # the physical page each token's row goes to
@@ -129,6 +157,73 @@ class PagedTokens(NamedTuple):
     kv_lens: jax.Array       # positions + 1
     live_lens: jax.Array     # 0 where not active: such a token attends to
     #                          nothing, an all-masked slot copies no page
+
+
+def join_rows(parts):
+    """Every group's [B_g, t_g, ...] rows side by side as [1, R, ...]; a
+    single group stays as it is."""
+    if len(parts) == 1:
+        return parts[0]
+    return jnp.concatenate(
+        [a.reshape((1, -1) + a.shape[2:]) for a in parts], axis=1)
+
+
+def split_rows(toks, a):
+    """:func:`join_rows` undone: a [1, R, ...] -> each group's
+    [B_g, t_g, ...]."""
+    if len(toks) == 1:
+        return [a]
+    parts, at = [], 0
+    for t in toks:
+        n = t.positions.size
+        parts.append(a[0, at:at + n].reshape(t.positions.shape + a.shape[2:]))
+        at += n
+    return parts
+
+
+def _rows(toks, field):
+    """``field`` of every group's tokens, in x's row layout."""
+    return join_rows([getattr(t, field) for t in toks])
+
+
+def _lane_width(takes, width: int) -> int:
+    """The widest window, ``width`` halved as often as it must be, that
+    the kernel's gate ``takes``."""
+    while width > 1 and not takes(width):
+        width //= 2
+    return width
+
+
+def shared_layers(layer, pre: str):
+    """``layer(p, i, x, k_pool, v_pool, toks)`` for a program that traces
+    and lowers a layer once a KIND of layer, not once a layer (a second
+    step program is seconds of set-up, most of it the Python that builds
+    24 copies of one layer): layers whose own parameters are alike run ONE
+    jitted function, each on its own parameters under the first such
+    layer's names, with the pool's layer index an operand (``at``). XLA
+    inlines the calls, so the executable is the unrolled layers'. A layer
+    with no parameter of its own under ``{pre}l{i}_`` runs as it is."""
+    one = jax.jit(lambda lp, i0, at, x, k_pool, v_pool, toks:
+                  layer(lp, i0, x, k_pool, v_pool, toks, at=at),
+                  static_argnums=1)
+    of_a_layer = re.compile(re.escape(pre) + r"l\d+_")
+    first: dict = {}
+
+    def run(p, i, x, k_pool, v_pool, toks):
+        own = f"{pre}l{i}_"
+        mine = {n[len(own):]: v for n, v in p.items() if n.startswith(own)}
+        if not mine:
+            return layer(p, i, x, k_pool, v_pool, toks)
+        i0 = first.setdefault(tuple(sorted(
+            (n, v.shape, str(v.dtype)) for n, v in mine.items())), i)
+        # the table's shared parameters, layer 0's (a description reads
+        # its widths off them), and this layer's under the first's names
+        lp = {n: v for n, v in p.items()
+              if not of_a_layer.match(n) or n.startswith(f"{pre}l0_")}
+        lp.update({f"{pre}l{i0}_{n}": v for n, v in mine.items()})
+        return one(lp, i0, np.int32(i), x, k_pool, v_pool, toks)
+
+    return run
 
 
 # ------------------------------------------------------------ cache kinds
@@ -153,6 +248,19 @@ class PerHeadCache:
     #: executable built for another layout can never be resolved
     LAYOUT = "L,N,page,g*dh"
     refuses: dict = {}
+    #: the scatter's and the gather's index and the kernel's operand
+    layer_operand = True
+    #: prompt tokens a step takes through its lanes beside the slots: ONE
+    #: deployment's reading, not derived from the decoder's widths or its
+    #: slot count (PERF.md section 7). Beside OPT-1.3B's 32 slots at 340
+    #: cached tokens a lane step cost 1.0 / 1.8 / 2.4 / 4.3 ms more than a
+    #: plain one at 32 / 64 / 96 / 128 lane tokens (v5e, PERF.md section
+    #: 6, PR 38: past 128 rows in all XLA computes the products that read
+    #: the weights as copies into VMEM plus convolutions, which overlap
+    #: the read worse), and the steps that carry a lane are the steps that
+    #: carry an admission, which set the 95th-percentile gap. 64 is one
+    #: step for a turn's suffix and five for the longest chat prompt
+    LANE_TOKENS = 64
 
     @staticmethod
     def dense_init(block, p, pre, b, max_len):
@@ -217,8 +325,11 @@ class PerHeadCache:
         self.plan = {"pool_layout": self.LAYOUT, "kv_heads": self.kv_heads,
                      "head_dim": self.head_dim}
 
-    def kernel_supported(self) -> bool:
+    def kernel_supported(self, group=None) -> bool:
+        """Does the kernel take the slot group's [S, W] queries (or
+        ``group``'s, another (rows, window))?"""
         (S, W, P), (_, N, ps) = self._query, self.rows
+        S, W = group or (S, W)
         g, dh, int8 = self.kv_heads, self.head_dim, self.kv_quant == "int8"
         return paged_ops.paged_kernel_supported(
             jax.ShapeDtypeStruct((S, W, self.n_heads, dh), self.dtype),
@@ -226,6 +337,20 @@ class PerHeadCache:
                                  jnp.int8 if int8 else self.dtype),
             jax.ShapeDtypeStruct((N, ps, g), jnp.float32) if int8 else None,
             pages_per_slot=P)
+
+    def lanes(self) -> tuple:
+        """(lanes, tokens a lane). A lane is as wide as fills the
+        kernel's q block, one MXU tile of query rows a lane chunk (64
+        tokens at two heads of 64 a chunk; the one-token step pays for
+        that tile with two rows), narrower where the gate says so; the
+        lanes together take ``LANE_TOKENS`` tokens a step."""
+        n = self.LANE_TOKENS
+        width = min(n, paged_ops.window_tile_tokens(
+            self.n_heads, self.kv_heads, self.head_dim))
+        if self.kernel_supported():
+            width = _lane_width(
+                lambda w: self.kernel_supported((n // w, w)), width)
+        return n // width, width
 
     def init_pools(self):
         """Zeroed (k_pool, v_pool) in the stored layout."""
@@ -251,30 +376,34 @@ class PerHeadCache:
             return {"q": heads(page["q"]), "s": page["s"]}
         return heads(page)
 
-    def layer(self, p, i, x, k_pool, v_pool, tok, *, use_kernel,
-              interpret):
+    def layer(self, p, i, x, k_pool, v_pool, toks, *, use_kernel,
+              interpret, at=None):
+        """``at``: the pools' layer index where it is not the Python
+        ``i`` that names the parameters (:func:`shared_layers`)."""
         blk, pre, g = self.block, self.pre, self.kv_heads
-        S, W = x.shape[0], x.shape[1]
-        q, k, v = blk.qkv(p, pre, i, x, tok.positions, flat=True)
-        # unconditional scatter: every window token writes its K/V at
-        # (layer, page, offset) of the donated pool, in place and BEFORE
-        # attention, so later window tokens attend to earlier ones; the
-        # caller routed masked tokens to the null page
-        rows_p = tok.page_idx.reshape(-1)
-        rows_o = tok.offs.reshape(-1)
+        at = i if at is None else at
+        n = x.shape[0] * x.shape[1]
+        q, k, v = blk.qkv(p, pre, i, x, _rows(toks, "positions"), flat=True)
+        # unconditional scatter: every token of every group writes its
+        # K/V at (layer, page, offset) of the donated pool, in place and
+        # BEFORE any attention, so later tokens of a window or of a slot's
+        # lanes attend to earlier ones; the caller routed masked tokens to
+        # the null page
+        rows_p = _rows(toks, "page_idx").reshape(-1)
+        rows_o = _rows(toks, "offs").reshape(-1)
 
         def put(pool, rows):
-            return pool.at[i, rows_p, rows_o].set(rows.astype(pool.dtype))
+            return pool.at[at, rows_p, rows_o].set(rows.astype(pool.dtype))
 
         # the scopes name the regions in a device trace (PERF.md section 3)
         scales = {}
         if self.kv_quant == "int8":
             with jax.named_scope("kv_write"):
-                kq, ks = paged_ops.quantize_kv(k.reshape(S * W, g, -1))
-                vq, vs = paged_ops.quantize_kv(v.reshape(S * W, g, -1))
-                k_pool = {"q": put(k_pool["q"], kq.reshape(S * W, -1)),
+                kq, ks = paged_ops.quantize_kv(k.reshape(n, g, -1))
+                vq, vs = paged_ops.quantize_kv(v.reshape(n, g, -1))
+                k_pool = {"q": put(k_pool["q"], kq.reshape(n, -1)),
                           "s": put(k_pool["s"], ks)}
-                v_pool = {"q": put(v_pool["q"], vq.reshape(S * W, -1)),
+                v_pool = {"q": put(v_pool["q"], vq.reshape(n, -1)),
                           "s": put(v_pool["s"], vs)}
             k_pages, v_pages = k_pool["q"], v_pool["q"]
             scales = dict(k_scales=k_pool["s"], v_scales=v_pool["s"])
@@ -283,13 +412,13 @@ class PerHeadCache:
                 k_pool, v_pool = put(k_pool, k), put(v_pool, v)
             k_pages, v_pages = k_pool, v_pool
         with jax.named_scope("paged_attn"):
-            attn = paged_ops.paged_window_attention(
-                q, k_pages, v_pages, tok.page_tables, tok.live_lens,
-                layer=i, use_kernel=use_kernel, interpret=interpret,
-                **scales)
+            attn = join_rows([paged_ops.paged_window_attention(
+                q_g, k_pages, v_pages, tok.page_tables, tok.live_lens,
+                layer=at, use_kernel=use_kernel, interpret=interpret,
+                **scales) for q_g, tok in zip(split_rows(toks, q), toks)])
         x = x + blk.project(p, pre, i, attn.reshape(x.shape))
         with jax.named_scope("ffn"):
-            x, load = blk.ffn(p, pre, i, x, tok.active)
+            x, load = blk.ffn(p, pre, i, x, _rows(toks, "active"))
         return x, k_pool, v_pool, load
 
 
@@ -310,6 +439,9 @@ class LatentCache:
     empty pytree: every page program and donation maps over nothing."""
 
     LAYOUT = "L,N,page,c_kv|k_rope|0"
+    #: the latent kernel's block index maps hold the layer as a number
+    #: of the program, fixed when it is traced
+    layer_operand = False
     refuses = {
         "kv_quant": "kv_quant is not supported on a latent (MLA) cache: the "
         "int8 layout packs per-head scales, and a latent row has no heads",
@@ -317,6 +449,13 @@ class LatentCache:
         "DraftDecoder's slot-private caches are per-head K/V",
         "speculation": "speculative decoding (draft / spec_k) is not "
         "supported on a latent (MLA) block"}
+    #: prompt tokens a step takes through its lanes beside the slots: a
+    #: reckoning at Kimi-K2's widths, measured at that one deployment
+    #: alone (PERF.md section 7). The absorbed read costs a token heads x
+    #: (row + c_kv) products a cached row, two terms each (0.6 GFLOP a
+    #: token and layer at 64 heads over 2k rows): 32 tokens are 0.6 ms of
+    #: the chip's peak over six layers, 128 would be a quarter of the step
+    LANE_TOKENS = 32
 
     @staticmethod
     def dense_init(block, p, pre, b, max_len):
@@ -350,13 +489,30 @@ class LatentCache:
         rkv, dr = block.cache_widths(p, pre)
         self.row_lanes = -(-(rkv + dr) // 128) * 128
         self.shape = (n_layers, num_pages, page_size, self.row_lanes)
-        self._query = (num_slots, window * block.sizes(p, pre)["H"],
+        self._query = (num_slots, window, block.sizes(p, pre)["H"],
                        self.row_lanes, rkv, page_size, max_pages_per_slot)
         self.plan = {"pool_layout": self.LAYOUT,
                      "row_lanes": self.row_lanes}
 
-    def kernel_supported(self) -> bool:
-        return paged_ops.latent_kernel_supported(*self._query, self.dtype)
+    def kernel_supported(self, group=None) -> bool:
+        """Does the kernel take the slot group's queries (or ``group``'s,
+        another (rows, window))?"""
+        S, W, H, *rest = self._query
+        S, W = group or (S, W)
+        return paged_ops.latent_kernel_supported(S, W * H, *rest, self.dtype)
+
+    def lanes(self) -> tuple:
+        """(lanes, tokens a lane). A lane's window x heads rows, two
+        terms each, stand in the kernel's VMEM beside a step's pages and
+        the [rows, rkv] accumulator: the lane is as wide as that gate
+        takes (4 tokens at 64 heads), and ``LANE_TOKENS`` a step over as
+        many lanes. Where the gate takes nothing (toy shapes, run by
+        gather or interpreted) one lane holds them all."""
+        n = self.LANE_TOKENS
+        width = _lane_width(
+            lambda w: self.kernel_supported((n // w, w)), n) \
+            if self.kernel_supported() else n
+        return n // width, width
 
     def init_pools(self):
         return jnp.zeros(self.shape, self.dtype), {}
@@ -364,27 +520,31 @@ class LatentCache:
     def page_payload(self, page):
         return page                # a latent row has no heads to split
 
-    def layer(self, p, i, x, pool, none, tok, *, use_kernel, interpret):
-        """The token's row scattered into the donated pool in place, the
-        absorbed attention over the slot's pages, the block's own FFN."""
+    def layer(self, p, i, x, pool, none, toks, *, use_kernel, interpret):
+        """Every token's row scattered into the donated pool in place, the
+        absorbed attention of each group over its slots' pages, the
+        block's own FFN."""
         blk, pre = self.block, self.pre
-        q_nope, q_rope, c_kv, k_rope = blk.qkv(p, pre, i, x, tok.positions)
+        q_nope, q_rope, c_kv, k_rope = blk.qkv(p, pre, i, x,
+                                               _rows(toks, "positions"))
         with jax.named_scope("latent_kv_write"):
             row = jnp.concatenate([c_kv, k_rope], axis=-1)
             row = row.reshape(-1, row.shape[-1]).astype(pool.dtype)
-            pool = pool.at[i, tok.page_idx.reshape(-1),
-                           tok.offs.reshape(-1)].set(
+            pool = pool.at[i, _rows(toks, "page_idx").reshape(-1),
+                           _rows(toks, "offs").reshape(-1)].set(
                 jnp.pad(row, ((0, 0), (0, pool.shape[-1] - row.shape[-1]))))
         with jax.named_scope("latent_attn"):
-            o_lat = paged_ops.paged_latent_attention(
-                blk.absorb_q(p, pre, i, q_nope), q_rope, pool,
-                tok.page_tables, tok.kv_lens, layer=i,
+            q_lat = blk.absorb_q(p, pre, i, q_nope)
+            o_lat = join_rows([paged_ops.paged_latent_attention(
+                ql, qr, pool, tok.page_tables, tok.kv_lens, layer=i,
                 scale=blk.softmax_scale, use_kernel=use_kernel,
                 interpret=interpret)
+                for ql, qr, tok in zip(split_rows(toks, q_lat),
+                                       split_rows(toks, q_rope), toks)])
             attn = blk.expand_o(p, pre, i, o_lat)
         x = x + blk.project(p, pre, i, attn.reshape(x.shape[:2] + (-1,)))
         with jax.named_scope("ffn"):
-            x, load = blk.ffn(p, pre, i, x, tok.active)
+            x, load = blk.ffn(p, pre, i, x, _rows(toks, "active"))
         return x, pool, none, load
 
 

@@ -20,12 +20,14 @@ serves updated parameter tables without retracing.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.models.block import DefaultBlock, PagedTokens
+from paddle_tpu.models.block import (DefaultBlock, PagedTokens, join_rows,
+                                     shared_layers, split_rows)
 
 
 def _sample(logits, temperature, key):
@@ -390,7 +392,22 @@ class PagedDecoder:
     one-token step (masked columns) with zero extra compiles. In-window
     causality holds because every window token's row is scattered into
     the pool BEFORE attention and each token's kv_len masks later
-    positions. ``attention`` selects the cache-read path: "gather" (the
+    positions.
+
+    PREFILL LANES are that same property spent on prompts: beside its
+    [S, W] slot group the lane program (:meth:`_step_impl` with
+    ``lanes``) takes a second fixed-shape group of rows, ``Sp`` lanes of
+    ``C`` tokens, each lane a chunk of ONE slot's prompt at consecutive
+    positions, read and written through that slot's page-table row (a
+    slot may hold several lanes, one after the other: one longer
+    chunk). What reads weights runs once over the rows of both groups,
+    the cache kind's write and read once a group, and the head over the
+    slot group's rows and each lane's LAST row. The lanes' shape is the
+    cache kind's (``self.lanes``, the widest window its paged read
+    takes), never a caller's; the plain program is untouched by them and
+    is what every step without a prompt chunk runs.
+
+    ``attention`` selects the cache-read path: "gather" (the
     exact einsum over the full page view), "kernel" (the live-pages
     Pallas kernel — ops/pallas_decode.py), or "auto" (kernel on TPU when
     supported, gather elsewhere). ``kv_quant="int8"`` asks the cache
@@ -433,13 +450,18 @@ class PagedDecoder:
         self.use_kernel = attention == "kernel" or (
             attention == "auto" and on_tpu and self.cache.kernel_supported())
         self.kernel_interpret = self.use_kernel and not on_tpu
+        #: (lanes, tokens a lane) of the lane program's prefill group
+        self.lanes = self.cache.lanes()
         # donating the pools lets XLA update pages in place (the pools
         # ARE the device memory budget); the CPU backend has no donation
         # and would warn on every dispatch
         cpu = jax.default_backend() == "cpu"
         pools = dict(donate_argnums=() if cpu else (0, 1))
-        self._step = jax.jit(self._step_impl,
-                             donate_argnums=() if cpu else (1, 2))
+        # one step function, two programs: without and with the lane
+        # group, the second under a name of its own
+        donate = dict(donate_argnums=() if cpu else (1, 2))
+        self._step = jax.jit(self._step_impl, **donate)
+        self._lane_step = jax.jit(self._step_impl_lanes, **donate)
         self._copy = jax.jit(self._copy_page_impl, **pools)
         self._read = jax.jit(self._read_page_impl)
         self._write = jax.jit(self._write_page_impl, **pools)
@@ -453,17 +475,21 @@ class PagedDecoder:
         what = dict(self.cache.plan, block=repr(dense.block),
                     kv_quant=self.kv_quant, page_size=self.page_size,
                     num_pages=self.num_pages)
-        self._step_fp = fingerprint("paged_step", dense.p, plan=dict(
+        step_plan = dict(
             what, num_slots=self.num_slots, window=self.window,
             max_pages_per_slot=self.max_pages_per_slot,
             temperature=self.temperature, use_kernel=self.use_kernel,
-            kernel_interpret=self.kernel_interpret))
+            kernel_interpret=self.kernel_interpret)
+        self._step_fp = fingerprint("paged_step", dense.p, plan=step_plan)
+        self._lane_step_fp = fingerprint(
+            "paged_lane_step", dense.p, plan=dict(step_plan,
+                                                  lanes=self.lanes))
         page_plan = dict(what, n_layers=dense.n_layers,
                          dtype=str(jnp.dtype(self.dtype)))
         self._copy_fp, self._read_fp, self._write_fp = (
             fingerprint(f"paged_{which}", dense.p, plan=page_plan)
             for which in ("copy", "read", "write"))
-        self._step_exe = self._copy_exe = None
+        self._step_exe = self._lane_step_exe = self._copy_exe = None
         self._read_exe = self._write_exe = None
 
     def init_pools(self):
@@ -474,38 +500,80 @@ class PagedDecoder:
         return sum(x.size * x.dtype.itemsize for x in jax.tree_util
                    .tree_leaves(jax.eval_shape(self.init_pools)))
 
+    def _group(self, positions, active, page_tables) -> PagedTokens:
+        """One group's tokens as the cache kind reads them."""
+        ps = self.page_size
+        page_idx = jnp.take_along_axis(
+            page_tables, positions // ps, axis=1)
+        page_idx = jnp.where(active, page_idx, 0)       # null the dead
+        offs = jnp.where(active, positions % ps, 0)
+        kv_lens = positions + 1
+        return PagedTokens(positions, active, page_idx, offs, page_tables,
+                           kv_lens, jnp.where(active, kv_lens, 0))
+
     def _step_impl(self, p, k_pool, v_pool, tokens, positions,
-                   page_tables, active, key):
+                   page_tables, active, key, lanes=None):
         """tokens/positions/active [S, W]; page_tables [S, P] int32 ->
         (next_tokens [S, W] int32, k_pool', v_pool'), or ((next_tokens,
         held load [2]), ...) from a block with expert layers. Column w is
         the model's choice after window tokens 0..w: the teacher-forced
-        continuation AND the speculative verify verdict in one read."""
+        continuation AND the speculative verify verdict in one read.
+
+        ``lanes`` [Sp, 3 + C] int32 makes it the lane program's body
+        (:meth:`_step_impl_lanes`): a row is
+        one lane, (the slot whose page-table row it reads and writes, its
+        first position, how many of its C tokens are fed, the tokens).
+        next_tokens is then flat, [S*W + Sp]: the slot group's choices
+        row by row, then each lane's choice after its LAST fed token."""
         d0 = self.dense
-        blk, pre, ps = d0.block, d0._pre, self.page_size
+        blk, pre = d0.block, d0._pre
+        groups = [(tokens, positions, active, page_tables)]
+        if lanes is not None:
+            col = jnp.arange(lanes.shape[1] - 3)[None, :]
+            fed = col < lanes[:, 2:3]
+            groups.append((lanes[:, 3:],
+                           jnp.where(fed, lanes[:, 1:2] + col, 0), fed,
+                           page_tables[lanes[:, 0]]))
         with jax.named_scope("embed"):
-            x = blk.embed(p, pre, tokens, positions)    # [S, W, d]
-        page_idx = jnp.take_along_axis(
-            page_tables, positions // ps, axis=1)       # [S, W]
-        page_idx = jnp.where(active, page_idx, 0)       # null the dead
-        offs = jnp.where(active, positions % ps, 0)
-        kv_lens = positions + 1
-        tok = PagedTokens(positions, active, page_idx, offs, page_tables,
-                          kv_lens, jnp.where(active, kv_lens, 0))
+            x = blk.embed(p, pre, join_rows([g[0] for g in groups]),
+                          join_rows([g[1] for g in groups]))   # [S, W, d]
+        toks = tuple(self._group(*g[1:]) for g in groups)
+        layer = functools.partial(self.cache.layer,
+                                  use_kernel=self.use_kernel,
+                                  interpret=self.kernel_interpret)
+        if lanes is not None and self.cache.layer_operand:
+            # a second program is seconds of set-up: this one traces and
+            # lowers one layer for all of a kind (the plain program is
+            # held to the text it had, a layer after the other)
+            layer = shared_layers(layer, pre)
         loads = []
         for i in range(d0.n_layers):
-            x, k_pool, v_pool, load = self.cache.layer(
-                p, i, x, k_pool, v_pool, tok, use_kernel=self.use_kernel,
-                interpret=self.kernel_interpret)
+            x, k_pool, v_pool, load = layer(p, i, x, k_pool, v_pool, toks)
             if load is not None:
                 loads.append(load)
+        if lanes is not None:
+            # the head reads a lane's last fed row alone: no other row's
+            # choice is ever used
+            x, x_lanes = split_rows(toks, x)
+            last = jnp.maximum(lanes[:, 2] - 1, 0)[:, None, None]
+            x = join_rows([x, jnp.take_along_axis(x_lanes, last, axis=1)])
         with jax.named_scope("logits"):
             nxt = _sample(blk.logits(p, pre, x), self.temperature, key)
+        if lanes is not None:
+            nxt = nxt[0]
         if loads:
             # two small sums over the step's expert layers ride beside
             # the tokens: (assignments on held experts, held experts hit)
             nxt = (nxt, sum(loads))
         return nxt, k_pool, v_pool
+
+    def _step_impl_lanes(self, p, k_pool, v_pool, tokens, positions,
+                         page_tables, active, lanes, key):
+        """The lane program, under a name of its own (two programs of one
+        process are told apart by name): :meth:`_step_impl` with its
+        ``lanes``."""
+        return self._step_impl(p, k_pool, v_pool, tokens, positions,
+                               page_tables, active, key, lanes)
 
     @staticmethod
     def _page_slice(leaf, page):
@@ -568,26 +636,33 @@ class PagedDecoder:
                     jnp.int32(page))
 
     def step(self, k_pool, v_pool, tokens, positions, page_tables,
-             active, key=None):
+             active, key=None, lanes=None):
         """Dispatch one decode step: the classic [S] one-token arrays
         (returns next tokens [S]) or the [S, W] window contract (returns
-        [S, W]). Compiles exactly once for the engine's lifetime —
-        joins/evictions/window occupancy only change VALUES."""
+        [S, W]); with ``lanes`` ([Sp, 3 + C], :meth:`_step_impl`) the lane
+        program (returns the flat [S*W + Sp]). Each program compiles
+        exactly once for the engine's lifetime — joins/evictions/window
+        and lane occupancy only change VALUES."""
         if key is None:
             key = jax.random.PRNGKey(0)
         tokens = jnp.asarray(tokens, jnp.int32)
         squeeze = tokens.ndim == 1
         if squeeze:
-            assert self.window == 1, (
+            assert self.window == 1 and lanes is None, (
                 "one-token [S] arrays only drive a window=1 decoder")
             tokens = tokens[:, None]
             positions = jnp.asarray(positions, jnp.int32)[:, None]
             active = jnp.asarray(active, jnp.bool_)[:, None]
-        nxt, k_pool, v_pool = _run(
-            self, "step", self.dense.p, k_pool, v_pool, tokens,
-            jnp.asarray(positions, jnp.int32),
-            jnp.asarray(page_tables, jnp.int32),
-            jnp.asarray(active, jnp.bool_), key)
+        small = (tokens, jnp.asarray(positions, jnp.int32),
+                 jnp.asarray(page_tables, jnp.int32),
+                 jnp.asarray(active, jnp.bool_))
+        if lanes is None:
+            nxt, k_pool, v_pool = _run(
+                self, "step", self.dense.p, k_pool, v_pool, *small, key)
+        else:
+            nxt, k_pool, v_pool = _run(
+                self, "lane_step", self.dense.p, k_pool, v_pool, *small,
+                jnp.asarray(lanes, jnp.int32), key)
         if self.n_expert_layers:
             # the engine fetches it with the tokens (one sync)
             nxt, self.expert_counts = nxt

@@ -185,6 +185,18 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+# query rows that fill the MXU's tile: the kernel's q block holds
+# hp x window x rep rows a lane chunk and pays for a whole tile of them
+_Q_TILE_ROWS = 128
+
+
+def window_tile_tokens(h: int, g: int, dh: int) -> int:
+    """The window whose query rows fill one MXU tile of the kernel's q
+    block (64 tokens at two heads of 64 a lane chunk): what a prefill
+    lane is cut to (models/block.py PerHeadCache.lanes)."""
+    return max(1, _Q_TILE_ROWS // (_heads_per_chunk(g, dh) * (h // g)))
+
+
 def _walk_live_pages(tables_ref, used_ref, pools, bufs, sems, half_ref,
                      body, *, lead, page_size, pages_per_block):
     """One slot's share of the page walk: the grid is over slots, and

@@ -17,10 +17,14 @@ scheduling, PagedAttention's block-pooled KV) built for serving LLMs:
   AND the prefix trie at once; it returns to the free list only at
   refcount zero.
 - ``DecodeEngine``: a persistent decode loop over a FIXED slot batch.
-  Each iteration feeds every active slot a WINDOW of up to W tokens
-  (prompt tokens teacher-forced first — prefill interleaves with other
-  slots' decoding, no whole-batch barrier), dispatches ONE jitted step,
-  and does host-side bookkeeping: requests join free slots mid-flight,
+  Each iteration feeds every active slot a WINDOW of up to W tokens and,
+  through the step's PREFILL LANES, the oldest prompts a chunk each
+  (a prompt enters a lane's width of tokens at a time beside the
+  decoding slots, in the one dispatch that reads the weights anyway:
+  time to first token follows steps, not prompt tokens — prefill
+  interleaves with other slots' decoding, no whole-batch barrier),
+  dispatches ONE jitted step, and does host-side bookkeeping: requests
+  join free slots mid-flight,
   finished/cancelled/expired requests free their pages immediately, and
   page-pool exhaustion first reclaims cold prefix-cache pages, then
   PREEMPTS the youngest request (pages back to the pool, request
@@ -147,6 +151,11 @@ class PagePool:
         self._free_list = list(range(self.num_pages - 1, 0, -1))
         self._allocated: set = set()     # ptlint: guarded-by(serving.pagepool)
         self._refs: Dict[int, int] = {}  # ptlint: guarded-by(serving.pagepool)
+        # pages the prefix index holds a ref on, and how many of them
+        # have no other holder: kept as refcounts change, so that an
+        # admission reads it where it used to walk the whole trie
+        self._indexed: set = set()       # ptlint: guarded-by(serving.pagepool)
+        self._reclaimable = 0            # ptlint: guarded-by(serving.pagepool)
         self.high_water = 0
 
     @property
@@ -184,6 +193,35 @@ class PagePool:
                     f"page {page} ref'd but not allocated — the "
                     "refcount plumbing lost track of it")
             self._refs[page] += 1
+            if self._refs[page] == 2 and page in self._indexed:
+                self._reclaimable -= 1
+
+    def index(self, page: int) -> None:
+        """The prefix index holds one of ``page``'s refs from now on
+        (called after its :meth:`ref`)."""
+        with self._lock:
+            self._indexed.add(page)
+            self._reclaimable += self._refs[page] == 1
+
+    def unindex(self, page: int) -> None:
+        """The prefix index is about to :meth:`free` its ref."""
+        with self._lock:
+            self._indexed.discard(page)
+            self._reclaimable -= self._refs[page] == 1
+
+    @property
+    def reclaimable(self) -> int:
+        """Indexed pages whose only holder is the index (refcount 1):
+        what an eviction loop could return to the free list."""
+        with self._lock:
+            return self._reclaimable
+
+    def refcounts(self) -> Dict[int, int]:
+        """{page: refcount}, one copy under one lock: a walk over many
+        pages reads it where a :meth:`refcount` a page took the lock
+        each time."""
+        with self._lock:
+            return dict(self._refs)
 
     def refcount(self, page: int) -> int:
         with self._lock:
@@ -207,6 +245,8 @@ class PagePool:
                         "allocated — double free, refcount underflow "
                         "or foreign page id")
                 self._refs[p] -= 1
+                if self._refs[p] == 1 and p in self._indexed:
+                    self._reclaimable += 1
                 if self._refs[p] == 0:
                     del self._refs[p]
                     self._allocated.discard(p)
@@ -325,6 +365,15 @@ class DecodeEngine:
     reuse. Construction is cheap; the single XLA compile per jitted
     function happens on first use.
 
+    How a prompt is fed: a slot with more than ``window`` replay tokens
+    left (its prompt past the prefix match, or a preempted request's
+    prompt + generated tokens) takes free PREFILL LANES of the step,
+    oldest arrival first, as many as its remainder fills; the lanes'
+    shape is the cache kind's (``paged.lanes``). A slot no lane is left
+    for feeds its ``window`` tokens in the slot group as it always did,
+    so no request waits for a lane. A step that feeds a lane dispatches
+    the lane program, every other step the plain one.
+
     Drive it synchronously (``step()`` / ``run()`` — deterministic, the
     test/bench mode) or as a background thread (``start()`` /
     ``shutdown()`` — the serving mode; InferenceServer wires this)."""
@@ -414,6 +463,10 @@ class DecodeEngine:
         self._positions = np.zeros((S, W), np.int32)
         self._tables = np.zeros((S, P), np.int32)
         self._active = np.zeros((S, W), np.bool_)
+        # the lane program's own input, a row a lane: (slot,
+        # first position, tokens fed, the tokens) — PagedDecoder._step_impl
+        self._lane_shape = lanes, width = self.paged.lanes
+        self._lanes = np.zeros((lanes, 3 + width), np.int32)
         self._waiting: deque = deque()  # ptlint: guarded-by(serving.engine)
         self._cv = named_condition("serving.engine")
         self._accepting = True
@@ -432,6 +485,14 @@ class DecodeEngine:
                           "rejected_queue": 0, "rejected_capacity": 0,
                           "closed": 0, "step_failures": 0,
                           "tokens_out": 0, "prefill_tokens": 0,
+                          # every row fed, slot group and lanes; steps
+                          # dispatched with the lane program, the prompt
+                          # tokens they fed through lanes (of
+                          # prefill_tokens) and those rows' cache reads
+                          # (of cache_tokens_read)
+                          "tokens_fed": 0, "prefill_lane_steps": 0,
+                          "prefill_lane_tokens": 0,
+                          "prefill_lane_cache_tokens_read": 0,
                           "prefix_hit_pages": 0, "prefix_miss_pages": 0,
                           "prefix_cow_copies": 0,
                           "prefix_evicted_pages": 0,
@@ -1115,7 +1176,7 @@ class DecodeEngine:
                 self._reap(self._clock())
                 self._admit()
             with self._phase("serving/plan", "host_plan_ns"):
-                plan, live = self._plan_windows()
+                plan, live, lane_of = self._plan_windows()
                 key = self._key0
                 if live and self.temperature is not None:
                     import jax
@@ -1131,7 +1192,7 @@ class DecodeEngine:
                         nxt, self.k_pool, self.v_pool = self.paged.step(
                             self.k_pool, self.v_pool, self._tokens,
                             self._positions, self._tables, self._active,
-                            key)
+                            key, self._lanes if lane_of else None)
                     with self._phase("serving/sync", "host_sync_ns"):
                         # the ONE host sync per step; an expert model's
                         # two load sums come with the tokens
@@ -1146,7 +1207,7 @@ class DecodeEngine:
                 self._recover_from_step_failure(e)
                 return False
             with self._phase("serving/commit", "host_commit_ns"):
-                self._commit(plan, live, nxt)
+                self._commit(plan, live, nxt, lane_of)
                 if load is not None:
                     with self._cv:
                         c = self._counters
@@ -1156,15 +1217,16 @@ class DecodeEngine:
             return True
 
     def _plan_windows(self):
-        """The step's host plan: each active slot's window (a replay
-        chunk, or the pending token + the draft's proposals), its pages
-        ensured (which may preempt), and the step's small int32 inputs
-        filled. -> (plan, live slot indices); nothing live means no
-        dispatch."""
+        """The step's host plan: each active slot's tokens (a replay
+        chunk through prefill lanes or the slot's window, or the pending
+        token + the draft's proposals), its pages ensured (which may
+        preempt), and the step's small int32 inputs filled. -> (plan,
+        live slot indices, {lane-fed slot: its lanes}); nothing live
+        means no dispatch, no lane-fed slot the plain program."""
         active_idx = [s for s in range(self.num_slots)
                       if self.slots[s] is not None]
         if not active_idx:
-            return {}, []
+            return {}, [], {}
         props = self._draft_propose(active_idx)
         # window plan: a replay chunk (multi-token prefill) or the
         # pending token + the draft's proposals (speculative verify)
@@ -1179,30 +1241,65 @@ class DecodeEngine:
                 p_s = props.get(s, [])[:W - 1]
                 room = self.max_seq_len - 1 - slot.pos
                 plan[s] = [slot.next_input()] + p_s[:max(room, 0)]
+        # prefill lanes: a slot with more replay left than its window
+        # holds takes free lanes, oldest first, as many as its remainder
+        # fills (consecutive lanes of one slot are one longer chunk); a
+        # slot no lane is left for keeps the window planned above
+        n_lanes, width = self._lane_shape
+        lane_of: Dict[int, range] = {}
+        taken = 0
+        for s in sorted((s for s in active_idx if n_lanes and
+                         len(self.slots[s].replay) - self.slots[s].pos > W),
+                        key=lambda s: self.slots[s].arrival):
+            if taken == n_lanes:
+                break
+            slot = self.slots[s]
+            left = len(slot.replay) - slot.pos
+            lane_of[s] = range(taken, min(n_lanes, taken + -(-left // width)))
+            taken = lane_of[s].stop
+            plan[s] = slot.replay[
+                slot.pos:slot.pos + min(left, len(lane_of[s]) * width)]
         self._ensure_pages(plan)
         live = [s for s in active_idx
                 if self.slots[s] is not None and s in plan]
+        lane_of = {s: ln for s, ln in lane_of.items() if s in live}
         if not live:
-            return plan, live
+            return plan, live, lane_of
         self._active[:, :] = False
         self._tokens[:, :] = 0
         self._positions[:, :] = 0
+        self._lanes[:, :] = 0
         for s in live:
             slot = self.slots[s]
-            w = len(plan[s])
-            self._tokens[s, :w] = plan[s]
-            self._positions[s, :w] = np.arange(slot.pos, slot.pos + w)
-            self._active[s, :w] = True
-        return plan, live
+            toks = plan[s]
+            for j, lane in enumerate(lane_of.get(s, ())):
+                chunk = toks[j * width:(j + 1) * width]
+                self._lanes[lane, :3] = s, slot.pos + j * width, len(chunk)
+                self._lanes[lane, 3:3 + len(chunk)] = chunk
+            if s not in lane_of:    # a lane-fed slot idles in its group
+                w = len(toks)
+                self._tokens[s, :w] = toks
+                self._positions[s, :w] = np.arange(slot.pos, slot.pos + w)
+                self._active[s, :w] = True
+        return plan, live, lane_of
 
     def _commit(self, plan: Dict[int, List[int]], live: List[int],
-                nxt) -> None:
+                nxt, lane_of: Dict[int, range]) -> None:
         """Everything after the sync: count the step, commit each live
-        slot's tokens, finish what is done."""
+        slot's tokens, finish what is done. A lane-fed slot's chunk is
+        the replay chunk it is, whatever its length; its one choice is
+        that of its last lane."""
         t_after = self._clock()
+        if lane_of:
+            # the lane program's flat choices: the slot group's, then a
+            # lane's each
+            n = self.num_slots * self.window
+            nxt, lane_nxt = nxt[:n].reshape(self.num_slots, -1), nxt[n:]
         with self._cv:
             self._steps += 1
             self._active_steps_sum += len(live)
+            self._counters["prefill_lane_steps"] += bool(lane_of)
+            self._counters["tokens_fed"] += sum(len(plan[s]) for s in live)
         if PROFILER.enabled:
             PROFILER.on_step("decode")
         for s in live:
@@ -1219,9 +1316,10 @@ class DecodeEngine:
                           trace_id=req.trace_id,
                           engine_step=self._steps, slot=s, pos=fed,
                           width=w)
+            # row j reads the fed + j + 1 tokens cached up to itself
+            cache_read = w * fed + w * (w + 1) // 2
             with self._cv:
-                self._cache_tokens_read += sum(
-                    fed + j + 1 for j in range(w))
+                self._cache_tokens_read += cache_read
             if fed < len(slot.replay) - 1:
                 # replay chunk: all rows teacher-forced; the last row
                 # commits one token iff it reached the replay tail
@@ -1229,9 +1327,14 @@ class DecodeEngine:
                 n_prefill = min(w, len(slot.replay) - 1 - fed)
                 with self._cv:
                     self._counters["prefill_tokens"] += n_prefill
+                    if s in lane_of:
+                        c = self._counters
+                        c["prefill_lane_tokens"] += n_prefill
+                        c["prefill_lane_cache_tokens_read"] += cache_read
                 slot.pos = fed + w
                 if fed + w == len(slot.replay):
-                    commits = [int(nxt[s, w - 1])]
+                    commits = [int(lane_nxt[lane_of[s][-1]]) if s in lane_of
+                               else int(nxt[s, w - 1])]
             else:
                 # speculative verify: outs[j] is the target's choice
                 # after feeding tokens 0..j. Proposal j (toks[j+1]) is
@@ -1354,21 +1457,25 @@ class DecodeEngine:
         admitted — the warm-start plane's engine hook (docs/
         robustness.md "Warm start & artifact integrity").
 
-        Dispatches one all-inactive step through the target (and
-        draft, when speculating): inactive slots write only the
+        Dispatches one all-inactive step through each of the target's
+        two programs, the plain one and the lane program (and the draft,
+        when speculating): inactive slots and unfed lanes write only the
         reserved null page / null row, so pools are semantically
         untouched, and the dispatch shapes are exactly the serving
-        shapes — the executable resolved here IS the one every later
+        shapes — the executables resolved here ARE the ones every later
         step reuses. With a warm artifact store the whole call is
         zero-compile (deserialized executables trace nothing); cold,
-        it pays the compile up front and backfills the store, so
-        first-token latency never pays it. Returns resolver stats."""
+        it pays the compiles up front and backfills the store, so
+        first-token latency never pays them. Returns resolver stats."""
         from paddle_tpu.artifacts import EXECUTABLES
         S, W = self.num_slots, self.window
         z = np.zeros((S, W), np.int32)
         inactive = np.zeros((S, W), np.bool_)
-        _, self.k_pool, self.v_pool = self.paged.step(
-            self.k_pool, self.v_pool, z, z, self._tables, inactive)
+        self._lanes[:, :] = 0
+        for lanes in (None, self._lanes)[:1 + (self._lane_shape[0] > 0)]:
+            _, self.k_pool, self.v_pool = self.paged.step(
+                self.k_pool, self.v_pool, z, z, self._tables, inactive,
+                lanes=lanes)
         if self.draft is not None:
             _, self._draft_kc, self._draft_vc = self.draft.step(
                 self._draft_kc, self._draft_vc, z, z, inactive)
@@ -1539,6 +1646,8 @@ class DecodeEngine:
                 .itemsize) * 8,
             "page_size": self.page_size,
             "window": self.window,
+            "prefill_lanes": self._lane_shape[0],
+            "prefill_lane_width": self._lane_shape[1],
             "spec_k": self.spec_k,
             "prefix_nodes": self.prefix.page_count()
             if self.prefix is not None else 0,

@@ -116,6 +116,10 @@ _COUNTER_KEYS = {
     "admitted", "queue_wait_ns", "host_admit_ns", "host_plan_ns",
     "host_dispatch_ns", "host_sync_ns", "host_commit_ns",
     "host_idle_ns",
+    # every row fed, and the prefill lanes' share of the steps, the
+    # prompt tokens and the cache reads
+    "tokens_fed", "prefill_lane_steps", "prefill_lane_tokens",
+    "prefill_lane_cache_tokens_read",
 }
 
 

@@ -155,6 +155,7 @@ class PrefixIndex:
                 if child is None:
                     page = pages[i // ps]
                     self.pool.ref(page)
+                    self.pool.index(page)
                     child = _Node(key, page, node)
                     node.children[key] = child
                     self._nodes += 1
@@ -176,23 +177,30 @@ class PrefixIndex:
         with self._lock:
             while len(freed) < n:
                 victim = None
+                refs = self.pool.refcounts()
                 stack = [self._root]
                 while stack:
                     nd = stack.pop()
                     if nd.page is not None and not nd.children and \
-                            self.pool.refcount(nd.page) == 1:
+                            refs.get(nd.page) == 1:
                         if victim is None or \
                                 nd.last_used < victim.last_used:
                             victim = nd
                     stack.extend(nd.children.values())
                 if victim is None:
                     break
-                del victim.parent.children[victim.key]
-                self._nodes -= 1
-                self.pool.free([victim.page])
+                self._drop_locked(victim)
                 freed.append(victim.page)
             self.evicted_pages += len(freed)
         return freed
+
+    def _drop_locked(self, node: _Node) -> None:
+        """Unlink a leaf and give its page's ref back to the pool (the
+        caller holds the lock)."""
+        del node.parent.children[node.key]
+        self._nodes -= 1
+        self.pool.unindex(node.page)
+        self.pool.free([node.page])
 
     # --------------------------------------------------------------- spill
     @staticmethod
@@ -218,11 +226,12 @@ class PrefixIndex:
         ordering (read, evict+free, commit) under ITS control."""
         with self._lock:
             leaves = []
+            refs = self.pool.refcounts()
             stack = [self._root]
             while stack:
                 nd = stack.pop()
                 if nd.page is not None and not nd.children and \
-                        self.pool.refcount(nd.page) == 1:
+                        refs.get(nd.page) == 1:
                     leaves.append(nd)
                 stack.extend(nd.children.values())
             leaves.sort(key=lambda nd: nd.last_used)
@@ -247,26 +256,14 @@ class PrefixIndex:
                     return None
             if node.children or self.pool.refcount(node.page) != 1:
                 return None
-            page = node.page
-            del node.parent.children[node.key]
-            self._nodes -= 1
-            self.pool.free([page])
+            self._drop_locked(node)
             self.evicted_pages += 1
-            return page
+            return node.page
 
     def reclaimable_pages(self) -> int:
         """Pages an eviction loop could eventually return to the free
         list: trie pages no slot is also holding (refcount 1)."""
-        with self._lock:
-            count = 0
-            stack = [self._root]
-            while stack:
-                nd = stack.pop()
-                if nd.page is not None and \
-                        self.pool.refcount(nd.page) == 1:
-                    count += 1
-                stack.extend(nd.children.values())
-            return count
+        return self.pool.reclaimable
 
     # ------------------------------------------------------------ lifecycle
     def flush(self) -> int:
@@ -276,6 +273,7 @@ class PrefixIndex:
         with self._lock:
             dropped = self._collect_pages()
             for page in dropped:
+                self.pool.unindex(page)
                 self.pool.free([page])
             n = self._nodes
             self._root = _Node(None, None, None)

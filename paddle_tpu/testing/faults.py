@@ -626,13 +626,17 @@ class FaultPlan:
         return out
 
     def prefix_evict_storm(self, engine, *, waves: int = 4,
-                           per_wave: int = 2, gap: int = 3,
+                           per_wave: int = 2, gap: int = 2,
                            prompt_len: int = 8, max_new: int = 3,
                            vocab: int = 32):
         """Join ``per_wave`` requests with DISTINCT prompts every
         ``gap`` engine steps: finished requests stack their pages into
         the radix index until admission must reclaim LRU trie leaves
         (journaled ``engine/prefix_evict``) before any slot preemption.
+        A request lives ``max_new`` steps once admitted (its prompt is
+        one prefill-lane chunk, whose step also commits the first
+        token): keep ``gap`` under that, or the engine drains before
+        the next wave and ``run()`` returns.
         The first wave submits immediately (so ``run()`` has work);
         later waves are a decode_script schedule. Returns
         ``(schedule, submitted)`` — ``submitted`` fills with
@@ -655,7 +659,7 @@ class FaultPlan:
 
     # ------------------------------------- (s) two-tier KV spill chaos
     def spill_storm(self, engine, *, waves: int = 5, per_wave: int = 2,
-                    gap: int = 4, prompt_len: int = 8, max_new: int = 3,
+                    gap: int = 2, prompt_len: int = 8, max_new: int = 3,
                     vocab: int = 32, revisit_from: int = 2):
         """``prefix_evict_storm``'s two-tier twin: join ``per_wave``
         DISTINCT-prefix requests every ``gap`` engine steps so pool

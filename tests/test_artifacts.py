@@ -282,7 +282,9 @@ class TestWarmDecode:
                                     prefix_cache=False))
         assert got_cold == want
         names = [r["name"] for r in store.entries()]
+        # the step's two programs: plain, and with the prefill lanes
         assert any(n.startswith("paged_step-") for n in names)
+        assert any(n.startswith("paged_lane_step-") for n in names)
 
         # "respawned engine", same process: the executable cache
         # serves it — no disk read, no trace, no compile
@@ -297,8 +299,9 @@ class TestWarmDecode:
                          if "_step_impl" in k}
         assert step_compiles == {}, step_compiles
         assert A.EXECUTABLES.stats()["hits"] > hits0
-        # and no second build was journaled — one artifact, shared
-        assert len(_journal("build")) == 1
+        # and no further build was journaled — one artifact a
+        # program (the lane step, the plain step), shared
+        assert len(_journal("build")) == 2
 
     def test_corrupt_store_still_serves_token_identical(
             self, store, decoder):
@@ -322,6 +325,37 @@ class TestWarmDecode:
                                    prefix_cache=False))
             assert got == want
             assert _journal("fallback")[-1]["reason"] == "corrupt"
+
+    @pytest.mark.parametrize("kind", ["paged_step", "paged_lane_step"])
+    def test_one_corrupt_step_artifact_of_two(self, store, decoder, kind):
+        """The step is two programs, so two artifacts: with either one
+        corrupt the engine serves the same tokens, that program from a
+        fresh build and the other from the store, side by side."""
+        rng = np.random.RandomState(5)
+        prompt = rng.randint(0, 40, (6,)).astype("int32")
+
+        def run():
+            eng = DecodeEngine(decoder, num_slots=2, page_size=4,
+                               max_seq_len=DEC_CFG["max_len"],
+                               prefix_cache=False)
+            r = eng.submit(prompt, 8)
+            eng.run(timeout=300)
+            assert eng.stats()["prefill_lane_steps"] >= 1
+            return r.get(timeout=1)
+
+        want = run()                                    # builds store
+        name, = [r["name"][:-len(".ptaf")] for r in store.entries()
+                 if r["name"].startswith(kind + "-")]
+        A.EXECUTABLES.clear()
+        loads0, builds0 = len(_journal("load")), len(_journal("build"))
+        with FaultPlan.corrupt_artifact(store, name=name, mode="payload"):
+            assert run() == want
+        assert [r["name"] for r in _journal("fallback")][-1] == name
+        other = [r["name"] for r in _journal("load")[loads0:]
+                 if r["name"].startswith("paged_")]
+        assert len(other) == 1 and not other[0].startswith(kind + "-")
+        # the fresh build repaired the store
+        assert [r["name"] for r in _journal("build")[builds0:]] == [name]
 
     def test_engine_warmup_resolves_before_traffic(self, store,
                                                    decoder):

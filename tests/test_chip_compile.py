@@ -43,12 +43,9 @@ def one_chip(topo):
 def cache_off():
     """No persistent compile cache around a compile for a described
     device: such an entry is written but cannot be read back."""
-    from jax.experimental.compilation_cache import compilation_cache
     from paddle_tpu.artifacts import cache
     with cache.disabled():
-        compilation_cache.reset_cache()
         yield
-    compilation_cache.reset_cache()
 
 
 @pytest.fixture
@@ -315,6 +312,63 @@ def _pool_sized_ops(hlo, pool_shape):
             for m in [sized.search(line)] if m]
 
 
+def _lane_args(paged, plain_args):
+    """The lane program's arguments: the plain program's with the
+    ``lanes`` [Sp, 3 + C] before the key."""
+    *args, key = plain_args
+    lanes, width = paged.lanes
+    return (*args, _sds((lanes, 3 + width), jnp.int32), key)
+
+
+@pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["bf16", "int8"])
+def test_serving_lane_step_updates_the_pools_in_place(
+        opt13b_decoder, chip_executable, monkeypatch, kv_quant):
+    """The LANE program of the benchmark's serving step (32 slots beside
+    one prefill lane of 64 tokens, the cache kind's own statement at
+    OPT-1.3B's widths): both kernels of a layer lower, the slot group's
+    [32, 1] queries and the lane's [1, 64]; the pools are still written
+    in place by ONE scatter a pool and layer over the rows of both
+    groups, with no copy or slice of a pool and temporaries far under
+    the pools' bytes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paged = opt13b_decoder.paged(num_slots=32, page_size=16, num_pages=896,
+                                 max_pages_per_slot=128, warm_start=False,
+                                 kv_quant=kv_quant)
+    assert paged.use_kernel and not paged.kernel_interpret
+    assert paged.lanes == (1, 64)
+    assert paged.cache.kernel_supported((1, 64))
+    k_pool, v_pool = jax.eval_shape(paged.init_pools)
+    sw = _sds((32, 1), jnp.int32)
+    args = (paged.dense.p, k_pool, v_pool, sw, sw, _sds((32, 128), jnp.int32),
+            _sds((32, 1), jnp.bool_), _sds((2,), jnp.uint32))
+    compiled = chip_executable(paged._step_impl_lanes,
+                               *_lane_args(paged, args), donate=(1, 2))
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 * 24
+    values = k_pool["q"] if kv_quant else k_pool
+    in_place = ("parameter", "get-tuple-element", "tuple", "bitcast",
+                "scatter")
+    strays = [line.strip()[:160]
+              for op, line in _pool_sized_ops(hlo, values.shape)
+              if op not in in_place
+              and not (op == "fusion" and "kv_write/scatter" in line)]
+    assert not strays, strays
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == paged.pool_bytes()
+    if kv_quant is None:
+        assert mem.temp_size_in_bytes < paged.pool_bytes() // 8, mem
+    else:
+        # the int8 step's own temporaries are 565 MB with or without
+        # lanes (the scales' pools are not yet scattered in place): the
+        # lanes add their rows' worth, not a pool's
+        plain = chip_executable(paged._step_impl, *args,
+                                donate=(1, 2)).memory_analysis()
+        assert mem.temp_size_in_bytes < plain.temp_size_in_bytes \
+            + paged.pool_bytes() // 16, (mem, plain)
+    # the head reads 32 + 1 rows, never the lane's 64
+    assert "[96,50272]" not in hlo and "[1,96,50272]" not in hlo
+
+
 @pytest.mark.parametrize("num_pages", [896, 1536],
                          ids=["pages896", "pages1536"])
 def test_serving_step_updates_the_pools_in_place(
@@ -433,6 +487,39 @@ def test_latent_step_updates_the_pool_in_place(chip_executable,
     compiled = chip_executable(paged._step_impl, *args, donate=(1, 2))
     hlo = compiled.as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    in_place = ("parameter", "get-tuple-element", "tuple", "bitcast",
+                "scatter")
+    strays = [line.strip()[:160]
+              for op, line in _pool_sized_ops(hlo, pool.shape)
+              if op not in in_place
+              and not (op == "fusion"
+                       and "latent_kv_write/scatter" in line)]
+    assert not strays, strays
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == paged.pool_bytes()
+    assert mem.temp_size_in_bytes < paged.pool_bytes() // 8, mem
+
+
+def test_latent_lane_step_updates_the_pool_in_place(chip_executable,
+                                                    monkeypatch):
+    """The LANE program of ``kimik2_agent_2k``'s step: 64 slots beside 8
+    lanes of 4 tokens (what the latent kernel's gate takes at 64 heads),
+    two kernel calls a layer, the pool written in place by one scatter a
+    layer over the rows of both groups."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dec = _kimi_k2_decoder()
+    paged = dec.paged(num_slots=64, page_size=32, num_pages=8192,
+                      max_pages_per_slot=128, warm_start=False)
+    assert paged.use_kernel and not paged.kernel_interpret
+    assert paged.lanes == (8, 4)
+    pool, none = jax.eval_shape(paged.init_pools)
+    sw = _sds((64, 1), jnp.int32)
+    args = (dec.p, pool, none, sw, sw, _sds((64, 128), jnp.int32),
+            _sds((64, 1), jnp.bool_), _sds((2,), jnp.uint32))
+    compiled = chip_executable(paged._step_impl_lanes,
+                               *_lane_args(paged, args), donate=(1, 2))
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 * 3
     in_place = ("parameter", "get-tuple-element", "tuple", "bitcast",
                 "scatter")
     strays = [line.strip()[:160]

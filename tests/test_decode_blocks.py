@@ -196,6 +196,34 @@ def test_third_description_is_served_by_the_engine(how):
     assert eng.stats()["expert_layer_steps"] == 0
 
 
+@pytest.mark.parametrize("how", ["gather", "kernel"])
+def test_third_description_prompts_go_through_prefill_lanes(how,
+                                                            monkeypatch):
+    """A description that reuses ``PerHeadCache`` has its prefill lanes
+    with it, cut to ITS heads (4 query heads over 2 kv heads of 8: a
+    128-row q block is 32 tokens, so 2 lanes), with no word about lanes
+    in the description: a prompt of up to 24 tokens is one lane's chunk,
+    three at once share the two lanes, and the output is the plain
+    forward's and that of the same engine without lanes."""
+    dec = _tiny_decoder()
+    prompts = _prompts(5, TINY["V"], seed=11, lo=2, hi=25)
+    serve = lambda eng: [r.get(timeout=1) for r in [
+        [eng.submit(p, 6) for p in prompts], eng.run(timeout=300)][0]]
+    eng = DecodeEngine(dec, num_slots=3, page_size=4, max_seq_len=TINY["T"],
+                       attention=how)
+    assert eng.paged.lanes == (2, 32)
+    got = serve(eng)
+    st = eng.stats()
+    assert got == [_tiny_plain_greedy(dec.p, p, 6) for p in prompts]
+    assert st["prefill_lane_tokens"] > st["prefill_tokens"] // 2 > 0
+    assert eng.page_accounting()["leaked"] == 0
+    monkeypatch.setattr(blocks.PerHeadCache, "lanes", lambda self: (0, 0))
+    plain = DecodeEngine(dec, num_slots=3, page_size=4,
+                         max_seq_len=TINY["T"], attention=how)
+    assert serve(plain) == got and plain.stats()["prefill_lane_steps"] == 0
+    assert st["steps"] < plain.stats()["steps"]
+
+
 # ----------------------------------------------- the seam, by its sources
 def test_decoders_and_engine_do_not_ask_which_block():
     asks = re.compile(r"block is (not )?None|\.latent\b"
@@ -353,7 +381,8 @@ def test_dense_paged_and_draft_steps_agree(which, kv_quant):
 DESCRIPTION = ("cache", "positions", "table_dtype", "vocab_size", "embed",
                "ffn", "logits", "n_expert_layers")
 CACHE_KIND = ("refuses", "LAYOUT", "dense_init", "dense_layer",
-              "kernel_supported", "init_pools", "page_payload", "layer")
+              "kernel_supported", "init_pools", "page_payload", "lanes",
+              "layer", "layer_operand")
 ASKED_BY_KIND = {
     blocks.PerHeadCache: ("heads", "qkv", "project"),
     blocks.LatentCache: ("cache_widths", "sizes", "qkv", "absorb_q",

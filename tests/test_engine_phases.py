@@ -124,6 +124,78 @@ def test_host_counters_are_monotone_and_sum_to_the_steps_wall_time():
     assert 0.75 * wall <= work <= wall, (work, wall)
 
 
+LANE_KEYS = ("tokens_fed", "prefill_lane_steps", "prefill_lane_tokens",
+             "prefill_lane_cache_tokens_read")
+
+
+def test_lane_counters_and_slot_step_widths_follow_a_scripted_schedule():
+    """Prompts of 10, 7 and 6 tokens at once, three slots, one lane,
+    three tokens each. Step 1: the oldest takes the lane (its whole
+    prompt, and its first token), the others feed one token each in the
+    slot group. Step 2: the second's remaining six are the lane's chunk
+    beside the first's decoding; step 3: the third's remaining four.
+    Steps 4 and 5 are plain. Every counter is exact per token: row j of
+    a chunk fed from position p reads p + j + 1 cached tokens."""
+    eng = _engine(num_slots=3, prefix_cache=False)
+    assert eng.paged.lanes == (1, 64)
+    st0 = eng.stats()
+    assert all(st0[k] == 0 for k in LANE_KEYS)
+    rng = np.random.RandomState(5)
+    reqs = [eng.submit(rng.randint(0, CFG["vocab_size"], (n,))
+                       .astype("int32"), 3) for n in (10, 7, 6)]
+    want = [   # tokens_fed, lane steps, lane tokens, lane reads,
+               # prefill_tokens, cache_tokens_read, active_slot_steps
+        (12, 1, 9, 55, 11, 55 + 1 + 1, 3),
+        (20, 2, 14, 55 + 27, 17, 57 + 11 + 27 + 2, 6),
+        (26, 3, 17, 82 + 18, 20, 97 + 12 + 8 + 18, 9),
+        (28, 3, 17, 100, 20, 135 + 9 + 7, 11),
+        (29, 3, 17, 100, 20, 151 + 8, 12)]
+    for row in want:
+        assert eng.step()
+        st = eng.stats()
+        assert tuple(st[k] for k in LANE_KEYS + (
+            "prefill_tokens", "cache_tokens_read",
+            "active_slot_steps")) == row
+    assert not eng._has_work() and eng.stats()["steps"] == 5
+    assert [len(r.tokens) for r in reqs] == [3, 3, 3]
+    ids = {r.trace_id: i for i, r in enumerate(reqs)}
+    widths = [(rec["engine_step"], ids[rec["trace_id"]], rec["pos"],
+               rec["width"]) for rec in FLIGHT.snapshot()
+              if rec.get("name") == "engine/slot_step"
+              and rec.get("trace_id") in ids]
+    assert widths == [(1, 0, 0, 10), (1, 1, 0, 1), (1, 2, 0, 1),
+                      (2, 0, 10, 1), (2, 1, 1, 6), (2, 2, 1, 1),
+                      (3, 0, 11, 1), (3, 1, 7, 1), (3, 2, 2, 4),
+                      (4, 1, 8, 1), (4, 2, 6, 1), (5, 2, 7, 1)]
+
+
+def test_host_counters_sum_to_the_wall_time_with_lanes_on():
+    """The lane's host work (choosing lanes, packing their one input,
+    committing a chunk) lies inside serving/plan and serving/commit: with
+    every step a lane step but the last, the six counters still cover the
+    steps' wall time."""
+    # the interpreted kernel makes a step long against the few
+    # bytecodes that lie between the phases
+    eng = _engine(num_slots=2, prefix_cache=False, attention="kernel")
+    eng.warmup()                        # both programs: no compile below
+    rng = np.random.RandomState(6)
+    for n in (30, 29, 28, 27, 26, 25, 24, 23):
+        eng.submit(rng.randint(0, CFG["vocab_size"], (n,)).astype("int32"),
+                   2)
+    before = dict(eng._counters)
+    t0 = time.perf_counter_ns()
+    while eng._has_work():
+        eng.step()
+    wall = time.perf_counter_ns() - t0
+    st = eng.stats()
+    assert st["prefill_lane_steps"] >= 4
+    assert st["prefill_tokens"] == sum(range(22, 30))
+    assert st["prefill_lane_tokens"] > st["prefill_tokens"] // 2
+    work = sum(st[k] - before[k] for k in HOST_KEYS)
+    assert st["host_idle_ns"] == 0
+    assert 0.75 * wall <= work <= wall, (work, wall)
+
+
 def test_the_loops_phases_and_its_idle_wait_close_to_its_wall_time():
     """Started loop: with ``host_idle_ns`` the six counters cover the
     loop thread's life from start() to shutdown() to within 20 % on a
@@ -195,7 +267,8 @@ def test_step_allocates_no_span_when_tracer_and_flight_are_off(monkeypatch):
     assert made == []
 
 
-@pytest.mark.parametrize("key", HOST_KEYS + ("admitted", "queue_wait_ns"))
+@pytest.mark.parametrize("key", HOST_KEYS + ("admitted", "queue_wait_ns")
+                         + LANE_KEYS)
 def test_the_http_exposition_counts_the_new_keys_as_counters(key):
     from paddle_tpu.serving.http import _COUNTER_KEYS
     assert key in _COUNTER_KEYS

@@ -107,8 +107,71 @@ def test_paged_step_serves_what_the_reference_puts_first(
     # two expert layers a step; every token chooses 2 of 16, 4 are held
     assert st["expert_layer_steps"] == 2 * st["steps"]
     assert 0 < st["expert_hits_held"] <= st["expert_assignments_held"] \
-        <= 2 * 2 * st["active_slot_steps"]
+        <= 2 * 2 * st["tokens_fed"]
     assert st["expert_hits_held"] <= 4 * st["expert_layer_steps"]
+
+
+@pytest.mark.parametrize("lanes", [None, (4, 8)], ids=["own", "4x8"])
+@pytest.mark.parametrize("attention", ["gather", "kernel"])
+def test_latent_prompts_through_prefill_lanes(ref_params, decoder,
+                                              attention, lanes,
+                                              monkeypatch):
+    """A latent prompt enters through lanes of the latent read: the cache
+    kind's own shape at this size (one lane of 32: the kernel's gate says
+    nothing of toy rows), and four lanes of 8, which puts one slot over
+    several lanes in a step and leaves others to the slot group. Served
+    tokens are the dense path's, the reference's first choice, and what
+    the same engine serves with no lanes at all; the expert layers' load
+    counts the lanes' active rows with the slots'."""
+    from paddle_tpu.models.block import LatentCache
+    if lanes is not None:
+        monkeypatch.setattr(LatentCache, "lanes", lambda self: lanes)
+    eng = _engine(decoder, attention, max_seq_len=64)
+    assert eng.paged.lanes == (lanes or (1, 32))
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(0, CFG["vocab_size"], n).astype(np.int32)
+               for n in (40, 3, 31, 32, 33, 8, 9, 1)]
+    reqs = [eng.submit(p, 7) for p in prompts]
+    eng.run()
+    st = eng.stats()
+    assert st["prefill_lane_steps"] >= 4
+    assert st["prefill_lane_tokens"] >= st["prefill_tokens"] - len(prompts) \
+        - (20 if lanes else 0)      # 4 x 8: the younger feed their slots
+    assert st["tokens_fed"] > st["active_slot_steps"]
+    assert 0 < st["expert_hits_held"] <= st["expert_assignments_held"] \
+        <= 2 * 2 * st["tokens_fed"]
+    assert eng.page_accounting()["leaked"] == 0
+    monkeypatch.setattr(LatentCache, "lanes", lambda self: (0, 0))
+    plain = _engine(decoder, attention, max_seq_len=64)
+    again = [plain.submit(p, 7) for p in prompts]
+    plain.run()
+    assert plain.stats()["prefill_lane_steps"] == 0
+    for prompt, r, r1 in zip(prompts, reqs, again):
+        served = np.asarray(r.tokens, np.int32)
+        assert r.tokens == r1.tokens
+        assert r.tokens == decoder.generate(prompt[None],
+                                            max_len=len(prompt) + 7)[0]
+        assert _gaps(ref_params, prompt, served).max() < 1e-4
+
+
+def test_the_latent_lanes_are_what_the_kernels_gate_takes():
+    """At the published widths (64 heads, rows of 640 lanes, pages of 32,
+    bf16) a lane is 4 tokens wide, 256 rows of two terms each: 8 tokens
+    fail the kernel's VMEM gate by its own arithmetic. 8 lanes take the
+    kind's 32 tokens a step."""
+    assert paged_ops.latent_kernel_supported(8, 4 * 64, 640, 512, 32, 128,
+                                             jnp.bfloat16)
+    assert not paged_ops.latent_kernel_supported(4, 8 * 64, 640, 512, 32,
+                                                 128, jnp.bfloat16)
+    cell = manifest.cell(manifest.load_manifest(), "kimik2_agent_2k")
+    cfg = dict(cell["config"], num_hidden_layers=2)
+    p = {cell["model"].program_name(k): jax.ShapeDtypeStruct(v, jnp.bfloat16)
+         for k, v in cell["reference"].leaf_shapes(cfg).items()}
+    block = cell["model"].block_of(cfg, 4096)
+    kind = block.cache(block, p, f"_{cell['model'].NAME}_", n_layers=2,
+                       num_slots=64, window=1, page_size=32, num_pages=8192,
+                       max_pages_per_slot=128, kv_quant=None)
+    assert kind.kernel_supported() and kind.lanes() == (8, 4)
 
 
 @pytest.mark.parametrize("attention", ["gather", "kernel"])

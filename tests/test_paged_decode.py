@@ -222,6 +222,32 @@ class TestPagedWindowKernel:
             *args, use_kernel=True, interpret=True))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
+    @pytest.mark.parametrize("h,g", [(4, 4), (4, 2), (4, 1)])
+    def test_wide_window_parity_gqa(self, h, g):
+        """A prefill lane's window is wide: 12 tokens a slot here,
+        ragged and mid-page, each row masked by its own length, one slot
+        part-fed (its last rows masked by length 0), every head width."""
+        from paddle_tpu.ops import pallas_decode as pd
+        rng = np.random.RandomState(5)
+        S, W, dh, ps, npages = 3, 12, 8, 4, 24
+        k_pages = rng.randn(npages, ps, g, dh).astype(np.float32)
+        v_pages = rng.randn(npages, ps, g, dh).astype(np.float32)
+        q = rng.randn(S, W, h, dh).astype(np.float32)
+        tables = np.array([[3, 1, 7, 12, 13, 0, 0],
+                           [2, 9, 4, 11, 8, 14, 15],
+                           [5, 6, 16, 17, 0, 0, 0]], np.int32)
+        base = np.array([7, 15, 1], np.int32)
+        lens = (base[:, None] + np.arange(W)[None, :]).astype(np.int32)
+        lens[2, 9:] = 0                           # fed 9 of its 12
+        args = [jax.numpy.asarray(a) for a in
+                (q, _stored(k_pages), _stored(v_pages), tables, lens)]
+        want = np.asarray(pd.paged_window_attention(*args))
+        got = np.asarray(pd.paged_window_attention(
+            *args, use_kernel=True, interpret=True))
+        np.testing.assert_allclose(got[:2], want[:2], rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(got[2, :9], want[2, :9],
+                                   rtol=2e-4, atol=2e-5)
+
     @pytest.mark.parametrize("layered", [False, True],
                              ids=["pool", "layer-axis"])
     @pytest.mark.parametrize("W", [1, 3], ids=["W1", "W3"])
@@ -749,9 +775,11 @@ class TestTokenIdentity:
         dec = _decoder(params)
         eng = DecodeEngine(dec, num_slots=2, page_size=4,
                            max_seq_len=20, num_pages=8)
-        warm = eng.submit(np.zeros((3,), "int32"), 1)
-        eng.run(timeout=120)                  # compiles the step once
-        assert warm.get(timeout=1)
+        warm = eng.submit(np.full((3,), 7, "int32"), 2)
+        eng.run(timeout=120)    # compiles the step's two programs: the
+        assert warm.get(timeout=1)      # prompt's lane step, then a plain
+        assert eng.stats()["prefill_lane_steps"] == 1
+        assert eng.stats()["steps"] == 2
         r0 = eng.submit(np.zeros((4,), "int32"), 10)
         joined = []
         with compile_watch() as watch:
@@ -966,17 +994,19 @@ class TestPrefixReuse:
                            max_seq_len=CFG["max_len"])
         cold = eng.submit(prompt, 5)
         eng.run(timeout=120)
-        steps_cold = eng.stats()["steps"]
+        fed_cold = eng.stats()["prefill_tokens"]
         assert cold.get(timeout=1) == [int(t) for t in want]
         assert cold.prefix_hit_pages == 0
         warm = eng.submit(prompt, 5)
         eng.run(timeout=120)
-        steps_warm = eng.stats()["steps"] - steps_cold
+        fed_warm = eng.stats()["prefill_tokens"] - fed_cold
         # same tokens, but the shared prefill never re-runs: the warm
         # request attaches the cached pages and feeds only the tail
+        # (the step count no longer tells them apart: the cold prompt
+        # is one prefill-lane step)
         assert warm.get(timeout=1) == [int(t) for t in want]
         assert warm.prefix_hit_pages >= 2
-        assert steps_warm < steps_cold
+        assert fed_cold == 12 and fed_warm == 0
         st = eng.stats()
         assert st["prefix_hit_pages"] >= 2
         assert st["kv_pages_shared"] >= 0
@@ -1009,6 +1039,44 @@ class TestPrefixReuse:
         assert rb.prefix_hit_pages >= 1     # page 0 attached whole
         assert st["prefix_cow_copies"] >= 1  # page 1 copied on write
         _balanced(eng)
+
+    def test_reclaimable_count_is_the_walk_it_replaced(self):
+        """``PagePool`` keeps, as refcounts change, how many indexed pages
+        only the index holds; an admission reads it where it used to walk
+        the whole trie. Under shared-prefix churn, copy-on-write, LRU
+        reclaim and a preemption it equals the walk at every step."""
+        dec = _decoder(_model())
+        eng = DecodeEngine(dec, num_slots=2, page_size=4,
+                           max_seq_len=CFG["max_len"], num_pages=12)
+
+        def walked():
+            n, stack = 0, [eng.prefix._root]
+            while stack:
+                nd = stack.pop()
+                n += nd.page is not None and \
+                    eng.pool.refcount(nd.page) == 1
+                stack.extend(nd.children.values())
+            return n
+
+        rng = np.random.RandomState(11)
+        base = rng.randint(0, 40, (9,)).astype("int32")
+        reqs = []
+        for i in range(8):
+            p = base.copy() if i % 3 else rng.randint(0, 40, (9,)) \
+                .astype("int32")
+            p[6 + i % 3] = (p[6 + i % 3] + i) % 40
+            reqs.append(eng.submit(p, 6 + i))
+        seen = set()
+        while eng._has_work():
+            eng.step()
+            assert eng.prefix.reclaimable_pages() == walked()
+            seen.add(walked())
+        assert len(seen) > 3 and eng.stats()["prefix_evicted_pages"] > 0
+        assert all(len(r.get(timeout=1)) == 6 + i
+                   for i, r in enumerate(reqs))
+        _balanced(eng)
+        eng.prefix.flush()
+        assert eng.prefix.reclaimable_pages() == 0 == walked()
 
     def test_prefix_cache_off_frees_everything(self):
         params = _model()
@@ -1069,6 +1137,316 @@ class TestPrefixReuse:
         assert joined[0].state in ("cancelled", "done")
         assert len(joined[1].get(timeout=1)) == 6
         _balanced(eng)
+
+
+LANE_MAX_LEN = 256      # positions enough for prompts of several lanes
+
+
+def _lane_decoder(**over):
+    return _decoder(_model(max_len=LANE_MAX_LEN, **over),
+                    n_heads=over.get("n_heads"))
+
+
+def _lane_prompts(lens, seed=0):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, CFG["vocab_size"], (n,)).astype("int32")
+            for n in lens]
+
+
+def _serve(eng, prompts, max_new):
+    reqs = [eng.submit(p, max_new) for p in prompts]
+    eng.run(timeout=600)
+    return [r.get(timeout=1) for r in reqs]
+
+
+class TestPrefillLanes:
+    """ISSUE 38: a prompt enters the paged step a lane's width of
+    tokens at a time beside the decoding slots. Greedy output through
+    lanes is token-identical to one-token prefill (the same engine over
+    a cache kind that states no lanes) and to ``generate``; the lanes'
+    shape is the cache kind's; no slot waits for a lane; the engine's
+    lifetime holds exactly the step's two programs."""
+
+    @pytest.fixture
+    def no_lanes(self, monkeypatch):
+        """(0, 0) is a legal answer of a cache kind: the engine then
+        prefills a window a step, as it did before there were lanes."""
+        from paddle_tpu.models.block import PerHeadCache
+
+        def off():
+            monkeypatch.setattr(PerHeadCache, "lanes", lambda self: (0, 0))
+        return off
+
+    def test_the_lanes_shape_is_the_cache_kinds(self):
+        """Two heads of 8 make one lane chunk of 2 x 64 query rows, the
+        MXU tile: the kind's 64 tokens a step are one lane of 64. At
+        rep 2 (GQA) the same tile is 32 tokens, so two lanes. No caller
+        chooses either."""
+        eng = DecodeEngine(_lane_decoder(), num_slots=2, page_size=4,
+                           max_seq_len=LANE_MAX_LEN)
+        assert eng.paged.lanes == (1, 64)
+        st = eng.stats()
+        assert (st["prefill_lanes"], st["prefill_lane_width"]) == (1, 64)
+        gqa = DecodeEngine(_lane_decoder(n_heads=4, n_kv_heads=2),
+                           num_slots=2, page_size=4,
+                           max_seq_len=LANE_MAX_LEN)
+        assert gqa.paged.lanes == (2, 32)
+
+    @pytest.mark.parametrize("kv_quant", [None, "int8"], ids=["fp", "int8"])
+    @pytest.mark.parametrize("attention", ["gather", "kernel"])
+    def test_lane_prefill_is_token_identical(self, attention, kv_quant,
+                                             no_lanes):
+        """Prompts of 1 token to three lanes' worth, ending before, on
+        and after a page edge (16) and a lane edge (64), more requests
+        than slots: lanes == one-token prefill == generate."""
+        dec = _lane_decoder()
+        prompts = _lane_prompts([150, 5, 64, 65, 63, 1, 2, 15, 16, 17,
+                                 200])
+        kw = dict(num_slots=3, page_size=16, max_seq_len=LANE_MAX_LEN,
+                  attention=attention, kv_quant=kv_quant,
+                  prefix_cache=False)   # the counters compare exactly
+        eng = DecodeEngine(dec, **kw)
+        got = _serve(eng, prompts, 6)
+        st = eng.stats()
+        assert st["prefill_lane_steps"] > 0
+        # most of it: a prompt that finds the one lane taken feeds a
+        # token a step in its slot's own window meanwhile
+        assert st["prefill_lane_tokens"] > st["prefill_tokens"] // 2
+        assert eng.page_accounting()["leaked"] == 0
+        no_lanes()
+        plain = DecodeEngine(dec, **kw)
+        assert plain.paged.lanes == (0, 0)
+        assert got == _serve(plain, prompts, 6)
+        st1 = plain.stats()
+        assert st1["prefill_lane_steps"] == st1["prefill_lane_tokens"] == 0
+        assert st1["prefill_tokens"] == st["prefill_tokens"]
+        assert st1["cache_tokens_read"] == st["cache_tokens_read"]
+        assert st1["tokens_fed"] == st["tokens_fed"] \
+            == st1["active_slot_steps"]
+        assert st["steps"] < st1["steps"] // 3
+        assert got == [[int(t) for t in w]
+                       for w in _dense_rows(dec, prompts, [6] * len(prompts))]
+
+    def test_one_slot_over_two_lanes_in_a_step(self):
+        """A GQA decoder's two lanes of 32: 80 tokens take both at
+        consecutive positions in step 1 (one longer chunk), the remaining
+        16 and the first token in step 2."""
+        dec = _lane_decoder(n_heads=4, n_kv_heads=2)
+        prompt, = _lane_prompts([80], seed=4)
+        eng = DecodeEngine(dec, num_slots=2, page_size=4,
+                           max_seq_len=LANE_MAX_LEN)
+        assert eng.paged.lanes == (2, 32)
+        req = eng.submit(prompt, 5)
+        assert eng.step()
+        st = eng.stats()
+        assert st["tokens_fed"] == st["prefill_lane_tokens"] == 64
+        assert st["prefill_lane_cache_tokens_read"] == 64 * 65 // 2
+        assert req.tokens == []
+        assert eng.step()
+        assert eng.stats()["tokens_fed"] == 80 and len(req.tokens) == 1
+        eng.run(timeout=300)
+        assert req.get(timeout=1) == dec.generate(
+            prompt[None, :], max_len=85)[0]
+        assert eng.stats()["steps"] == 2 + 4
+        _balanced(eng)
+
+    def test_more_prompts_than_lanes_and_none_starves(self):
+        """Four 70-token prompts at once against one lane of 64: the
+        oldest takes it (64, then its last 6), the others feed a token
+        each in the slot group, as without lanes, and take the lane in
+        turn."""
+        dec = _lane_decoder()
+        prompts = _lane_prompts([70] * 4, seed=5)
+        eng = DecodeEngine(dec, num_slots=4, page_size=4,
+                           max_seq_len=LANE_MAX_LEN)
+        reqs = [eng.submit(p, 6) for p in prompts]
+        fed = []
+        for _ in range(6):
+            assert eng.step()
+            fed.append([sl.pos for sl in eng.slots])
+        assert fed == [[64, 1, 1, 1], [70, 2, 2, 2], [71, 66, 3, 3],
+                       [72, 70, 4, 4], [73, 71, 68, 5], [74, 72, 70, 6]]
+        assert eng.stats()["active_slot_steps"] == 24
+        eng.run(timeout=300)
+        for r, p in zip(reqs, prompts):
+            assert r.get(timeout=1) == dec.generate(
+                p[None, :], max_len=76)[0]
+        _balanced(eng)
+
+    def test_a_replay_that_starts_mid_page_after_a_cow_attach(self):
+        """The second prompt shares 6 tokens with the first: page 0
+        attached, 2 rows of page 1 copied on write, so its lane starts at
+        position 6, mid-page, and writes on into the copied page."""
+        dec = _lane_decoder()
+        a, b = _lane_prompts([40, 70], seed=6)
+        b[:6] = a[:6]
+        b[6] = (a[6] + 1) % CFG["vocab_size"]
+        eng = DecodeEngine(dec, num_slots=1, page_size=4,
+                           max_seq_len=LANE_MAX_LEN)
+        ra = eng.submit(a, 5)
+        eng.run(timeout=300)
+        lane0 = eng.stats()["prefill_lane_tokens"]
+        rb = eng.submit(b, 5)
+        eng.run(timeout=300)
+        st = eng.stats()
+        assert rb.prefix_hit_pages == 1 and st["prefix_cow_copies"] == 1
+        assert st["prefill_lane_tokens"] - lane0 == 70 - 6 - 1
+        assert ra.get(timeout=1) == dec.generate(a[None, :], max_len=45)[0]
+        assert rb.get(timeout=1) == dec.generate(b[None, :], max_len=75)[0]
+        _balanced(eng)
+
+    def test_a_preempted_requests_replay_goes_through_lanes(self):
+        """A pool too small for both: the younger is preempted mid-decode
+        and replays prompt + generated tokens as lane chunks."""
+        dec = _lane_decoder()
+        p1, p2 = _lane_prompts([21, 22], seed=7)
+        eng = DecodeEngine(dec, num_slots=2, page_size=4,
+                           max_seq_len=LANE_MAX_LEN, num_pages=15,
+                           prefix_cache=False)
+        r1, r2 = eng.submit(p1, 14), eng.submit(p2, 14)
+        eng.run(timeout=300)
+        st = eng.stats()
+        assert st["preemptions"] >= 1
+        assert r1.get(timeout=1) == dec.generate(p1[None, :], max_len=35)[0]
+        assert r2.get(timeout=1) == dec.generate(p2[None, :], max_len=36)[0]
+        # the replays' tokens beyond the two prompts' own
+        assert st["prefill_lane_tokens"] > 20 + 21
+        # all but the token a prompt feeds its slot while the one lane
+        # is taken
+        assert st["prefill_lane_tokens"] >= st["prefill_tokens"] - 2
+        assert eng.page_accounting()["leaked"] == 0
+
+    def test_warmup_resolves_both_step_programs(self):
+        """warmup() returns with the plain program AND the lane program
+        resolved and dispatched once (all slots inactive, no lane fed:
+        the pools read as they did); the first prompt goes through a
+        lane at once, and nothing compiles after warmup()."""
+        from paddle_tpu.analysis.sanitizer import compile_watch
+        dec = _lane_decoder()
+        prompts = _lane_prompts([40, 33], seed=12)
+        want = [[int(t) for t in w]
+                for w in _dense_rows(dec, prompts, [5, 5])]
+        eng = DecodeEngine(dec, num_slots=2, page_size=4,
+                           max_seq_len=LANE_MAX_LEN, prefix_cache=False)
+        pools = jax.tree_util.tree_map(np.asarray, (eng.k_pool, eng.v_pool))
+        eng.warmup()
+        assert eng.paged._step_exe is not None
+        assert eng.paged._lane_step_exe is not None
+        # null page apart, the pools are as they were
+        for was, now in zip(jax.tree_util.tree_leaves(pools),
+                            jax.tree_util.tree_leaves(
+                                (eng.k_pool, eng.v_pool))):
+            np.testing.assert_array_equal(was[:, 1:], np.asarray(now)[:, 1:])
+        with compile_watch() as served:
+            first = eng.submit(prompts[0], 5)
+            assert eng.step()
+            assert eng.stats()["prefill_lane_steps"] == 1
+            second = eng.submit(prompts[1], 5)
+            eng.run(timeout=300)
+        assert not {k: v for k, v in served.per_function.items()
+                    if k.startswith("_step_impl")}
+        st = eng.stats()
+        # each prompt in one lane step, all of it but its last token
+        # (whose row commits the first token)
+        assert st["prefill_lane_steps"] == 2
+        assert st["prefill_lane_tokens"] == 39 + 32
+        assert [first.get(timeout=1), second.get(timeout=1)] == want
+        _balanced(eng)
+
+    def test_the_lane_program_traces_one_layer_a_kind(self):
+        """The plain program builds its layers one after the other (it is
+        held to its text); the lane program builds ONE layer for all
+        whose own parameters are alike and calls it with the pool's layer
+        index as an operand. Layer 1 of 3 here has a wider FFN (the new
+        columns zero: the same function, another kind by its shapes): the
+        lane program traces layer 0 for layers 0 and 2, and layer 1."""
+        import collections
+        params = dict(_model(max_len=LANE_MAX_LEN, n_layers=3))
+        pre = next(n for n in params if n.endswith("l1_up.w0"))[:-8]
+        for name, axis in (("up.w0", 1), ("up.wbias", 0), ("down.w0", 0)):
+            a = np.asarray(params[f"{pre}l1_{name}"])
+            params[f"{pre}l1_{name}"] = jax.numpy.asarray(
+                np.concatenate([a, np.zeros_like(a)], axis=axis))
+        dec = models.TransformerDecoder(params, n_layers=3,
+                                        n_heads=CFG["n_heads"])
+        prompts = _lane_prompts([70, 9, 41], seed=13)
+        eng = DecodeEngine(dec, num_slots=2, page_size=4,
+                           max_seq_len=LANE_MAX_LEN, prefix_cache=False,
+                           warm_start=False)
+        traced, layer = [], eng.paged.cache.layer
+        eng.paged.cache.layer = lambda p, i, *a, **kw: (
+            traced.append((i, "at" in kw)), layer(p, i, *a, **kw))[1]
+        eng.warmup()
+        assert collections.Counter(traced) == {
+            (0, False): 1, (1, False): 1, (2, False): 1,    # plain
+            (0, True): 1, (1, True): 1}                     # lanes
+        assert _serve(eng, prompts, 6) == [
+            [int(t) for t in w] for w in _dense_rows(dec, prompts, [6] * 3)]
+        assert eng.stats()["prefill_lane_steps"] >= 3
+        assert len(traced) == 5
+        _balanced(eng)
+
+    def test_a_draft_catches_up_behind_a_lane_fed_target(self):
+        """Speculation: the target's prompt goes in one lane step, the
+        draft teacher-forces it window by window afterwards; output
+        stays token-identical and proposals are accepted."""
+        dec = _lane_decoder()
+        prompts = _lane_prompts([90, 7, 33], seed=8)
+        eng = DecodeEngine(dec, num_slots=2, page_size=4,
+                           max_seq_len=LANE_MAX_LEN,
+                           draft=_lane_decoder(), spec_k=2)
+        assert eng.window == 3 and eng.paged.lanes == (1, 64)
+        got = _serve(eng, prompts, 12)
+        assert got == [[int(t) for t in w]
+                       for w in _dense_rows(dec, prompts, [12] * 3)]
+        st = eng.stats()
+        assert st["prefill_lane_steps"] >= 3
+        assert st["spec_accepted_tokens"] > 0
+        _balanced(eng)
+
+    @pytest.mark.recompile_budget(max_compiles=10)
+    def test_an_engines_lifetime_holds_exactly_the_two_step_programs(self):
+        """warmup() resolves the plain program and the lane program (an
+        engine built with ``warm_start=False``, as here, so that no
+        other test's executables can touch the count); a storm of long
+        and short joins, a cancel mid-prompt and a pool-pressure
+        preemption afterwards compile nothing."""
+        from paddle_tpu.analysis.sanitizer import compile_watch
+        from paddle_tpu.testing import FaultPlan
+        dec = _lane_decoder()
+        long_a, long_b, short = _lane_prompts([140, 100, 5], seed=9)
+        with compile_watch() as life:
+            eng = DecodeEngine(dec, num_slots=2, page_size=4,
+                               max_seq_len=LANE_MAX_LEN, num_pages=44,
+                               warm_start=False, prefix_cache=False)
+            eng.warmup()
+            steps = lambda w: {k: v for k, v in w.per_function.items()
+                               if k.startswith("_step_impl")}
+            both = {"_step_impl": 1, "_step_impl_lanes": 1}
+            assert steps(life) == both
+            assert _serve(eng, [long_b[:70]], 1)
+            assert steps(life) == both
+            r0 = eng.submit(short, 40)
+            joined = []
+            with compile_watch() as churn:
+                with FaultPlan.decode_script(eng, {
+                        1: lambda: joined.append(eng.submit(long_a, 6)),
+                        2: lambda: joined[0].cancel(),
+                        3: lambda: joined.append(eng.submit(long_b, 40)),
+                        5: lambda: joined.append(eng.submit(short, 4)),
+                        }) as script:
+                    eng.run(timeout=600)
+                assert script["fired"] == [1, 2, 3, 5]
+            assert churn.total == 0, churn.per_function
+        assert steps(life) == both, life.per_function
+        assert joined[0].state == "cancelled" and joined[0].tokens == []
+        assert r0.get(timeout=1) == dec.generate(
+            short[None, :], max_len=45)[0]
+        assert joined[1].get(timeout=1) == dec.generate(
+            long_b[None, :], max_len=140)[0]
+        st = eng.stats()
+        assert st["preemptions"] >= 1 and st["prefill_lane_steps"] >= 3
+        assert eng.page_accounting()["leaked"] == 0
 
 
 class TestBenchSmoke:

@@ -540,6 +540,83 @@ class TestDecodeEngineChaos:
         assert eng.stats()["expired"] == 1
 
 
+class TestPrefillLaneChaos:
+    """A slot fed through prefill lanes is cancelled, or runs out of
+    time, MID-PROMPT (its 200 tokens take two steps of 128): it settles
+    typed with no token, every page it had written returns, its lanes
+    serve the next prompt, and the slot decoding beside it is
+    token-identical to a solo run."""
+
+    def _setup(self, **kw):
+        DEC = dict(DEC_CFG, max_len=256)
+        paddle.init(use_tpu=False, seed=0)
+        from paddle_tpu.core.registry import reset_name_counters
+        reset_name_counters()
+        spec = models.transformer_lm(**DEC)
+        topo = paddle.Topology(spec.cost, extra_outputs=[spec.output])
+        dec = models.TransformerDecoder(
+            topo.init_params(jax.random.PRNGKey(7)),
+            n_layers=DEC["n_layers"], n_heads=DEC["n_heads"])
+        eng = DecodeEngine(dec, num_slots=2, page_size=4, max_seq_len=256,
+                           **kw)
+        assert eng.paged.lanes == (1, 64)
+        rng = np.random.RandomState(3)
+        short = rng.randint(0, 40, (5,)).astype("int32")
+        long_ = rng.randint(0, 40, (200,)).astype("int32")
+        return dec, eng, short, long_
+
+    def test_cancel_mid_prompt_returns_the_lanes_and_the_pages(self):
+        dec, eng, short, long_ = self._setup()
+        want = dec.generate(short[None, :], max_len=5 + 9)[0]
+        r0 = eng.submit(short, 9)
+        joined = []
+        with FaultPlan.decode_script(eng, {
+                2: lambda: joined.append(eng.submit(long_, 4)),
+                3: lambda: joined[0].cancel(),
+                4: lambda: joined.append(eng.submit(long_[:150], 3)),
+                }) as script:
+            eng.run(timeout=300)
+        assert script["fired"] == [2, 3, 4]
+        doomed, after = joined
+        assert doomed.state == "cancelled" and doomed.get(timeout=1) == []
+        assert r0.get(timeout=1) == [int(t) for t in want]
+        # the cancelled prompt's first 64 tokens were fed and indexed:
+        # the next prompt, its first 150 tokens, attaches them
+        assert after.prefix_hit_pages == 64 // 4
+        assert after.get(timeout=1) == [int(t) for t in dec.generate(
+            long_[None, :150], max_len=153)[0]]
+        st = eng.stats()
+        assert st["cancelled"] == 1 and st["finished"] == 2
+        assert st["prefill_lane_tokens"] == 4 + 64 + (150 - 64 - 1)
+        assert_pool_balanced(eng)
+
+    def test_expiry_mid_prompt_is_typed_and_leaks_nothing(self):
+        now = [0.0]
+        dec, eng, short, long_ = self._setup(
+            clock=lambda: time.monotonic() + now[0])
+        want = dec.generate(short[None, :], max_len=5 + 9)[0]
+        r0 = eng.submit(short, 9)
+        joined = []
+
+        def late():                      # the deadline passes mid-prompt
+            now[0] += 3600.0
+
+        with FaultPlan.decode_script(eng, {
+                2: lambda: joined.append(eng.submit(long_, 4,
+                                                    deadline=600.0)),
+                3: late}) as script:
+            eng.run(timeout=300)
+        assert script["fired"] == [2, 3]
+        with pytest.raises(Expired):
+            joined[0].get(timeout=1)
+        assert joined[0].tokens == []
+        assert r0.get(timeout=1) == [int(t) for t in want]
+        st = eng.stats()
+        assert st["expired"] == 1 and st["finished"] == 1
+        assert st["prefill_lane_tokens"] == 4 + 64
+        assert_pool_balanced(eng)
+
+
 class TestServerEngineIntegration:
     """InferenceServer with an attached DecodeEngine: generate() routes
     through page-aware admission, stats() carries the KV/slot gauges,
@@ -721,7 +798,7 @@ class TestPrefixSpecChaos:
                            max_seq_len=20, num_pages=9)
         plan = FaultPlan(seed=22)
         schedule, submitted = plan.prefix_evict_storm(
-            eng, waves=4, per_wave=2, gap=3, prompt_len=8, max_new=3,
+            eng, waves=4, per_wave=2, gap=2, prompt_len=8, max_new=3,
             vocab=40)
         with FaultPlan.decode_script(eng, schedule) as script:
             eng.run(timeout=300)
@@ -813,7 +890,7 @@ class TestTwoTierChaos:
         eng = self._engine(dec)
         plan = FaultPlan(seed=31)
         schedule, submitted = plan.spill_storm(
-            eng, waves=5, per_wave=2, gap=4, prompt_len=8, max_new=3,
+            eng, waves=5, per_wave=2, gap=2, prompt_len=8, max_new=3,
             vocab=40, revisit_from=2)
         with FaultPlan.decode_script(eng, schedule) as script:
             eng.run(timeout=300)
@@ -840,7 +917,7 @@ class TestTwoTierChaos:
         assert eng.stats()["kv_quant_bits"] == 8
         plan = FaultPlan(seed=32)
         schedule, submitted = plan.spill_storm(
-            eng, waves=4, per_wave=2, gap=4, prompt_len=8, max_new=3,
+            eng, waves=4, per_wave=2, gap=2, prompt_len=8, max_new=3,
             vocab=40, revisit_from=2)
         with FaultPlan.decode_script(eng, schedule):
             eng.run(timeout=300)
@@ -860,7 +937,7 @@ class TestTwoTierChaos:
         # revisit_from past the last wave: storm only spills, so the
         # store is populated (not drained) when the corruption lands
         schedule, submitted = plan.spill_storm(
-            eng, waves=4, per_wave=2, gap=4, prompt_len=8, max_new=3,
+            eng, waves=4, per_wave=2, gap=2, prompt_len=8, max_new=3,
             vocab=40, revisit_from=4)
         with FaultPlan.decode_script(eng, schedule):
             eng.run(timeout=300)
@@ -912,7 +989,7 @@ class TestTwoTierChaos:
         eng = self._engine(dec)
         plan = FaultPlan(seed=34)
         schedule, submitted = plan.spill_storm(
-            eng, waves=4, per_wave=2, gap=4, prompt_len=8, max_new=3,
+            eng, waves=4, per_wave=2, gap=2, prompt_len=8, max_new=3,
             vocab=40, revisit_from=4)
         with FaultPlan.decode_script(eng, schedule):
             with FaultPlan.kill_during_spill(eng, at=0, stage=stage) \

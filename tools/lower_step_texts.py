@@ -17,6 +17,10 @@ and 2), its ``generate`` and page read; at toy size the default block
 (MHA; GQA tied; capacity-routed experts drop-free and at a factor): the
 paged step (gather and kernel, fp and int8, W 1 and 3, sampled),
 ``DraftDecoder._step``, ``generate`` greedy and sampled, both beams.
+Where the tree's ``PagedDecoder`` has prefill lanes (PR 38), the LANE
+program of each deployment-shape and tiny step is lowered beside the plain
+one, as ``<name>_lanes``: a tree without them lowers the plain ones alone,
+so the plain hashes of a parent and a change still compare.
 """
 import base64
 import hashlib
@@ -94,6 +98,16 @@ def step_args(paged, S, P, W=1):
             sds((S, W), jnp.bool_), sds((2,), jnp.uint32))
 
 
+def lane_args(paged, S, P, W=1):
+    """``step_args`` with the lane program's ``lanes`` before the key, or
+    None for a tree (or a cache kind) without prefill lanes."""
+    lanes, width = getattr(paged, "lanes", (0, 0))
+    if not lanes:
+        return None
+    *args, key = step_args(paged, S, P, W)
+    return (*args, sds((lanes, 3 + width), jnp.int32), key)
+
+
 def lm(**cfg):
     registry.reset_name_counters()
     paddle.init(use_tpu=False, seed=0)
@@ -120,6 +134,10 @@ with cache.disabled():
         record(f"opt13b_step_{kvq or 'fp'}",
                lower_chip(paged._step_impl, *step_args(paged, 32, 128),
                           donate=(1, 2)))
+        if lane_args(paged, 32, 128):
+            record(f"opt13b_step_{kvq or 'fp'}_lanes",
+                   lower_chip(paged._step_impl_lanes,
+                              *lane_args(paged, 32, 128), donate=(1, 2)))
         if kvq is None:
             k_pool, v_pool = jax.eval_shape(paged.init_pools)
             page = sds((), jnp.int32)
@@ -152,6 +170,10 @@ with cache.disabled():
     record("kimik2_step_deploy",
            lower_chip(paged._step_impl, *step_args(paged, 64, 128),
                       donate=(1, 2)))
+    if lane_args(paged, 64, 128):
+        record("kimik2_step_deploy_lanes",
+               lower_chip(paged._step_impl_lanes,
+                          *lane_args(paged, 64, 128), donate=(1, 2)))
     jax.default_backend = real_backend
 
     # ---------------- (c): kimi-k2 tiny, on the CPU (gather, and the
@@ -170,6 +192,10 @@ with cache.disabled():
                                attention=att, window=W)
             record(f"kimik2_tiny_step_{att}_W{W}", paged._step.lower(
                 *step_args(paged, 4, 16, W)).as_text())
+            if lane_args(paged, 4, 16, W):
+                record(f"kimik2_tiny_step_{att}_W{W}_lanes",
+                       paged._lane_step.lower(
+                           *lane_args(paged, 4, 16, W)).as_text())
     record("kimik2_tiny_generate", tiny._build(5, 12, None).lower(
         tiny.p, sds((2, 5), jnp.int32), sds((2,), jnp.uint32)).as_text())
     paged = tiny.paged(num_slots=4, page_size=4, num_pages=80,
@@ -213,6 +239,11 @@ with cache.disabled():
                     record(f"toy_{tag}_step_{att}_{kvq or 'fp'}_W{W}",
                            paged._step.lower(
                                *step_args(paged, 3, 8, W)).as_text())
+                    if lane_args(paged, 3, 8, W):
+                        record(
+                            f"toy_{tag}_step_{att}_{kvq or 'fp'}_W{W}_lanes",
+                            paged._lane_step.lower(
+                                *lane_args(paged, 3, 8, W)).as_text())
         paged = dec.paged(num_slots=3, page_size=4, num_pages=20,
                           max_pages_per_slot=8, warm_start=False,
                           temperature=0.7)
