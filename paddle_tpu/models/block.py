@@ -5,7 +5,7 @@ table ``p`` and ONE description of the block that reads it. What a block
 computes, what it caches a token and which paged attention reads that
 cache are stated here and nowhere else: the decoders call the description
 and never ask which one it is. The protocol is informal (two descriptions,
-two cache kinds: no base class, no registry); ``pre`` is ``_<name>_``.
+three cache kinds: no base class, no registry); ``pre`` is ``_<name>_``.
 
 A description (a frozen object of pure functions over the table):
 
@@ -22,13 +22,14 @@ A description (a frozen object of pure functions over the table):
 
 A cache kind:
 
-    refuses                        {"kv_quant" | "draft" | "speculation":
-                                   why this kind cannot}
-    dense_init(block, p, pre, b, max_len)    one layer's dense caches
+    refuses                        {"kv_quant" | "draft" | "speculation" |
+                                   "spill": why this kind cannot}
+    dense_init(block, p, pre, b, max_len, i)    layer i's dense caches
     dense_layer(block, p, pre, i, x, cache, positions, pos, kv_len)
                                    -> (x, cache): generate / beam_search
     Kind(block, p, pre, n_layers=, num_slots=, window=, page_size=,
-         num_pages=, max_pages_per_slot=, kv_quant=)    the paged pools:
+         num_pages=, max_pages_per_slot=, kv_quant=)    the paged pools
+         (and ``state_snapshots=`` where the engine is given one):
       .dtype, .plan (the artifact fingerprints' facts), .init_pools(),
       .kernel_supported(), .page_payload(page) (the spill codec's shape),
       .lanes() -> (lanes, tokens a lane): the prefill group its paged read
@@ -39,11 +40,24 @@ A cache kind:
                                    the pool's layer index as an OPERAND (a
                                    traced number) beside the ``i`` that names
                                    the parameters: :func:`shared_layers`
+      .map_pages(fn, pool, *rest)  the page programs' map over a pool's
+                                   leaves (``jax.tree_util.tree_map`` where
+                                   every leaf is laid out by page)
+      .state_rows                  rows of recurrent state it keeps a slot
+                                   beside the pages, 0 for none. With rows
+                                   (:class:`StateLatentCache`): slot s's
+                                   state is row s, ``.snapshot_rows`` are the
+                                   prefix index's to give out, ``.zero_row``
+                                   is what a slot without a snapshot starts
+                                   from, and ``.map_state(fn, pool)`` maps a
+                                   row program (the engine's row copy)
 
 The paged step feeds its rows in GROUPS (``toks``, a tuple of
-:class:`PagedTokens`): the [S, W] slot windows and, in the lane program,
-[Sp, C] prefill lanes, each lane a chunk of one slot's prompt read and
-written through that slot's page-table row. ``x`` holds the rows of all
+:class:`PagedTokens`): FIRST the [S, W] slot windows, row s slot s's, and
+then, in the lane program, [Sp, C] prefill lanes, each lane a chunk of
+one slot's prompt read and written through that slot's page-table row
+(``slots`` names each row's slot; a slot's lanes lie one after the other
+in position order and all but its last are full). ``x`` holds the rows of all
 groups: one group's own [S, W, d], or every group's rows side by side as
 [1, R, d] (:func:`join_rows`, :func:`split_rows`). A ``layer`` runs what
 reads weights ONCE over ``x``, scatters the rows of every group into the
@@ -70,6 +84,10 @@ positions (YaRN) on part of each query head, a low-rank query, multi-head
 LATENT attention whose cache row is one ``[c_kv | k_rope]`` per token and
 layer (not per head), SwiGLU, leading dense layers, then sigmoid-routed
 experts of which this chip holds a share, a shared expert, an untied head.
+
+:class:`DeltaLatentBlock` is the Kimi-Linear block: that block's stream,
+experts and head over layers of two kinds by index, most of them keeping a
+recurrent state a slot (its docstring, and :class:`StateLatentCache`'s).
 
 Its precision. The weights and the cache are what the table holds
 (bfloat16 as served); the residual stream, the norms, the router and every
@@ -109,6 +127,7 @@ import numpy as np
 
 from paddle_tpu.ops import moe as moe_ops
 from paddle_tpu.ops import pallas_decode as paged_ops
+from paddle_tpu.ops import pallas_kda as kda_ops
 from paddle_tpu.ops.linear import einsum_two_terms as mm
 
 NEG_INF = -1e30
@@ -157,6 +176,7 @@ class PagedTokens(NamedTuple):
     kv_lens: jax.Array       # positions + 1
     live_lens: jax.Array     # 0 where not active: such a token attends to
     #                          nothing, an all-masked slot copies no page
+    slots: jax.Array         # [S]: the slot each row of the group is of
 
 
 def join_rows(parts):
@@ -262,8 +282,13 @@ class PerHeadCache:
     #: step for a turn's suffix and five for the longest chat prompt
     LANE_TOKENS = 64
 
+    #: rows of recurrent state a slot (none), and what the page programs
+    #: map over (every leaf of a pool is laid out by page)
+    state_rows = 0
+    map_pages = staticmethod(jax.tree_util.tree_map)
+
     @staticmethod
-    def dense_init(block, p, pre, b, max_len):
+    def dense_init(block, p, pre, b, max_len, i=0):
         _, g, dh = block.heads(p, pre)
         dtype = block.table_dtype(p, pre)
         return (jnp.zeros((b, max_len, g, dh), dtype),
@@ -456,9 +481,11 @@ class LatentCache:
     #: token and layer at 64 heads over 2k rows): 32 tokens are 0.6 ms of
     #: the chip's peak over six layers, 128 would be a quarter of the step
     LANE_TOKENS = 32
+    state_rows = 0
+    map_pages = staticmethod(jax.tree_util.tree_map)
 
     @staticmethod
-    def dense_init(block, p, pre, b, max_len):
+    def dense_init(block, p, pre, b, max_len, i=0):
         dtype = block.table_dtype(p, pre)
         return tuple(jnp.zeros((b, max_len, w), dtype)
                      for w in block.cache_widths(p, pre))
@@ -524,19 +551,25 @@ class LatentCache:
         """Every token's row scattered into the donated pool in place, the
         absorbed attention of each group over its slots' pages, the
         block's own FFN."""
+        x, pool, load = self._latent_layer(p, i, i, x, pool, toks,
+                                           use_kernel, interpret)
+        return x, pool, none, load
+
+    def _latent_layer(self, p, i, at, x, pool, toks, use_kernel, interpret):
+        """Layer ``i``'s parameters over layer ``at`` of the pool."""
         blk, pre = self.block, self.pre
         q_nope, q_rope, c_kv, k_rope = blk.qkv(p, pre, i, x,
                                                _rows(toks, "positions"))
         with jax.named_scope("latent_kv_write"):
             row = jnp.concatenate([c_kv, k_rope], axis=-1)
             row = row.reshape(-1, row.shape[-1]).astype(pool.dtype)
-            pool = pool.at[i, _rows(toks, "page_idx").reshape(-1),
+            pool = pool.at[at, _rows(toks, "page_idx").reshape(-1),
                            _rows(toks, "offs").reshape(-1)].set(
                 jnp.pad(row, ((0, 0), (0, pool.shape[-1] - row.shape[-1]))))
         with jax.named_scope("latent_attn"):
             q_lat = blk.absorb_q(p, pre, i, q_nope)
             o_lat = join_rows([paged_ops.paged_latent_attention(
-                ql, qr, pool, tok.page_tables, tok.kv_lens, layer=i,
+                ql, qr, pool, tok.page_tables, tok.kv_lens, layer=at,
                 scale=blk.softmax_scale, use_kernel=use_kernel,
                 interpret=interpret)
                 for ql, qr, tok in zip(split_rows(toks, q_lat),
@@ -545,7 +578,174 @@ class LatentCache:
         x = x + blk.project(p, pre, i, attn.reshape(x.shape[:2] + (-1,)))
         with jax.named_scope("ffn"):
             x, load = blk.ffn(p, pre, i, x, _rows(toks, "active"))
-        return x, pool, none, load
+        return x, pool, load
+
+
+class StateLatentCache(LatentCache):
+    """Two pools for a block whose layers are of two kinds by index
+    (:class:`DeltaLatentBlock`): the latent page pool of
+    :class:`LatentCache` over the block's latent layers ALONE
+    ([latent layers, n_pages, page_size, lanes]), and beside it a STATE
+    pool over its recurrent (delta-rule) layers, rows not pages:
+
+        {"S":    [state layers, rows, H, dk, dv] float32, a head's state,
+         "conv": [state layers, rows, 3 * 3*H*dk / 128, 128] float32, the
+                 last three inputs of the short convolutions, one after
+                 the other in whole lane tiles (ops/pallas_kda.py
+                 ``kda_short_conv`` reads and writes them; 3*H*dk is whole
+                 lane tiles)}
+
+    Row s < num_slots is slot s's own, from admission to finish; the
+    ``state_snapshots`` rows after them hold SNAPSHOTS (the state of all
+    layers after exactly n tokens of some sequence, n a page boundary:
+    the engine owns which); then one row that stays zero (what a slot
+    with no snapshot starts from) and one that holds nothing (where a row
+    that is fed nothing writes). The state pool is the engine's second
+    pool: the page programs pass it by (``map_pages``), ``map_state``
+    maps a row program over it.
+
+    Asks of its block what :class:`LatentCache` asks, and
+    ``state_layers``, ``state_sizes``, ``state_inputs``,
+    ``state_conv_weights``, ``state_qkv``, ``state_output``."""
+
+    LAYOUT = "latent layers,N,page,c_kv|k_rope|0 + " \
+        "state layers,rows,H,dk,dv f32 | rows,3*3*H*dk/128,128 f32"
+    refuses = {
+        "kv_quant": "kv_quant is not supported on a latent (MLA) cache "
+        "beside a recurrent state: a latent row has no heads to scale, "
+        "and the state is float32",
+        "draft": "a draft over a latent (MLA) block with a recurrent "
+        "state is not supported: DraftDecoder's caches are per-head K/V",
+        "speculation": "speculative decoding (draft / spec_k) is not "
+        "supported over a recurrent state: a rejected token of a window "
+        "would have to be rolled back out of it",
+        "spill": "kv_spill_pages is not supported over a recurrent state: "
+        "a spilled page's snapshot would have to travel with it"}
+
+    #: prompt tokens a step takes through its lanes beside the slots, for
+    #: this kind 128 (16 lanes of 8 at 32 heads), four times the latent
+    #: kind's: most of its layers read no cached rows for a prompt token
+    #: (a lane costs the state kernel 0.09 ms a layer), and at the latent
+    #: kind's 32 the lanes of `kimilinear_agent_2k` ran half full, so that
+    #: a turn's suffix QUEUED for them: time to first token 4 steps at the
+    #: median and 7-10 at the 95th percentile, which spread by 25 % over
+    #: six seeds (my chip runs, PR 39). One deployment's reading, like the
+    #: others' (PERF.md section 7)
+    LANE_TOKENS = 128
+
+    @staticmethod
+    def dense_init(block, p, pre, b, max_len, i=0):
+        if i not in block.state_layers:
+            return LatentCache.dense_init(block, p, pre, b, max_len)
+        H, dk, dv = block.state_sizes(p, pre)
+        return (jnp.zeros((b, H, dk, dv), jnp.float32),
+                jnp.zeros((b, 3, 3 * H * dk), jnp.float32))
+
+    @staticmethod
+    def dense_layer(block, p, pre, i, x, cache, positions, pos, kv_len):
+        """A state layer's dense cache is its state: (S, the conv tail);
+        the t tokens of x go through it in order."""
+        if i not in block.state_layers:
+            return LatentCache.dense_layer(block, p, pre, i, x, cache,
+                                           positions, pos, kv_len)
+        St, tail = cache
+        t = x.shape[1]
+        pre_conv, g, beta, z = block.state_inputs(p, pre, i, x)
+        ext = jnp.concatenate([tail, pre_conv], axis=1)      # [b, 3 + t, .]
+        win = jnp.stack([ext[:, j:j + t] for j in range(4)], axis=2)
+        q, k, v = block.state_qkv(kda_ops.conv_of_windows(
+            win, block.state_conv_weights(p, pre, i)))
+        with jax.named_scope("kda_state"):
+            o, St = kda_ops.recurrent_kda(St, q, k, v, g, beta)
+        x = x + block.state_output(p, pre, i, o, z)
+        return block.ffn(p, pre, i, x)[0], (St, ext[:, t:])
+
+    def __init__(self, block, p, pre, *, n_layers, num_slots, window,
+                 page_size, num_pages, max_pages_per_slot, kv_quant,
+                 state_snapshots=None):
+        #: model layer -> its layer of the latent pool, or of the state pool
+        self.latent_at = {i: j for j, i in enumerate(
+            i for i in range(n_layers) if i not in block.state_layers)}
+        self.state_at = {i: j for j, i in enumerate(
+            i for i in range(n_layers) if i in block.state_layers)}
+        super().__init__(block, p, pre, n_layers=len(self.latent_at),
+                         num_slots=num_slots, window=window,
+                         page_size=page_size, num_pages=num_pages,
+                         max_pages_per_slot=max_pages_per_slot,
+                         kv_quant=kv_quant)
+        n = num_slots if state_snapshots is None else int(state_snapshots)
+        #: the pool's rows: slots, snapshots, the zero row, the junk row
+        self.snapshot_rows = range(num_slots, num_slots + n)
+        self.zero_row, self.junk_row = num_slots + n, num_slots + n + 1
+        self.state_rows = num_slots + n + 2
+        H, dk, dv = block.state_sizes(p, pre)
+        self.state_shapes = {
+            "S": (len(self.state_at), self.state_rows, H, dk, dv),
+            "conv": (len(self.state_at), self.state_rows,
+                     9 * H * dk // 128, 128)}
+        assert 3 * H * dk % 128 == 0, (H, dk)
+        self.state_kernel = kda_ops.state_kernel_supported(H, dk, dv)
+        self.plan = dict(self.plan, state_rows=self.state_rows,
+                         state_layers=tuple(self.state_at))
+
+    def init_pools(self):
+        return jnp.zeros(self.shape, self.dtype), {
+            k: jnp.zeros(v, jnp.float32)
+            for k, v in self.state_shapes.items()}
+
+    @staticmethod
+    def _is_state(pool) -> bool:
+        return isinstance(pool, dict) and "S" in pool
+
+    def map_pages(self, fn, pool, *rest):
+        """The page programs' map: the state pool has no pages and passes
+        as it is."""
+        if self._is_state(pool):
+            return pool
+        return jax.tree_util.tree_map(fn, pool, *rest)
+
+    def map_state(self, fn, pool):
+        """A row program's map: over the state pool's leaves alone."""
+        if self._is_state(pool):
+            return jax.tree_util.tree_map(fn, pool)
+        return pool
+
+    def layer(self, p, i, x, pool, state, toks, *, use_kernel, interpret):
+        if i in self.latent_at:
+            x, pool, load = self._latent_layer(
+                p, i, self.latent_at[i], x, pool, toks, use_kernel, interpret)
+            return x, pool, state, load
+        blk, pre, at = self.block, self.pre, self.state_at[i]
+        pre_conv, g, beta, z = blk.state_inputs(p, pre, i, x)
+        outs = []
+        for n, (tok, pc, g_, b_) in enumerate(zip(
+                toks, split_rows(toks, pre_conv), split_rows(toks, g),
+                split_rows(toks, beta))):
+            slots = tok.slots
+            fed = jnp.sum(tok.active, axis=1, dtype=jnp.int32)
+            # the first group is the slots' own: every row of it starts
+            # from the pool. A lane goes on from the lane before it where
+            # that is the same slot's and both are fed (one longer chunk)
+            first = None if n == 0 else jnp.concatenate([
+                jnp.ones((1,), jnp.bool_),
+                (slots[1:] != slots[:-1]) | (fed[1:] == 0) | (fed[:-1] == 0)])
+            kernel = dict(use_kernel=use_kernel and self.state_kernel,
+                          interpret=interpret, junk_row=self.junk_row,
+                          layer=at)
+            with jax.named_scope("kda_conv"):
+                y, conv = kda_ops.kda_short_conv(
+                    state["conv"], pc, blk.state_conv_weights(p, pre, i),
+                    slots, first, fed, **kernel)
+                q, k, v = blk.state_qkv(y)
+            with jax.named_scope("kda_state"):
+                o, S = kda_ops.kda_state_update(
+                    state["S"], q, k, v, g_, b_, slots, first, fed, **kernel)
+                state = dict(S=S, conv=conv)
+            outs.append(o)
+        x = x + blk.state_output(p, pre, i, join_rows(outs), z)
+        with jax.named_scope("ffn"):
+            x, load = blk.ffn(p, pre, i, x, _rows(toks, "active"))
+        return x, pool, state, load
 
 
 # ----------------------------------------------------------- descriptions
@@ -662,8 +862,11 @@ class LatentBlock:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    rotary: bool = True             # False: no rotation anywhere (NoPE)
 
     cache = LatentCache
+    #: the first latent layer: where the table's latent sizes are read
+    latent0 = 0
 
     def positions(self, p, pre) -> int:
         return self.max_positions        # rotary: what the table was cut to
@@ -724,7 +927,10 @@ class LatentBlock:
     def rope(self, x, pos):
         """x [..., dr] at positions ``pos`` (x's leading dims, or those
         without the heads axis when x is [..., H, dr]); rotate-half
-        pairing (j, j + dr/2), float32 inside."""
+        pairing (j, j + dr/2), float32 inside. The lanes pass as they
+        are where the block does not rotate."""
+        if not self.rotary:
+            return x
         cos, sin = jnp.asarray(self.rope_table())[:, pos]
         if x.ndim == cos.ndim + 1:                  # a heads axis
             cos, sin = cos[..., None, :], sin[..., None, :]
@@ -737,13 +943,15 @@ class LatentBlock:
     def sizes(self, p, pre: str) -> dict:
         dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
                       self.v_head_dim)
-        rkv = p[f"{pre}l0_kv_norm.w0"].shape[0]
+        l0 = f"{pre}l{self.latent0}_"
+        rkv = p[f"{l0}kv_norm.w0"].shape[0]
         return {"dn": dn, "dr": dr, "dv": dv, "rkv": rkv,
-                "H": p[f"{pre}l0_kv_up.w0"].shape[1] // (dn + dv)}
+                "H": p[f"{l0}kv_up.w0"].shape[1] // (dn + dv)}
 
     def cache_widths(self, p, pre: str) -> tuple:
         """(c_kv lanes, k_rope lanes) of a token's cache row."""
-        return (p[f"{pre}l0_kv_norm.w0"].shape[0], self.qk_rope_head_dim)
+        return (p[f"{pre}l{self.latent0}_kv_norm.w0"].shape[0],
+                self.qk_rope_head_dim)
 
     # ------------------------------------------------------------- parts
     def embed(self, p, pre, ids, pos=None):
@@ -757,14 +965,18 @@ class LatentBlock:
     def qkv(self, p, pre, i, x, pos):
         """x [B, t, d] at positions pos [B, t] -> (q_nope [B, t, H, dn],
         q_rope [B, t, H, dr] rotated, c_kv [B, t, rkv], k_rope [B, t, dr]
-        rotated), float32: a token's cache row is the last two."""
+        rotated), float32: a token's cache row is the last two. A table
+        with no ``q_down`` has no low-rank query: ``q = h W_q``."""
         lp = f"{pre}l{i}_"
         z = self.sizes(p, pre)
         h = rms_norm(x, p[f"{lp}attn_norm.w0"], self.rms_eps)
-        c_q = rms_norm(mm("btd,dr->btr", h, p[f"{lp}q_down.w0"]),
-                       p[f"{lp}q_norm.w0"], self.rms_eps)
-        q = mm("btr,rf->btf", c_q, p[f"{lp}q_up.w0"]).reshape(
-            x.shape[:-1] + (z["H"], z["dn"] + z["dr"]))
+        if f"{lp}q_down.w0" in p:
+            c_q = rms_norm(mm("btd,dr->btr", h, p[f"{lp}q_down.w0"]),
+                           p[f"{lp}q_norm.w0"], self.rms_eps)
+            q = mm("btr,rf->btf", c_q, p[f"{lp}q_up.w0"])
+        else:
+            q = mm("btd,df->btf", h, p[f"{lp}q.w0"])
+        q = q.reshape(x.shape[:-1] + (z["H"], z["dn"] + z["dr"]))
         ckr = mm("btd,df->btf", h, p[f"{lp}kv_down.w0"])
         c_kv = rms_norm(ckr[..., :z["rkv"]], p[f"{lp}kv_norm.w0"],
                         self.rms_eps)
@@ -864,3 +1076,97 @@ class LatentBlock:
                                    p[f"{lp}shared.up"],
                                    p[f"{lp}shared.down"])
         return x + y.reshape(shape), load
+
+
+@dataclasses.dataclass(frozen=True)
+class DeltaLatentBlock(LatentBlock):
+    """The Kimi-Linear block: :class:`LatentBlock`'s residual stream,
+    norms, expert FFN and head, with layers of two kinds by index. A layer
+    in ``state_layers`` (0-based) is a Kimi Delta Attention (KDA) layer,
+    which keeps no keys and values but a recurrent state a head
+    (ops/pallas_kda.py) and the last three inputs of three short
+    convolutions; every other layer is :class:`LatentBlock`'s latent
+    attention, here with a plain query (no ``q_down`` in the table) and
+    ``rotary=False``. Precision as the latent block's: stored matrices in
+    the table's dtype, products two-term, the convolution, the gates, the
+    state and the output norm float32.
+
+    A KDA layer's table (H heads of dk = dv):
+
+        <pre>l<i>_attn_norm.w0 [d]
+        <pre>l<i>_kda_q.w0, _kda_k.w0, _kda_v.w0 [d, H*dk]
+        <pre>l<i>_kda_q_conv.w0, _kda_k_conv.w0, _kda_v_conv.w0 [4, H*dk]
+        <pre>l<i>_kda_f_a.w0 [d, r], _kda_f_b.w0 [r, H*dk]    (the decay)
+        <pre>l<i>_kda_a_log.w0 [H], _kda_dt_bias.w0 [H*dk]
+        <pre>l<i>_kda_beta.w0 [d, H]
+        <pre>l<i>_kda_g_a.w0 [d, r], _kda_g_b.w0 [r, H*dv]    (output gate)
+        <pre>l<i>_kda_o_norm.w0 [dv],  <pre>l<i>_proj.w0 [H*dv, d]"""
+
+    state_layers: tuple = ()
+    state_heads: int = 1
+
+    cache = StateLatentCache
+
+    @property
+    def latent0(self) -> int:
+        return next(i for i in range(len(self.state_layers) + 1)
+                    if i not in self.state_layers)
+
+    def state_sizes(self, p, pre) -> tuple:
+        """(H, dk, dv) of a state layer."""
+        lp = f"{pre}l{self.state_layers[0]}_"
+        H = self.state_heads
+        return (H, p[f"{lp}kda_q.w0"].shape[1] // H,
+                p[f"{lp}kda_o_norm.w0"].shape[0])
+
+    def state_inputs(self, p, pre, i, x):
+        """x [B, t, d] -> (the three convolutions' inputs side by side
+        [B, t, 3*H*dk] (q, k, v), the log-decay g [B, t, H, dk] <= 0,
+        beta [B, t, H] in (0, 1), the output gate's logits
+        [B, t, H*dv]), float32."""
+        lp = f"{pre}l{i}_"
+        H = self.state_heads
+        h = rms_norm(x, p[f"{lp}attn_norm.w0"], self.rms_eps)
+        pre_conv = jnp.concatenate(
+            [mm("btd,df->btf", h, p[f"{lp}kda_{n}.w0"]) for n in "qkv"],
+            axis=-1)
+        with jax.named_scope("kda_gates"):
+            a = mm("btr,rf->btf", mm("btd,dr->btr", h, p[f"{lp}kda_f_a.w0"]),
+                   p[f"{lp}kda_f_b.w0"])
+            a = split_heads(a + p[f"{lp}kda_dt_bias.w0"].astype(jnp.float32),
+                            H)
+            g = -jnp.exp(p[f"{lp}kda_a_log.w0"].astype(jnp.float32)
+                         )[:, None] * jax.nn.softplus(a)
+            beta = jax.nn.sigmoid(mm("btd,dh->bth", h, p[f"{lp}kda_beta.w0"]))
+            z = mm("btr,rf->btf", mm("btd,dr->btr", h, p[f"{lp}kda_g_a.w0"]),
+                   p[f"{lp}kda_g_b.w0"])
+        return pre_conv, g, beta, z
+
+    def state_conv_weights(self, p, pre, i):
+        """The three depthwise kernels side by side, [4, 3*H*dk]: a
+        token's convolution is ``silu(sum_j w[j] * input three back + j)``."""
+        lp = f"{pre}l{i}_"
+        return jnp.concatenate([p[f"{lp}kda_{n}_conv.w0"] for n in "qkv"],
+                               axis=-1).astype(jnp.float32)
+
+    def state_qkv(self, y):
+        """The convolutions' outputs y [B, t, 3*H*dk] -> q, k, v
+        [B, t, H, dk]: q and k to unit length a head, q scaled by
+        dk^-0.5."""
+        q, k, v = (split_heads(a, self.state_heads)
+                   for a in jnp.split(y, 3, axis=-1))
+
+        def unit(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+        return unit(q) * q.shape[-1] ** -0.5, unit(k), v
+
+    def state_output(self, p, pre, i, o, z):
+        """o [B, t, H, dv], gate logits z [B, t, H*dv] ->
+        (RMSNorm(o) * sigmoid(z)) W_o, [B, t, d]."""
+        lp = f"{pre}l{i}_"
+        y = rms_norm(o, p[f"{lp}kda_o_norm.w0"], self.rms_eps) * \
+            jax.nn.sigmoid(split_heads(z, o.shape[-2]))
+        return mm("btf,fd->btd", y.reshape(y.shape[:2] + (-1,)),
+                  p[f"{lp}proj.w0"])

@@ -90,8 +90,8 @@ class TransformerDecoder:
         """The fixed-size caches and the one batched causal pass over the
         prompt. -> (logits [b, plen, V], caches)."""
         blk, b = self.block, prompt.shape[0]
-        caches = [blk.cache.dense_init(blk, p, self._pre, b, max_len)
-                  for _ in range(self.n_layers)]
+        caches = [blk.cache.dense_init(blk, p, self._pre, b, max_len, i)
+                  for i in range(self.n_layers)]
         pos = jnp.arange(plen)[None, :].repeat(b, 0)
         return self._forward(p, prompt, pos, caches, 0, plen)
 
@@ -417,7 +417,8 @@ class PagedDecoder:
                  page_size: int, num_pages: int, max_pages_per_slot: int,
                  temperature: Optional[float] = None, window: int = 1,
                  attention: str = "auto", warm_start: bool = True,
-                 kv_quant: Optional[str] = None):
+                 kv_quant: Optional[str] = None,
+                 state_snapshots: Optional[int] = None):
         assert num_pages >= 2, "need at least the null page + one real"
         assert max_pages_per_slot * page_size <= dense.max_positions, (
             "slot capacity exceeds the position table — positions past "
@@ -444,7 +445,10 @@ class PagedDecoder:
             dense.block, dense.p, dense._pre, n_layers=dense.n_layers,
             num_slots=self.num_slots, window=self.window,
             page_size=self.page_size, num_pages=self.num_pages,
-            max_pages_per_slot=self.max_pages_per_slot, kv_quant=kv_quant)
+            max_pages_per_slot=self.max_pages_per_slot, kv_quant=kv_quant,
+            # a cache kind with a state a slot takes its snapshot rows
+            **({} if state_snapshots is None
+               else {"state_snapshots": state_snapshots}))
         self.dtype = self.cache.dtype
         on_tpu = jax.default_backend() == "tpu"
         self.use_kernel = attention == "kernel" or (
@@ -463,6 +467,7 @@ class PagedDecoder:
         self._step = jax.jit(self._step_impl, **donate)
         self._lane_step = jax.jit(self._step_impl_lanes, **donate)
         self._copy = jax.jit(self._copy_page_impl, **pools)
+        self._copy_state = jax.jit(self._copy_state_impl, **pools)
         self._read = jax.jit(self._read_page_impl)
         self._write = jax.jit(self._write_page_impl, **pools)
         # warm-start plane (paddle_tpu/artifacts): the jitted functions
@@ -486,11 +491,11 @@ class PagedDecoder:
                                                   lanes=self.lanes))
         page_plan = dict(what, n_layers=dense.n_layers,
                          dtype=str(jnp.dtype(self.dtype)))
-        self._copy_fp, self._read_fp, self._write_fp = (
+        self._copy_fp, self._read_fp, self._write_fp, self._copy_state_fp = (
             fingerprint(f"paged_{which}", dense.p, plan=page_plan)
-            for which in ("copy", "read", "write"))
+            for which in ("copy", "read", "write", "copy_state"))
         self._step_exe = self._lane_step_exe = self._copy_exe = None
-        self._read_exe = self._write_exe = None
+        self._read_exe = self._write_exe = self._copy_state_exe = None
 
     def init_pools(self):
         """Zeroed (k_pool, v_pool) as the cache kind stores them."""
@@ -500,7 +505,7 @@ class PagedDecoder:
         return sum(x.size * x.dtype.itemsize for x in jax.tree_util
                    .tree_leaves(jax.eval_shape(self.init_pools)))
 
-    def _group(self, positions, active, page_tables) -> PagedTokens:
+    def _group(self, positions, active, page_tables, slots) -> PagedTokens:
         """One group's tokens as the cache kind reads them."""
         ps = self.page_size
         page_idx = jnp.take_along_axis(
@@ -509,7 +514,7 @@ class PagedDecoder:
         offs = jnp.where(active, positions % ps, 0)
         kv_lens = positions + 1
         return PagedTokens(positions, active, page_idx, offs, page_tables,
-                           kv_lens, jnp.where(active, kv_lens, 0))
+                           kv_lens, jnp.where(active, kv_lens, 0), slots)
 
     def _step_impl(self, p, k_pool, v_pool, tokens, positions,
                    page_tables, active, key, lanes=None):
@@ -527,13 +532,14 @@ class PagedDecoder:
         row by row, then each lane's choice after its LAST fed token."""
         d0 = self.dense
         blk, pre = d0.block, d0._pre
-        groups = [(tokens, positions, active, page_tables)]
+        groups = [(tokens, positions, active, page_tables,
+                   jnp.arange(tokens.shape[0], dtype=jnp.int32))]
         if lanes is not None:
             col = jnp.arange(lanes.shape[1] - 3)[None, :]
             fed = col < lanes[:, 2:3]
             groups.append((lanes[:, 3:],
                            jnp.where(fed, lanes[:, 1:2] + col, 0), fed,
-                           page_tables[lanes[:, 0]]))
+                           page_tables[lanes[:, 0]], lanes[:, 0]))
         with jax.named_scope("embed"):
             x = blk.embed(p, pre, join_rows([g[0] for g in groups]),
                           join_rows([g[1] for g in groups]))   # [S, W, d]
@@ -591,30 +597,42 @@ class PagedDecoder:
             leaf, data.reshape((leaf.shape[0], 1) + leaf.shape[2:])
             .astype(leaf.dtype), start)
 
+    def _copy_rows(self, mapped, k_pool, v_pool, src, dst):
+        """Index ``src`` of axis 1 -> index ``dst``, all layers, in every
+        leaf that ``mapped`` (a map of the cache kind's) reaches."""
+        def cp(pool):
+            return mapped(lambda leaf: self._page_update(
+                leaf, self._page_slice(leaf, src), dst), pool)
+
+        return cp(k_pool), cp(v_pool)
+
     def _copy_page_impl(self, k_pool, v_pool, src, dst):
         """Device-side page copy (all layers) — the copy-on-write step
         behind partial-page prefix reuse (serving/prefix.py). src/dst
         are TRACED scalars: every pair shares ONE compilation. Mapped
         over the pool pytree: the int8 layout copies values AND scales."""
-        def cp(pool):
-            return jax.tree_util.tree_map(
-                lambda leaf: self._page_update(
-                    leaf, self._page_slice(leaf, src), dst), pool)
+        return self._copy_rows(self.cache.map_pages, k_pool, v_pool, src,
+                               dst)
 
-        return cp(k_pool), cp(v_pool)
+    def _copy_state_impl(self, k_pool, v_pool, src, dst):
+        """Row ``src`` of a cache kind's state pool -> row ``dst``, all
+        layers, in place (a snapshot taken or given back; a slot's row
+        zeroed from the kind's zero row). Traced rows: ONE compilation."""
+        return self._copy_rows(self.cache.map_state, k_pool, v_pool, src,
+                               dst)
 
     def _read_page_impl(self, k_pool, v_pool, page):
         """Device -> host leg of page spill (serving/spill.py): one
         physical page of both pools in the cache kind's payload shape.
         ``page`` is a traced scalar: one compilation covers every spill."""
-        rd = lambda pool: self.cache.page_payload(jax.tree_util.tree_map(
+        rd = lambda pool: self.cache.page_payload(self.cache.map_pages(
             lambda leaf: self._page_slice(leaf, page), pool))
         return rd(k_pool), rd(v_pool)
 
     def _write_page_impl(self, k_pool, v_pool, k_page, v_page, page):
         """Host -> device leg of page restore: the inverse of
         :meth:`_read_page_impl`."""
-        wr = lambda pool, data: jax.tree_util.tree_map(
+        wr = lambda pool, data: self.cache.map_pages(
             lambda leaf, d: self._page_update(leaf, d, page),
             pool, data)
         return wr(k_pool, k_page), wr(v_pool, v_page)
@@ -623,6 +641,15 @@ class PagedDecoder:
         """Copy physical page ``src`` -> ``dst`` in both pools."""
         return _run(self, "copy", k_pool, v_pool, jnp.int32(src),
                     jnp.int32(dst))
+
+    def copy_state(self, k_pool, v_pool, src: int, dst: int):
+        """Copy state row ``src`` -> ``dst`` (a cache kind with
+        ``state_rows``)."""
+        import numpy as np
+        # numpy scalars: a jnp scalar is a device array made by a jitted
+        # convert, half a millisecond of host time each (my chip run, PR 39)
+        return _run(self, "copy_state", k_pool, v_pool, np.int32(src),
+                    np.int32(dst))
 
     def read_page(self, k_pool, v_pool, page: int):
         """One physical page of both pools as [L, 1, ...] pytrees —

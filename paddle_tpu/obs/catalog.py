@@ -155,6 +155,7 @@ _JOURNAL_DECLS = (
     _j("engine", "prefix_evict", ("pages", "free_pages",
                                   "engine_step")),
     _j("engine", "cow_copy_failure", ("error",), ("trace_id",)),
+    _j("engine", "state_copy_failure", ("error",), ("trace_id",)),
     _j("engine", "draft_failure", ("error", "engine_step")),
     _j("engine", "step_failure", ("error", "engine_step"),
        ("trace_ids", "waiting_trace_ids")),

@@ -390,10 +390,13 @@ class DecodeEngine:
                  attention: str = "auto",
                  warm_start: bool = True,
                  kv_quant: Optional[str] = None,
-                 kv_spill_pages: int = 0):
+                 kv_spill_pages: int = 0,
+                 state_snapshots: Optional[int] = None):
         pos_rows = decoder.max_positions
         if draft is not None and spec_k:
             decoder.require("speculation")
+        if kv_spill_pages:
+            decoder.require("spill")
         if max_seq_len is None:
             max_seq_len = pos_rows
         self.max_seq_len = min(int(max_seq_len), pos_rows)
@@ -417,7 +420,8 @@ class DecodeEngine:
             num_pages=int(num_pages),
             max_pages_per_slot=pages_per_slot, temperature=temperature,
             window=self.window, attention=attention,
-            warm_start=self.warm_start, kv_quant=kv_quant)
+            warm_start=self.warm_start, kv_quant=kv_quant,
+            state_snapshots=state_snapshots)
         if kv_quant is not None and not self.paged.use_kernel:
             # int8 pages without the dequant-fused kernel: attention
             # reads through the dequantizing gather (exact einsum) —
@@ -432,9 +436,16 @@ class DecodeEngine:
         # one pool and an empty pytree) is only ever handed back to its
         # step and its page copy / read / write
         self.k_pool, self.v_pool = self.paged.init_pools()
+        # a cache kind that keeps a recurrent state a slot beside its
+        # pages (``state_rows``): slot s's state is row s of its state
+        # pool, the kind's snapshot rows are the prefix index's to give
+        # out, and every state_* step below is skipped for a kind without
+        self._state = self.paged.cache if self.paged.cache.state_rows \
+            else None
         self.prefix: Optional[PrefixIndex] = (
-            PrefixIndex(self.pool, self.page_size) if prefix_cache
-            else None)
+            PrefixIndex(self.pool, self.page_size,
+                        self._state.snapshot_rows if self._state else ())
+            if prefix_cache else None)
         if kv_spill_pages and not prefix_cache:
             raise ValueError(
                 "kv_spill_pages needs the prefix cache: spilled pages "
@@ -516,6 +527,20 @@ class DecodeEngine:
                           "expert_assignments_held": 0,
                           "expert_hits_held": 0,
                           "expert_layer_steps": 0,
+                          # a cache kind with a recurrent state a slot:
+                          # slot-steps that read and wrote a state (a
+                          # slot fed by several lanes counted once a
+                          # step), snapshots copied out of a slot's row
+                          # (those a node gave up again are the prefix
+                          # index's count, ``state_snapshots_evicted`` in
+                          # stats()), tokens a
+                          # snapshot let an admission skip, and tokens
+                          # the page trie matched that were fed again
+                          # for want of a snapshot (all 0 without)
+                          "state_rows_stepped": 0,
+                          "state_snapshots_taken": 0,
+                          "snapshot_attach_tokens": 0,
+                          "snapshot_miss_tokens": 0,
                           # host nanoseconds by phase of step()/_loop:
                           # each is written by _phase() with the span of
                           # the same boundary (PERF.md section 3)
@@ -682,6 +707,41 @@ class DecodeEngine:
         seq = slot.req.prompt + slot.req.tokens
         self.prefix.insert(seq[:slot.pos], slot.pages)
 
+    def _copy_state(self, src: int, dst: int) -> None:
+        """State row ``src`` -> ``dst`` on the device (a snapshot taken
+        or given to a slot, a slot's row zeroed)."""
+        with self._phase("serving/state_copy"):
+            self.k_pool, self.v_pool = self.paged.copy_state(
+                self.k_pool, self.v_pool, src, dst)
+
+    def _snapshot(self, s: int, slot: _Slot) -> None:
+        """Slot ``s`` stands on a page boundary: leave its full pages in
+        the prefix index now and, where the node of the last has no
+        snapshot yet, a copy of its state row as that node's. A boundary
+        is snapshotted where a replay's last one is reached (the planner
+        cuts the chunk there) and where a request ends or is preempted on
+        one: a slot that decodes past boundaries leaves none behind
+        (a copy every page_size tokens a slot)."""
+        if self._state is None or self.prefix is None or not slot.pos \
+                or slot.pos % self.page_size:
+            return
+        seq = (slot.req.prompt + slot.req.tokens)[:slot.pos]
+        self.prefix.insert(seq, slot.pages)
+        if self.prefix.has_snapshot(seq) is not False:
+            return                      # one is there, or no such node
+        row = self.prefix.take_snapshot_row()
+        if row is None:
+            return
+        try:
+            self._copy_state(s, row)
+        except Exception as e:          # pools rebuilt on next dispatch
+            self.prefix.free_snapshot_row(row)
+            journal_emit("engine", "state_copy_failure",
+                         error=repr(e)[:200], trace_id=slot.req.trace_id)
+            return
+        if self.prefix.set_snapshot(seq, row):
+            self._counters["state_snapshots_taken"] += 1
+
     def _finish(self, s: int, state: str,
                 error: Optional[ServingError] = None) -> None:
         """Release slot ``s``: pages to the prefix index then back to
@@ -692,6 +752,7 @@ class DecodeEngine:
             # failed/closed slots may hold garbage KV (step failure) —
             # never index those pages
             self._index_slot_pages(slot)
+            self._snapshot(s, slot)
         self.pool.free(slot.pages)
         slot.pages = []
         self._tables[s, :] = 0
@@ -727,6 +788,7 @@ class DecodeEngine:
         replays them on re-admission — greedy output is unchanged)."""
         slot = self.slots[s]
         self._index_slot_pages(slot)
+        self._snapshot(s, slot)
         self.pool.free(slot.pages)
         slot.pages = []
         self._tables[s, :] = 0
@@ -1025,6 +1087,10 @@ class DecodeEngine:
                     self._restore_spilled(replay)
                 match = self.prefix.match(replay) \
                     if self.prefix is not None else None
+                trie_matched = match.matched if match is not None else 0
+                if match is not None and self._state is not None:
+                    # worth only as deep as a snapshot of the state exists
+                    match = match.cut_to_snapshot(self.page_size)
                 shared = len(match.pages) if match is not None else 0
                 need_now = self._pages_for(len(replay) + 1) - shared
                 avail = self.pool.free_pages
@@ -1046,6 +1112,15 @@ class DecodeEngine:
                 if match is not None and \
                         (match.pages or match.cow is not None):
                     self._attach_prefix(s, slot, match)
+                if self._state is not None:
+                    # the slot's row starts from the snapshot at its match
+                    # or from zero, never from the row's last tenant
+                    self._copy_state(
+                        match.snapshots[-1] if match is not None
+                        and match.pages else self._state.zero_row, s)
+                    c = self._counters
+                    c["snapshot_attach_tokens"] += slot.pos
+                    c["snapshot_miss_tokens"] += trie_matched - slot.pos
                 FLIGHT.record("mark", "engine/admit",
                               trace_id=req.trace_id, slot=s,
                               replay=len(replay),
@@ -1255,6 +1330,12 @@ class DecodeEngine:
                 break
             slot = self.slots[s]
             left = len(slot.replay) - slot.pos
+            if self._state is not None:
+                # a chunk ends ON the replay's last page boundary, where
+                # the state is snapshotted, before it goes past it
+                last = len(slot.replay) // self.page_size * self.page_size
+                if slot.pos < last:
+                    left = last - slot.pos
             lane_of[s] = range(taken, min(n_lanes, taken + -(-left // width)))
             taken = lane_of[s].stop
             plan[s] = slot.replay[
@@ -1300,6 +1381,8 @@ class DecodeEngine:
             self._active_steps_sum += len(live)
             self._counters["prefill_lane_steps"] += bool(lane_of)
             self._counters["tokens_fed"] += sum(len(plan[s]) for s in live)
+            if self._state is not None:
+                self._counters["state_rows_stepped"] += len(live)
         if PROFILER.enabled:
             PROFILER.on_step("decode")
         for s in live:
@@ -1332,6 +1415,9 @@ class DecodeEngine:
                         c["prefill_lane_tokens"] += n_prefill
                         c["prefill_lane_cache_tokens_read"] += cache_read
                 slot.pos = fed + w
+                if slot.pos == len(slot.replay) // self.page_size \
+                        * self.page_size:
+                    self._snapshot(s, slot)
                 if fed + w == len(slot.replay):
                     commits = [int(lane_nxt[lane_of[s][-1]]) if s in lane_of
                                else int(nxt[s, w - 1])]
@@ -1479,6 +1565,8 @@ class DecodeEngine:
         if self.draft is not None:
             _, self._draft_kc, self._draft_vc = self.draft.step(
                 self._draft_kc, self._draft_vc, z, z, inactive)
+        if self._state is not None:     # the row copy: zero row onto itself
+            self._copy_state(self._state.zero_row, self._state.zero_row)
         return dict(EXECUTABLES.stats(), warm_start=self.warm_start)
 
     def start(self) -> "DecodeEngine":
@@ -1587,6 +1675,12 @@ class DecodeEngine:
             len(s.pages) for s in self.slots if s is not None)
         acc["held_by_trie"] = self.prefix.page_count() \
             if self.prefix is not None else 0
+        if self._state is not None and self.prefix is not None:
+            # snapshot rows are a pool of their own: one that neither the
+            # free rows nor a node holds is leaked like a page
+            snap = self.prefix.snapshot_accounting()
+            acc["leaked"] += snap.pop("snapshot_rows_leaked")
+            acc.update(snap)
         if self.spill is not None:
             acc.update(self.spill.accounting())
             acc["spill_cleared"] = self._counters["kv_spill_cleared"]
@@ -1612,8 +1706,12 @@ class DecodeEngine:
         spilled_now = len(self.spill) if self.spill is not None else 0
         spill_cap = self.spill.capacity if self.spill is not None else 0
         _SPILLED_NOW.set(spilled_now)
+        snaps = self.prefix.snapshot_accounting()["snapshot_rows_held"] \
+            if self.prefix is not None else 0
         out = dict(counters)
         out.update({
+            "state_snapshots_evicted": self.prefix.snapshots_dropped
+            if self.prefix is not None else 0,
             "slots": self.num_slots,
             "active_slots": active,
             "waiting": waiting,
@@ -1654,6 +1752,9 @@ class DecodeEngine:
             "steps": steps,
             "active_slot_steps": active_sum,
             "cache_tokens_read": cache_read,
+            # state rows in use: the occupied slots' and the snapshots'
+            "state_rows_live": active + snaps
+            if self._state is not None else 0,
             "token_latency_p50_ms":
                 round(self._percentile(lat, 0.50) * 1e3, 3),
             "token_latency_p99_ms":

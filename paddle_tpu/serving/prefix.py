@@ -38,10 +38,29 @@ Under pool pressure the engine reclaims least-recently-used LEAF nodes
 happens on the engine's stepping thread, but stats()/flight providers
 read from arbitrary threads. Lock order is engine -> prefix -> pagepool
 (never the reverse), witnessed by the autouse lockdep fixture.
+
+STATE SNAPSHOTS. A cache kind that keeps a recurrent state a slot beside
+its pages (models/block.py ``StateLatentCache``) cannot start a slot at
+position n from pages alone: it needs the state after exactly n tokens.
+A node may therefore own a SNAPSHOT: a row of the kind's state pool that
+holds the state of all layers after the tokens of the path that ends
+with this node's page (n a page boundary by construction). The index
+keeps the free snapshot rows; a row returns to them when its node is
+dropped (evicted with the trie's leaves, flushed) or when the row is
+taken for a newer snapshot (:meth:`take_snapshot_row`): first from the
+snapshots no match has used yet, least recently used first, then from
+those one has (what a turn leaves behind at its prompt's end is used
+again only if the conversation goes on; the history every turn of a
+client starts from is used every time, but last TOUCHED when its running
+turn was admitted, which under plain LRU made it the first to go:
+56 % of the matched tokens were fed again, my chip run, PR 39). A match is worth only as deep as its
+deepest snapshot (:meth:`PrefixMatch.cut_to_snapshot`). What is IN a row
+is the engine's: the index never touches the device.
 """
 
 from __future__ import annotations
 
+import bisect
 from typing import Dict, List, Optional, Tuple
 
 from paddle_tpu.analysis.lockdep import named_lock
@@ -50,7 +69,8 @@ __all__ = ["PrefixIndex", "PrefixMatch"]
 
 
 class _Node:
-    __slots__ = ("key", "page", "parent", "children", "last_used")
+    __slots__ = ("key", "page", "parent", "children", "last_used",
+                 "snapshot", "snapshot_used")
 
     def __init__(self, key, page, parent):
         self.key = key                   # page_size token tuple
@@ -58,6 +78,8 @@ class _Node:
         self.parent = parent
         self.children: Dict[tuple, "_Node"] = {}
         self.last_used = 0
+        self.snapshot: Optional[int] = None   # a state-pool row, or none
+        self.snapshot_used = False            # a match ended on it
 
 
 class PrefixMatch:
@@ -65,15 +87,28 @@ class PrefixMatch:
     (in logical order), ``matched`` counts their tokens, and ``cow``
     (when set) is ``(physical_page, rows)`` — the best partially-
     matching child page whose first ``rows`` tokens agree, a
-    copy-on-write candidate."""
+    copy-on-write candidate. ``snapshots`` runs beside ``pages``: the
+    state-pool row that holds the state after each page's last token,
+    or None."""
 
-    __slots__ = ("pages", "matched", "cow")
+    __slots__ = ("pages", "matched", "cow", "snapshots")
 
     def __init__(self, pages: List[int], matched: int,
-                 cow: Optional[Tuple[int, int]]):
+                 cow: Optional[Tuple[int, int]],
+                 snapshots: Optional[List[Optional[int]]] = None):
         self.pages = pages
         self.matched = matched
         self.cow = cow
+        self.snapshots = snapshots or [None] * len(pages)
+
+    def cut_to_snapshot(self, page_size: int) -> "PrefixMatch":
+        """The match as deep as its deepest snapshot and no deeper (no
+        copy-on-write: rows of a page are no use without the state at
+        them); no snapshot on the path is no match."""
+        deep = max((j + 1 for j, row in enumerate(self.snapshots)
+                    if row is not None), default=0)
+        return PrefixMatch(self.pages[:deep], deep * page_size, None,
+                           self.snapshots[:deep])
 
 
 class PrefixIndex:
@@ -81,9 +116,19 @@ class PrefixIndex:
     public methods take the named ``serving.prefix`` lock; the engine
     calls the mutators from its stepping thread only."""
 
-    def __init__(self, pool, page_size: int):
+    def __init__(self, pool, page_size: int, snapshot_rows=()):
         self.pool = pool
         self.page_size = int(page_size)
+        #: the state pool's snapshot rows, and those no node owns
+        # leaves an eviction may take, coldest LAST, as one walk found
+        # them: [(last_used then, node)]. Each eviction used to walk the
+        # whole trie for its one victim: with every page of a 12,288-page
+        # pool held by the trie that was 10 ms of host time a step (my
+        # chip run, PR 39); now a walk serves evictions until it runs dry
+        self._cold: list = []          # ptlint: guarded-by(serving.prefix)
+        self.snapshot_rows = tuple(snapshot_rows)
+        self._snapshot_free = list(reversed(self.snapshot_rows))  # ptlint: guarded-by(serving.prefix)
+        self.snapshots_dropped = 0
         self._lock = named_lock("serving.prefix")
         # the radix trie + LRU clock  # ptlint: guarded-by(serving.prefix)
         self._root = _Node(None, None, None)
@@ -105,9 +150,11 @@ class PrefixIndex:
         toks = [int(t) for t in tokens]
         limit = len(toks) - 1
         pages: List[int] = []
+        snaps: List[Optional[int]] = []
         cow = None
         with self._lock:
             node = self._root
+            deepest = None
             i = 0
             while i + ps <= limit:
                 child = node.children.get(tuple(toks[i:i + ps]))
@@ -116,8 +163,13 @@ class PrefixIndex:
                 self._seq += 1
                 child.last_used = self._seq
                 pages.append(child.page)
+                snaps.append(child.snapshot)
+                if child.snapshot is not None:
+                    deepest = child
                 node = child
                 i += ps
+            if deepest is not None:     # the one a state kind starts from
+                deepest.snapshot_used = True
             # partial-page (copy-on-write) candidate: the child sharing
             # the longest leading token run inside the next page
             best = 0
@@ -135,7 +187,7 @@ class PrefixIndex:
             if best_child is not None:
                 self._seq += 1
                 best_child.last_used = self._seq
-        return PrefixMatch(pages, i, cow)
+        return PrefixMatch(pages, i, cow, snaps)
 
     # -------------------------------------------------------------- insert
     def insert(self, tokens, pages: List[int]) -> int:
@@ -176,31 +228,143 @@ class PrefixIndex:
         freed: List[int] = []
         with self._lock:
             while len(freed) < n:
-                victim = None
-                refs = self.pool.refcounts()
-                stack = [self._root]
-                while stack:
-                    nd = stack.pop()
-                    if nd.page is not None and not nd.children and \
-                            refs.get(nd.page) == 1:
-                        if victim is None or \
-                                nd.last_used < victim.last_used:
-                            victim = nd
-                    stack.extend(nd.children.values())
+                victim = self._coldest_locked()
                 if victim is None:
                     break
+                parent = victim.parent
                 self._drop_locked(victim)
                 freed.append(victim.page)
+                if parent.page is not None and not parent.children and \
+                        self.pool.refcount(parent.page) == 1:
+                    # a leaf now: among the cold ones by its own age
+                    bisect.insort(self._cold, (parent.last_used, parent),
+                                  key=lambda e: -e[0])
             self.evicted_pages += len(freed)
         return freed
 
+    def _coldest_locked(self) -> Optional[_Node]:
+        """The least recently used leaf only the trie holds, or None. It
+        comes off the list the last walk left; an entry that has since
+        been touched, taken by a slot, given children or dropped is
+        passed over (it is found again, at its new age, by the next
+        walk); a walk is made when the list runs dry. A page that only
+        became cold after the walk was touched after it, so everything
+        on the list is older."""
+        for walked in (False, True):
+            while self._cold:
+                stamp, nd = self._cold.pop()
+                if nd.last_used == stamp and not nd.children and \
+                        nd.parent.children.get(nd.key) is nd and \
+                        self.pool.refcount(nd.page) == 1:
+                    return nd
+            if walked:
+                return None
+            refs = self.pool.refcounts()
+            stack = [self._root]
+            while stack:
+                nd = stack.pop()
+                if nd.page is not None and not nd.children and \
+                        refs.get(nd.page) == 1:
+                    self._cold.append((nd.last_used, nd))
+                stack.extend(nd.children.values())
+            self._cold.sort(key=lambda e: -e[0])
+        return None
+
     def _drop_locked(self, node: _Node) -> None:
-        """Unlink a leaf and give its page's ref back to the pool (the
-        caller holds the lock)."""
+        """Unlink a leaf and give its page's ref back to the pool, its
+        snapshot row back to the free rows (the caller holds the lock)."""
         del node.parent.children[node.key]
         self._nodes -= 1
+        self._free_snapshot_locked(node)
         self.pool.unindex(node.page)
         self.pool.free([node.page])
+
+    # ----------------------------------------------------------- snapshots
+    def _free_snapshot_locked(self, node: _Node) -> None:
+        if node.snapshot is not None:
+            self._snapshot_free.append(node.snapshot)
+            node.snapshot, node.snapshot_used = None, False
+            self.snapshots_dropped += 1
+
+    def _node_at_locked(self, tokens) -> Optional[_Node]:
+        """The node of the page that ends at ``len(tokens)`` (a whole
+        number of pages) on that token path, or None."""
+        ps = self.page_size
+        toks = [int(t) for t in tokens]
+        if not toks or len(toks) % ps:
+            return None
+        node = self._root
+        for i in range(0, len(toks), ps):
+            node = node.children.get(tuple(toks[i:i + ps]))
+            if node is None:
+                return None
+        return node
+
+    def has_snapshot(self, tokens) -> Optional[bool]:
+        """Does the node at ``tokens`` own a snapshot (None: no such
+        node). A node that does is touched."""
+        with self._lock:
+            node = self._node_at_locked(tokens)
+            if node is None:
+                return None
+            if node.snapshot is not None:
+                self._seq += 1
+                node.last_used = self._seq
+            return node.snapshot is not None
+
+    def take_snapshot_row(self) -> Optional[int]:
+        """A state-pool row for a new snapshot: a free one, else a
+        node's (whose pages stay: a match through it is cut back from now
+        on): the least recently used of those no match has ended on, or
+        failing those of all. None where the kind keeps none."""
+        with self._lock:
+            if self._snapshot_free:
+                return self._snapshot_free.pop()
+            victim = None
+            stack = [self._root]
+            while stack:
+                nd = stack.pop()
+                if nd.snapshot is not None and (
+                        victim is None
+                        or (nd.snapshot_used, nd.last_used)
+                        < (victim.snapshot_used, victim.last_used)):
+                    victim = nd
+                stack.extend(nd.children.values())
+            if victim is None:
+                return None
+            self._free_snapshot_locked(victim)
+            return self._snapshot_free.pop()
+
+    def set_snapshot(self, tokens, row: int) -> bool:
+        """The node at ``tokens`` owns ``row`` from now on (the engine
+        has copied the state there). False, and the row free again, where
+        the node is gone or has one."""
+        with self._lock:
+            node = self._node_at_locked(tokens)
+            if node is None or node.snapshot is not None:
+                self._snapshot_free.append(row)
+                return False
+            node.snapshot = row
+            return True
+
+    def free_snapshot_row(self, row: int) -> None:
+        """A row from :meth:`take_snapshot_row` that no node came to own."""
+        with self._lock:
+            self._snapshot_free.append(row)
+
+    def snapshot_accounting(self) -> dict:
+        """Rows by where they are; ``leaked`` is what neither the free
+        rows nor a node holds (0 always)."""
+        with self._lock:
+            held, stack = 0, [self._root]
+            while stack:
+                nd = stack.pop()
+                held += nd.snapshot is not None
+                stack.extend(nd.children.values())
+            total, free = len(self.snapshot_rows), len(self._snapshot_free)
+        return {"snapshot_rows_total": total, "snapshot_rows_free": free,
+                "snapshot_rows_held": held,
+                "snapshot_rows_leaked": total - free - held}
 
     # --------------------------------------------------------------- spill
     @staticmethod
@@ -244,17 +408,10 @@ class PrefixIndex:
         returns the freed page, or None if the node changed since
         :meth:`spill_candidates` picked it (grew children, gained a
         slot ref, vanished) — the caller then simply skips the spill."""
-        ps = self.page_size
-        path = tuple(int(t) for t in path)
-        if not path or len(path) % ps != 0:
-            return None
         with self._lock:
-            node = self._root
-            for i in range(0, len(path), ps):
-                node = node.children.get(path[i:i + ps])
-                if node is None:
-                    return None
-            if node.children or self.pool.refcount(node.page) != 1:
+            node = self._node_at_locked(path)
+            if node is None or node.children or \
+                    self.pool.refcount(node.page) != 1:
                 return None
             self._drop_locked(node)
             self.evicted_pages += 1
@@ -278,6 +435,8 @@ class PrefixIndex:
             n = self._nodes
             self._root = _Node(None, None, None)
             self._nodes = 0
+            self._cold = []
+            self._snapshot_free = list(reversed(self.snapshot_rows))
         return n
 
     def reset(self) -> None:
@@ -287,6 +446,8 @@ class PrefixIndex:
         with self._lock:
             self._root = _Node(None, None, None)
             self._nodes = 0
+            self._cold = []
+            self._snapshot_free = list(reversed(self.snapshot_rows))
 
     def _collect_pages(self) -> List[int]:
         pages = []

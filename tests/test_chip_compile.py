@@ -531,3 +531,109 @@ def test_latent_lane_step_updates_the_pool_in_place(chip_executable,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == paged.pool_bytes()
     assert mem.temp_size_in_bytes < paged.pool_bytes() // 8, mem
+
+
+# ------------------------------------------- recurrent state beside the pages
+@pytest.mark.parametrize("B,C", [(128, 1), (4, 8)], ids=["slots", "lanes"])
+def test_state_kernel_lowers(chip_executable, B, C):
+    """``kda_state_update`` at the published widths (32 heads of
+    [128, 128] float32) over a pool of 322 rows: the 128 slots' one token,
+    and four lanes of 8. The pool is written in place: all its bytes
+    aliased, no temporary of its size."""
+    from paddle_tpu.ops import pallas_kda as kk
+    L, R, H, d = 6, 322, 32, 128
+    assert kk.state_kernel_supported(H, d, d)
+    assert not kk.state_kernel_supported(H, 64, 64)
+
+    def fn(pool, q, k, v, g, beta, rows, first, fed):
+        return kk.kda_state_update(pool, q, k, v, g, beta, rows, first, fed,
+                                   layer=3, junk_row=R - 1, use_kernel=True)
+
+    x = _sds((B, C, H, d), jnp.float32)
+    compiled = chip_executable(
+        fn, _sds((L, R, H, d, d), jnp.float32), x, x, x, x,
+        _sds((B, C, H), jnp.float32), _sds((B,), jnp.int32),
+        _sds((B,), jnp.bool_), _sds((B,), jnp.int32), donate=(0,))
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    mem = compiled.memory_analysis()
+    pool_bytes = L * R * H * d * d * 4
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100, mem
+
+
+@pytest.fixture(scope="module")
+def kimi_linear_paged():
+    """``kimilinear_agent_2k``'s paged decoder at the published widths
+    over zero weights (only shapes are compiled): its 8 layers, 128 slots,
+    12,288 pages of 32, 192 snapshot rows."""
+    from benchmarks.lib import manifest
+    from paddle_tpu import models
+    cell = manifest.cell(manifest.load_manifest(), "kimilinear_agent_2k")
+    cfg, dep = cell["config"], cell["config"]["deployment"]
+    params = {cell["model"].program_name(k, cfg):
+              jax.ShapeDtypeStruct(v, jnp.bfloat16)
+              for k, v in cell["reference"].leaf_shapes(cfg).items()}
+    dec = models.TransformerDecoder(
+        {}, n_layers=8, n_heads=32, name=cell["model"].NAME,
+        block=cell["model"].block_of(cfg, dep["max_seq_len"]))
+    dec.p = params
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        paged = dec.paged(num_slots=dep["num_slots"],
+                          page_size=dep["page_size"],
+                          num_pages=dep["num_pages"], max_pages_per_slot=128,
+                          state_snapshots=dep["state_snapshots"],
+                          warm_start=False)
+    finally:
+        jax.default_backend = backend
+    return dec, paged
+
+
+@pytest.mark.parametrize("program", ["plain", "lanes", "copy_state"])
+def test_state_step_updates_both_pools_in_place(chip_executable,
+                                                kimi_linear_paged, program):
+    """The serving step of the cell, its lane program and the row copy of
+    a snapshot: the latent page pool over the 2 MLA layers alone and the
+    float32 state pool over the 6 KDA layers (5.3 GB together) are donated
+    and every byte of them aliased; temporaries stay under an eighth; a
+    KDA layer is two kernel calls a group of rows (its convolution with
+    the tails, its state), an MLA layer one."""
+    dec, paged = kimi_linear_paged
+    assert paged.use_kernel and not paged.kernel_interpret
+    assert paged.cache.state_kernel and paged.lanes == (16, 8)
+    k_pool, v_pool = jax.eval_shape(paged.init_pools)
+    assert k_pool.shape == (2, 12288, 32, 640)
+    assert v_pool["S"].shape == (6, 128 + 192 + 2, 32, 128, 128)
+    assert v_pool["conv"].shape == (6, 322, 9 * 32, 128)
+    if program == "copy_state":
+        row = _sds((), jnp.int32)
+        compiled = chip_executable(paged._copy_state_impl, k_pool, v_pool,
+                                   row, row, donate=(0, 1))
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == paged.pool_bytes()
+        assert mem.temp_size_in_bytes < 32 * 1024 * 1024, mem
+        return
+    sw = _sds((128, 1), jnp.int32)
+    args = (dec.p, k_pool, v_pool, sw, sw, _sds((128, 128), jnp.int32),
+            _sds((128, 1), jnp.bool_), _sds((2,), jnp.uint32))
+    if program == "lanes":
+        compiled = chip_executable(paged._step_impl_lanes,
+                                   *_lane_args(paged, args), donate=(1, 2))
+    else:
+        compiled = chip_executable(paged._step_impl, *args, donate=(1, 2))
+    groups = 2 if program == "lanes" else 1
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == groups * (2 * 6 + 2)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == paged.pool_bytes()
+    assert mem.temp_size_in_bytes < paged.pool_bytes() // 8, mem
+    # neither pool of states is copied, sliced or re-laid out whole
+    hlo = compiled.as_text()
+    for shape in (v_pool["S"].shape, v_pool["conv"].shape):
+        strays = [line.strip()[:160]
+                  for op, line in _pool_sized_ops(hlo, shape)
+                  if op not in ("parameter", "get-tuple-element", "tuple",
+                                "bitcast", "custom-call")]
+        assert not strays, strays

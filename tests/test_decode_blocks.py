@@ -382,15 +382,21 @@ DESCRIPTION = ("cache", "positions", "table_dtype", "vocab_size", "embed",
                "ffn", "logits", "n_expert_layers")
 CACHE_KIND = ("refuses", "LAYOUT", "dense_init", "dense_layer",
               "kernel_supported", "init_pools", "page_payload", "lanes",
-              "layer", "layer_operand")
+              "layer", "layer_operand", "state_rows", "map_pages")
+#: what a kind with ``state_rows`` answers besides
+STATE_KIND = ("map_state", "snapshot_rows", "zero_row")
 ASKED_BY_KIND = {
     blocks.PerHeadCache: ("heads", "qkv", "project"),
     blocks.LatentCache: ("cache_widths", "sizes", "qkv", "absorb_q",
                          "expand_o", "project", "attend", "softmax_scale")}
+ASKED_BY_KIND[blocks.StateLatentCache] = ASKED_BY_KIND[blocks.LatentCache] + (
+    "state_layers", "state_sizes", "state_inputs", "state_conv_weights",
+    "state_qkv", "state_output")
 
 
 @pytest.mark.parametrize("description", [blocks.DefaultBlock,
-                                         blocks.LatentBlock, TinyBlock],
+                                         blocks.LatentBlock,
+                                         blocks.DeltaLatentBlock, TinyBlock],
                          ids=lambda c: c.__name__)
 def test_every_description_answers_the_whole_protocol(description):
     for name in DESCRIPTION + ASKED_BY_KIND[description.cache]:
@@ -398,7 +404,10 @@ def test_every_description_answers_the_whole_protocol(description):
     for name in CACHE_KIND:
         assert hasattr(description.cache, name), name
     assert set(description.cache.refuses) <= {"kv_quant", "draft",
-                                              "speculation"}
+                                              "speculation", "spill"}
+    assert description.cache.state_rows == 0    # of the class: none yet
+    if description.cache is blocks.StateLatentCache:
+        assert hasattr(description.cache, "map_state")
 
 
 def test_the_decoders_call_nothing_outside_the_protocol():
@@ -412,6 +421,6 @@ def test_the_decoders_call_nothing_outside_the_protocol():
         # (not the file names block.py and cache.py in a comment)
         called |= set(re.findall(r"\b(?:blk|block|cache)\.(?!py\b)(\w+)",
                                  src))
-    allowed = set(DESCRIPTION + CACHE_KIND + ("dtype", "plan")
+    allowed = set(DESCRIPTION + CACHE_KIND + STATE_KIND + ("dtype", "plan")
                   + ASKED_BY_KIND[blocks.PerHeadCache])
     assert called and called <= allowed, called - allowed

@@ -316,39 +316,44 @@ def test_router_bias_moves_the_choice_and_not_the_weight():
 
 
 # --------------------------------------------------------- the share test
-def _uncut():
-    """The tiny configuration uncut: one rank holds all 16 experts."""
-    return dict(CFG, n_routed_experts=16, ep_ranks=1, ep_rank=0)
-
-
 def _share_of(w: dict, rank: int, held: int) -> dict:
     lo = rank * held
     return dict(w, **{k: w[k][lo:lo + held]
                       for k in ("e_gate", "e_up", "e_down")})
 
 
-def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer():
-    """The guide's share test: the parts of the expert layer that the
-    four ranks give (each its 4 held experts' terms), with the shared
-    expert counted once, add up to what the uncut reference gives; and
-    the program's share-aware layer gives each rank's part."""
-    full_cfg = _uncut()
-    zf = REF.sizes(full_cfg)
-    w = REF._layer_params(_jit_params(full_cfg), 1)
+@pytest.mark.parametrize("arch,experts,ranks", [
+    ("kimi_k2", "n_routed_experts", 4), ("kimi_linear", "num_experts", 8)])
+def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(arch, experts,
+                                                           ranks):
+    """The guide's share test, for each architecture with a share-aware
+    expert layer: the parts of the layer that the ranks give (each its
+    held experts' terms, 16 experts over 4 ranks or over 8), with the
+    shared expert counted once, add up to what the uncut reference gives;
+    and the program's share-aware layer gives each rank's part."""
+    ref = manifest.load_module("reference", arch)
+    tiny = manifest.load_module("models", arch).tiny()
+    held = 16 // ranks
+    full_cfg = dict(tiny, **{experts: 16, "ep_ranks": 1, "ep_rank": 0})
+    zf = ref.sizes(full_cfg)
+    w = ref._layer_params(jax.jit(lambda lo, hi: ref.init_params(
+        (lo, hi), full_cfg))(*ref.seed_words(SEED)), 1)
     rng = np.random.default_rng(8)
-    h = jnp.asarray(rng.normal(size=(10, CFG["hidden_size"])), jnp.float32)
+    h = jnp.asarray(rng.normal(size=(10, tiny["hidden_size"])), jnp.float32)
     ident = lambda x: x
-    whole = REF.expert_ffn(h, w, zf, ident)
-    shared = REF.swiglu(h, w["s_gate"], w["s_up"], w["s_down"], ident)
+    whole = ref.expert_ffn(h, w, zf, ident)
+    shared = ref.swiglu(h, w["s_gate"], w["s_up"], w["s_down"], ident)
     parts, prog_parts = [], []
-    for rank in range(4):
-        cfg_r = dict(CFG, ep_rank=rank)
-        w_r = _share_of(w, rank, 4)
-        parts.append(REF.expert_ffn(h, w_r, REF.sizes(cfg_r), ident,
+    for rank in range(ranks):
+        cfg_r = dict(tiny, **{experts: held, "ep_ranks": ranks,
+                              "ep_rank": rank})
+        w_r = _share_of(w, rank, held)
+        parts.append(ref.expert_ffn(h, w_r, ref.sizes(cfg_r), ident,
                                     shared=False))
         idx, wts = moe_ops.sigmoid_topk_route(
-            h, w["router"], w["router_bias"], k=2, scale=2.5)
-        comb = moe_ops.held_combine(idx, wts, lo=4 * rank, n_held=4)
+            h, w["router"], w["router_bias"], k=zf["k"],
+            scale=zf["route_scale"])
+        comb = moe_ops.held_combine(idx, wts, lo=held * rank, n_held=held)
         prog_parts.append(moe_ops.held_experts_ffn(
             h, comb, w_r["e_gate"], w_r["e_up"], w_r["e_down"]))
     assert float(jnp.max(jnp.abs(whole - shared))) > 1e-2

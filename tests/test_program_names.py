@@ -73,6 +73,38 @@ def test_latent_step_is_the_module_the_benchmark_matches(latent_step_text):
 
 
 @pytest.fixture(scope="module")
+def state_step_text():
+    """The step of a block with a recurrent state beside its latent
+    pages (models/block.py DeltaLatentBlock), on the kernel path."""
+    from test_state_decode import CFG as LCFG, MODEL, REF, SEED
+    from paddle_tpu import models
+    named = MODEL.make_weights(REF, SEED, LCFG, jnp.float32)
+    dec = models.TransformerDecoder(
+        named, n_layers=LCFG["num_hidden_layers"],
+        n_heads=LCFG["num_attention_heads"], name=MODEL.NAME,
+        block=MODEL.block_of(LCFG, 64))
+    eng = DecodeEngine(dec, num_slots=2, page_size=4, max_seq_len=32,
+                       attention="kernel", state_snapshots=2)
+    z = jnp.zeros((2, 1), jnp.int32)
+    args = (dec.p, eng.k_pool, eng.v_pool, z, z, jnp.asarray(eng._tables),
+            jnp.zeros((2, 1), jnp.bool_), jax.random.PRNGKey(0))
+    return eng.paged._step.lower(*args).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [
+    "embed", "kda_conv", "kda_short_conv", "kda_gates", "kda_state",
+    "kda_state_update",
+    "latent_kv_write", "latent_attn", "paged_latent_attention", "ffn",
+    "router", "experts", "shared_expert", "logits"])
+def test_state_step_carries_the_name(state_step_text, name):
+    assert _has(state_step_text, name)
+
+
+def test_state_step_is_the_module_the_benchmark_matches(state_step_text):
+    assert "module @jit__step_impl" in state_step_text
+
+
+@pytest.fixture(scope="module")
 def train_step_text():
     from benchmarks.lib import manifest, paddle_lm
     cfg = {"hidden_size": 32, "num_hidden_layers": 2,
@@ -125,8 +157,9 @@ def test_every_pallas_call_of_the_kernel_files_is_named():
     """No mix: a ``pl.pallas_call`` without ``name=`` reads in a trace
     under whatever the tracer of autodiff leaves."""
     import inspect
-    from paddle_tpu.ops import pallas_attention, pallas_decode, pallas_rnn
-    for mod in (pallas_attention, pallas_decode, pallas_rnn):
+    from paddle_tpu.ops import (pallas_attention, pallas_decode, pallas_kda,
+                                pallas_rnn)
+    for mod in (pallas_attention, pallas_decode, pallas_kda, pallas_rnn):
         src = inspect.getsource(mod)
         calls = [m.start() for m in re.finditer(r"pl\.pallas_call\(", src)]
         assert calls, mod.__name__
