@@ -466,6 +466,9 @@ class PagedDecoder:
         donate = dict(donate_argnums=() if cpu else (1, 2))
         self._step = jax.jit(self._step_impl, **donate)
         self._lane_step = jax.jit(self._step_impl_lanes, **donate)
+        # the feed, by the shape of the choices it reads: a plain step's
+        # [S, W] or a lane step's flat [S*W + Sp]
+        self._feed = self._lane_feed = jax.jit(self._feed_impl)
         self._copy = jax.jit(self._copy_page_impl, **pools)
         self._copy_state = jax.jit(self._copy_state_impl, **pools)
         self._read = jax.jit(self._read_page_impl)
@@ -494,7 +497,13 @@ class PagedDecoder:
         self._copy_fp, self._read_fp, self._write_fp, self._copy_state_fp = (
             fingerprint(f"paged_{which}", dense.p, plan=page_plan)
             for which in ("copy", "read", "write", "copy_state"))
+        self._feed_fp, self._lane_feed_fp = (
+            fingerprint(f"paged_{which}", dense.p, plan=dict(
+                num_slots=self.num_slots, window=self.window,
+                lanes=self.lanes))
+            for which in ("feed", "lane_feed"))
         self._step_exe = self._lane_step_exe = self._copy_exe = None
+        self._feed_exe = self._lane_feed_exe = None
         self._read_exe = self._write_exe = self._copy_state_exe = None
 
     def init_pools(self):
@@ -582,6 +591,18 @@ class PagedDecoder:
                                page_tables, active, key, lanes)
 
     @staticmethod
+    def _feed_impl(prev, src, tokens):
+        """The next step's ``tokens`` [S, W], column 0 of row s taken from
+        ``prev`` (the choices of the step before: [S, W], or the lane
+        program's flat [S*W + Sp]) at flat row ``src[s]`` where that is
+        not negative, the host's own token elsewhere. A program of its
+        own: both step programs keep their text, and the choices never
+        leave the device between two steps (serving/engine.py, the step
+        in flight)."""
+        fed = prev.reshape(-1)[jnp.maximum(src, 0)]
+        return tokens.at[:, 0].set(jnp.where(src >= 0, fed, tokens[:, 0]))
+
+    @staticmethod
     def _page_slice(leaf, page):
         """[L, 1, ...] view of one physical page in the stored layout —
         rank-generic, so it covers the value leaves [L, N, ps, g*dh]
@@ -661,6 +682,15 @@ class PagedDecoder:
         of both pools — the restore leg of page spill."""
         return _run(self, "write", k_pool, v_pool, k_page, v_page,
                     jnp.int32(page))
+
+    def feed(self, prev, src, tokens):
+        """``tokens`` [S, W] with the rows that ``src`` [S] names fed from
+        ``prev``, the device's array of the last step's choices
+        (:meth:`_feed_impl`): what :meth:`step` then takes as its
+        ``tokens``, still on the device."""
+        return _run(self, "feed" if prev.ndim == 2 else "lane_feed", prev,
+                    jnp.asarray(src, jnp.int32),
+                    jnp.asarray(tokens, jnp.int32))
 
     def step(self, k_pool, v_pool, tokens, positions, page_tables,
              active, key=None, lanes=None):

@@ -19,11 +19,12 @@ scope reaches the report only through the compiled program's own text
 (``hlo_text=``: instruction -> ``op_name``; ``paddle_tpu train --job
 profile`` hands it the train step's); a kernel's ``name=`` is in the
 instruction's name and needs nothing. And the device plane's clock may
-sit a few milliseconds off the host plane's: for the synchronous
-serving loop (``SYNC_LOOP``: the span that launches the step and the
-span that waits for it) :func:`clock_offset_bounds` holds the two to
-causality and the device's events are shifted back inside the bounds
-before any gap is attributed.
+sit a few milliseconds off the host plane's: for the serving loop
+(``SYNC_LOOP``: the span that launches a step and the span that waits
+for one, the same step's or, where the loop keeps a step in flight, the
+one before) :func:`clock_offset_bounds` holds the two to causality and
+the device's events are shifted back inside the bounds before any gap
+is attributed.
 
 The arithmetic works on plain ``(start_ns, duration_ns, name)`` tuples
 (tests/test_xplane.py checks it on hand-made lists).
@@ -49,8 +50,10 @@ Event = Tuple[int, int, str]            # start_ns, duration_ns, name
 #: the TPU runtime names them
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-#: the synchronous serving loop: the span that launches the step and the
-#: span that waits for its result (serving/engine.py)
+#: the serving loop's exchange with the device: the span that launches a
+#: step and the span that waits for a step's result (serving/engine.py;
+#: with a step in flight the wait is for the step BEFORE the one the same
+#: turn launched)
 SYNC_LOOP = ("serving/dispatch", "serving/sync")
 #: host spans the program opens itself (stat_timer names)
 SPAN_PREFIXES = ("serving/", "train")
@@ -197,20 +200,36 @@ def clock_offset_bounds(modules: Sequence[Event],
                         launches: Sequence[Event],
                         waits: Sequence[Event]) -> Optional[Tuple[int, int]]:
     """How far the device's clock may sit ahead of the host's (ns), by
-    causality in a synchronous loop: an execution of the step program
-    (``modules``, device clock) starts no earlier than the host span
-    that launches it starts, and ends no later than the host span that
-    waits for it ends. Each execution is held to the launch whose start
-    and the wait whose end lie nearest (right while the offset is under
-    half a step). -> (lo, hi): device - host lies in [lo, hi]; the
-    clocks agree where lo <= 0 <= hi. None without the spans."""
+    causality in the serving loop: the k-th execution of the step program
+    (``modules``, device clock) starts no earlier than the k-th launch
+    span starts, and ends no later than the k-th wait span ends. The
+    loop may keep a step in flight (serving/engine.py): the wait for
+    step k then ends after the launch of step k+1 has started, and the
+    launch NEAREST to an execution's start is the next step's. So the
+    launches are held to the executions by order, one each: of the
+    alignments (the trace's edges cut an unknown number of spans) that
+    leave ``lo <= hi``, the one whose bounds lie nearest to 0, then the
+    narrowest. Each execution's wait is the one whose end lies nearest
+    (right while the offset is under half a step, with or without a step
+    in flight). -> (lo, hi): device - host lies in [lo, hi]; the clocks
+    agree where lo <= 0 <= hi. None without the spans, or where no
+    alignment keeps every execution behind its launch."""
     if not (modules and launches and waits):
         return None
     starts = sorted(s for s, _, _ in launches)
     ends = sorted(s + d for s, d, _ in waits)
-    hi = min(s - starts[_nearest(starts, s)] for s, _, _ in modules)
-    lo = max(s + d - ends[_nearest(ends, s + d)] for s, d, _ in modules)
-    return lo, hi
+    mods = sorted(modules)
+    lo = max(s + d - ends[_nearest(ends, s + d)] for s, d, _ in mods)
+    best = None
+    for a in range(len(starts) - len(mods) + 1):
+        hi = min(s - starts[i + a] for i, (s, _, _) in enumerate(mods))
+        if lo <= hi:
+            key = (max(lo, 0, -hi), hi - lo)    # how far from 0, how wide
+            if best is None or key < best[0]:
+                best = key, hi
+    # no alignment keeps causality (the launches of the first executions
+    # cut off): nothing to hold the clocks to
+    return None if best is None else (lo, best[1])
 
 
 # ------------------------------------------------------------------ names

@@ -351,6 +351,34 @@ class _Slot:
         return self.last_tok
 
 
+class _Fed:
+    """One live slot of a dispatched step, as its landing needs it: the
+    slot with its request (never looked up in ``DecodeEngine.slots``
+    again: the slot index may have a new tenant by then), the position
+    its first token was fed at, the tokens, whether they were a replay
+    chunk, and the flat row of the step's choices that holds its (first)
+    choice, or None where the chunk ended before the replay's tail."""
+
+    __slots__ = ("s", "slot", "pos", "toks", "chunk", "row")
+
+    def __init__(self, s, slot, pos, toks, chunk, row):
+        self.s, self.slot, self.pos = s, slot, pos
+        self.toks, self.chunk, self.row = toks, chunk, row
+
+
+class _Step:
+    """What :meth:`DecodeEngine._launch` sent: the device's futures (the
+    choices, an expert model's load sums), the step's number, and a
+    :class:`_Fed` a live slot. ``landed`` once its values were committed,
+    or given up with a failed step."""
+
+    __slots__ = ("step", "nxt", "load", "fed", "landed")
+
+    def __init__(self, step, nxt, load, fed):
+        self.step, self.nxt, self.load, self.fed = step, nxt, load, fed
+        self.landed = False
+
+
 class DecodeEngine:
     """Persistent continuous-batching decode loop (see module doc).
 
@@ -376,7 +404,26 @@ class DecodeEngine:
 
     Drive it synchronously (``step()`` / ``run()`` — deterministic, the
     test/bench mode) or as a background thread (``start()`` /
-    ``shutdown()`` — the serving mode; InferenceServer wires this)."""
+    ``shutdown()`` — the serving mode; InferenceServer wires this).
+
+    A step has two halves. :meth:`_launch` reaps, admits, plans,
+    dispatches and writes down everything that is arithmetic on what the
+    host holds (positions, pages, counters, the state snapshots);
+    :meth:`_land` waits for the step's choices and commits the VALUES
+    (tokens, stamps, EOS and ``max_new``, ``_finish``). ``step()`` is
+    ``_land(_launch())``: one call, one step, committed at return. The
+    thread keeps ONE step in flight: it launches step N+1 while step N
+    runs, then lands N, so the device finds its next step queued and the
+    host's work hides behind the device's. A decoding row of N+1 takes
+    its input, N's choice, on the device (``PagedDecoder.feed``). Who
+    drives decides the order of the halves; no argument does. What the
+    host cannot know before N lands makes ``_launch`` land N first (a
+    drain, counted in ``ahead_drains``): a draft (acceptance decides
+    positions), a spill store (its pages go through host memory), a
+    preemption (the victim's replay is ``prompt + tokens``). EOS is the
+    one stop the host learns a step late: the row N+1 ran for that slot
+    is computed and DROPPED, never appended, stamped or delivered, and
+    the slot is free from step N+2."""
 
     def __init__(self, decoder, *, num_slots: int = 4,
                  page_size: int = 16, num_pages: Optional[int] = None,
@@ -546,7 +593,18 @@ class DecodeEngine:
                           # the same boundary (PERF.md section 3)
                           "host_admit_ns": 0, "host_plan_ns": 0,
                           "host_dispatch_ns": 0, "host_sync_ns": 0,
-                          "host_commit_ns": 0, "host_idle_ns": 0}
+                          "host_commit_ns": 0, "host_idle_ns": 0,
+                          # steps dispatched while the step before was
+                          # still in flight, and steps that landed it
+                          # first (a draft, a spill store, a preemption:
+                          # the reason is in the flight record); the
+                          # rest of ``steps`` found nothing in flight
+                          "steps_launched_ahead": 0, "ahead_drains": 0}
+        # the dispatched step whose values have not landed (_launch sets
+        # it, _land clears it), and the rows of the next step's tokens
+        # that are fed from its choices on the device (-1: the host's)
+        self._in_flight: Optional[_Step] = None
+        self._src = np.full((S,), -1, np.int32)
         import jax
         self._key0 = jax.random.PRNGKey(0)
         # live-state provider for postmortem bundles: the slot table
@@ -743,16 +801,19 @@ class DecodeEngine:
             self._counters["state_snapshots_taken"] += 1
 
     def _finish(self, s: int, state: str,
-                error: Optional[ServingError] = None) -> None:
+                error: Optional[ServingError] = None,
+                snapshot: bool = True) -> None:
         """Release slot ``s``: pages to the prefix index then back to
         the pool FIRST (the no-leak invariant), then settle the
-        request."""
+        request. ``snapshot`` False where the slot's state row has moved
+        past ``slot.pos`` (a dead row after EOS, :meth:`_land`)."""
         slot = self.slots[s]
         if state in ("done", "cancelled"):
             # failed/closed slots may hold garbage KV (step failure) —
             # never index those pages
             self._index_slot_pages(slot)
-            self._snapshot(s, slot)
+            if snapshot:
+                self._snapshot(s, slot)
         self.pool.free(slot.pages)
         slot.pages = []
         self._tables[s, :] = 0
@@ -1126,11 +1187,15 @@ class DecodeEngine:
                               replay=len(replay),
                               prefix_tokens=slot.pos)
 
-    def _ensure_pages(self, plan: Dict[int, List[int]]) -> None:
+    def _ensure_pages(self, plan: Dict[int, List[int]]) -> bool:
         """Allocate each planned slot's pages through the LAST position
         its window will write; on pool exhaustion reclaim trie leaves
         first, then preempt the YOUNGEST slot (LIFO — oldest requests
-        keep their progress) until the allocation succeeds."""
+        keep their progress) until the allocation succeeds. False, and
+        nobody preempted, where a preemption is due while a step is in
+        flight: a victim's replay is ``prompt + tokens``, and one token
+        may still be on the device (:meth:`_launch` lands it and plans
+        again; the pages allocated so far stay with their slots)."""
         for s in sorted(
                 (i for i in range(self.num_slots)
                  if self.slots[i] is not None and i in plan),
@@ -1142,6 +1207,8 @@ class DecodeEngine:
             while len(slot.pages) * self.page_size <= last:
                 page = self._alloc_page()
                 if page is None:
+                    if self._in_flight is not None:
+                        return False
                     victims = sorted(
                         (i for i in range(self.num_slots)
                          if self.slots[i] is not None),
@@ -1153,6 +1220,7 @@ class DecodeEngine:
                     continue
                 slot.pages.append(page)
                 self._tables[s, len(slot.pages) - 1] = page
+        return True
 
     # ----------------------------------------------------------- speculation
     def _draft_propose(self, active_idx: List[int]) -> Dict[int, List[int]]:
@@ -1235,61 +1303,114 @@ class DecodeEngine:
 
     # ------------------------------------------------------------- the loop
     def step(self) -> bool:
-        """One engine iteration: reap, admit, draft-propose, window-
-        plan, page-ensure, ONE jitted target dispatch, bookkeep.
-        Returns True iff a device step ran. Single-threaded by
-        contract: the engine thread in serving mode, the caller in
-        sync mode. Each phase is one ``_phase``: a ``host_*_ns``
-        counter and a span under ``serving/step``, whose ``step`` is
-        the ``engine_step`` of its slots' flight records."""
+        """One engine iteration, whole: :meth:`_launch` (reap, admit,
+        draft-propose, window-plan, page-ensure, ONE jitted target
+        dispatch, the positions written down) then :meth:`_land` of that
+        very step (the one sync, the tokens committed). Returns True iff
+        a device step ran. Single-threaded by contract: the caller in
+        sync mode (the engine's thread runs :meth:`_loop`, which lands
+        the step BEFORE the one it launched). Each phase is one
+        ``_phase``: a ``host_*_ns`` counter and a span under
+        ``serving/step``, whose ``step`` is the ``engine_step`` of its
+        slots' flight records."""
         with obs_context.bind(step=self._steps + 1), \
-                self._phase("serving/step"):
-            with self._phase("serving/admit", "host_admit_ns"):
-                interceptor = self._step_interceptor
-                if interceptor is not None:
-                    interceptor(self._steps)
-                self._reap(self._clock())
-                self._admit()
+                self._phase("serving/step"), contextlib.ExitStack() as dev:
+            rec = self._launch(dev)
+            return rec is not None and self._land(rec, dev)
+
+    def _pending(self, s: int) -> Optional[_Fed]:
+        """Slot ``s``'s part of the step in flight if that step chooses a
+        token for it (the one value the host lacks about the slot)."""
+        rec = self._in_flight
+        fed = rec.fed.get(s) if rec is not None else None
+        if fed is None or fed.slot is not self.slots[s] or fed.row is None:
+            return None
+        return fed
+
+    def _give_up_in_flight(self) -> None:
+        """The step in flight will never land (its pools went with a
+        failed step, or the engine closes now): its tokens are dropped
+        with the requests it ran for, which settle where they stand."""
+        if self._in_flight is not None:
+            self._in_flight.landed = True
+            self._in_flight = None
+
+    def _drain(self, why: str) -> None:
+        """Land the step in flight before this one is planned: its plan
+        needs values the host does not have yet."""
+        rec = self._in_flight
+        FLIGHT.record("mark", "engine/ahead_drain", reason=why,
+                      engine_step=rec.step)
+        self._land(rec)
+
+    def _launch(self, dev: contextlib.ExitStack) -> Optional[_Step]:
+        """The first half of a step, everything that needs no value of
+        the step in flight: reap, admit, plan (pages ensured), dispatch,
+        and what the host can write down of the step it sent: the step
+        count, positions, the counters of tokens fed and cache read, the
+        flight records, a state snapshot where a chunk ends on its
+        boundary (dispatched behind the step, so before the next step
+        writes the row). -> the :class:`_Step` now in flight, or None
+        (nothing live, or the dispatch failed and everything settled).
+        ``dev`` takes the ``serving/decode_step`` span, which the
+        caller's :meth:`_land` closes after its sync."""
+        drained = self._in_flight is not None and (
+            self.draft is not None or self.spill is not None)
+        if drained:
+            # acceptance decides the next positions; a spill store reads
+            # and restores pages through host memory, keyed by tokens
+            self._drain("draft" if self.draft is not None else "spill")
+        with self._phase("serving/admit", "host_admit_ns"):
+            interceptor = self._step_interceptor
+            if interceptor is not None:
+                interceptor(self._steps)
+            self._reap(self._clock())
+            self._admit()
+        with self._phase("serving/plan", "host_plan_ns"):
+            planned = self._plan_windows()
+        if planned is None:         # a preemption is due
+            drained = True
+            self._drain("preempt")
             with self._phase("serving/plan", "host_plan_ns"):
-                plan, live, lane_of = self._plan_windows()
+                planned = self._plan_windows()
+        plan, live, lane_of = planned
+        older = self._in_flight
+        if live or older is not None:
+            # the turn's exchange with the device: this dispatch and
+            # the caller's sync
+            dev.enter_context(stat_timer("serving/decode_step"))
+        if not live:
+            return None
+        try:
+            # dispatch: the host-to-device transfers, the enqueue, and
+            # what was sent written down
+            with self._phase("serving/dispatch", "host_dispatch_ns"):
                 key = self._key0
-                if live and self.temperature is not None:
+                if self.temperature is not None:
                     import jax
                     key = jax.random.fold_in(self._key0, self._steps)
-            if not live:
-                return False
-            try:
-                with stat_timer("serving/decode_step"):
-                    # dispatch: the host-to-device transfers and the
-                    # enqueue; sync: the host waiting for the device
-                    with self._phase("serving/dispatch",
-                                     "host_dispatch_ns"):
-                        nxt, self.k_pool, self.v_pool = self.paged.step(
-                            self.k_pool, self.v_pool, self._tokens,
-                            self._positions, self._tables, self._active,
-                            key, self._lanes if lane_of else None)
-                    with self._phase("serving/sync", "host_sync_ns"):
-                        # the ONE host sync per step; an expert model's
-                        # two load sums come with the tokens
-                        load = self.paged.expert_counts
-                        if load is None:
-                            nxt = np.asarray(nxt)
-                        else:
-                            import jax
-                            nxt, load = jax.device_get((nxt, load))
-            # ptlint: disable=R7(serving boundary — in-flight requests settle typed and the pools rebuild; the engine thread must never die)
-            except Exception as e:
-                self._recover_from_step_failure(e)
-                return False
-            with self._phase("serving/commit", "host_commit_ns"):
-                self._commit(plan, live, nxt, lane_of)
-                if load is not None:
-                    with self._cv:
-                        c = self._counters
-                        c["expert_assignments_held"] += int(load[0])
-                        c["expert_hits_held"] += int(load[1])
-                        c["expert_layer_steps"] += self.paged.n_expert_layers
-            return True
+                # copies: the next plan fills these arrays again while
+                # this step may still be reading them (the CPU backend
+                # takes an aligned numpy array without a copy)
+                tokens = self._tokens.copy()
+                if (self._src >= 0).any():
+                    tokens = self.paged.feed(older.nxt, self._src.copy(),
+                                             tokens)
+                nxt, self.k_pool, self.v_pool = self.paged.step(
+                    self.k_pool, self.v_pool, tokens,
+                    self._positions.copy(), self._tables.copy(),
+                    self._active.copy(), key,
+                    self._lanes.copy() if lane_of else None)
+                rec = self._sent(plan, live, lane_of, nxt,
+                                 self.paged.expert_counts)
+        # ptlint: disable=R7(serving boundary — in-flight requests settle typed and the pools rebuild; the engine thread must never die)
+        except Exception as e:
+            self._recover_from_step_failure(e)
+            return None
+        self._counters["steps_launched_ahead"] += older is not None
+        self._counters["ahead_drains"] += drained
+        self._in_flight = rec
+        return rec
 
     def _plan_windows(self):
         """The step's host plan: each active slot's tokens (a replay
@@ -1297,7 +1418,12 @@ class DecodeEngine:
         token + the draft's proposals), its pages ensured (which may
         preempt), and the step's small int32 inputs filled. -> (plan,
         live slot indices, {lane-fed slot: its lanes}); nothing live
-        means no dispatch, no lane-fed slot the plain program."""
+        means no dispatch, no lane-fed slot the plain program. None where
+        a preemption is due while a step is in flight
+        (:meth:`_ensure_pages`). With a step in flight a slot whose last
+        token (by ``max_new``) that step chooses is not planned, and a
+        decoding slot's pending token is that step's choice: ``_src``
+        names its row, and the device feeds it."""
         active_idx = [s for s in range(self.num_slots)
                       if self.slots[s] is not None]
         if not active_idx:
@@ -1307,15 +1433,22 @@ class DecodeEngine:
         # pending token + the draft's proposals (speculative verify)
         W = self.window
         plan: Dict[int, List[int]] = {}
+        src: Dict[int, int] = {}
         for s in active_idx:
             slot = self.slots[s]
             if slot.pos < len(slot.replay) - 1:
                 wlen = min(W, len(slot.replay) - slot.pos)
                 plan[s] = slot.replay[slot.pos:slot.pos + wlen]
-            else:
-                p_s = props.get(s, [])[:W - 1]
-                room = self.max_seq_len - 1 - slot.pos
-                plan[s] = [slot.next_input()] + p_s[:max(room, 0)]
+                continue
+            pending = self._pending(s)
+            if pending is not None:
+                if slot.req.num_generated + 1 >= slot.req.max_new:
+                    continue            # its last token is in flight
+                src[s] = pending.row
+            p_s = props.get(s, [])[:W - 1]
+            room = self.max_seq_len - 1 - slot.pos
+            plan[s] = [0 if pending is not None else slot.next_input()] \
+                + p_s[:max(room, 0)]
         # prefill lanes: a slot with more replay left than its window
         # holds takes free lanes, oldest first, as many as its remainder
         # fills (consecutive lanes of one slot are one longer chunk); a
@@ -1340,7 +1473,8 @@ class DecodeEngine:
             taken = lane_of[s].stop
             plan[s] = slot.replay[
                 slot.pos:slot.pos + min(left, len(lane_of[s]) * width)]
-        self._ensure_pages(plan)
+        if not self._ensure_pages(plan):
+            return None
         live = [s for s in active_idx
                 if self.slots[s] is not None and s in plan]
         lane_of = {s: ln for s, ln in lane_of.items() if s in live}
@@ -1350,7 +1484,9 @@ class DecodeEngine:
         self._tokens[:, :] = 0
         self._positions[:, :] = 0
         self._lanes[:, :] = 0
+        self._src[:] = -1
         for s in live:
+            self._src[s] = src.get(s, -1)
             slot = self.slots[s]
             toks = plan[s]
             for j, lane in enumerate(lane_of.get(s, ())):
@@ -1364,18 +1500,15 @@ class DecodeEngine:
                 self._active[s, :w] = True
         return plan, live, lane_of
 
-    def _commit(self, plan: Dict[int, List[int]], live: List[int],
-                nxt, lane_of: Dict[int, range]) -> None:
-        """Everything after the sync: count the step, commit each live
-        slot's tokens, finish what is done. A lane-fed slot's chunk is
-        the replay chunk it is, whatever its length; its one choice is
-        that of its last lane."""
-        t_after = self._clock()
-        if lane_of:
-            # the lane program's flat choices: the slot group's, then a
-            # lane's each
-            n = self.num_slots * self.window
-            nxt, lane_nxt = nxt[:n].reshape(self.num_slots, -1), nxt[n:]
+    def _sent(self, plan: Dict[int, List[int]], live: List[int],
+              lane_of: Dict[int, range], nxt, load) -> _Step:
+        """The positional half of a step's commit, right behind its
+        dispatch: count the step, move each live slot's position past a
+        replay chunk or its one decoding token (a verify window's moves
+        with what is accepted, in :meth:`_land`), snapshot a state where
+        a chunk ended on its boundary, and keep for the landing which
+        row of the choices is whose."""
+        S, W = self.num_slots, self.window
         with self._cv:
             self._steps += 1
             self._active_steps_sum += len(live)
@@ -1383,44 +1516,114 @@ class DecodeEngine:
             self._counters["tokens_fed"] += sum(len(plan[s]) for s in live)
             if self._state is not None:
                 self._counters["state_rows_stepped"] += len(live)
-        if PROFILER.enabled:
-            PROFILER.on_step("decode")
+        fed: Dict[int, _Fed] = {}
         for s in live:
             slot = self.slots[s]
             toks = plan[s]
             w = len(toks)
-            fed = slot.pos
-            req = slot.req
+            pos = slot.pos
             # one compact flight record per slot-step: the "each decode
             # step" link of the request's trace chain — a postmortem
             # bundle reconstructs the request's whole schedule from
             # these by trace_id (tests/test_flight.py acceptance)
             FLIGHT.record("mark", "engine/slot_step",
-                          trace_id=req.trace_id,
-                          engine_step=self._steps, slot=s, pos=fed,
+                          trace_id=slot.req.trace_id,
+                          engine_step=self._steps, slot=s, pos=pos,
                           width=w)
-            # row j reads the fed + j + 1 tokens cached up to itself
-            cache_read = w * fed + w * (w + 1) // 2
+            # row j reads the pos + j + 1 tokens cached up to itself
+            cache_read = w * pos + w * (w + 1) // 2
+            chunk = pos < len(slot.replay) - 1
+            # the slot group's choices lie row by row, then a lane's each
+            # (the plain program's [S, W] read flat is the same rows)
+            row = s * W
             with self._cv:
                 self._cache_tokens_read += cache_read
-            if fed < len(slot.replay) - 1:
-                # replay chunk: all rows teacher-forced; the last row
-                # commits one token iff it reached the replay tail
-                commits = []
-                n_prefill = min(w, len(slot.replay) - 1 - fed)
-                with self._cv:
-                    self._counters["prefill_tokens"] += n_prefill
+                if chunk:
+                    # replay chunk: all rows teacher-forced; the last row
+                    # commits one token iff it reached the replay tail
+                    n_prefill = min(w, len(slot.replay) - 1 - pos)
+                    c = self._counters
+                    c["prefill_tokens"] += n_prefill
                     if s in lane_of:
-                        c = self._counters
                         c["prefill_lane_tokens"] += n_prefill
                         c["prefill_lane_cache_tokens_read"] += cache_read
-                slot.pos = fed + w
+            if chunk:
+                slot.pos = pos + w
                 if slot.pos == len(slot.replay) // self.page_size \
                         * self.page_size:
                     self._snapshot(s, slot)
-                if fed + w == len(slot.replay):
-                    commits = [int(lane_nxt[lane_of[s][-1]]) if s in lane_of
-                               else int(nxt[s, w - 1])]
+                if slot.pos != len(slot.replay):
+                    row = None
+                elif s in lane_of:
+                    row = S * W + lane_of[s][-1]
+                else:
+                    row += w - 1
+            elif w == 1:
+                slot.pos = pos + 1
+            fed[s] = _Fed(s, slot, pos, toks, chunk, row)
+        return _Step(self._steps, nxt, load, fed)
+
+    def _land(self, rec: _Step,
+              dev: Optional[contextlib.ExitStack] = None) -> bool:
+        """The second half of a step: the ONE host sync, then the values:
+        each live slot's tokens appended and stamped, a verify window's
+        acceptance, EOS and ``max_new``, ``_finish``, an expert model's
+        load sums. A request that settled while the step was in flight
+        (cancelled, expired, closed) is skipped: its token is dropped. A
+        slot that stops on EOS here may already be fed in the step now in
+        flight; that row is dead weight (never appended, stamped or
+        delivered; its one cache row lies past the sequence's length on a
+        page the slot then held). ``dev`` holds the open
+        ``serving/decode_step`` span of the caller's turn and is closed
+        after the sync (none given: the span is the sync's own). False
+        iff the step failed (everything in flight settled typed)."""
+        if rec.landed:
+            return True
+        rec.landed = True
+        if self._in_flight is rec:
+            self._in_flight = None
+        with obs_context.bind(step=rec.step):
+            try:
+                with dev if dev is not None \
+                        else stat_timer("serving/decode_step"), \
+                        self._phase("serving/sync", "host_sync_ns"):
+                    # an expert model's two load sums come with the tokens
+                    if rec.load is None:
+                        nxt, load = np.asarray(rec.nxt), None
+                    else:
+                        import jax
+                        nxt, load = jax.device_get((rec.nxt, rec.load))
+            # ptlint: disable=R7(serving boundary — in-flight requests settle typed and the pools rebuild; the engine thread must never die)
+            except Exception as e:
+                self._recover_from_step_failure(e)
+                return False
+            with self._phase("serving/commit", "host_commit_ns"):
+                self._commit(rec, nxt.reshape(-1))
+                if load is not None:
+                    with self._cv:
+                        c = self._counters
+                        c["expert_assignments_held"] += int(load[0])
+                        c["expert_hits_held"] += int(load[1])
+                        c["expert_layer_steps"] += self.paged.n_expert_layers
+            return True
+
+    def _commit(self, rec: _Step, nxt) -> None:
+        """The value half of a step's commit, after the sync: ``nxt`` is
+        the step's choices read flat (:meth:`_sent` kept whose row is
+        which). Commit each live slot's tokens, finish what is done."""
+        t_after = self._clock()
+        if PROFILER.enabled:
+            PROFILER.on_step("decode")
+        for fed in rec.fed.values():
+            s, slot, toks = fed.s, fed.slot, fed.toks
+            req = slot.req
+            if self.slots[s] is not slot or req.done.is_set():
+                continue                # settled while in flight
+            w = len(toks)
+            if fed.chunk:
+                if fed.row is None:
+                    continue
+                commits = [int(nxt[fed.row])]
             else:
                 # speculative verify: outs[j] is the target's choice
                 # after feeding tokens 0..j. Proposal j (toks[j+1]) is
@@ -1428,7 +1631,7 @@ class DecodeEngine:
                 # ends the run and its row becomes dead weight the
                 # kv_len mask never reads.
                 m = w - 1
-                outs = [int(nxt[s, j]) for j in range(w)]
+                outs = [int(nxt[fed.row + j]) for j in range(w)]
                 commits = [outs[0]]
                 a = 0
                 while a < m and toks[a + 1] == commits[-1]:
@@ -1444,8 +1647,6 @@ class DecodeEngine:
                     req.accepted_tokens += a
                 seq_before = len(req.prompt) + len(req.tokens)
                 slot.draft_pos = min(slot.draft_pos, seq_before + a)
-            if not commits:
-                continue
             done = False
             n_commit = 0
             with self._cv:
@@ -1469,19 +1670,31 @@ class DecodeEngine:
                     for _ in range(n_commit):
                         self._lat.append(dt / n_commit)
             global_counters.bump("serving/decode_tokens", n_commit)
-            if fed >= len(slot.replay) - 1:
+            # a slot the step in flight already feeds stands one past
+            # what is committed here (its pending token, on the device)
+            ahead = slot.pos > fed.pos + w
+            if not fed.chunk:
                 # keep only the fed rows that match the committed
                 # sequence: pending token + (n_commit - 1) accepted
-                slot.pos = fed + n_commit
-                slot.draft_pos = min(slot.draft_pos, fed + n_commit)
+                if w > 1:
+                    slot.pos = fed.pos + n_commit
+                slot.draft_pos = min(slot.draft_pos, fed.pos + n_commit)
             if done:
-                self._finish(s, "done")
+                if ahead:
+                    # EOS, learned a step late: the row in flight is dead
+                    # weight. The slot ends where the committed sequence
+                    # does; a state row has moved past it
+                    slot.pos -= 1
+                self._finish(s, "done", snapshot=not ahead)
 
     def _recover_from_step_failure(self, exc: Exception) -> None:
         """A failed dispatch may have consumed the (donated) pools:
         settle everything in flight with a typed error, then rebuild
         pools + free-list + prefix index + draft caches so fresh
-        traffic can still be served."""
+        traffic can still be served. A step still in flight goes with
+        the one that failed (its pools were donated to it): its requests
+        hold their slots and settle here, and its record is given up."""
+        self._give_up_in_flight()
         in_flight = [self.slots[s].req.trace_id
                      for s in range(self.num_slots)
                      if self.slots[s] is not None]
@@ -1559,9 +1772,11 @@ class DecodeEngine:
         inactive = np.zeros((S, W), np.bool_)
         self._lanes[:, :] = 0
         for lanes in (None, self._lanes)[:1 + (self._lane_shape[0] > 0)]:
-            _, self.k_pool, self.v_pool = self.paged.step(
+            nxt, self.k_pool, self.v_pool = self.paged.step(
                 self.k_pool, self.v_pool, z, z, self._tables, inactive,
                 lanes=lanes)
+            # the feed that hands this program's choices to the next step
+            self.paged.feed(nxt, self._src, z)
         if self.draft is not None:
             _, self._draft_kc, self._draft_vc = self.draft.step(
                 self._draft_kc, self._draft_vc, z, z, inactive)
@@ -1582,11 +1797,16 @@ class DecodeEngine:
         return self
 
     def _loop(self) -> None:
+        """The engine's thread: one step in flight. Each turn launches
+        step N+1 while step N runs on the device, then lands N (waits
+        for it, commits its values) and goes round, so the device finds
+        its next step queued and the host's cycle hides behind the
+        device's. The depth is one, fixed."""
         while True:
             with self._cv:
                 if self._close_now:
                     break
-                if not self._has_work():
+                if self._in_flight is None and not self._has_work():
                     if self._stopping:
                         return
                     # one span and one counter update per idle stretch
@@ -1595,13 +1815,21 @@ class DecodeEngine:
                                    or self._close_now):
                             self._cv.wait(0.05)
                     continue
-            self.step()
+            with obs_context.bind(step=self._steps + 1), \
+                    self._phase("serving/step"), \
+                    contextlib.ExitStack() as dev:
+                older = self._in_flight
+                self._launch(dev)
+                if older is not None:
+                    self._land(older, dev)
         self._close_all()
 
     def _close_all(self) -> None:
         """Settle everything in flight with ServerClosed and return
         every page — runs on the STEPPING thread, so it never races a
-        dispatch."""
+        dispatch. A step still in flight is given up: its tokens are
+        dropped with the requests it ran for."""
+        self._give_up_in_flight()
         for s in range(self.num_slots):
             if self.slots[s] is not None:
                 self._finish(s, "failed", ServerClosed(

@@ -120,6 +120,9 @@ _COUNTER_KEYS = {
     # prompt tokens and the cache reads
     "tokens_fed", "prefill_lane_steps", "prefill_lane_tokens",
     "prefill_lane_cache_tokens_read",
+    # steps the loop dispatched with the step before still in flight, and
+    # steps that landed it first (DecodeEngine._launch)
+    "steps_launched_ahead", "ahead_drains",
 }
 
 

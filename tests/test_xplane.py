@@ -149,6 +149,52 @@ def test_clock_offset_bounds_hold_the_device_to_causality():
     assert xplane.clock_offset_bounds([], launches, waits) is None
 
 
+@pytest.mark.parametrize("offset_ms", [0, 3, -2])
+def test_clock_offset_bounds_with_a_step_in_flight(offset_ms):
+    """The loop keeps a step in flight (serving/engine.py ``_loop``): the
+    device runs step k over [10k, 10k + 10] back to back; a turn launches
+    step k+1 at 10k + 1.5 and then waits for step k until 10k + 11, so the
+    wait for k ends AFTER the launch of k+1 has started, and the launch
+    nearest to an execution's start is the next step's. The k-th
+    execution is held between the k-th launch's start and the k-th wait's
+    end, whatever the trace's edges cut off."""
+    launches = [(int((10 * k - 8.5) * MS), 2 * MS, "serving/dispatch")
+                for k in range(1, 8)]                   # steps 1..7
+    waits = [(int((10 * k + 3.5) * MS), int(7.5 * MS), "serving/sync")
+             for k in range(0, 7)]                      # steps 0..6
+    for k in range(1, 7):   # sync k ends after dispatch k+1 starts
+        assert waits[k][0] + waits[k][1] > launches[k][0]
+    shift = offset_ms * MS
+    mods = [(10 * k * MS + shift, 10 * MS, "jit__step_impl")
+            for k in range(1, 5)]                       # steps 1..4
+    lo, hi = xplane.clock_offset_bounds(mods, launches, waits)
+    assert (lo, hi) == (shift - 1 * MS, shift + int(8.5 * MS))
+    assert lo <= shift <= hi
+    # the nearest launch is the next step's: it would put the device
+    # 1.5 ms behind a host that launched the step 8.5 ms before
+    nearest = min(s - min((l for l, _, _ in launches),
+                          key=lambda l: abs(l - s)) for s, _, _ in mods)
+    assert nearest == shift - int(1.5 * MS)
+
+
+def test_clock_offset_bounds_say_nothing_where_no_alignment_is_causal():
+    """The first execution's launch cut off at the head of the trace: no
+    one-to-one alignment keeps every execution behind its launch, and the
+    nearest launch to the first execution is the second's. Nothing is
+    held, where a guess would move the device by a whole step."""
+    launches = [(12 * MS, 2 * MS, "serving/dispatch"),
+                (24 * MS, 2 * MS, "serving/dispatch")]
+    waits = [(2 * MS, 8 * MS, "serving/sync"),
+             (14 * MS, 8 * MS, "serving/sync")]
+    mods = [(1 * MS, 8 * MS, "jit__step_impl"),
+            (13 * MS, 8 * MS, "jit__step_impl")]
+    assert xplane.clock_offset_bounds(mods, launches, waits) is None
+    # with the launch there, the same executions are held as ever
+    assert xplane.clock_offset_bounds(
+        mods, [(0, 2 * MS, "serving/dispatch")] + launches, waits) == (
+            -1 * MS, 1 * MS)
+
+
 def test_scopes_from_hlo_text():
     text = """
 HloModule jit__step_impl
