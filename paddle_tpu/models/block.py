@@ -4,8 +4,8 @@ The decoders (models/decode.py) and ``DecodeEngine`` hold a parameter
 table ``p`` and ONE description of the block that reads it. What a block
 computes, what it caches a token and which paged attention reads that
 cache are stated here and nowhere else: the decoders call the description
-and never ask which one it is. The protocol is informal (two descriptions,
-three cache kinds: no base class, no registry); ``pre`` is ``_<name>_``.
+and never ask which one it is. The protocol is informal (four descriptions,
+four cache kinds: no base class, no registry); ``pre`` is ``_<name>_``.
 
 A description (a frozen object of pure functions over the table):
 
@@ -34,8 +34,17 @@ A cache kind:
       .kernel_supported(), .page_payload(page) (the spill codec's shape),
       .lanes() -> (lanes, tokens a lane): the prefill group its paged read
                                    takes beside the slots; (0, 0) is none
-      .layer(p, i, x, k_pool, v_pool, toks, use_kernel=, interpret=)
-                                   -> (x, k_pool, v_pool, held load or None)
+      .init_pools() -> (a, b): the kind's TWO pools, each whatever pytree
+                                   it says (K and V; their int8 pytrees; a
+                                   latent pool and nothing; a latent pool
+                                   and a state; {"k", "v"} page pools and a
+                                   state). The decoders and the engine pass
+                                   both through whole, to the step and to
+                                   the page and row programs, and never look
+                                   inside: ``map_pages`` / ``map_state``
+                                   choose the leaves
+      .layer(p, i, x, a, b, toks, use_kernel=, interpret=)
+                                   -> (x, a, b, held load or None)
       .layer_operand               True where ``layer`` also takes ``at=``,
                                    the pool's layer index as an OPERAND (a
                                    traced number) beside the ``i`` that names
@@ -45,7 +54,8 @@ A cache kind:
                                    every leaf is laid out by page)
       .state_rows                  rows of recurrent state it keeps a slot
                                    beside the pages, 0 for none. With rows
-                                   (:class:`StateLatentCache`): slot s's
+                                   (:class:`StateLatentCache`,
+                                   :class:`StatePerHeadCache`): slot s's
                                    state is row s, ``.snapshot_rows`` are the
                                    prefix index's to give out, ``.zero_row``
                                    is what a slot without a snapshot starts
@@ -88,6 +98,14 @@ experts of which this chip holds a share, a shared expert, an untied head.
 :class:`DeltaLatentBlock` is the Kimi-Linear block: that block's stream,
 experts and head over layers of two kinds by index, most of them keeping a
 recurrent state a slot (its docstring, and :class:`StateLatentCache`'s).
+
+:class:`ShortConvBlock` is the LFM2 block: RMSNorm, layers of two kinds by
+index, gated short convolutions (two float32 tails a sequence) three to
+one grouped-query attention layer with a norm a head on q and k and rotary
+positions, over :class:`StatePerHeadCache` (per-head K/V pages over the
+attention layers alone, the tails as rows of a state pool); leading dense
+layers, then sigmoid-routed experts of which this chip holds a share and no
+shared expert.
 
 Its precision. The weights and the cache are what the table holds
 (bfloat16 as served); the residual stream, the norms, the router and every
@@ -212,6 +230,44 @@ def _lane_width(takes, width: int) -> int:
     while width > 1 and not takes(width):
         width //= 2
     return width
+
+
+def _state_rows_of(n: int, tok: PagedTokens) -> tuple:
+    """Group ``n``'s rows as a state kernel takes them: (the state row of
+    each, which is its slot's; whether it starts from the pool; how many
+    of its tokens are fed). The first group is the slots' own: every row
+    of it starts from the pool (None). A lane goes on from the lane before
+    it where that is the same slot's and both are fed (one longer
+    chunk)."""
+    slots = tok.slots
+    fed = jnp.sum(tok.active, axis=1, dtype=jnp.int32)
+    first = None if n == 0 else jnp.concatenate([
+        jnp.ones((1,), jnp.bool_),
+        (slots[1:] != slots[:-1]) | (fed[1:] == 0) | (fed[:-1] == 0)])
+    return slots, first, fed
+
+
+def swiglu_or_experts(p, lp: str, x, *, rms_eps: float, expert: bool,
+                      shared: bool, active=None, **route):
+    """x [B, t, d] -> (x + FFN(RMSNorm(x; ``{lp}ffn_norm``)), held load
+    int32 [2] or None): a dense SwiGLU (``{lp}gate`` / ``up`` / ``down``),
+    or where ``expert`` the chip's share of a sigmoid-routed expert layer
+    (ops/moe.py ``routed_experts_ffn``, which takes ``route``: k, scale,
+    rank, eps), with the ``{lp}shared`` expert where the family has one.
+    ``active`` [B, t] bool masks the load count (never the result)."""
+    shape = x.shape
+    h = rms_norm(x, p[f"{lp}ffn_norm.w0"], rms_eps)
+    h = h.reshape(-1, shape[-1])
+    if not expert:
+        y = moe_ops.swiglu(h, p[f"{lp}gate.w0"], p[f"{lp}up.w0"],
+                           p[f"{lp}down.w0"])
+        return x + y.reshape(shape), None
+    three = lambda name: tuple(p[f"{lp}{name}.{n}"]
+                               for n in ("gate", "up", "down"))
+    y, load = moe_ops.routed_experts_ffn(
+        h, p[f"{lp}router.w0"], p[f"{lp}router.wbias"], three("experts"),
+        three("shared") if shared else None, active=active, **route)
+    return x + y.reshape(shape), load
 
 
 def shared_layers(layer, pre: str):
@@ -350,6 +406,11 @@ class PerHeadCache:
         self.plan = {"pool_layout": self.LAYOUT, "kv_heads": self.kv_heads,
                      "head_dim": self.head_dim}
 
+    @property
+    def query_dtype(self):
+        """What the block's ``qkv`` hands the kernel as q."""
+        return self.dtype
+
     def kernel_supported(self, group=None) -> bool:
         """Does the kernel take the slot group's [S, W] queries (or
         ``group``'s, another (rows, window))?"""
@@ -357,7 +418,7 @@ class PerHeadCache:
         S, W = group or (S, W)
         g, dh, int8 = self.kv_heads, self.head_dim, self.kv_quant == "int8"
         return paged_ops.paged_kernel_supported(
-            jax.ShapeDtypeStruct((S, W, self.n_heads, dh), self.dtype),
+            jax.ShapeDtypeStruct((S, W, self.n_heads, dh), self.query_dtype),
             jax.ShapeDtypeStruct((N, ps, g * dh),
                                  jnp.int8 if int8 else self.dtype),
             jax.ShapeDtypeStruct((N, ps, g), jnp.float32) if int8 else None,
@@ -581,7 +642,39 @@ class LatentCache:
         return x, pool, load
 
 
-class StateLatentCache(LatentCache):
+class _StateRows:
+    """What the kinds with a recurrent state a slot share: the state
+    pool's row scheme and the two maps that tell its leaves from the
+    pages'. The state pool is a dict with a ``"conv"`` leaf (both kinds
+    keep a short convolution's tails); no page pool is."""
+
+    def _set_state_rows(self, num_slots: int, state_snapshots) -> None:
+        """The pool's rows: slots, snapshots, the zero row, the junk
+        row."""
+        n = num_slots if state_snapshots is None else int(state_snapshots)
+        self.snapshot_rows = range(num_slots, num_slots + n)
+        self.zero_row, self.junk_row = num_slots + n, num_slots + n + 1
+        self.state_rows = num_slots + n + 2
+
+    @staticmethod
+    def _is_state(pool) -> bool:
+        return isinstance(pool, dict) and "conv" in pool
+
+    def map_pages(self, fn, pool, *rest):
+        """The page programs' map: the state pool has no pages and passes
+        as it is."""
+        if self._is_state(pool):
+            return pool
+        return jax.tree_util.tree_map(fn, pool, *rest)
+
+    def map_state(self, fn, pool):
+        """A row program's map: over the state pool's leaves alone."""
+        if self._is_state(pool):
+            return jax.tree_util.tree_map(fn, pool)
+        return pool
+
+
+class StateLatentCache(_StateRows, LatentCache):
     """Two pools for a block whose layers are of two kinds by index
     (:class:`DeltaLatentBlock`): the latent page pool of
     :class:`LatentCache` over the block's latent layers ALONE
@@ -673,11 +766,7 @@ class StateLatentCache(LatentCache):
                          page_size=page_size, num_pages=num_pages,
                          max_pages_per_slot=max_pages_per_slot,
                          kv_quant=kv_quant)
-        n = num_slots if state_snapshots is None else int(state_snapshots)
-        #: the pool's rows: slots, snapshots, the zero row, the junk row
-        self.snapshot_rows = range(num_slots, num_slots + n)
-        self.zero_row, self.junk_row = num_slots + n, num_slots + n + 1
-        self.state_rows = num_slots + n + 2
+        self._set_state_rows(num_slots, state_snapshots)
         H, dk, dv = block.state_sizes(p, pre)
         self.state_shapes = {
             "S": (len(self.state_at), self.state_rows, H, dk, dv),
@@ -693,23 +782,6 @@ class StateLatentCache(LatentCache):
             k: jnp.zeros(v, jnp.float32)
             for k, v in self.state_shapes.items()}
 
-    @staticmethod
-    def _is_state(pool) -> bool:
-        return isinstance(pool, dict) and "S" in pool
-
-    def map_pages(self, fn, pool, *rest):
-        """The page programs' map: the state pool has no pages and passes
-        as it is."""
-        if self._is_state(pool):
-            return pool
-        return jax.tree_util.tree_map(fn, pool, *rest)
-
-    def map_state(self, fn, pool):
-        """A row program's map: over the state pool's leaves alone."""
-        if self._is_state(pool):
-            return jax.tree_util.tree_map(fn, pool)
-        return pool
-
     def layer(self, p, i, x, pool, state, toks, *, use_kernel, interpret):
         if i in self.latent_at:
             x, pool, load = self._latent_layer(
@@ -721,14 +793,7 @@ class StateLatentCache(LatentCache):
         for n, (tok, pc, g_, b_) in enumerate(zip(
                 toks, split_rows(toks, pre_conv), split_rows(toks, g),
                 split_rows(toks, beta))):
-            slots = tok.slots
-            fed = jnp.sum(tok.active, axis=1, dtype=jnp.int32)
-            # the first group is the slots' own: every row of it starts
-            # from the pool. A lane goes on from the lane before it where
-            # that is the same slot's and both are fed (one longer chunk)
-            first = None if n == 0 else jnp.concatenate([
-                jnp.ones((1,), jnp.bool_),
-                (slots[1:] != slots[:-1]) | (fed[1:] == 0) | (fed[:-1] == 0)])
+            slots, first, fed = _state_rows_of(n, tok)
             kernel = dict(use_kernel=use_kernel and self.state_kernel,
                           interpret=interpret, junk_row=self.junk_row,
                           layer=at)
@@ -746,6 +811,144 @@ class StateLatentCache(LatentCache):
         with jax.named_scope("ffn"):
             x, load = blk.ffn(p, pre, i, x, _rows(toks, "active"))
         return x, pool, state, load
+
+
+class StatePerHeadCache(_StateRows, PerHeadCache):
+    """Per-head K/V pages beside a recurrent state, for a block whose
+    layers are of two kinds by index (:class:`ShortConvBlock`):
+    :class:`PerHeadCache`'s pools over the block's attention layers ALONE,
+    and a STATE pool of convolution tails over its ``state_layers``, with
+    :class:`StateLatentCache`'s row scheme. The engine's two pools are
+
+        pages: {"k", "v": [attention layers, n_pages, page_size, g*dh]},
+               in the layout paged_window_attention reads, in place
+        state: {"conv": [state layers, rows, (K-1) * d / 128, 128] float32,
+                the last K - 1 inputs of a layer's short convolution, one
+                after the other in whole lane tiles (ops/pallas_kda.py
+                ``short_conv`` reads and writes them)}
+
+    Row s < num_slots of the state is slot s's own; then
+    ``state_snapshots`` snapshot rows, the zero row and the junk row, as
+    :class:`StateLatentCache` has them. The page programs map over
+    ``pages`` alone (``map_pages``), the row copy over ``state`` alone
+    (``map_state``).
+
+    Asks of its block what :class:`PerHeadCache` asks (``heads``, ``qkv``
+    with q float32, ``project``), and ``state_layers``, ``state_width``,
+    ``state_inputs``, ``state_conv_weights``, ``state_output``."""
+
+    LAYOUT = "attention layers,N,page,g*dh x {k,v} + " \
+        "state layers,rows,(K-1)*d/128,128 f32"
+    #: two kinds of layer, two pools with layer axes of their own: a layer
+    #: is traced under its own index
+    layer_operand = False
+    refuses = {
+        "kv_quant": "kv_quant is not supported on per-head pages beside a "
+        "recurrent state: this kind's query is float32 against bfloat16 "
+        "pages, and the int8 layout has no such path",
+        "draft": "a draft over a block with a recurrent state is not "
+        "supported: DraftDecoder's slot-private caches are per-head K/V "
+        "alone",
+        "speculation": "speculative decoding (draft / spec_k) is not "
+        "supported over a recurrent state: a rejected token of a window "
+        "would have to be rolled back out of it",
+        "spill": "kv_spill_pages is not supported over a recurrent state: "
+        "a spilled page's snapshot would have to travel with it"}
+
+    #: prompt tokens a step takes through its lanes beside the slots: 8
+    #: lanes of 16 tokens (``window_tile_tokens(32, 8, 64)``: a lane's
+    #: 2 x 16 x 4 query rows a chunk fill the kernel's q tile), twice
+    #: :class:`PerHeadCache`'s, because three of this block's four layers
+    #: read no cached row for a prompt token. One deployment's reading, as
+    #: the others' (LFM2-24B-A2B, 32 slots of 8.4k cached tokens, turns of
+    #: 64-128 new tokens; v5e, PERF.md section 6, PR 41): at 128 a turn's
+    #: suffix is ONE lane step of 33 ms beside plain steps of 22; at 256 a
+    #: lane step is 43 ms, which is the 95th-percentile gap, and throughput
+    #: 10 % lower, for a set-up's 262k-token prefill 33 s in place of 40
+    LANE_TOKENS = 128
+
+    @staticmethod
+    def dense_init(block, p, pre, b, max_len, i=0):
+        if i not in block.state_layers:
+            return PerHeadCache.dense_init(block, p, pre, b, max_len)
+        taps, ch = block.state_width(p, pre)
+        return (jnp.zeros((b, taps - 1, ch), jnp.float32),)
+
+    @staticmethod
+    def dense_layer(block, p, pre, i, x, cache, positions, pos, kv_len):
+        """A state layer's dense cache is its tail; the t tokens of x go
+        through the convolution in order."""
+        if i not in block.state_layers:
+            return PerHeadCache.dense_layer(block, p, pre, i, x, cache,
+                                            positions, pos, kv_len)
+        (tail,) = cache
+        t = x.shape[1]
+        v, gate = block.state_inputs(p, pre, i, x)
+        ext = jnp.concatenate([tail, v], axis=1)          # [b, K-1 + t, .]
+        win = jnp.stack([ext[:, j:j + t]
+                         for j in range(tail.shape[1] + 1)], axis=2)
+        with jax.named_scope("short_conv"):
+            y = kda_ops.conv_of_windows(
+                win, block.state_conv_weights(p, pre, i), silu=False)
+        x = x + block.state_output(p, pre, i, y, gate)
+        return block.ffn(p, pre, i, x)[0], (ext[:, t:],)
+
+    def __init__(self, block, p, pre, *, n_layers, num_slots, window,
+                 page_size, num_pages, max_pages_per_slot, kv_quant,
+                 state_snapshots=None):
+        #: model layer -> its layer of the page pools, or of the state pool
+        self.pages_at = {i: j for j, i in enumerate(
+            i for i in range(n_layers) if i not in block.state_layers)}
+        self.state_at = {i: j for j, i in enumerate(
+            i for i in range(n_layers) if i in block.state_layers)}
+        super().__init__(block, p, pre, n_layers=len(self.pages_at),
+                         num_slots=num_slots, window=window,
+                         page_size=page_size, num_pages=num_pages,
+                         max_pages_per_slot=max_pages_per_slot,
+                         kv_quant=kv_quant)
+        self._set_state_rows(num_slots, state_snapshots)
+        taps, ch = block.state_width(p, pre)
+        #: whole lane tiles a tap: what the kernel's blocks are cut to
+        self.state_kernel = ch % 128 == 0
+        self.state_shape = (len(self.state_at), self.state_rows) + (
+            ((taps - 1) * ch // 128, 128) if self.state_kernel
+            else ((taps - 1), ch))
+        self.plan = dict(self.plan, state_rows=self.state_rows,
+                         state_layers=tuple(self.state_at))
+
+    @property
+    def query_dtype(self):
+        return jnp.float32
+
+    def init_pools(self):
+        k, v = super().init_pools()
+        return {"k": k, "v": v}, {
+            "conv": jnp.zeros(self.state_shape, jnp.float32)}
+
+    def layer(self, p, i, x, pages, state, toks, *, use_kernel, interpret):
+        if i in self.pages_at:
+            x, k, v, load = super().layer(
+                p, i, x, pages["k"], pages["v"], toks,
+                use_kernel=use_kernel, interpret=interpret,
+                at=self.pages_at[i])
+            return x, {"k": k, "v": v}, state, load
+        blk, pre = self.block, self.pre
+        v, gate = blk.state_inputs(p, pre, i, x)
+        tails, outs = state["conv"], []
+        for n, (tok, v_g) in enumerate(zip(toks, split_rows(toks, v))):
+            slots, first, fed = _state_rows_of(n, tok)
+            with jax.named_scope("short_conv"):
+                y, tails = kda_ops.short_conv(
+                    tails, v_g, blk.state_conv_weights(p, pre, i), slots,
+                    first, fed, layer=self.state_at[i],
+                    junk_row=self.junk_row, silu=False,
+                    use_kernel=use_kernel and self.state_kernel,
+                    interpret=interpret)
+            outs.append(y)
+        x = x + blk.state_output(p, pre, i, join_rows(outs), gate)
+        with jax.named_scope("ffn"):
+            x, load = blk.ffn(p, pre, i, x, _rows(toks, "active"))
+        return x, pages, {"conv": tails}, load
 
 
 # ----------------------------------------------------------- descriptions
@@ -1039,43 +1242,15 @@ class LatentBlock:
     def n_expert_layers(self, n_layers: int) -> int:
         return max(n_layers - self.first_dense_layers, 0)
 
-    def route(self, p, pre, i, h):
-        """h [n, d] (the expert layer's normalised input) -> (idx [n, k]
-        over ALL router outputs, weights [n, k] float32)."""
-        lp = f"{pre}l{i}_"
-        return moe_ops.sigmoid_topk_route(
-            h, p[f"{lp}router.w0"], p[f"{lp}router.wbias"],
-            k=self.experts_per_token, scale=self.routed_scaling_factor)
-
     def ffn(self, p, pre, i, x, active=None):
         """x [B, t, d] -> (x + FFN_i(RMSNorm(x)), held load int32 [2] or
         None for a dense layer). ``active`` [B, t] bool masks the load
         count (never the result)."""
-        lp = f"{pre}l{i}_"
-        shape = x.shape
-        h = rms_norm(x, p[f"{lp}ffn_norm.w0"], self.rms_eps)
-        h = h.reshape(-1, shape[-1])
-        if not self.is_expert_layer(i):
-            y = moe_ops.swiglu(h, p[f"{lp}gate.w0"], p[f"{lp}up.w0"],
-                               p[f"{lp}down.w0"])
-            return x + y.reshape(shape), None
-        n_held = p[f"{lp}experts.gate"].shape[0]
-        lo = n_held * self.expert_rank
-        with jax.named_scope("router"):
-            idx, wts = self.route(p, pre, i, h)
-            comb = moe_ops.held_combine(idx, wts, lo=lo, n_held=n_held)
-            act = jnp.ones((h.shape[0],), jnp.bool_) if active is None \
-                else active.reshape(-1)
-            load = moe_ops.held_load(idx, act, lo=lo, n_held=n_held)
-        with jax.named_scope("experts"):
-            y = moe_ops.held_experts_ffn(
-                h, comb, p[f"{lp}experts.gate"], p[f"{lp}experts.up"],
-                p[f"{lp}experts.down"])
-        with jax.named_scope("shared_expert"):
-            y = y + moe_ops.swiglu(h, p[f"{lp}shared.gate"],
-                                   p[f"{lp}shared.up"],
-                                   p[f"{lp}shared.down"])
-        return x + y.reshape(shape), load
+        return swiglu_or_experts(
+            p, f"{pre}l{i}_", x, rms_eps=self.rms_eps,
+            expert=self.is_expert_layer(i), shared=True, active=active,
+            k=self.experts_per_token, scale=self.routed_scaling_factor,
+            rank=self.expert_rank)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1170,3 +1345,157 @@ class DeltaLatentBlock(LatentBlock):
             jax.nn.sigmoid(split_heads(z, o.shape[-2]))
         return mm("btf,fd->btd", y.reshape(y.shape[:2] + (-1,)),
                   p[f"{lp}proj.w0"])
+
+
+@dataclasses.dataclass(frozen=True)
+class ShortConvBlock:
+    """The LFM2 (``lfm2_moe``) block: RMSNorm with a gain and no bias
+    anywhere, layers of two kinds by index. A layer in ``conv_layers``
+    (0-based) is a GATED SHORT CONVOLUTION, which keeps the last taps - 1
+    of its inputs a sequence and no keys or values:
+
+        [B | C | u] = h W_in;  v = B * u;  c_t = sum_j w_j v_{t-K+1+j};
+        y = (C * c) W_out
+
+    every other layer is full attention over per-head K/V: grouped-query
+    (``n_heads`` on ``n_kv_heads``), RMSNorm a head on q and k, rotary
+    positions over the whole head (halves paired). The first
+    ``first_dense_layers`` layers carry a dense SwiGLU, the rest
+    sigmoid-routed experts of which this chip holds a share and NO shared
+    expert (ops/moe.py ``routed_experts_ffn``, :class:`LatentBlock`'s
+    too). The head is ``lm_head`` where the table has one, else the
+    embedding (tied). Precision as the file's head says: stored matrices
+    and K/V in the table's dtype, products two-term, the stream, norms,
+    router, convolution and tails float32.
+
+    Its table:
+
+        <pre>tok_emb.w0 [V, d]   <pre>norm_f.w0 [d]   (<pre>lm_head.w0 [V, d])
+        <pre>l<i>_op_norm.w0 [d]     <pre>l<i>_ffn_norm.w0 [d]
+        conv:      <pre>l<i>_conv_in.w0 [d, 3d], _conv.w0 [K, d],
+                   _conv_out.w0 [d, d]
+        attention: <pre>l<i>_q.w0 [d, h*dh], _k.w0, _v.w0 [d, g*dh],
+                   _q_norm.w0, _k_norm.w0 [dh], _proj.w0 [h*dh, d]
+        dense and expert layers: as :class:`LatentBlock`'s, no ``_shared``"""
+
+    n_heads: int
+    head_dim: int
+    max_positions: int
+    conv_layers: tuple = ()
+    first_dense_layers: int = 2
+    experts_per_token: int = 4
+    routed_scaling_factor: float = 1.0
+    route_eps: float = 1e-6
+    expert_rank: int = 0            # this chip among those sharing a layer
+    rms_eps: float = 1e-5
+    rope_theta: float = 1e6
+
+    cache = StatePerHeadCache
+
+    @property
+    def state_layers(self) -> tuple:
+        return self.conv_layers
+
+    def positions(self, p, pre) -> int:
+        return self.max_positions        # rotary: what the table was cut to
+
+    def table_dtype(self, p, pre):
+        return p[f"{pre}tok_emb.w0"].dtype
+
+    def vocab_size(self, p, pre) -> int:
+        return p[f"{pre}tok_emb.w0"].shape[0]
+
+    def _first(self, conv: bool) -> int:
+        """The first layer of a kind: where the table's sizes are read."""
+        return next(i for i in range(len(self.conv_layers) + 1)
+                    if (i in self.conv_layers) == conv)
+
+    def heads(self, p, pre) -> tuple:
+        """(h, g, dh), the kv heads from the k projection's width."""
+        k = p[f"{pre}l{self._first(False)}_k.w0"]
+        return self.n_heads, k.shape[1] // self.head_dim, self.head_dim
+
+    def n_expert_layers(self, n_layers: int) -> int:
+        return max(n_layers - self.first_dense_layers, 0)
+
+    @functools.cache
+    def rope_table(self) -> np.ndarray:
+        """[2, max_positions, dh / 2] float32: cos and sin of position x
+        ``theta^(-2j/dh)``, made on the host in float64 (a table for the
+        reason :meth:`LatentBlock.rope_table` gives)."""
+        dh = self.head_dim
+        ang = np.outer(np.arange(self.max_positions, dtype=np.float64),
+                       self.rope_theta ** (
+                           -np.arange(0, dh, 2, dtype=np.float64) / dh))
+        return np.stack([np.cos(ang), np.sin(ang)]).astype(np.float32)
+
+    def rope(self, x, pos):
+        """x [B, t, H, dh] float32 at positions pos [B, t]: rotate-half
+        pairing (j, j + dh/2) over the whole head."""
+        cos, sin = jnp.asarray(self.rope_table())[:, pos][..., None, :]
+        a, b = jnp.split(x, 2, axis=-1)
+        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                               axis=-1)
+
+    # ------------------------------------------------------------- parts
+    def embed(self, p, pre, ids, pos=None):
+        return p[f"{pre}tok_emb.w0"][ids].astype(jnp.float32)
+
+    def logits(self, p, pre, x):
+        """x [B, t, d] -> float32 logits [B, t, V]."""
+        h = rms_norm(x, p[f"{pre}norm_f.w0"], self.rms_eps)
+        head = p.get(f"{pre}lm_head.w0", p[f"{pre}tok_emb.w0"])
+        return mm("btd,vd->btv", h, head)
+
+    def qkv(self, p, pre, i, x, pos, flat: bool = False):
+        """x [B, t, d] at positions pos [B, t] -> q [B, t, h, dh], k, v
+        [B, t, g, dh] float32, q and k normalised a head and rotated
+        (``flat``: k, v as the pool stores a token's row, [B*t, g*dh])."""
+        lp = f"{pre}l{i}_"
+        h_, g, _ = self.heads(p, pre)
+        h = rms_norm(x, p[f"{lp}op_norm.w0"], self.rms_eps)
+        q, k, v = (split_heads(mm("btd,df->btf", h, p[f"{lp}{n}.w0"]), m)
+                   for n, m in (("q", h_), ("k", g), ("v", g)))
+        with jax.named_scope("qk_norm_rope"):
+            q = self.rope(rms_norm(q, p[f"{lp}q_norm.w0"], self.rms_eps), pos)
+            k = self.rope(rms_norm(k, p[f"{lp}k_norm.w0"], self.rms_eps), pos)
+        if flat:
+            k, v = (a.reshape(x.shape[0] * x.shape[1], -1) for a in (k, v))
+        return q, k, v
+
+    def project(self, p, pre, i, attn):
+        return mm("btf,fd->btd", attn, p[f"{pre}l{i}_proj.w0"])
+
+    def state_width(self, p, pre) -> tuple:
+        """(taps, channels) of a conv layer's convolution."""
+        return p[f"{pre}l{self._first(True)}_conv.w0"].shape
+
+    def state_inputs(self, p, pre, i, x):
+        """x [B, t, d] -> (the convolution's input v = B * u, the output
+        gate C), both [B, t, d] float32."""
+        lp = f"{pre}l{i}_"
+        h = rms_norm(x, p[f"{lp}op_norm.w0"], self.rms_eps)
+        bcu = mm("btd,df->btf", h, p[f"{lp}conv_in.w0"])
+        with jax.named_scope("conv_gates"):
+            b, c, u = jnp.split(bcu, 3, axis=-1)
+            return b * u, c
+
+    def state_conv_weights(self, p, pre, i):
+        """[K, d] float32: ``c_t = sum_j w[j] * v_{t-K+1+j}``."""
+        return p[f"{pre}l{i}_conv.w0"].astype(jnp.float32)
+
+    def state_output(self, p, pre, i, y, gate):
+        """(C * conv) W_out: [B, t, d] -> [B, t, d]."""
+        with jax.named_scope("conv_gates"):
+            y = gate * y
+        return mm("btd,df->btf", y, p[f"{pre}l{i}_conv_out.w0"])
+
+    def ffn(self, p, pre, i, x, active=None):
+        """x [B, t, d] -> (x + FFN_i(RMSNorm(x)), held load int32 [2] or
+        None for a dense layer)."""
+        return swiglu_or_experts(
+            p, f"{pre}l{i}_", x, rms_eps=self.rms_eps,
+            expert=i >= self.first_dense_layers, shared=False,
+            active=active, k=self.experts_per_token,
+            scale=self.routed_scaling_factor, rank=self.expert_rank,
+            eps=self.route_eps)

@@ -507,7 +507,11 @@ class PagedDecoder:
         self._read_exe = self._write_exe = self._copy_state_exe = None
 
     def init_pools(self):
-        """Zeroed (k_pool, v_pool) as the cache kind stores them."""
+        """The cache kind's two pools, zeroed: whatever pytrees it says
+        they are (K and V pages; a latent pool and nothing; page pools and
+        a state's rows). Every method below names them ``k_pool, v_pool``
+        after the first kind and passes both through whole: the kind's
+        ``layer``, ``map_pages`` and ``map_state`` alone know the leaves."""
         return self.cache.init_pools()
 
     def pool_bytes(self) -> int:

@@ -271,19 +271,21 @@ def moe_ffn(x: jnp.ndarray, valid: Optional[jnp.ndarray],
 # one chip computes before the exchange, which this file does not stand in
 # for.
 def sigmoid_topk_route(h: jnp.ndarray, gate_w: jnp.ndarray,
-                       bias: jnp.ndarray, *, k: int, scale: float
+                       bias: jnp.ndarray, *, k: int, scale: float,
+                       eps: float = 1e-20
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """h [n, d], gate_w [d, E], bias [E] -> (idx [n, k] int32 over all E
     outputs, weights [n, k] float32). Scores, choice and weights are
     float32 at the highest precision whatever the compute dtype: the bias
     moves the CHOICE (``top_k(s + bias)``), never the weight
-    (``s[idx] / (sum s[idx] + 1e-20) * scale``)."""
+    (``s[idx] / (sum s[idx] + eps) * scale``; ``eps`` is the family's own:
+    DeepSeek-V3's and Kimi's 1e-20, LFM2's 1e-6)."""
     s = jax.nn.sigmoid(jnp.matmul(
         h.astype(jnp.float32), gate_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
     picked = jnp.take_along_axis(s, idx, axis=-1)
-    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), weights * scale
 
 
@@ -326,3 +328,33 @@ def held_experts_ffn(x, comb, w_gate, w_up, w_down) -> jnp.ndarray:
     a = jax.nn.silu(_mm("nd,edf->nef", x, w_gate)) * \
         _mm("nd,edf->nef", x, w_up)
     return _mm("nef,efd->nd", a * comb[:, :, None], w_down)
+
+
+def routed_experts_ffn(h, router_w, router_bias, experts, shared=None, *,
+                       k: int, scale: float, rank: int = 0,
+                       eps: float = 1e-20, active=None):
+    """A chip's share of a sigmoid-routed expert layer, the one function
+    every description with such a layer calls (models/block.py): h [n, d]
+    float32, the layer's normalised input; the router over ALL its
+    outputs (:func:`sigmoid_topk_route` with the family's ``eps``);
+    ``experts`` (gate, up [n_held, d, f], down [n_held, f, d]), the held
+    ones, which are ``[n_held * rank, n_held * (rank + 1))`` of the
+    router's outputs; ``shared`` (gate, up, down) a shared expert every
+    token passes whole, or None where the family has none; ``active`` [n]
+    bool masks the load count, never the result. -> (y [n, d] float32,
+    the held load int32 [2] of :func:`held_load`)."""
+    n_held = experts[0].shape[0]
+    lo = n_held * rank
+    with jax.named_scope("router"):
+        idx, wts = sigmoid_topk_route(h, router_w, router_bias, k=k,
+                                      scale=scale, eps=eps)
+        comb = held_combine(idx, wts, lo=lo, n_held=n_held)
+        act = jnp.ones((h.shape[0],), jnp.bool_) if active is None \
+            else active.reshape(-1)
+        load = held_load(idx, act, lo=lo, n_held=n_held)
+    with jax.named_scope("experts"):
+        y = held_experts_ffn(h, comb, *experts)
+    if shared is not None:
+        with jax.named_scope("shared_expert"):
+            y = y + swiglu(h, *shared)
+    return y, load

@@ -308,8 +308,11 @@ def _paged_window_kernel(tables_ref, used_ref, lens_ref, layer_ref, q_ref,
     is bfloat16 and the pools bfloat16 or int8 (every int8 is a
     bfloat16), their product goes to the MXU as it is — a bf16 x bf16
     product summed in float32 is exact — and the float32 probabilities
-    go as two bfloat16 terms (:func:`_two_terms`); every other pairing
-    of dtypes is cast to float32 first. m, l and the accumulator are
+    go as two bfloat16 terms (:func:`_two_terms`); a float32 q over
+    bfloat16 pools goes as two bfloat16 terms too (``split_q``: a block
+    with a float32 stream keeps 16 bits of its query, and no page is
+    copied to float32); every other pairing of dtypes is cast to float32
+    first. m, l and the accumulator are
     float32.
 
     ``quant`` is the dequant-FUSED variant over int8 pools. A row's
@@ -332,6 +335,13 @@ def _paged_window_kernel(tables_ref, used_ref, lens_ref, layer_ref, q_ref,
     span = pages_per_block * page_size
     exact = q_ref.dtype == jnp.bfloat16 and pools[0].dtype in (
         jnp.bfloat16, jnp.int8)
+    # a float32 query over bfloat16 pages (a block whose stream is
+    # float32, models/block.py): the pages go to the MXU as they are and
+    # the query as two bfloat16 terms, twice the query rows and no
+    # float32 copy of a page
+    split_q = q_ref.dtype == jnp.float32 and \
+        pools[0].dtype == jnp.bfloat16 and not quant
+    exact = exact or split_q
     cdt = jnp.bfloat16 if exact else jnp.float32
 
     m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
@@ -367,9 +377,13 @@ def _paged_window_kernel(tables_ref, used_ref, lens_ref, layer_ref, q_ref,
         for c in range(n_chunks):
             lanes = (slice(None), slice(c * C, (c + 1) * C))
             sc = jax.lax.dot_general(
-                q_ref[0, c].astype(cdt), k_ref[lanes].astype(cdt),
+                _two_terms(q_ref[0, c]) if split_q
+                else q_ref[0, c].astype(cdt), k_ref[lanes].astype(cdt),
                 (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * (scale * LOG2E)
+                preferred_element_type=jnp.float32)
+            if split_q:
+                sc = sc[:rows_n] + sc[rows_n:]
+            sc = sc * (scale * LOG2E)
             if quant:
                 sc = sc * row_scales(ks_ref, c)
             sc = jnp.where(live, sc, NEG_INF)          # [rows_n, span]
@@ -429,16 +443,30 @@ _PAGED_SMEM_BYTES = 960 * 1024
 # many pages, few enough that a slot's last, part-filled block wastes
 # little (a block is computed whole)
 _WINDOW_ROWS_PER_BLOCK = 128
+# ... times this many for rows narrower than 2,048 lanes (32 kv heads of
+# 64), so that a block's tile keeps about the elements those 128 rows have
+# there, up to 512 rows: a block's fixed work (its pages' DMAs started and
+# waited for, the mask, a chunk's two small products) stands against its
+# bytes, and at 512 lanes (8 kv heads of 64) 128 rows are a quarter of
+# them. LFM2's 8.4k-token slots read 1.60 s of a 3-s trace at 128 rows and
+# 1.18 s at 512 (v5e, PERF.md section 6, PR 41). Judged at 512 and 2,048
+# lanes alone: a third width (1,024 lanes) is the first chip trial a later
+# kernel PR owes the rule
+_WINDOW_ROWS_MOST = 512
 
 
 def _window_pages_per_block(page_size: int, pages_per_slot: int,
-                            row_bytes: int) -> int:
+                            row_bytes: int, lanes: int) -> int:
     """Pages in one compute block of the window kernel: about
-    ``_WINDOW_ROWS_PER_BLOCK`` cache rows, no more than a slot's table
+    ``_WINDOW_ROWS_PER_BLOCK`` cache rows of 2,048 ``lanes`` (g*dh) and
+    in proportion more of narrower ones, no more than a slot's table
     holds, halved until K's and V's double-buffered tiles
     (``row_bytes`` a cache row of one) take at most half the VMEM
     budget. -> 0 where not even one page does."""
-    k = max(1, min(_WINDOW_ROWS_PER_BLOCK // page_size, pages_per_slot))
+    rows = max(_WINDOW_ROWS_PER_BLOCK,
+               min(_WINDOW_ROWS_MOST,
+                   _WINDOW_ROWS_PER_BLOCK * max(1, 2048 // lanes)))
+    k = max(1, min(rows // page_size, pages_per_slot))
     while k and 4 * k * page_size * row_bytes > _PAGED_VMEM_BYTES // 2:
         k //= 2
     return k
@@ -451,7 +479,7 @@ def _window_vmem(q, k_pages, quant, pages_per_slot):
     ps, gd = k_pages.shape[-2:]
     g = gd // dh
     row_bytes = _round_up(gd, 128) * jnp.dtype(k_pages.dtype).itemsize
-    K = _window_pages_per_block(ps, pages_per_slot, row_bytes)
+    K = _window_pages_per_block(ps, pages_per_slot, row_bytes, gd)
     span = _round_up(max(K, 1) * ps, 128)
     hp = _heads_per_chunk(g, dh)
     chunk = _round_up(hp * dh, 128)

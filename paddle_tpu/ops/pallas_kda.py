@@ -17,10 +17,12 @@ spread over the lanes), which the vector unit does with adds alone;
 held transposed they were reductions along the lanes, three a token, and
 the kernel read 35 % of its roofline (my chip run, PR 39).
 
-:func:`kda_short_conv` is the layer's short convolution (depthwise,
-causal, kernel 4, SiLU) over the same rows, with the last three inputs of
-each sequence kept in a pool of TAILS beside the states and moved in place
-the same way (kernel ``kda_short_conv``). The tails pool is touched by
+:func:`short_conv` is a layer's short convolution (depthwise, causal;
+the taps from the weights' shape, SiLU or none from the description:
+Kimi-Linear's is 4 taps and SiLU, :func:`kda_short_conv`; LFM2's 3 taps
+and none) over the same rows, with the last taps - 1 inputs of each
+sequence kept in a pool of TAILS and moved in place the same way (kernel
+``kda_short_conv`` / ``short_conv``). The tails pool is touched by
 kernels alone: left to XLA's scatter, a pool of rows this wide was
 re-laid out whole around every layer's write (12-18 ms of a 40 ms step,
 my chip runs, PR 39).
@@ -221,16 +223,23 @@ def kda_state_update(pool, q, k, v, g, beta, rows, first, fed, *, layer,
 
 
 # ------------------------------------------------------- the short convolution
+# One convolution for every block that has one (models/block.py): depthwise,
+# causal, ``K = w.shape[0]`` taps (Kimi-Linear's 4, LFM2's 3), followed by
+# SiLU or by nothing, with the last K - 1 inputs of each sequence kept as a
+# row of a TAILS pool.
+
+
 def conv_windows(x, tail, fed, cont):
     """The short convolution's inputs for a group's rows. x [B, C, ch],
-    row b's tokens (the first ``fed[b]`` are fed); tail [B, 3, ch], the
-    last three inputs of row b's sequence before this step; ``cont[b]``:
+    row b's tokens (the first ``fed[b]`` are fed); tail [B, K - 1, ch], the
+    last K - 1 inputs of row b's sequence before this step; ``cont[b]``:
     row b goes on where row b - 1 ended (such a chain's rows lie one after
-    the other and all but its last are full). -> (win [B, C, 4, ch]: each
-    token's inputs three back to itself, from its chain or, before the
-    chain's start, from the tail; the new tail [B, 3, ch] after row b's
-    last fed token)."""
+    the other and all but its last are full). -> (win [B, C, K, ch]: each
+    token's inputs K - 1 back to itself, from its chain or, before the
+    chain's start, from the tail; the new tail [B, K - 1, ch] after row
+    b's last fed token)."""
     B, C, ch = x.shape
+    back_n = tail.shape[1]
     rows = jnp.arange(B, dtype=jnp.int32)
     head = jax.lax.cummax(jnp.where(cont, -1, rows), axis=0)  # chain's first
     # tokens of the chain before this row: its rows are full but the last
@@ -243,32 +252,37 @@ def conv_windows(x, tail, fed, cont):
         ``at``): the chain's own token, or the tail's."""
         mine = flat[jnp.clip(at - n, 0, B * C - 1)]
         old = jnp.take_along_axis(
-            tail, jnp.clip(3 + off - n, 0, 2)[..., None], axis=1)
+            tail, jnp.clip(back_n + off - n, 0, back_n - 1)[..., None],
+            axis=1)
         return jnp.where((off >= n)[..., None], mine, old)
 
-    win = jnp.stack([back(at, off, 3), back(at, off, 2), back(at, off, 1),
-                     x], axis=2)
-    # the tail after the row's last fed token: inputs e - 3 .. e - 1 of
-    # the chain, e its tokens through this row
+    win = jnp.stack([back(at, off, back_n - j) for j in range(back_n)]
+                    + [x], axis=2)
+    # the tail after the row's last fed token: inputs e - (K - 1) .. e - 1
+    # of the chain, e its tokens through this row
     e = (rows - head)[:, None] * C + fed[:, None]            # [B, 1]
     at_e = head[:, None] * C + e
-    new = jnp.concatenate([back(at_e, e, 3 - j) for j in range(3)], axis=1)
+    new = jnp.concatenate([back(at_e, e, back_n - j) for j in range(back_n)],
+                          axis=1)
     return win, new
 
 
-def conv_of_windows(win, w):
-    """win [..., 4, ch], w [4, ch] -> silu(sum_j w[j] * win[..., j, :])."""
-    return jax.nn.silu(jnp.sum(win * w.astype(jnp.float32), axis=-2))
+def conv_of_windows(win, w, silu: bool = True):
+    """win [..., K, ch], w [K, ch] -> sum_j w[j] * win[..., j, :], through
+    SiLU where the block's convolution has one."""
+    y = jnp.sum(win * w.astype(jnp.float32), axis=-2)
+    return jax.nn.silu(y) if silu else y
 
 
-def _kda_conv_kernel(src_ref, fed_ref, first_ref, x_ref, w_ref, t_in_ref,
-                     y_ref, t_out_ref, *, tokens, chained):
-    """Grid (B,): row b's tail is one [3*T, 128] block of the pool, in and
-    out under one block index (as the state kernel's); x_ref, y_ref
-    [1, C, T, 128]; w_ref [4, T, 128]."""
+def _short_conv_kernel(src_ref, fed_ref, first_ref, x_ref, w_ref, t_in_ref,
+                       y_ref, t_out_ref, *, tokens, chained, silu):
+    """Grid (B,): row b's tail is one [(K-1)*T, 128] block of the pool, in
+    and out under one block index (as the state kernel's); x_ref, y_ref
+    [1, C, T, 128]; w_ref [K, T, 128]."""
     b = pl.program_id(0)
     n = fed_ref[b]
     T = x_ref.shape[2]
+    back_n = w_ref.shape[0] - 1
 
     @pl.when(n > 0)
     def _fed():
@@ -276,27 +290,35 @@ def _kda_conv_kernel(src_ref, fed_ref, first_ref, x_ref, w_ref, t_in_ref,
             if chained else t_in_ref[0, 0]
         def token(c, taps):
             x = x_ref[0, c]
-            y = w_ref[3] * x
-            for j in range(3):
+            y = w_ref[back_n] * x
+            for j in range(back_n):
                 y = y + w_ref[j] * taps[j]
-            y_ref[0, c] = y * jax.nn.sigmoid(y)
-            return taps[1], taps[2], x
+            y_ref[0, c] = y * jax.nn.sigmoid(y) if silu else y
+            return taps[1:] + (x,)
 
-        taps = tuple(src[j * T:(j + 1) * T] for j in range(3))
+        taps = tuple(src[j * T:(j + 1) * T] for j in range(back_n))
         taps = token(0, taps) if tokens == 1 else \
             jax.lax.fori_loop(0, n, token, taps)
         t_out_ref[0, 0] = jnp.concatenate(taps, axis=0)
 
 
-def kda_short_conv(tails, x, w, rows, first, fed, *, layer, junk_row,
-                   use_kernel=False, interpret=False):
-    """tails [L, R, 3*T, 128] float32 (T = ch / 128; donated by the step:
-    updated in place): the last three inputs of each sequence, one after
-    the other; x [B, C, ch] the rows' inputs, of which the first
-    ``fed[b]`` are fed; w [4, ch]; rows, first, fed, ``layer``,
-    ``junk_row`` as :func:`kda_state_update`'s. -> (y [B, C, ch] =
-    silu(causal depthwise conv), of the fed tokens; tails')."""
+def short_conv(tails, x, w, rows, first, fed, *, layer, junk_row,
+               use_kernel=False, interpret=False, silu: bool = True,
+               name: str = "short_conv"):
+    """tails [L, R, (K-1)*T, 128] float32 (K = w.shape[0] taps, T = ch /
+    128; donated by the step: updated in place): the last K - 1 inputs of
+    each sequence, one after the other; x [B, C, ch] the rows' inputs, of
+    which the first ``fed[b]`` are fed; w [K, ch]; rows, first, fed,
+    ``layer``, ``junk_row`` as :func:`kda_state_update`'s; ``name`` the
+    kernel's in a device trace. -> (y [B, C, ch] = the causal depthwise
+    convolution, through SiLU where ``silu``, of the fed tokens; tails').
+
+    One block shape for both families: a row's tail and a token's channels
+    as [., 128] tiles of float32, whole in VMEM (Kimi-Linear's 12,288
+    channels x 3 tails are 144 KB a row, LFM2's 2,048 x 2 are 16 KB); the
+    grid walks the fed rows."""
     B, C, ch = x.shape
+    back_n = w.shape[0] - 1
     rows = jnp.asarray(rows, jnp.int32)
     fed = jnp.asarray(fed, jnp.int32)
     chained = first is not None
@@ -306,37 +328,43 @@ def kda_short_conv(tails, x, w, rows, first, fed, *, layer, junk_row,
     if not use_kernel:
         # a row that goes on from the row before it reads what that row
         # would have written: the windows take it from the chain itself
-        win, new = conv_windows(x, tails[layer, rows].reshape(B, 3, ch),
+        win, new = conv_windows(x, tails[layer, rows].reshape(B, back_n, ch),
                                 fed, ~first)
         last = (fed > 0) & jnp.concatenate(
             [first[1:], jnp.ones((1,), jnp.bool_)])
         tails = tails.at[layer, jnp.where(last, rows, junk_row)].set(
             new.reshape((B,) + tails.shape[2:]))
-        return conv_of_windows(win, w), tails
+        return conv_of_windows(win, w, silu), tails
     T = ch // 128
     src = _visited(rows, fed, junk_row)
 
     def _row_map(b, *_):
         return (b, 0, 0, 0)
 
-    tail_spec = pl.BlockSpec((1, 1, 3 * T, 128),
+    tail_spec = pl.BlockSpec((1, 1, back_n * T, 128),
                              lambda b, src, *_: (layer, src[b], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[pl.BlockSpec((1, C, T, 128), _row_map),
-                  pl.BlockSpec((4, T, 128), lambda b, *_: (0, 0, 0)),
+                  pl.BlockSpec((back_n + 1, T, 128), lambda b, *_: (0, 0, 0)),
                   tail_spec],
         out_specs=[pl.BlockSpec((1, C, T, 128), _row_map), tail_spec])
     y, tails = pl.pallas_call(
-        functools.partial(_kda_conv_kernel, tokens=C, chained=chained),
+        functools.partial(_short_conv_kernel, tokens=C, chained=chained,
+                          silu=silu),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, C, T, 128), jnp.float32),
                    jax.ShapeDtypeStruct(tails.shape, tails.dtype)],
         input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret, name="kda_short_conv",
+        interpret=interpret, name=name,
     )(src, fed, first.astype(jnp.int32), x.reshape(B, C, T, 128),
-      w.astype(jnp.float32).reshape(4, T, 128), tails)
+      w.astype(jnp.float32).reshape(back_n + 1, T, 128), tails)
     return y.reshape(B, C, ch), tails
+
+
+#: Kimi-Linear's: 4 taps and SiLU, under the name its reader knows
+#: (benchmarks/layer_metrics, PERF.md section 3)
+kda_short_conv = functools.partial(short_conv, name="kda_short_conv")

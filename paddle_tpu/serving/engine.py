@@ -478,10 +478,11 @@ class DecodeEngine:
             journal_emit("engine", "dequant_fallback",
                          reason="kernel_unsupported", kv_quant=kv_quant)
         self.pool = PagePool(int(num_pages))
-        # the pools are the decoder's business: whatever init_pools()
-        # gives (per-head K and V, the int8 pytrees, a latent block's
-        # one pool and an empty pytree) is only ever handed back to its
-        # step and its page copy / read / write
+        # the pools are the decoder's business: whatever TWO pytrees
+        # init_pools() gives (per-head K and V, the int8 pytrees, a latent
+        # block's one pool and an empty pytree, page pools and a state's
+        # rows) are only ever handed back, whole, to its step and its
+        # page and row programs
         self.k_pool, self.v_pool = self.paged.init_pools()
         # a cache kind that keeps a recurrent state a slot beside its
         # pages (``state_rows``): slot s's state is row s of its state

@@ -637,3 +637,151 @@ def test_state_step_updates_both_pools_in_place(chip_executable,
                   if op not in ("parameter", "get-tuple-element", "tuple",
                                 "bitcast", "custom-call")]
         assert not strays, strays
+
+
+# --------------------------------- short-convolution tails beside per-head pages
+@pytest.mark.parametrize("S,W", [(32, 1), (16, 16)], ids=["slots", "lanes"])
+def test_paged_kernel_lowers_at_gqa_over_long_tables(chip_compile, S, W):
+    """``lfm2_doc_8k``'s own calls: 32 query heads on 8 kv heads of 64
+    (two kv heads x four query heads a lane chunk), a float32 query over
+    bfloat16 pages (two terms), a table of 288 pages of 32, the pool with
+    its layer axis; the slots' one token and 16 lanes of 16. One Mosaic
+    call, and no copy of a pool beside it."""
+    from paddle_tpu.ops.pallas_decode import (paged_kernel_supported,
+                                              paged_window_attention)
+    q = _sds((S, W, 32, 64), jnp.float32)
+    pool = _sds((10, 9216, 32, 512), jnp.bfloat16)
+    assert paged_kernel_supported(q, pool, None, pages_per_slot=288)
+
+    def fn(q, k, v, tables, lens):
+        return paged_window_attention(q, k, v, tables, lens, layer=7,
+                                      use_kernel=True)
+
+    hlo = chip_compile(fn, q, pool, pool, _sds((S, 288), jnp.int32),
+                       _sds((S, W), jnp.int32))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    strays = [line.strip()[:160]
+              for op, line in _pool_sized_ops(hlo, pool.shape)
+              if op != "parameter"]
+    assert not strays, strays
+
+
+@pytest.mark.parametrize("taps,silu,ch", [(3, False, 2048), (4, True, 12288)],
+                         ids=["lfm2", "kimi-linear"])
+@pytest.mark.parametrize("B,C", [(32, 1), (16, 16)], ids=["slots", "lanes"])
+def test_short_conv_kernel_lowers(chip_executable, B, C, taps, silu, ch):
+    """``short_conv`` at both families' widths (LFM2: 2,048 channels, 3
+    taps, no activation; Kimi-Linear: 12,288 channels, 4 taps, SiLU) over
+    a pool of 98 rows: the tails are written in place, all their bytes
+    aliased, no temporary of their size."""
+    from paddle_tpu.ops import pallas_kda as kk
+    L, R = 6, 98
+
+    def fn(tails, x, w, rows, first, fed):
+        return kk.short_conv(tails, x, w, rows, first, fed, layer=3,
+                             junk_row=R - 1, use_kernel=True, silu=silu)
+
+    compiled = chip_executable(
+        fn, _sds((L, R, (taps - 1) * ch // 128, 128), jnp.float32),
+        _sds((B, C, ch), jnp.float32), _sds((taps, ch), jnp.float32),
+        _sds((B,), jnp.int32), _sds((B,), jnp.bool_), _sds((B,), jnp.int32),
+        donate=(0,))
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    mem = compiled.memory_analysis()
+    pool_bytes = L * R * (taps - 1) * ch * 4
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < max(pool_bytes // 10,
+                                        4 * B * C * ch * 4), mem
+
+
+@pytest.fixture(scope="module")
+def lfm2_paged():
+    """``lfm2_doc_8k``'s paged decoder at the published widths over zero
+    weights (only shapes are compiled), its first two periods (8 of the 40
+    layers: the program is the same a period, and 40 compile in 40 s):
+    32 slots, 9,216 pages of 32."""
+    from benchmarks.lib import manifest
+    from paddle_tpu import models
+    cell = manifest.cell(manifest.load_manifest(), "lfm2_doc_8k")
+    cfg = dict(cell["config"], num_hidden_layers=8,
+               layer_types=cell["config"]["layer_types"][:8])
+    dep = cfg["deployment"]
+    params = {cell["model"].program_name(k):
+              jax.ShapeDtypeStruct(v, jnp.bfloat16)
+              for k, v in cell["reference"].leaf_shapes(cfg).items()}
+    dec = models.TransformerDecoder(
+        {}, n_layers=8, n_heads=32, name=cell["model"].NAME,
+        block=cell["model"].block_of(cfg, dep["max_seq_len"]))
+    dec.p = params
+    backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        paged = dec.paged(num_slots=dep["num_slots"],
+                          page_size=dep["page_size"],
+                          num_pages=dep["num_pages"], max_pages_per_slot=288,
+                          state_snapshots=dep["state_snapshots"],
+                          warm_start=False)
+    finally:
+        jax.default_backend = backend
+    return dec, paged
+
+
+@pytest.mark.parametrize("program", ["plain", "lanes", "copy_state",
+                                     "copy_page"])
+def test_conv_step_updates_pages_and_tails_in_place(chip_executable,
+                                                    lfm2_paged, program):
+    """The serving step of the cell, its lane program, the row copy of a
+    snapshot and the page copy of a partial match: K and V pages over the
+    attention layers alone and the float32 tails over the conv layers are
+    donated and every byte of them aliased; a conv layer is one kernel
+    call a group of rows, an attention layer one; no pool is copied,
+    sliced or re-laid out whole."""
+    from paddle_tpu.models.block import StatePerHeadCache
+    dec, paged = lfm2_paged
+    assert paged.use_kernel and not paged.kernel_interpret
+    assert paged.cache.state_kernel
+    assert paged.lanes == (StatePerHeadCache.LANE_TOKENS // 16, 16)
+    pages, state = jax.eval_shape(paged.init_pools)
+    assert pages["k"].shape == pages["v"].shape == (2, 9216, 32, 512)
+    assert state["conv"].shape == (6, 32 + 64 + 2, 2 * 16, 128)
+    if program.startswith("copy"):
+        n = _sds((), jnp.int32)
+        impl = paged._copy_state_impl if program == "copy_state" \
+            else paged._copy_page_impl
+        compiled = chip_executable(impl, pages, state, n, n, donate=(0, 1))
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == paged.pool_bytes()
+        assert mem.temp_size_in_bytes < 32 * 1024 * 1024, mem
+        return
+    sw = _sds((32, 1), jnp.int32)
+    args = (dec.p, pages, state, sw, sw, _sds((32, 288), jnp.int32),
+            _sds((32, 1), jnp.bool_), _sds((2,), jnp.uint32))
+    if program == "lanes":
+        compiled = chip_executable(paged._step_impl_lanes,
+                                   *_lane_args(paged, args), donate=(1, 2))
+    else:
+        compiled = chip_executable(paged._step_impl, *args, donate=(1, 2))
+    groups = 2 if program == "lanes" else 1
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == groups * 8
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == paged.pool_bytes()
+    assert mem.temp_size_in_bytes < paged.pool_bytes() // 8, mem
+    # what may have a pool's shape: the pools themselves, the in-place
+    # row scatters of the kv_write scope, and the kernel that writes
+    # tails; of the tails pool also the compiler's own moves of it into
+    # its fast memory space and back (this fixture's 9.6 MB it moves once;
+    # the cell's 48 MB over 40 layers it leaves where they are at 128
+    # lane tokens and moved 28 times at 256: PERF.md section 7, "Open
+    # after PR 41"), never a re-layout
+    own = ("parameter", "get-tuple-element", "tuple", "bitcast",
+           "custom-call", "scatter")
+    moves = ("copy-start", "copy-done", "slice-start", "slice-done")
+    for shape, allowed in ((pages["k"].shape, own),
+                           (state["conv"].shape, own + moves)):
+        strays = [line.strip()[:160]
+                  for op, line in _pool_sized_ops(hlo, shape)
+                  if op not in allowed
+                  and not (op == "fusion" and "kv_write/scatter" in line)]
+        assert not strays, strays

@@ -141,13 +141,14 @@ def _walk_parity(case, *, W, h, g, dh, ps, layered, quant, seed=31,
             jax.numpy.asarray(tables.astype(np.int32)),
             jax.numpy.asarray(lens.astype(np.int32)))
     want = np.asarray(pd.paged_window_attention(*args, **kw))
-    rows_was = pd._WINDOW_ROWS_PER_BLOCK
-    pd._WINDOW_ROWS_PER_BLOCK = span
+    rows_was = pd._WINDOW_ROWS_PER_BLOCK, pd._WINDOW_ROWS_MOST
+    # narrow rows get no wider block either: the cases count on K pages
+    pd._WINDOW_ROWS_PER_BLOCK = pd._WINDOW_ROWS_MOST = span
     try:
         got = np.asarray(pd.paged_window_attention(
             *args, use_kernel=True, interpret=interpret, **kw))
     finally:
-        pd._WINDOW_ROWS_PER_BLOCK = rows_was
+        pd._WINDOW_ROWS_PER_BLOCK, pd._WINDOW_ROWS_MOST = rows_was
     live = base > 0
     np.testing.assert_allclose(got[live], want[live], rtol=2e-4,
                                atol=2e-5)

@@ -105,6 +105,40 @@ def test_state_step_is_the_module_the_benchmark_matches(state_step_text):
 
 
 @pytest.fixture(scope="module")
+def conv_step_text():
+    """The step of a block of gated short convolutions beside per-head
+    pages (models/block.py ShortConvBlock), on the kernel path."""
+    from test_conv_decode import CFG as CCFG, MODEL, REF, SEED
+    from paddle_tpu import models
+    named = MODEL.make_weights(REF, SEED, CCFG, jnp.float32)
+    dec = models.TransformerDecoder(
+        named, n_layers=CCFG["num_hidden_layers"],
+        n_heads=CCFG["num_attention_heads"], name=MODEL.NAME,
+        block=MODEL.block_of(CCFG, 64))
+    eng = DecodeEngine(dec, num_slots=2, page_size=4, max_seq_len=32,
+                       attention="kernel", state_snapshots=2)
+    z = jnp.zeros((2, 1), jnp.int32)
+    args = (dec.p, eng.k_pool, eng.v_pool, z, z, jnp.asarray(eng._tables),
+            jnp.zeros((2, 1), jnp.bool_), jax.random.PRNGKey(0))
+    return eng.paged._step.lower(*args).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", [
+    "embed", "conv_gates", "short_conv", "qk_norm_rope", "kv_write",
+    "paged_attn", "paged_window_attention", "ffn", "router", "experts",
+    "logits"])
+def test_conv_step_carries_the_name(conv_step_text, name):
+    assert _has(conv_step_text, name)
+
+
+def test_conv_step_has_no_shared_expert_and_is_the_module_matched(
+        conv_step_text):
+    assert not _has(conv_step_text, "shared_expert")
+    assert not _has(conv_step_text, "kda_short_conv")
+    assert "module @jit__step_impl" in conv_step_text
+
+
+@pytest.fixture(scope="module")
 def train_step_text():
     from benchmarks.lib import manifest, paddle_lm
     cfg = {"hidden_size": 32, "num_hidden_layers": 2,

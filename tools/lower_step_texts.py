@@ -20,7 +20,10 @@ paged step (gather and kernel, fp and int8, W 1 and 3, sampled),
 Where the tree's ``PagedDecoder`` has prefill lanes (PR 38), the LANE
 program of each deployment-shape and tiny step is lowered beside the plain
 one, as ``<name>_lanes``: a tree without them lowers the plain ones alone,
-so the plain hashes of a parent and a change still compare.
+so the plain hashes of a parent and a change still compare. Where the tree
+has them, the blocks with a state a slot (Kimi-Linear, LFM2) are lowered
+the same way, at their cells' deployment shapes and at ``tiny()``; a tree
+whose program cannot serve one skips it.
 """
 import base64
 import hashlib
@@ -204,6 +207,58 @@ with cache.disabled():
     page = sds((), jnp.int32)
     record("kimik2_tiny_read", paged._read.lower(k_pool, v_pool,
                                                  page).as_text())
+
+    # ---------------- (c'): the blocks with a state a slot, where the tree
+    # has them (Kimi-Linear since PR 39, LFM2 since PR 41): the step at the
+    # cell's deployment shape, kernel path, and at ``tiny()`` on the CPU
+    def state_family(tag, workload, S, P):
+        try:
+            cell = manifest.cell(manifest.load_manifest(), workload)
+        except (SystemExit, ImportError, FileNotFoundError) as e:
+            print("skip", tag, type(e).__name__, e)
+            return
+        cfg, M, R = dict(cell["config"]), cell["model"], cell["reference"]
+        dep = cfg["deployment"]
+        L = int(cfg["num_hidden_layers"])
+        kp = {M.program_name(k, cfg): jax.ShapeDtypeStruct(v, jnp.bfloat16)
+              for k, v in R.leaf_shapes(cfg).items()}
+        dec = models.TransformerDecoder(
+            {}, n_layers=L, n_heads=int(cfg["num_attention_heads"]),
+            name=M.NAME, block=M.block_of(cfg, int(dep["max_seq_len"])))
+        dec.p = kp
+        jax.default_backend = lambda: "tpu"
+        paged = dec.paged(num_slots=S, page_size=int(dep["page_size"]),
+                          num_pages=int(dep["num_pages"]),
+                          max_pages_per_slot=P, warm_start=False,
+                          state_snapshots=int(dep["state_snapshots"]))
+        assert paged.use_kernel and not paged.kernel_interpret
+        record(f"{tag}_step_deploy",
+               lower_chip(paged._step_impl, *step_args(paged, S, P),
+                          donate=(1, 2)))
+        record(f"{tag}_step_deploy_lanes",
+               lower_chip(paged._step_impl_lanes, *lane_args(paged, S, P),
+                          donate=(1, 2)))
+        jax.default_backend = real_backend
+        tcfg = M.tiny()
+        named = M.make_weights(R, 7, tcfg, jnp.float32)
+        tiny = models.TransformerDecoder(
+            named, n_layers=tcfg["num_hidden_layers"],
+            n_heads=tcfg["num_attention_heads"], name=M.NAME,
+            block=M.block_of(tcfg, 64))
+        for att in ("gather", "kernel"):
+            paged = tiny.paged(num_slots=4, page_size=4, num_pages=80,
+                               max_pages_per_slot=16, warm_start=False,
+                               attention=att, state_snapshots=6)
+            record(f"{tag}_tiny_step_{att}", paged._step.lower(
+                *step_args(paged, 4, 16)).as_text())
+            record(f"{tag}_tiny_step_{att}_lanes", paged._lane_step.lower(
+                *lane_args(paged, 4, 16)).as_text())
+        record(f"{tag}_tiny_generate", tiny._build(5, 12, None).lower(
+            tiny.p, sds((2, 5), jnp.int32),
+            sds((2,), jnp.uint32)).as_text())
+
+    state_family("kimilinear", "kimilinear_agent_2k", 128, 128)
+    state_family("lfm2", "lfm2_doc_8k", 32, 288)
 
     # ---------------- (d): toy default block: paged (fp/int8, gather and
     # interpret kernel, W 1 and 3), draft, generate, beams, MoE
