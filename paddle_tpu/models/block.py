@@ -525,8 +525,9 @@ class LatentCache:
     empty pytree: every page program and donation maps over nothing."""
 
     LAYOUT = "L,N,page,c_kv|k_rope|0"
-    #: the latent kernel's block index maps hold the layer as a number
-    #: of the program, fixed when it is traced
+    #: ``layer`` traces a layer under its own index. The kernel has taken
+    #: the layer as an operand since PR 42; the lane program's text (and
+    #: a start's trace) change with this flag, which ROADMAP S7 flips
     layer_operand = False
     refuses = {
         "kv_quant": "kv_quant is not supported on a latent (MLA) cache: the "

@@ -7,9 +7,9 @@ of models/block.py) reads its page pools through two entry points:
 (:func:`_paged_window_kernel`) walks the LIVE pages itself. The pools
 stay in HBM whole; the grid is over slots alone; inside a slot's step a
 loop of ``ceil(used[s] / K)`` compute blocks (:func:`_walk_live_pages`,
-which knows nothing of heads or softmax, and of which this kernel is the
-one caller) copies each live page with a DMA of its own into its rows of
-a double-buffered [K*page_size, g*dh] tile while the block before is
+which knows nothing of heads or softmax: both kernels of this file are
+bodies over it) copies each live page with a DMA of its own into its rows
+of a double-buffered [K*page_size, g*dh] tile while the block before is
 computed, and starts the NEXT live slot's first block before this slot's
 last one ends. A page past a slot's allocation is never named, copied or
 visited, and an idle slot costs no DMA: a call's time follows the cached
@@ -24,8 +24,9 @@ across blocks.
 
 :func:`paged_latent_attention` over a latent (MLA) pool, one
 [c_kv | k_rope] row a token. Its kernel (:func:`_paged_latent_kernel`)
-still lets the pipeline walk the table with its own grid (S, blocks), a
-second page walk beside ``_walk_live_pages`` (ROADMAP D11).
+is a second body over the same walk (PR 42): ONE pool, the row's 640
+lanes whole tiles, every head's query rows against a block's tile in one
+product, the tile used as key and as value.
 
 :func:`paged_attention` is the GATHER REFERENCE both fall back to and
 are held to: the page gather produces the contiguous [b, T, g, dh] view
@@ -670,21 +671,35 @@ def _live_pages_call(q, k_pages, v_pages, page_tables, lens, layer,
 # row and the probabilities weight its first rkv lanes again: a latent page
 # brought from HBM once serves as key and as value, for all heads.
 #
-# What the kernel's grid costs (v5e, 64 slots x 64 heads, 4096 positions a
-# slot, bf16; my chip run, PR 35): every visit of a page operand by a grid
-# step costs ~65 ns of the scalar core whether its DMA is skipped or not,
-# so a call's time follows slots x table width x operands a page, not the
-# live tokens (an EMPTY cache read 1.89 ms where 2,300 tokens a slot read
-# 2.41, with c_kv and k_rope as two pools of 16-row pages). Hence one pool
-# (one operand a page), and pages of 32 rows where the deployment can
-# choose (1.28 against 2.41 ms at the same tokens).
-_LATENT_ROWS_PER_STEP = 512
+# What the walk is worth here (v5e, 64 slots x 64 heads, a table of 128
+# pages of 32, bf16, six calls chained as a step chains them, each with
+# its query's layout in XLA, ~0.10 ms; my chip runs, PR 42,
+# tests/test_tpu_smoke.py holds the ratio): a call reads 0.22 ms with 1
+# token a slot and 0.70 with 2,304, where the kernel with a grid of its
+# own over (slots, table entries) read 0.65 and 0.93, ~65 ns a page
+# operand visited whether its DMA was skipped or not. Of the 0.60 ms that
+# are the kernel's at 2,304 tokens, 0.28 are the walk with an empty body
+# (4,608 page copies; 189 MB are 0.23 ms at the HBM peak), 0.22 the scores
+# and the softmax, 0.10 p . c_kv: the copies' issue and the body's
+# products do not overlap, and that sum, not the bytes, is what is left
+# (ROADMAP S17).
+#
+# Cache rows in a compute block of the latent kernel: one product
+# q . tile^T over all of them, the softmax state touched once a block.
+_LATENT_ROWS_PER_BLOCK = 512
 
 
-def _pages_per_block(page_size: int, pages_per_slot: int) -> int:
-    """Pages one grid step of the latent kernel walks: 512 cache rows,
-    each page its own input block with its own DMA in flight."""
-    return max(1, min(_LATENT_ROWS_PER_STEP // page_size, pages_per_slot))
+def _latent_pages_per_block(page_size: int, pages_per_slot: int,
+                            row_bytes: int) -> int:
+    """Pages in one compute block of the latent kernel:
+    ``_LATENT_ROWS_PER_BLOCK`` cache rows, no more than a slot's table
+    holds, halved until the double-buffered tile (``row_bytes`` a cache
+    row) takes at most half the VMEM budget. -> 0 where not even one
+    page does."""
+    k = max(1, min(_LATENT_ROWS_PER_BLOCK // page_size, pages_per_slot))
+    while k and 2 * k * page_size * row_bytes > _PAGED_VMEM_BYTES // 2:
+        k //= 2
+    return k
 
 
 def latent_kernel_supported(num_slots: int, rows: int, lanes: int,
@@ -692,20 +707,26 @@ def latent_kernel_supported(num_slots: int, rows: int, lanes: int,
                             dtype) -> bool:
     """Does :func:`paged_latent_attention`'s kernel lower on the chip for
     these shapes: rows and their c_kv part of whole 128-lane tiles, pages
-    of whole sublane tiles of the pool's dtype, a step's pages and the
-    [rows, rkv] accumulator inside VMEM, the page tables inside SMEM.
-    ``rows`` = window x heads (the kernel stacks two terms of each)."""
+    of whole sublane tiles of the pool's dtype (what a page's own DMA
+    needs), the walk's tile twice, the body's working set and the
+    [rows, rkv] accumulator inside VMEM, the page tables and lengths
+    inside SMEM. ``rows`` = window x heads (the kernel stacks two terms
+    of each)."""
     esize = jnp.dtype(dtype).itemsize
     if lanes % 128 or rkv % 128 or page_size % (32 // esize):
         return False
-    k = _pages_per_block(page_size, pages_per_slot)
+    k = _latent_pages_per_block(page_size, pages_per_slot, lanes * esize)
     span = k * page_size
     rows8 = _round_up(2 * rows, 8)
-    pages = 2 * span * lanes * esize                     # double-buffered
+    tiles = 2 * span * lanes * esize                     # the walk's halves
+    # a block's rows as the products take them; q's two terms (double-
+    # buffered by Pallas); the output block, the accumulator and a
+    # block's p . c_kv; the scores, the mask and the probabilities
     work = span * lanes * esize + rows8 * (
         2 * lanes * esize + 3 * rkv * 4 + 3 * span * 4)
     smem = 4 * num_slots * (_round_up(pages_per_slot, 128) + 128 + 1)
-    return pages + work <= _PAGED_VMEM_BYTES and smem <= _PAGED_SMEM_BYTES
+    return k > 0 and tiles + work <= _PAGED_VMEM_BYTES and \
+        smem <= _PAGED_SMEM_BYTES
 
 
 def _two_terms(x):
@@ -721,18 +742,22 @@ def _two_terms(x):
     return jnp.concatenate([hi, x - hi], axis=0).astype(jnp.bfloat16)
 
 
-def _paged_latent_kernel(tables_ref, used_ref, lens_ref, q_ref, *rest,
-                         scale, page_size, window, heads, rkv,
+def _paged_latent_kernel(tables_ref, used_ref, lens_ref, layer_ref, q_ref,
+                         pool, out_ref, buf, sems, half_ref, m_ref, l_ref,
+                         acc_ref, *, scale, page_size, window, heads, rkv,
                          pages_per_block, split):
-    """Grid (S, ceil(P / K)), page blocks fastest. A step holds K pages
-    of the slot, each its own input block whose index map clamped the
-    page to the slot's last allocated one (a revisited page skips its
-    DMA; a step wholly past the allocation skips the math). The K pages
-    stand as one [K*page, lanes] tile of cache rows: the scores of all
-    ``window x heads`` query rows in ONE product ``q . rows^T`` (q is
-    [q_lat | q_rope | 0]), online softmax in float32, then
-    ``p . rows[:, :rkv]`` into the [rows, rkv] float32 accumulator: the
-    latent row is read from HBM once and used twice.
+    """Grid (S,): one step a slot, and inside it
+    :func:`_walk_live_pages` over the slot's live pages of the ONE pool,
+    K pages a compute block. The K pages stand as one [K*page, lanes]
+    tile of cache rows; the body below is called once a block: the
+    scores of all ``window x heads`` query rows in ONE product
+    ``q . tile^T`` (q is [q_lat | q_rope | 0]), online softmax in
+    float32, then ``p . tile[:, :rkv]`` into the [rows, rkv] float32
+    accumulator: the latent row is read from HBM once and used twice.
+    The per-token lengths are the third scalar-prefetch operand and the
+    layer the fourth, a number the program reads and not a constant of
+    it, so that every latent layer of a model runs ONE kernel, traced and
+    lowered once.
 
     Precision: the cache is what it is stored as; nothing else is
     rounded to it. With ``split`` (a bfloat16 pool) q
@@ -743,33 +768,25 @@ def _paged_latent_kernel(tables_ref, used_ref, lens_ref, q_ref, *rest,
     output rounded to bfloat16 is an error of 0.2-0.4 % of the attention
     output that no averaging over the cached tokens removes, and a router
     downstream turns it into moved top-k choices."""
-    K = pages_per_block
-    page_refs = rest[:K]
-    out_ref, m_ref, l_ref, acc_ref = rest[K:]
     s = pl.program_id(0)
-    b = pl.program_id(1)
     rows_n = acc_ref.shape[0]
-    span = K * page_size
-    page = (0,) * (len(page_refs[0].shape) - 2)
+    span = pages_per_block * page_size
 
-    @pl.when(b == 0)
-    def _init():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(b * K < used_ref[s])
-    def _accumulate():
-        c = jnp.concatenate([r[page] for r in page_refs], axis=0) \
-            if K > 1 else page_refs[0][page]               # [span, lanes]
+    def body(b, tiles):
+        c = tiles[0][...]                                  # [span, lanes]
         sc = jax.lax.dot_general(
             q_ref[0], c, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if split:
             sc = sc[:rows_n] + sc[rows_n:]
-        # causal/ragged mask against ABSOLUTE positions: row (w, h) is
-        # window token w and sees < lens[s, w]; a clamped (repeated)
-        # page lies past every length and is masked whole
+        # causal/ragged mask against ABSOLUTE positions: block b covers
+        # [b*span, (b+1)*span); row (w, h) is window token w and sees
+        # < lens[s, w]. Rows of the tile past the slot's last live page
+        # lie past every length.
         cols = b * span + jax.lax.broadcasted_iota(
             jnp.int32, (rows_n, span), 1)
         lim = jnp.full((rows_n, span), lens_ref[s, 0], jnp.int32)
@@ -791,9 +808,19 @@ def _paged_latent_kernel(tables_ref, used_ref, lens_ref, q_ref, *rest,
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_cur
 
-    @pl.when(b == pl.num_programs(1) - 1)
-    def _finalize():
-        out_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    # the layer is the index of every page copy, never a slice of the pool
+    _walk_live_pages(tables_ref, used_ref, [pool], [buf], sems, half_ref,
+                     body, lead=(layer_ref[0],), page_size=page_size,
+                     pages_per_block=pages_per_block)
+    # a slot with no live page (idle) leaves l = 0 and acc = 0: zeros
+    out_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _latent_q(q_lat, q_rope, lanes):
+    """[S, W, H, lanes] float32: q as the cache row is laid out,
+    [q_lat | q_rope | zero lanes where the row has them]."""
+    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32)
+    return jnp.pad(q, [(0, 0)] * 3 + [(0, lanes - q.shape[-1])])
 
 
 def paged_latent_attention(q_lat, q_rope, pages, page_tables, kv_lens, *,
@@ -803,70 +830,88 @@ def paged_latent_attention(q_lat, q_rope, pages, page_tables, kv_lens, *,
     q_lat [S, W, H, rkv] (``q_nope W_uk^T``) and q_rope [S, W, H, dr]
     (rotated); pages [L, n_pages, page_size, lanes], the pool AS STORED
     (a row is [c_kv | k_rope | zero lanes]), with ``layer`` (a Python
-    int) naming the layer read: it goes into the kernel's block index map
-    or the gather's index, never into a slice of the pool; page_tables
-    [S, P] int32; kv_lens [S, W] per-token valid lengths (token w of slot
-    s is the query at position kv_lens[s, w] - 1). Returns float32 o_lat
-    [S, W, H, rkv] = softmax(scores * scale) . c_kv, which the caller
-    expands by W_uv.
+    int) naming the layer read: it goes into the index of the kernel's
+    page copies (it reaches the kernel as an operand, so every layer runs
+    one program) or the gather's index, never into a slice of the pool;
+    page_tables [S, P] int32; kv_lens [S, W] per-token valid lengths
+    (token w of slot s is the query at position kv_lens[s, w] - 1).
+    Returns float32 o_lat [S, W, H, rkv] = softmax(scores * scale) . c_kv,
+    which the caller expands by W_uv.
 
     ``use_kernel=False`` gathers the slot's full table width and runs the
     same mathematics as einsums (the CPU path and the kernel's test
     reference); ``use_kernel=True`` runs :func:`_paged_latent_kernel`,
-    whose cache reads follow the slot's allocated pages."""
+    which copies ceil(len/page_size) pages a slot itself, so a call's
+    time follows the cached tokens and not slots x table width. A slot
+    whose lengths are all 0 (idle) costs no copy and returns zeros."""
     S, W, H, rkv = q_lat.shape
     ps, lanes = pages.shape[-2:]
     P = page_tables.shape[1]
     lens = jnp.asarray(kv_lens, jnp.int32).reshape(S, W)
-    # q as the row is laid out: zero lanes where the row has them
-    q = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32)
-    q = jnp.pad(q, [(0, 0)] * 3 + [(0, lanes - q.shape[-1])])
     if not use_kernel:
         hp = jax.lax.Precision.HIGHEST
+        q = _latent_q(q_lat, q_rope, lanes)
         c = gather_pages(pages, page_tables, layer).astype(jnp.float32)
         sc = jnp.einsum("swhr,skr->shwk", q, c, precision=hp)
         mask = jnp.arange(P * ps)[None, None, :] < lens[:, :, None]
         sc = jnp.where(mask[:, None], sc * scale, NEG_INF)
         return jnp.einsum("shwk,skr->swhr", jax.nn.softmax(sc, axis=-1),
                           c[..., :rkv], precision=hp)
-    K = _pages_per_block(ps, P)
-    used = jnp.clip(-(-jnp.max(lens, axis=1) // ps), 1, P)
+    # at least one page a block: shapes the gate turns away still run
+    # here in interpret mode (the tests' toy pages)
+    K = max(1, _latent_pages_per_block(
+        ps, P, lanes * jnp.dtype(pages.dtype).itemsize))
+    return _latent_pages_call(
+        q_lat, q_rope, pages, jnp.asarray(page_tables, jnp.int32), lens,
+        jnp.full((1,), layer, jnp.int32), scale=float(scale),
+        pages_per_block=K, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "pages_per_block",
+                                             "interpret"))
+def _latent_pages_call(q_lat, q_rope, pages, page_tables, lens, layer, *,
+                       scale, pages_per_block, interpret):
+    """:func:`paged_latent_attention`'s kernel path. A program of its
+    own, with the layer an operand: the latent layers of a step, and
+    both groups' calls of a lane step where their shapes agree, call the
+    same traced and lowered function."""
+    S, W, H, rkv = q_lat.shape
+    ps, lanes = pages.shape[-2:]
+    P, K = page_tables.shape[1], pages_per_block
+    # pages holding live rows for each slot; 0 for an idle one
+    used = jnp.clip(-(-jnp.max(lens, axis=1) // ps), 0, P)
     rows_n = W * H
     split = pages.dtype == jnp.bfloat16
-    q = q.reshape(S, rows_n, lanes)
+    q = _latent_q(q_lat, q_rope, lanes).reshape(S, rows_n, lanes)
     if split:       # [hi; lo] on each slot's rows
         q = jax.vmap(_two_terms)(q)
     else:
         q = q.astype(pages.dtype)
-    q_rows = q.shape[1]
 
-    def _slot_map(si, bi, tables, used_, lens_):
+    def _slot_map(si, *_):
         return (si, 0, 0)
-
-    def _page_map(j):
-        def index(si, bi, tables, used_, lens_):
-            return (layer, tables[si, jnp.minimum(bi * K + j,
-                                                  used_[si] - 1)], 0, 0)
-        return index
 
     kernel = functools.partial(
         _paged_latent_kernel, scale=scale, page_size=ps, window=W,
         heads=H, rkv=rkv, pages_per_block=K, split=split)
-    in_specs = [pl.BlockSpec((1, q_rows, lanes), _slot_map)]
-    in_specs += [pl.BlockSpec((1, 1, ps, lanes), _page_map(j))
-                 for j in range(K)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, -(-P // K)),
-        in_specs=in_specs,
+        num_scalar_prefetch=4,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, q.shape[1], lanes), _slot_map),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, rows_n, rkv), _slot_map),
-        scratch_shapes=[pltpu.VMEM((rows_n, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, K * ps, lanes), pages.dtype),
+                        pltpu.SemaphoreType.DMA((1, 2)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((rows_n, 1), jnp.float32),
                         pltpu.VMEM((rows_n, 1), jnp.float32),
                         pltpu.VMEM((rows_n, rkv), jnp.float32)])
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, rows_n, rkv), jnp.float32),
+        # the walk's state goes from one slot's grid step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret, name="paged_latent_attention",
-    )(jnp.asarray(page_tables, jnp.int32), used.astype(jnp.int32), lens,
-      q, *([pages] * K))
+    )(page_tables, used.astype(jnp.int32), lens, layer, q, pages)
     return out.reshape(S, W, H, rkv)

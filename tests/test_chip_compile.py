@@ -442,16 +442,27 @@ def _kimi_k2_decoder(n_layers=3):
     return dec
 
 
-@pytest.mark.parametrize("W", [1, 2], ids=["W1", "W2"])
-@pytest.mark.parametrize("ps", [16, 32], ids=["page16", "page32"])
-def test_latent_kernel_lowers(chip_compile, ps, W):
-    """What ``latent_kernel_supported`` accepts lowers: 64 heads against
-    one [c_kv 512 | k_rope 64 | 64 zero lanes] row a token, bf16."""
+@pytest.mark.parametrize("S,W,H,ps", [
+    (8, 1, 64, 16), (8, 2, 64, 16), (8, 1, 64, 32), (8, 2, 64, 32),
+    (64, 1, 64, 32), (8, 4, 64, 32), (128, 1, 32, 32), (16, 8, 32, 32),
+], ids=["page16-W1", "page16-W2", "page32-W1", "page32-W2",
+        "kimik2-slots", "kimik2-lanes", "kimilinear-slots",
+        "kimilinear-lanes"])
+def test_latent_kernel_lowers(chip_compile, S, W, H, ps):
+    """What ``latent_kernel_supported`` accepts lowers: heads against one
+    [c_kv 512 | k_rope 64 | 64 zero lanes] row a token, bf16, the pool
+    whole in HBM and the layer an operand; at 8 slots, and at both
+    deployments' groups (Kimi-K2: 64 slots of 64 heads, 8 lanes of 4
+    tokens; Kimi-Linear: 128 slots of 32 heads, 16 lanes of 8)."""
     from paddle_tpu.ops import pallas_decode as pd
-    S, H, P, N = 8, 64, 4096 // ps, 64
+    P, N = 4096 // ps, 64
     assert pd.latent_kernel_supported(S, W * H, 640, 512, ps, P,
                                       jnp.bfloat16)
     assert not pd.latent_kernel_supported(S, W * H, 576, 512, ps, P,
+                                          jnp.bfloat16)
+    # a lane twice as wide is what the gate turns away at both widths
+    assert pd.latent_kernel_supported(S, 256, 640, 512, ps, P, jnp.bfloat16)
+    assert not pd.latent_kernel_supported(S, 512, 640, 512, ps, P,
                                           jnp.bfloat16)
 
     def fn(ql, qr, pool, tables, lens):

@@ -229,24 +229,103 @@ def test_absorbed_attention_is_the_unabsorbed(decoder):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
 
 
-def test_latent_kernel_agrees_with_the_gather_path():
-    """W = 2 window rows, ragged lengths, more pages than one grid step
-    walks, a table that repeats a page."""
-    rng = np.random.default_rng(6)
-    S, W, H, rkv, dr, ps, P, L, N = 3, 2, 4, 16, 8, 4, 160, 2, 24
+def _latent_case(rng, S, W, H=4, rkv=16, dr=8, ps=4, P=160, L=2, N=24):
+    """(q_lat, q_rope, pool, tables): random queries and a random pool of
+    [c_kv | k_rope | zero lanes] rows under a table that repeats pages
+    (P entries drawn from N - 1 pages)."""
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
-    ql, qr = f(S, W, H, rkv), f(S, W, H, dr)
     pool = f(L, N, ps, 128).at[..., rkv + dr:].set(0.0)
     tables = jnp.asarray(rng.integers(1, N, (S, P)), jnp.int32)
-    lens = jnp.asarray([[5, 6], [600, 601], [1, 2]], jnp.int32)
-    assert paged_ops._pages_per_block(ps, P) == 128
-    want = paged_ops.paged_latent_attention(
-        ql, qr, pool, tables, lens, layer=1, scale=0.3)
+    return f(S, W, H, rkv), f(S, W, H, dr), pool, tables
+
+
+def _both_paths(ql, qr, pool, tables, lens, **kw):
+    kw = {"layer": 1, "scale": 0.3, **kw}
+    lens = jnp.asarray(lens, jnp.int32)
+    want = paged_ops.paged_latent_attention(ql, qr, pool, tables, lens, **kw)
     got = paged_ops.paged_latent_attention(
-        ql, qr, pool, tables, lens, layer=1, scale=0.3, use_kernel=True,
-        interpret=True)
+        ql, qr, pool, tables, lens, use_kernel=True, interpret=True, **kw)
     assert got.dtype == want.dtype == jnp.float32
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    return np.asarray(got), np.asarray(want)
+
+
+@pytest.mark.parametrize("lens", [
+    [[5, 6], [600, 601], [1, 2]],
+    [[509, 510, 511, 512], [513, 514, 515, 516], [637, 638, 639, 640]],
+], ids=["W2", "W4"])
+def test_latent_kernel_agrees_with_the_gather_path(lens):
+    """W = 2 and W = 4 window rows with per-token lengths, ragged slots,
+    more live pages than one compute block holds (128 pages of 4 rows a
+    block, 160 a table; lengths on both sides of a block's edge), a table
+    that repeats a page."""
+    rng = np.random.default_rng(6)
+    ql, qr, pool, tables = _latent_case(rng, 3, len(lens[0]))
+    assert paged_ops._latent_pages_per_block(4, 160, 128 * 4) == 128
+    got, want = _both_paths(ql, qr, pool, tables, lens)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_latent_block_is_sized_from_the_shapes():
+    """512 cache rows a compute block at the serving widths (16 pages of
+    32 rows of 640 bf16 lanes), never more than a slot's table, halved
+    where the tile twice would pass half the kernel's VMEM."""
+    k = paged_ops._latent_pages_per_block
+    assert k(32, 128, 640 * 2) == 16 and k(16, 256, 640 * 2) == 32
+    assert k(32, 6, 640 * 2) == 6
+    assert k(32, 128, 8192 * 4) == 2       # rows of 32 KB: 64 rows twice
+    assert k(256, 128, 8192 * 4) == 0      # one page twice is too much
+
+
+@pytest.mark.parametrize("idle", [(1,), (0, 1, 2)], ids=["one", "all"])
+def test_an_idle_latent_slot_returns_zeros(idle):
+    """A slot whose lengths are all 0 holds no live page: its output is
+    zeros, beside live slots that read what they would without it, and
+    where every slot is idle the kernel copies nothing at all."""
+    rng = np.random.default_rng(13)
+    ql, qr, pool, tables = _latent_case(rng, 3, 2, P=12)
+    lens = np.asarray([[9, 10], [30, 31], [2, 3]])
+    lens[list(idle)] = 0
+    got, want = _both_paths(ql, qr, pool, tables, lens)
+    live = [s for s in range(3) if s not in idle]
+    assert not got[list(idle)].any()
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+
+
+def test_the_latent_kernel_reads_no_page_past_the_live_ones():
+    """Pages no slot's live table entries name hold NaN: the answer is
+    finite and the gather path's over the clean pool (a page past
+    ``used`` is never copied, and a tile's unwritten rows are zeros, not
+    what VMEM held)."""
+    rng = np.random.default_rng(14)
+    ql, qr, pool, tables = _latent_case(rng, 3, 2, P=12, N=40)
+    lens = jnp.asarray([[9, 10], [0, 0], [41, 42]], jnp.int32)
+    tables = tables.at[:, :11].set(
+        jnp.asarray(rng.integers(1, 20, (3, 11)), jnp.int32))
+    tables = tables.at[:, 11:].set(25)
+    tables = tables.at[0, 3:].set(30).at[1, :].set(35)
+    dirty = pool.at[:, 20:].set(jnp.nan)
+    kw = dict(layer=1, scale=0.3)
+    got = paged_ops.paged_latent_attention(
+        ql, qr, dirty, tables, lens, use_kernel=True, interpret=True, **kw)
+    want = paged_ops.paged_latent_attention(ql, qr, pool, tables, lens, **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got)[[0, 2]],
+                               np.asarray(want)[[0, 2]], atol=2e-5)
+
+
+def test_the_layers_of_a_latent_pool_share_one_traced_kernel():
+    """The layer is an operand of the kernel's program: layers 0 and 1 of
+    one pool run through ONE trace of it and read each its own rows."""
+    rng = np.random.default_rng(15)
+    ql, qr, pool, tables = _latent_case(rng, 2, 1, P=10)
+    lens = [[17], [33]]
+    paged_ops._latent_pages_call.clear_cache()
+    outs = [_both_paths(ql, qr, pool, tables, lens, layer=i)
+            for i in (0, 1)]
+    assert paged_ops._latent_pages_call._cache_size() == 1
+    for got, want in outs:
+        np.testing.assert_allclose(got, want, atol=2e-5)
+    assert np.abs(outs[0][0] - outs[1][0]).max() > 1e-2
 
 
 # --------------------------------------------------------------- by hand

@@ -30,6 +30,19 @@ pytestmark = pytest.mark.skipif(not _on_tpu(),
                                 reason="needs real TPU hardware")
 
 
+def _median_ms_a_call(step, args, calls):
+    """Median of five timed runs of ``step(*args)`` (one warm-up before
+    them), in ms a chained call."""
+    import time
+    step(*args).block_until_ready()
+    times = []
+    for _ in range(5):
+        t0 = time.perf_counter()
+        step(*args).block_until_ready()
+        times.append(time.perf_counter() - t0)
+    return 1e3 * sorted(times)[2] / calls
+
+
 class TestFlashAttentionCompiled:
     @pytest.mark.parametrize("tq,tk,d", [
         (512, 512, 128),
@@ -165,7 +178,6 @@ class TestPagedKernelCompiled:
         it takes with every slot at 2,048. The grid-walk kernel this one
         replaced read 0.55 ms against 3.42 (its grid visited 32 x 128
         table entries whatever was live; ROADMAP.md D12)."""
-        import time
         from paddle_tpu.ops.pallas_decode import paged_window_attention
         S, P, h, dh, ps, L, calls = 32, 128, 32, 64, 16, 2, 24
         n_pages = S * P + 1
@@ -189,18 +201,100 @@ class TestPagedKernelCompiled:
 
         def ms_a_call(tokens):
             lens = jnp.full((S, 1), tokens, jnp.int32)
-            step(q, k, v, tables, lens).block_until_ready()
-            times = []
-            for _ in range(5):
-                t0 = time.perf_counter()
-                step(q, k, v, tables, lens).block_until_ready()
-                times.append(time.perf_counter() - t0)
-            return 1e3 * sorted(times)[2] / calls
+            return _median_ms_a_call(step, (q, k, v, tables, lens), calls)
 
         empty, full = ms_a_call(1), ms_a_call(P * ps)
         print(f"paged_window_attention ms a call: 1 token a slot "
               f"{empty:.4f}, {P * ps} tokens a slot {full:.4f}")
         assert empty < full / 5, (empty, full)
+
+
+class TestLatentKernelCompiled:
+    """The latent (MLA) kernel on the same walk, compiled: against the
+    gather path on the chip, and its time following the cached tokens
+    (ROADMAP.md D12, D13)."""
+
+    @pytest.mark.parametrize("S,W,H", [(8, 1, 64), (8, 4, 64), (16, 8, 32)],
+                             ids=["W1", "lanes-8x4", "lanes-16x8"])
+    def test_latent_matches_gather(self, S, W, H):
+        """Ragged slots, an idle one, out-of-order pages, per-token
+        lengths, a bfloat16 pool at the published row (512 + 64 of 640
+        lanes): the kernel's float32 output is the gather path's float32
+        mathematics over the stored rows to 1e-3 of its size."""
+        from paddle_tpu.ops.pallas_decode import (latent_kernel_supported,
+                                                  paged_latent_attention)
+        rng = np.random.RandomState(6)
+        ps, P, L = 32, 128, 2
+        n_pages = S * P + 1
+        assert latent_kernel_supported(S, W * H, 640, 512, ps, P,
+                                       jnp.bfloat16)
+        pool = jnp.asarray(rng.randn(L, n_pages, ps, 640), jnp.bfloat16)
+        pool = pool.at[..., 576:].set(0)
+        ql = jnp.asarray(rng.randn(S, W, H, 512), jnp.float32)
+        qr = jnp.asarray(rng.randn(S, W, H, 64), jnp.float32)
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, n_pages)).reshape(S, P), jnp.int32)
+        base = rng.randint(1, P * ps - W, (S,))
+        base[:3] = (1, 511, 2304)
+        lens = base[:, None] + np.arange(W)[None, :]
+        lens[3] = 0
+        lens = jnp.asarray(lens, jnp.int32)
+        kw = dict(layer=1, scale=0.07)
+        want = np.asarray(paged_latent_attention(ql, qr, pool, tables, lens,
+                                                 **kw))
+        got = np.asarray(paged_latent_attention(ql, qr, pool, tables, lens,
+                                                use_kernel=True, **kw))
+        assert not got[3].any()
+        live = np.arange(S) != 3
+        size = float(np.sqrt(np.mean(want[live] ** 2)))
+        err = float(np.abs(got[live] - want[live]).max()) / size
+        print(f"paged_latent_attention S={S} W={W} H={H}: "
+              f"max error {err:.2e} of the output's size")
+        assert err < 1e-3, err
+
+    def test_time_follows_the_cached_tokens(self):
+        """A layer's call at ``kimik2_agent_2k``'s shape (64 slots, 64
+        heads, a table of 128 pages of 32, bf16 rows of 640 lanes), six
+        calls chained in one program as a step chains them, each with
+        its own query laid out and split in two terms: with every slot
+        at 1 token it takes under half of what it takes with every slot
+        at 2,304 (0.24 ms against 0.70; the grid-walk kernel this one
+        replaced read 0.65 against 0.93 under this probe, its grid
+        visiting 64 x 128 table entries whatever was live). Not a fifth,
+        as the window kernel's: 0.10 ms of either reading is the query's
+        layout in XLA, and a slot with one live row still computes one
+        whole block of 512 (PERF.md, PR 42)."""
+        from paddle_tpu.ops.pallas_decode import paged_latent_attention
+        S, P, H, ps, L, calls = 64, 128, 64, 32, 2, 6
+        n_pages = S * P + 1
+        key = jax.random.PRNGKey(0)
+        pool = jax.random.normal(key, (L, n_pages, ps, 640), jnp.bfloat16)
+        ql = jax.random.normal(jax.random.fold_in(key, 1), (S, 1, H, 512),
+                               jnp.float32)
+        qr = jax.random.normal(jax.random.fold_in(key, 2), (S, 1, H, 64),
+                               jnp.float32)
+        tables = jnp.asarray(np.random.RandomState(0).permutation(
+            np.arange(1, n_pages)).reshape(S, P), jnp.int32)
+
+        @jax.jit
+        def step(ql, qr, pool, tables, lens):
+            x = ql
+            for i in range(calls):
+                o = paged_latent_attention(x, qr, pool, tables, lens,
+                                           layer=i % L, scale=0.07,
+                                           use_kernel=True)
+                x = ql + 0.001 * o
+            return x
+
+        def ms_a_call(tokens):
+            lens = jnp.full((S, 1), tokens, jnp.int32)
+            return _median_ms_a_call(step, (ql, qr, pool, tables, lens),
+                                     calls)
+
+        empty, full = ms_a_call(1), ms_a_call(2304)
+        print(f"paged_latent_attention ms a call: 1 token a slot "
+              f"{empty:.4f}, 2304 tokens a slot {full:.4f}")
+        assert empty < full / 2, (empty, full)
 
 
 class TestCpuTpuParity:
