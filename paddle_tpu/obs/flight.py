@@ -133,6 +133,17 @@ class FlightRecorder:
         with self._lock:
             self._ring.append(rec)
 
+    def record_unlocked(self, kind: str, name: str, **fields) -> None:
+        """``record`` for a caller that must not wait for a lock (a
+        ``gc.callbacks`` function runs under whatever the collecting
+        thread holds): ``deque.append`` is atomic under the GIL; a
+        ``configure`` resize racing it may leave this record out."""
+        if self.enabled:
+            # ptlint: disable=R10(a gc callback may not wait for the lock; deque.append is atomic under the GIL)
+            self._ring.append(dict(
+                {"t": time.time(), "kind": str(kind), "name": str(name)},
+                **fields))
+
     def record_raw(self, rec: dict) -> None:
         """Append a pre-built record (the tracer's compact span shape,
         the journal observer's event records) without re-stamping."""

@@ -238,19 +238,26 @@ class Tracer:
             _DROPPED.inc()
         ring.append(rec)
 
-    def _pop(self, name: str, t0: float, t1: float, args: dict) -> None:
+    def _span_record(self, name: str, t0: float, t1: float, ctx) -> dict:
+        """A span that ended on this thread, under the innermost span
+        still open there."""
         st = self._stack()
-        if st and st[-1] == name:
-            st.pop()
-        parent = st[-1] if st else None
-        ctx = obs_context.current()
-        rec = {"name": name, "t0": t0, "t1": t1, "parent": parent,
+        rec = {"name": name, "t0": t0, "t1": t1,
+               "parent": st[-1] if st else None,
                "tid": threading.get_ident(),
                "thread": threading.current_thread().name}
         if ctx.trace_id is not None:
             rec["trace_id"] = ctx.trace_id
         if ctx.step is not None:
             rec["step"] = ctx.step
+        return rec
+
+    def _pop(self, name: str, t0: float, t1: float, args: dict) -> None:
+        st = self._stack()
+        if st and st[-1] == name:
+            st.pop()
+        ctx = obs_context.current()
+        rec = self._span_record(name, t0, t1, ctx)
         if args:
             rec["args"] = args
         with self._lock:
@@ -266,6 +273,21 @@ class Tracer:
             if ctx.step is not None:
                 frec["step"] = ctx.step
             flight.record_raw(frec)
+
+    def add_span_unlocked(self, name: str, t0: float, t1: float) -> None:
+        """A span that ended, recorded by a caller that must not wait
+        for a lock (``utils.stats.watch_gc``'s collector callback, which
+        runs under whatever the collecting thread holds): into the trace
+        window's ring only, not the flight recorder. ``deque.append`` is
+        atomic under the GIL; a span the full ring pushes out counts in
+        ``dropped`` alone (the registry's counter takes a lock)."""
+        if not self.enabled:
+            return
+        rec = self._span_record(name, t0, t1, obs_context.current())
+        ring = self._spans
+        if len(ring) == ring.maxlen:
+            self.dropped += 1
+        ring.append(rec)
 
     def span(self, name: str, **args):
         """Context manager; a shared no-op object when neither a trace

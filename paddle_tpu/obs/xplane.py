@@ -55,8 +55,12 @@ MODULES_LINE = "XLA Modules"
 #: with a step in flight the wait is for the step BEFORE the one the same
 #: turn launched)
 SYNC_LOOP = ("serving/dispatch", "serving/sync")
-#: host spans the program opens itself (stat_timer names)
-SPAN_PREFIXES = ("serving/", "train")
+#: host spans the program opens itself (stat_timer names, and the
+#: collector watcher's ``host/gc``: utils/stats.py ``watch_gc``)
+SPAN_PREFIXES = ("serving/", "train", "host/")
+#: a collection of Python's garbage collector: it holds the interpreter,
+#: whichever thread runs it, so a gap under it is its own
+GC_SPAN = "host/gc"
 NO_SCOPE = "(no scope)"
 #: an event whose instruction the given program text does not hold: the
 #: text is of another program than the one traced
@@ -144,31 +148,60 @@ def _overlap_ns(gaps: Sequence[Tuple[int, int]],
     return dict(out)
 
 
+def _minus(spans: Sequence[Tuple[int, int]],
+           holes: Sequence[Tuple[int, int]]) -> List[Tuple[int, int]]:
+    """The sorted, disjoint (start, end) ``spans`` less the sorted,
+    disjoint ``holes``."""
+    out: List[Tuple[int, int]] = []
+    j = 0
+    for a, b in spans:
+        while j < len(holes) and holes[j][1] <= a:
+            j += 1
+        k = j
+        while k < len(holes) and holes[k][0] < b:
+            if holes[k][0] > a:
+                out.append((a, holes[k][0]))
+            a = max(a, holes[k][1])
+            k += 1
+        if a < b:
+            out.append((a, b))
+    return out
+
+
 def attribute_gaps(ops: Sequence[Event],
                    host_threads: Dict[str, Sequence[Event]]) -> dict:
     """Every idle gap of one device's busy union (first operation's
     start to the last one's end), attributed to the host spans open
     during it. ``host_threads`` maps a thread to its spans; on each
-    thread a gap's time goes to the innermost span. ``unattributed`` is
-    the gap time during which NO span of any thread was open; with
-    several host threads the named shares may overlap each other (a
-    feed thread's ``train/h2d`` under the loop's ``train/data_wait``),
-    never ``unattributed``.
+    thread a gap's time goes to the innermost span, except that the time
+    under a collection (``GC_SPAN``, on whichever thread ran it) is the
+    collection's ahead of every thread's spans. ``unattributed`` is the
+    gap time during which NO span of any thread was open; with several
+    host threads the named shares may overlap each other (a feed
+    thread's ``train/h2d`` under the loop's ``train/data_wait``), never
+    ``unattributed``.
 
     -> {"n_gaps", "gap_ns", "by_span": {name: ns}, "unattributed_ns",
         "longest": [{"start_ns", "gap_ns", "by_span"}, ...]}"""
     busy = busy_intervals(ops)
     gaps = [(b0, a1) for (_, b0), (a1, _) in zip(busy, busy[1:]) if a1 > b0]
-    per_thread = [innermost_segments(spans)
+    collections = busy_intervals([e for spans in host_threads.values()
+                                  for e in spans if e[2] == GC_SPAN])
+    per_thread = [innermost_segments([e for e in spans if e[2] != GC_SPAN])
                   for spans in host_threads.values()]
     covered = busy_intervals([(s, e - s, "") for segs in per_thread
-                              for s, e, _ in segs])
+                              for s, e, _ in segs]
+                             + [(s, e - s, "") for s, e in collections])
     cover_segs = [(s, e, "any") for s, e in covered]
 
     def split(some_gaps) -> Tuple[Dict[str, int], int]:
         by: Dict[str, int] = defaultdict(int)
+        gc_ns = _overlap_ns(some_gaps, [(s, e, GC_SPAN)
+                                        for s, e in collections])
+        by.update(gc_ns)
+        rest = _minus(some_gaps, collections)
         for segs in per_thread:
-            for name, ns in _overlap_ns(some_gaps, segs).items():
+            for name, ns in _overlap_ns(rest, segs).items():
                 by[name] += ns
         total = sum(b - a for a, b in some_gaps)
         return dict(by), total - _overlap_ns(some_gaps, cover_segs).get(

@@ -89,7 +89,8 @@ from paddle_tpu.serving.prefix import PrefixIndex
 from paddle_tpu.serving.server import (Expired, Rejected, ServerClosed,
                                        ServingError)
 from paddle_tpu.serving.spill import SpillEntry, SpillStore
-from paddle_tpu.utils.stats import global_counters, stat_timer
+from paddle_tpu.utils.stats import (GC_WATCH, global_counters, stat_timer,
+                                    watch_gc)
 
 # /metrics families for the round-9 multipliers (idempotent: the
 # registry returns the existing family on re-registration)
@@ -370,13 +371,24 @@ class _Step:
     """What :meth:`DecodeEngine._launch` sent: the device's futures (the
     choices, an expert model's load sums), the step's number, and a
     :class:`_Fed` a live slot. ``landed`` once its values were committed,
-    or given up with a failed step."""
+    or given up with a failed step. What the dry checks and the step's
+    timing need (:meth:`DecodeEngine._look`): whether it runs the lane
+    program, was launched with a step in flight and found that one
+    finished (``dry``); the host clock (ns) of the last look that saw it
+    running (``busy_at``; at first its enqueue) and the first that saw it
+    finished (``done_at``, in phase ``done_in``), the collector's total
+    pause at ``busy_at``, and where the device's queue puts its end
+    (``model_end``; None where no step of its program was timed)."""
 
-    __slots__ = ("step", "nxt", "load", "fed", "landed")
+    __slots__ = ("step", "nxt", "load", "fed", "landed", "lanes", "ahead",
+                 "dry", "busy_at", "done_at", "done_in", "gc_ns", "model_end")
 
-    def __init__(self, step, nxt, load, fed):
+    def __init__(self, step, nxt, load, fed, lanes=False):
         self.step, self.nxt, self.load, self.fed = step, nxt, load, fed
         self.landed = False
+        self.lanes, self.ahead, self.dry = lanes, False, False
+        self.busy_at = self.gc_ns = 0
+        self.done_at = self.done_in = self.model_end = None
 
 
 class DecodeEngine:
@@ -557,7 +569,6 @@ class DecodeEngine:
                           "prefix_evicted_pages": 0,
                           "spec_proposed_tokens": 0,
                           "spec_accepted_tokens": 0,
-                          "draft_failures": 0,
                           "kv_pages_spilled": 0,
                           "kv_pages_restored": 0,
                           "kv_spill_integrity_drops": 0,
@@ -600,12 +611,32 @@ class DecodeEngine:
                           # first (a draft, a spill store, a preemption:
                           # the reason is in the flight record); the
                           # rest of ``steps`` found nothing in flight
-                          "steps_launched_ahead": 0, "ahead_drains": 0}
+                          "steps_launched_ahead": 0, "ahead_drains": 0,
+                          # launches ahead that found the step in flight
+                          # finished (the device ran dry before them), by
+                          # the phase whose end first saw it so, and the
+                          # device's idle time before them, estimated
+                          # (_look, _dry)
+                          "steps_launched_dry": 0, "dry_in_sync": 0,
+                          "dry_in_commit": 0, "dry_in_admit": 0,
+                          "dry_in_plan": 0, "dry_in_dispatch": 0,
+                          "device_dry_ns": 0,
+                          # steps that ran back to back on the device,
+                          # timed between two syncs that waited, by
+                          # program (_timed)
+                          "steps_timed_plain": 0, "step_ns_plain": 0,
+                          "steps_timed_lanes": 0, "step_ns_lanes": 0}
         # the dispatched step whose values have not landed (_launch sets
         # it, _land clears it), and the rows of the next step's tokens
         # that are fed from its choices on the device (-1: the host's)
         self._in_flight: Optional[_Step] = None
         self._src = np.full((S,), -1, np.int32)
+        # the last landed step's (number, whether its sync's end is a
+        # reading of the device's clock, that end: _timed), and the last
+        # timed step of each program
+        # (ns; 0: none yet), indexed by whether it ran the lane program
+        self._last_sync: Optional[tuple] = None
+        self._step_ns = [0, 0]
         import jax
         self._key0 = jax.random.PRNGKey(0)
         # live-state provider for postmortem bundles: the slot table
@@ -1123,11 +1154,6 @@ class DecodeEngine:
             self.prefix.hit_pages += hit
             self.prefix.miss_pages += max(miss, 0)
         req.prefix_hit_pages = hit
-        if matched:
-            FLIGHT.record("mark", "engine/prefix_attach",
-                          trace_id=req.trace_id, slot=s,
-                          shared_pages=hit, matched_tokens=matched,
-                          cow=match.cow is not None)
 
     def _admit(self) -> None:
         """Waiting -> free slots, gated on FREE PAGES: the queue head
@@ -1282,7 +1308,6 @@ class DecodeEngine:
                 out = np.asarray(out)
             # ptlint: disable=R7(draft failures must not kill the serving loop — the target path continues unassisted)
             except Exception as e:
-                self._counters["draft_failures"] += 1
                 journal_emit("engine", "draft_failure",
                              error=repr(e)[:400],
                              engine_step=self._steps)
@@ -1367,6 +1392,7 @@ class DecodeEngine:
                 interceptor(self._steps)
             self._reap(self._clock())
             self._admit()
+        self._look("admit")
         with self._phase("serving/plan", "host_plan_ns"):
             planned = self._plan_windows()
         if planned is None:         # a preemption is due
@@ -1374,6 +1400,7 @@ class DecodeEngine:
             self._drain("preempt")
             with self._phase("serving/plan", "host_plan_ns"):
                 planned = self._plan_windows()
+        self._look("plan")
         plan, live, lane_of = planned
         older = self._in_flight
         if live or older is not None:
@@ -1394,6 +1421,7 @@ class DecodeEngine:
                 # this step may still be reading them (the CPU backend
                 # takes an aligned numpy array without a copy)
                 tokens = self._tokens.copy()
+                self._look("dispatch")
                 if (self._src >= 0).any():
                     tokens = self.paged.feed(older.nxt, self._src.copy(),
                                              tokens)
@@ -1402,6 +1430,13 @@ class DecodeEngine:
                     self._positions.copy(), self._tables.copy(),
                     self._active.copy(), key,
                     self._lanes.copy() if lane_of else None)
+                # enqueued: the step in flight's last dry check (a pause
+                # inside the enqueue itself, a collection or the
+                # runtime's, leaves the device idle too)
+                t_sent = time.perf_counter_ns()
+                gc_sent = GC_WATCH.pause_ns
+                dry = self._dry(older, t_sent) if older is not None \
+                    else None
                 rec = self._sent(plan, live, lane_of, nxt,
                                  self.paged.expert_counts)
         # ptlint: disable=R7(serving boundary — in-flight requests settle typed and the pools rebuild; the engine thread must never die)
@@ -1410,8 +1445,90 @@ class DecodeEngine:
             return None
         self._counters["steps_launched_ahead"] += older is not None
         self._counters["ahead_drains"] += drained
+        rec.ahead, rec.dry = older is not None, dry is not None
+        rec.busy_at, rec.gc_ns = t_sent, gc_sent
+        # the device's queue: the step starts at its enqueue or where the
+        # step in flight ends, and takes the last timed step of its program
+        start = t_sent if older is None or rec.dry else older.model_end
+        step_ns = self._step_ns[rec.lanes]
+        if start is not None and step_ns:
+            rec.model_end = max(start, t_sent) + step_ns
+        if rec.dry:
+            self._count_dry(rec.step, *dry)
         self._in_flight = rec
         return rec
+
+    def _ready(self, rec: _Step) -> bool:
+        """Whether the device has finished step ``rec`` (does not block)."""
+        return rec.nxt.is_ready()
+
+    def _look(self, phase: str, t: Optional[int] = None) -> None:
+        """A dry check at the end of ``phase`` (in ``dispatch``: before
+        the enqueue and after it): has the step in flight finished? Until
+        one look sees it finished, each look that sees it running moves
+        its ``busy_at`` (and the collector's total) up; the first that
+        sees it finished stamps ``done_at`` and the phase. Only under the
+        thread is a step in flight while the host works; one
+        ``is_ready()`` a look, which neither blocks nor polls."""
+        rec = self._in_flight
+        if rec is None or rec.done_at is not None:
+            return
+        t = time.perf_counter_ns() if t is None else t
+        if self._ready(rec):
+            rec.done_at, rec.done_in = t, phase
+        else:
+            rec.busy_at, rec.gc_ns = t, GC_WATCH.pause_ns
+
+    def _dry(self, older: _Step, t: int) -> Optional[tuple]:
+        """The last look at ``older``, once the step launched behind it is
+        enqueued (``t``). -> None where it is still running, else the
+        dry launch: (phase that first saw it finished, the device's idle
+        estimate, the bracket's lower and upper bounds, the collector's
+        pause inside the bracket), ns. The bracket runs from the first
+        look that saw it finished, and from the last that saw it running,
+        to the enqueue. The estimate is the enqueue less where the
+        device's queue puts ``older``'s end (``model_end``: from the last
+        sync that read the device's clock, a timed step of each program
+        queued behind it), clamped into the bracket; without such an end,
+        the lower bound."""
+        self._look("dispatch", t)
+        if older.done_at is None:
+            return None
+        lo, hi = t - older.done_at, t - older.busy_at
+        est = lo if older.model_end is None \
+            else min(max(t - older.model_end, lo), hi)
+        return older.done_in, est, lo, hi, GC_WATCH.pause_ns - older.gc_ns
+
+    def _count_dry(self, step: int, phase: str, est: int, lo: int, hi: int,
+                   gc_ns: int) -> None:
+        c = self._counters
+        c["steps_launched_dry"] += 1
+        c["dry_in_" + phase] += 1
+        c["device_dry_ns"] += est
+        FLIGHT.record("mark", "engine/dry", engine_step=step, phase=phase,
+                      est_ms=est / 1e6, lo_ms=lo / 1e6, hi_ms=hi / 1e6,
+                      gc_ms=gc_ns / 1e6)
+
+    def _timed(self, rec: _Step, clock: bool, t: int) -> None:
+        """``rec``'s sync ended at ``t``; ``clock``: ``t`` is where ``rec``
+        ended on the device (the sync waited for it, and no pause held its
+        return: :meth:`_land`). A step whose sync and its predecessor's
+        are such readings, launched ahead and not dry, ran back to back
+        with that predecessor: its device time is the distance between the
+        two syncs' ends, counted by its program. Such a reading also puts
+        the end of the step queued behind ``rec`` a timed step of its
+        program later."""
+        last, self._last_sync = self._last_sync, (rec.step, clock, t)
+        if clock and rec.ahead and not rec.dry and last is not None \
+                and last[0] == rec.step - 1 and last[1]:
+            ns, key = t - last[2], ("plain", "lanes")[rec.lanes]
+            self._counters["steps_timed_" + key] += 1
+            self._counters["step_ns_" + key] += ns
+            self._step_ns[rec.lanes] = ns
+        nxt = self._in_flight
+        if clock and nxt is not None and nxt.step == rec.step + 1 \
+                and nxt.ahead and not nxt.dry and self._step_ns[nxt.lanes]:
+            nxt.model_end = t + self._step_ns[nxt.lanes]
 
     def _plan_windows(self):
         """The step's host plan: each active slot's tokens (a replay
@@ -1562,7 +1679,7 @@ class DecodeEngine:
             elif w == 1:
                 slot.pos = pos + 1
             fed[s] = _Fed(s, slot, pos, toks, chunk, row)
-        return _Step(self._steps, nxt, load, fed)
+        return _Step(self._steps, nxt, load, fed, lanes=bool(lane_of))
 
     def _land(self, rec: _Step,
               dev: Optional[contextlib.ExitStack] = None) -> bool:
@@ -1584,10 +1701,12 @@ class DecodeEngine:
         if self._in_flight is rec:
             self._in_flight = None
         with obs_context.bind(step=rec.step):
+            gc_ns = GC_WATCH.pause_ns
             try:
                 with dev if dev is not None \
                         else stat_timer("serving/decode_step"), \
                         self._phase("serving/sync", "host_sync_ns"):
+                    waited = not self._ready(rec)
                     # an expert model's two load sums come with the tokens
                     if rec.load is None:
                         nxt, load = np.asarray(rec.nxt), None
@@ -1598,6 +1717,14 @@ class DecodeEngine:
             except Exception as e:
                 self._recover_from_step_failure(e)
                 return False
+            t = time.perf_counter_ns()
+            self._look("sync", t)
+            # the sync's end is where rec ended on the device unless a
+            # pause held its return: a collection inside it, or one long
+            # enough that the step behind rec has finished too
+            behind = self._in_flight
+            self._timed(rec, waited and GC_WATCH.pause_ns == gc_ns
+                        and (behind is None or behind.done_at is None), t)
             with self._phase("serving/commit", "host_commit_ns"):
                 self._commit(rec, nxt.reshape(-1))
                 if load is not None:
@@ -1606,6 +1733,7 @@ class DecodeEngine:
                         c["expert_assignments_held"] += int(load[0])
                         c["expert_hits_held"] += int(load[1])
                         c["expert_layer_steps"] += self.paged.n_expert_layers
+            self._look("commit")
             return True
 
     def _commit(self, rec: _Step, nxt) -> None:
@@ -1615,6 +1743,7 @@ class DecodeEngine:
         t_after = self._clock()
         if PROFILER.enabled:
             PROFILER.on_step("decode")
+        committed = 0
         for fed in rec.fed.values():
             s, slot, toks = fed.s, fed.slot, fed.toks
             req = slot.req
@@ -1670,7 +1799,7 @@ class DecodeEngine:
                 if dt is not None:
                     for _ in range(n_commit):
                         self._lat.append(dt / n_commit)
-            global_counters.bump("serving/decode_tokens", n_commit)
+            committed += n_commit
             # a slot the step in flight already feeds stands one past
             # what is committed here (its pending token, on the device)
             ahead = slot.pos > fed.pos + w
@@ -1687,6 +1816,8 @@ class DecodeEngine:
                     # does; a state row has moved past it
                     slot.pos -= 1
                 self._finish(s, "done", snapshot=not ahead)
+        if committed:
+            global_counters.bump("serving/decode_tokens", committed)
 
     def _recover_from_step_failure(self, exc: Exception) -> None:
         """A failed dispatch may have consumed the (donated) pools:
@@ -1786,6 +1917,7 @@ class DecodeEngine:
         return dict(EXECUTABLES.stats(), warm_start=self.warm_start)
 
     def start(self) -> "DecodeEngine":
+        watch_gc()
         with self._cv:
             if self._thread is not None:
                 return self
@@ -1990,4 +2122,8 @@ class DecodeEngine:
                 round(self._percentile(lat, 0.99) * 1e3, 3),
             "ttft_p50_ms": round(self._percentile(ttft, 0.50) * 1e3, 3),
         })
+        # the collector's pauses: PROCESS-wide (utils.stats.watch_gc,
+        # installed by start()), not this engine's alone
+        for k in ("gc_collections", "gc_gen2_collections", "gc_pause_ns"):
+            out[k] = global_counters.value(k)
         return out

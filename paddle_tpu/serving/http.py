@@ -110,7 +110,7 @@ _COUNTER_KEYS = {
     # round-9 prefix-cache / speculative-decoding counters
     "prefix_hit_pages", "prefix_miss_pages", "prefix_cow_copies",
     "prefix_evicted_pages", "spec_proposed_tokens",
-    "spec_accepted_tokens", "draft_failures",
+    "spec_accepted_tokens",
     # first admissions, their summed queue wait, and the engine loop's
     # host nanoseconds by phase (DecodeEngine._phase)
     "admitted", "queue_wait_ns", "host_admit_ns", "host_plan_ns",
@@ -123,6 +123,14 @@ _COUNTER_KEYS = {
     # steps the loop dispatched with the step before still in flight, and
     # steps that landed it first (DecodeEngine._launch)
     "steps_launched_ahead", "ahead_drains",
+    # launches that found the step in flight finished, by the phase that
+    # first saw it so, and the device's idle before them; steps timed
+    # between two waiting syncs, by program; the process's collections
+    "steps_launched_dry", "dry_in_sync", "dry_in_commit", "dry_in_admit",
+    "dry_in_plan", "dry_in_dispatch", "device_dry_ns",
+    "steps_timed_plain", "step_ns_plain", "steps_timed_lanes",
+    "step_ns_lanes", "gc_collections", "gc_gen2_collections",
+    "gc_pause_ns",
 }
 
 

@@ -31,7 +31,7 @@ from paddle_tpu.obs import events as obs_events
 from paddle_tpu.obs.profile import PROFILER
 from paddle_tpu.trainer import event as evt
 from paddle_tpu.trainer.parameters import Parameters
-from paddle_tpu.utils.stats import global_counters, stat_timer
+from paddle_tpu.utils.stats import global_counters, stat_timer, watch_gc
 
 
 class SGD:
@@ -847,6 +847,7 @@ class SGD:
         # one run_id for the whole run (generated here if the CLI set
         # none): every span/journal record the run emits carries it
         obs_context.ensure_run_id()
+        watch_gc()
         # warm start: a relaunched (auto_resume / elastic-replacement)
         # trainer re-pays the step compile unless the operator pointed
         # PADDLE_TPU_COMPILE_CACHE at a persistent cache — opt-in, so

@@ -13,6 +13,7 @@ XPlane traces when profiling on TPU.
 from __future__ import annotations
 
 import contextlib
+import gc
 import threading
 import time
 from typing import Dict
@@ -98,6 +99,14 @@ class CounterSet:
             self._counts[name] = v
             return v
 
+    def bump_unlocked(self, name: str, n: int = 1) -> None:
+        """``bump`` for a caller that must not wait for a lock (a
+        ``gc.callbacks`` function runs on whichever thread allocated,
+        under whatever locks that thread holds). Safe only for a name
+        that one writer at a time bumps: the read and the write are two
+        steps."""
+        self._counts[name] = self._counts.get(name, 0) + n
+
     def value(self, name: str) -> int:
         with self._lock:
             return self._counts.get(name, 0)
@@ -147,6 +156,68 @@ def stat_timer(name: str):
         with _tracer().span(name):
             yield
     global_stat.get(name).add(time.perf_counter() - t0)
+
+
+class _GcWatch:
+    """The collector's pauses, from inside the process: one
+    ``gc.callbacks`` function. Every collection is a
+    ``jax.profiler.TraceAnnotation`` ``host/gc`` (entered at ``start``,
+    exited at ``stop``, both on the collecting thread, so it lies in the
+    host plane of a profiler trace beside the device's operations), a
+    ``TRACER`` span of the same name, and the process-wide counters
+    ``gc_collections``, ``gc_gen2_collections`` and ``gc_pause_ns`` of
+    ``global_counters``; a generation-2 collection also leaves a
+    ``FLIGHT`` mark with its generation and duration. The callback takes
+    no lock: it runs under whatever locks the collecting thread holds.
+    Collections do not nest, so one pending annotation is enough."""
+
+    NAME = "host/gc"
+
+    def __init__(self):
+        self._ann = None
+        self._t0 = 0
+        self.tracer = self.flight = None    # set by watch_gc()
+        #: nanoseconds of every collection since the install, read
+        #: without a lock (serving/engine.py: did one fall inside a
+        #: dry launch's bracket?)
+        self.pause_ns = 0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._ann = jax.profiler.TraceAnnotation(self.NAME)
+            self._ann.__enter__()
+            self._t0 = time.perf_counter_ns()
+            return
+        t1 = time.perf_counter_ns()
+        ann, self._ann = self._ann, None
+        if ann is None:             # installed mid-collection
+            return
+        ann.__exit__(None, None, None)
+        ns, gen = t1 - self._t0, info.get("generation", 0)
+        self.pause_ns += ns
+        global_counters.bump_unlocked("gc_collections")
+        global_counters.bump_unlocked("gc_pause_ns", ns)
+        self.tracer.add_span_unlocked(self.NAME, self._t0 / 1e9, t1 / 1e9)
+        if gen == 2:
+            global_counters.bump_unlocked("gc_gen2_collections")
+            self.flight.record_unlocked("mark", self.NAME, generation=gen,
+                                        dur_ms=ns / 1e6)
+
+
+GC_WATCH = _GcWatch()
+_GC_INSTALL = threading.Lock()
+
+
+def watch_gc() -> None:
+    """Install the process's one collector watcher (:class:`_GcWatch`);
+    idempotent. ``DecodeEngine.start()`` and ``SGD.train`` call it."""
+    with _GC_INSTALL:
+        if GC_WATCH not in gc.callbacks:
+            # resolved here: an import inside a collection could wait for
+            # the import lock that the collecting thread holds
+            from paddle_tpu.obs.flight import FLIGHT
+            GC_WATCH.tracer, GC_WATCH.flight = _tracer(), FLIGHT
+            gc.callbacks.append(GC_WATCH)
 
 
 def timed(name: str):

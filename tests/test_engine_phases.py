@@ -224,7 +224,10 @@ def test_one_step_yields_the_span_tree():
         assert eng.step()
     finally:
         TRACER.stop()
-    spans = {s["name"]: s for s in TRACER.spans()}
+    # a collection of the garbage collector may fall inside the step: its
+    # span (utils.stats.watch_gc, installed by any started engine of the
+    # process) is not the step's
+    spans = {s["name"]: s for s in TRACER.spans() if s["name"] != "host/gc"}
     parents = {"serving/admit": "serving/step",
                "serving/plan": "serving/step",
                "serving/decode_step": "serving/step",

@@ -157,9 +157,12 @@ class TestStepProfiler:
 
     def test_live_mfu_agrees_with_bench_computation(self):
         """THE agreement acceptance: the live gauge and a bench.py-style
-        offline computation over the same measured window land within
-        10% — they share roofline() by construction, so the only slack
-        is mean-vs-median over the sample window."""
+        offline computation over the same measured steps agree — they
+        share roofline() by construction. The gauge is the mean step of
+        the last sample window (``sample_every`` steps), so the offline
+        side takes the mean of the same recorded samples: a median of
+        the whole run would hold the test to the machine's load (a few
+        slow sleeps move the two statistics apart), not to roofline()."""
         PROFILER.configure(peak_flops=1e12, hbm_gbps=1000.0,
                            assume_mxu=True)
         PROFILER.set_cost_source("train", lambda: (5.0e6, 2.0e6))
@@ -170,12 +173,13 @@ class TestStepProfiler:
             PROFILER.disable()
         snap = PROFILER.snapshot()
         live = snap["mfu"]["train"]
-        offline = roofline(snap["kinds"]["train"]["step_ms_median"],
+        window = list(PROFILER._kinds["train"].step_ms)[-4:]
+        offline = roofline(sum(window) / len(window),
                            flops=5.0e6, bytes_acc=2.0e6,
                            peak_flops=1e12, hbm_gbps=1000.0,
                            mxu=True)["mfu"]
         assert live > 0 and offline > 0
-        assert abs(live - offline) / offline < 0.10
+        assert live == pytest.approx(offline, abs=1e-6)   # the gauge's 6 digits
         assert snap["roofline_frac"]["train"] > 0
 
 

@@ -85,6 +85,31 @@ def test_two_host_threads_overlap_each_other_never_unattributed():
     assert got["unattributed_ns"] == 1 * MS                 # [4, 5]
 
 
+@pytest.mark.parametrize("thread", ["engine", "loadgen"])
+def test_a_gap_under_a_collection_is_the_collections(thread):
+    """A collection holds the interpreter whichever thread runs it: the
+    gap's time under ``host/gc`` is the collection's, ahead of the engine
+    thread's open ``serving/commit``; the rest stays the commit's."""
+    ops = [(0, 2 * MS, "a"), (10 * MS, 1 * MS, "b")]       # gap [2, 10]
+    host = {"engine": [(1 * MS, 9 * MS, "serving/commit")],  # [1, 10]
+            "loadgen": [(0, 1 * MS, "serving/idle")]}
+    host[thread] = host[thread] + [(4 * MS, 3 * MS, xplane.GC_SPAN)]
+    got = xplane.attribute_gaps(ops, host)
+    assert got["by_span"] == {xplane.GC_SPAN: 3 * MS,
+                              "serving/commit": 5 * MS}
+    assert got["unattributed_ns"] == 0
+    assert got["longest"][0]["by_span"] == got["by_span"]
+
+
+def test_a_collection_past_the_gaps_edges_and_under_no_other_span():
+    ops = [(0, 2 * MS, "a"), (5 * MS, 1 * MS, "b"), (9 * MS, 1 * MS, "c")]
+    host = {"loadgen": [(1 * MS, 2 * MS, xplane.GC_SPAN),   # [1, 3]
+                        (8 * MS, 4 * MS, xplane.GC_SPAN)]}  # [8, 12]
+    got = xplane.attribute_gaps(ops, host)
+    assert got["by_span"] == {xplane.GC_SPAN: 2 * MS}       # [2,3] [8,9]
+    assert got["unattributed_ns"] == 4 * MS                 # [3,5] [6,8]
+
+
 def test_two_devices_each_against_the_same_host_spans():
     host = {"loop": [(0, 10 * MS, "train_step")]}
     dev0 = [(0, 4 * MS, "fusion"), (6 * MS, 4 * MS, "fusion")]
